@@ -397,22 +397,22 @@ def test_pallas_supports_contract():
     assert not supports(good, good, kv_len=object())
 
 
-def test_pallas_unavailable_on_cpu_probe():
-    """The availability probe reads False off-TPU (dispatch then
-    falls through to the XLA formulation — never crashes)."""
-    from veles_tpu.ops import pallas_attention as PA
-    PA.reset_probe()
-    try:
-        assert PA.pallas_attention_available() is False
-    finally:
-        PA.reset_probe()
+def test_kernel_not_selected_off_tpu():
+    """Dispatch selects by PLATFORM: on this CPU backend the flash
+    kernel is never selected, whatever the knob and however well the
+    geometry fits — the XLA formulation is what runs."""
+    from veles_tpu.ops import attention as A
+    from veles_tpu.ops.pallas_lrn import tpu_available
+    assert tpu_available() is False
+    assert A._selects_pallas(PALLAS_GEOM, PALLAS_GEOM,
+                             mode="pallas") is False
 
 
 def test_kernel_knob_dispatch(engine_knobs, monkeypatch):
     """attention_kernel="pallas" routes blockwise_attention through
-    the kernel when the probe says yes (stubbed to the interpret
-    kernel here), silently falls back when the geometry is out of
-    contract, and never engages under the default "xla"."""
+    the kernel on a TPU (the platform is stubbed and the kernel runs
+    in interpret mode here), selects the XLA formulation when the
+    geometry is out of contract, and never engages under "xla"."""
     import jax.numpy as jnp
     from veles_tpu.ops import attention as A
     from veles_tpu.ops import pallas_attention as PA
@@ -429,23 +429,34 @@ def test_kernel_knob_dispatch(engine_knobs, monkeypatch):
                     operand_dtype=jnp.float32, interpret=True)
 
     monkeypatch.setattr(PA, "pallas_attention", fake_kernel)
-    monkeypatch.setattr(PA, "pallas_attention_available",
-                        lambda: True)
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
     engine_knobs.attention_kernel = "pallas"
     out = A.blockwise_attention(q, k, v, block_size=128, causal=True)
     assert len(calls) == 1
     numpy.testing.assert_allclose(
         numpy.asarray(out), numpy.asarray(ref), rtol=2e-5,
         atol=2e-5)
-    # Geometry outside the contract: silent fallback, no kernel call.
+    # Geometry outside the contract: the XLA path, no kernel call.
     q2, k2, v2 = (_rand((2, 32, 2, 16), seed=30 + i)
                   for i in range(3))
     A.blockwise_attention(q2, k2, v2, block_size=16, causal=True)
     assert len(calls) == 1
-    # Default mode never touches the kernel even when "available".
+    # Under a mesh the selected kernel runs inside shard_map (GSPMD
+    # cannot partition a Mosaic call): each of the two data shards
+    # sees ITS batch row, and the result is the reference's.
+    from veles_tpu.parallel import make_mesh
+    mesh = make_mesh(axes={"data": 2})
+    out = A.mesh_attention(q, k, v, mesh, causal=True,
+                           batch_axis="data", head_axis="model")
+    assert calls[1:] == [(1,) + PALLAS_GEOM[1:]]
+    numpy.testing.assert_allclose(
+        numpy.asarray(out), numpy.asarray(ref), rtol=2e-5,
+        atol=2e-5)
+    # "xla" never touches the kernel even on a TPU.
     engine_knobs.attention_kernel = "xla"
     A.blockwise_attention(q, k, v, block_size=128, causal=True)
-    assert len(calls) == 1
+    A.mesh_attention(q, k, v, mesh, causal=True, batch_axis="data")
+    assert len(calls) == 2
 
 
 def test_kernel_knob_rejects_unknown_mode(engine_knobs):

@@ -464,28 +464,22 @@ def test_kernel_mode_defaults_flipped():
 
 def test_default_dispatch_is_noop_off_platform():
     """Flip-safety on this CPU box: the "auto" defaults must produce
-    BIT-IDENTICAL results to forced-"xla" — the probes say no, so
-    the fallbacks run (parity is exact equality here, not a
-    tolerance)."""
+    BIT-IDENTICAL results to forced-"xla" — the platform is not a
+    TPU, so the XLA formulations are selected (parity is exact
+    equality here, not a tolerance)."""
     from veles_tpu.ops import attention as A
-    from veles_tpu.ops import pallas_attention as PA
-    PA.reset_probe()
     q, k, v = _qkv(S=16, seed=41)
     mesh = make_mesh(axes={"seq": 4})
-    try:
-        default = A.attention(q, k, v, causal=True)
-        pinned = A.attention(q, k, v, causal=True, kernel="xla")
-        numpy.testing.assert_array_equal(numpy.asarray(default),
-                                         numpy.asarray(pinned))
-        dring = A.sequence_parallel_attention(q, k, v, mesh, "seq",
-                                              causal=True)
-        pring = A.sequence_parallel_attention(q, k, v, mesh, "seq",
-                                              causal=True,
-                                              kernel="xla")
-        numpy.testing.assert_array_equal(numpy.asarray(dring),
-                                         numpy.asarray(pring))
-    finally:
-        PA.reset_probe()
+    default = A.attention(q, k, v, causal=True)
+    pinned = A.attention(q, k, v, causal=True, kernel="xla")
+    numpy.testing.assert_array_equal(numpy.asarray(default),
+                                     numpy.asarray(pinned))
+    dring = A.sequence_parallel_attention(q, k, v, mesh, "seq",
+                                          causal=True)
+    pring = A.sequence_parallel_attention(q, k, v, mesh, "seq",
+                                          causal=True, kernel="xla")
+    numpy.testing.assert_array_equal(numpy.asarray(dring),
+                                     numpy.asarray(pring))
 
 
 def test_ring_kernel_knob_rejects_unknown_mode():

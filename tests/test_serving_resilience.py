@@ -185,6 +185,38 @@ def test_breaker_trips_after_rebuild_budget():
     assert ei.value.status == 503
 
 
+@pytest.mark.parametrize("where, call", [
+    ("paged_step", 2), ("paged_extend", 1)],
+    ids=["decode step", "prefill"])
+def test_program_error_fails_loudly_without_recovery(where, call):
+    """A lowering/shape/type error out of a paged device call is the
+    PROGRAM being wrong, not the device: the requests of that call
+    fail with the error itself, ``errors.program`` counts it, and
+    nothing is rebuilt, replayed or tripped — a replay could only
+    raise the same error again."""
+    model = PagedFakeModel()
+    real = getattr(model, where)
+    calls = []
+
+    def broken(*args, **kwargs):
+        calls.append(1)
+        if len(calls) == call:
+            raise ValueError("block shape refused by the lowering")
+        return real(*args, **kwargs)
+
+    setattr(model, where, broken)
+    engine, results, errors = _gated_run(
+        model, requests=(([3, 1], 4, 0.0, 0), ([2], 3, 0.0, 0)))
+    assert results == [None, None]
+    assert all(isinstance(e, ValueError) for e in errors), errors
+    assert engine.stats.get("errors.program") == 1
+    for name in ("kv.pool.resets", "breaker.rebuilds",
+                 "breaker.trips", "readopt.rows"):
+        assert not engine.stats.get(name), name
+    assert engine._breaker == "closed"
+    assert engine.kv_pool.occupancy()["blocks_used"] == 0
+
+
 def test_breaker_rebuilding_answers_503_with_retry_after():
     engine = ServingEngine(PagedFakeModel(), kv_blocks=32)
     engine._breaker = "rebuilding"

@@ -81,17 +81,19 @@ def test_vl101_traced_method_convention(tmp_path):
 def test_vl101_shard_map_closures_are_entries(tmp_path):
     """shard_map-wrapped functions (the pipeline schedule closures,
     ISSUE 12) are traced entry points — hazards inside them and in
-    their nested scan bodies are caught, from BOTH import forms."""
+    their nested scan bodies are caught, from BOTH forms the
+    installed JAX offers (``jax.shard_map`` and ``from jax import
+    shard_map``)."""
     findings = _lint(tmp_path, """
         import numpy
-        from jax.experimental.shard_map import shard_map
+        import jax
 
         def pipelined(params, x, mesh):
             def stage_fn(p, h):
                 def body(carry, t):
                     return carry + numpy.asarray(t), None
                 return body(p, h)[0].item()
-            return shard_map(stage_fn, mesh=mesh)(params, x)
+            return jax.shard_map(stage_fn, mesh=mesh)(params, x)
         """)
     hits = [f for f in findings if f.rule == "VL101"]
     assert hits and _rules(findings) == {"VL101"}, findings
@@ -120,7 +122,7 @@ def test_vl102_partial_and_dict_dispatch_entries(tmp_path):
     findings = _lint(tmp_path, """
         import functools
         import time
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def _step_helper(x):
             return x * time.time()
@@ -308,8 +310,9 @@ def test_rule_catalog_and_cli(tmp_path, capsys):
 # -- the tier-1 gate -------------------------------------------------------
 
 def test_repo_wide_zero_findings():
-    """`python -m veles_tpu.analysis` over veles_tpu/, bench.py and
-    __graft_entry__.py reports ZERO unsuppressed findings — every
+    """`python -m veles_tpu.analysis` over veles_tpu/, bench.py,
+    __graft_entry__.py and chip_smoke.py reports ZERO unsuppressed
+    findings — every
     future hazard, unguarded write, silent except, or unregistered
     name fails tier-1 by construction."""
     findings = analysis.run(root=REPO)

@@ -1,0 +1,173 @@
+"""The kernels of the main path, compiled for a DESCRIBED TPU v5e at
+the geometries the LM trains and serves at — no chip needed: the TPU
+compiler installed with JAX compiles for a topology that is described
+and not attached (the ``on-chip-measurement`` guide, section 2).
+
+Interpret-mode parity tests cannot see what these see: a block shape
+the TPU lowering refuses, or a kernel that asks for more VMEM than a
+core has.  A compile that passes is not a chip run — it says nothing
+about results or speed; ``chip_smoke.py`` is the chip run.
+
+Everything that touches the topology lives in the module-scoped
+fixture below: only one process at a time may load the TPU library,
+so the call must not happen while any module is imported (every xdist
+worker imports every test file), and all of these tests stay in THIS
+file so one worker gets them all.  The persistent compile cache is
+turned off around them — an entry written for a described chip cannot
+be read back without one.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+#: The LM bench geometry (bench.py LM_*, chip_smoke.LM_GEOMETRY).
+LM = (8, 1024, 16, 128)  # B, S, H, D
+#: Decode: one new token per row over a 2048-slot gathered table.
+DECODE_L = 2048
+#: Ring shard: S=1024 over a 2-way seq axis.
+RING_SHARD = 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s"
+                    % e)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *structs):
+    return jax.jit(fn).lower(*structs).compile().as_text()
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("seq", [LM[1], 2048])
+@pytest.mark.parametrize("operands", ["float32", "bfloat16"])
+@pytest.mark.parametrize("grad", [False, True],
+                         ids=["fwd", "fwd+bwd"])
+def test_flash_attention_compiles(one_chip, grad, operands, seq):
+    """The training kernel at the LM geometry, and at ``MAX_SEQ`` —
+    the longest sequence ``supports()`` admits, where the backward's
+    dk/dv kernel (q, dO, lse, delta resident) must still fit VMEM."""
+    from veles_tpu.ops import pallas_attention as PA
+    shape = (LM[0], seq) + LM[2:]
+    assert seq <= PA.MAX_SEQ and PA.supports(shape, shape)
+    od = jnp.dtype(operands).type
+
+    def fwd(q, k, v):
+        return PA.pallas_attention(q, k, v, causal=True,
+                                   operand_dtype=od)
+
+    fn = fwd
+    if grad:
+        fn = jax.grad(lambda q, k, v: fwd(q, k, v).sum(),
+                      argnums=(0, 1, 2))
+    x = _struct(shape, jnp.float32, one_chip)
+    text = _compiled_text(fn, x, x, x)
+    assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("grad", [False, True],
+                         ids=["fwd", "fwd+bwd"])
+def test_flash_chunk_compiles_at_ring_shard(one_chip, grad):
+    """One ring step: local queries against one streamed k/v shard,
+    global causal offsets arriving as TRACED scalars."""
+    from veles_tpu.ops import pallas_attention as PA
+    shape = (LM[0], RING_SHARD) + LM[2:]
+
+    def chunk(q, k, v, q_off, k_off):
+        out, lse = PA.flash_chunk(q, k, v, causal=True,
+                                  q_offset=q_off, k_offset=k_off,
+                                  operand_dtype=jnp.float32)
+        return out.sum() + lse.sum()
+
+    fn = jax.grad(chunk, argnums=(0, 1, 2)) if grad else chunk
+    x = _struct(shape, jnp.float32, one_chip)
+    off = _struct((), jnp.float32, one_chip)
+    assert "tpu_custom_call" in _compiled_text(fn, x, x, x, off, off)
+
+
+@pytest.mark.parametrize("pool", ["float32", "int8+scales"])
+def test_decode_attention_compiles(one_chip, pool):
+    """The serving kernel at decode shape: f32 pool, and the int8
+    pool whose per-position scales are dequantized in the kernel."""
+    from veles_tpu.ops import pallas_attention as PA
+    B, _, H, D = LM
+    q = _struct((B, 1, H, D), jnp.float32, one_chip)
+    mask = _struct((B, 1, DECODE_L), jnp.bool_, one_chip)
+    quant = pool != "float32"
+    kv = _struct((B, DECODE_L, H, D),
+                 jnp.int8 if quant else jnp.float32, one_chip)
+    args = [q, kv, kv, mask]
+    if quant:
+        scale = _struct((B, DECODE_L, H), jnp.float32, one_chip)
+        args += [scale, scale]
+
+    def decode(q, k, v, mask, k_scale=None, v_scale=None):
+        return PA.pallas_decode_attention(
+            q, k, v, mask, operand_dtype=jnp.float32,
+            k_scale=k_scale, v_scale=v_scale)
+
+    assert "tpu_custom_call" in _compiled_text(decode, *args)
+
+
+def test_lrn_compiles(one_chip):
+    """AlexNet's first LRN at the bench batch, forward + backward."""
+    from veles_tpu.ops.pallas_lrn import lrn_pallas
+    x = _struct((512, 27, 27, 96), jnp.bfloat16, one_chip)
+
+    def loss(x):
+        # Squared, so the cotangent needs the forward's output and
+        # both kernels stay in the program.
+        y = lrn_pallas(x, 5, 1e-4, 0.75, 2.0).astype(jnp.float32)
+        return (y * y).sum()
+
+    assert _compiled_text(jax.grad(loss), x).count(
+        "tpu_custom_call") >= 2
+
+
+def test_flash_attention_partitions_over_a_dp_mesh(topo, monkeypatch):
+    """GSPMD cannot partition a Mosaic call, so under a mesh the
+    kernel must arrive wrapped in shard_map
+    (``ops.attention.mesh_attention``): forward + backward for FOUR
+    described chips, batch split over ``data``, kernel in the text.
+    The platform check is steered here, in the test — the process
+    runs on the CPU and would select the XLA path."""
+    import numpy
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from veles_tpu.ops import attention as A
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    mesh = Mesh(numpy.array(topo.devices[:4]), ("data",))
+    x = _struct(LM, jnp.float32,
+                NamedSharding(mesh, P("data", None, None, None)))
+
+    def loss(q, k, v):
+        return A.mesh_attention(q, k, v, mesh, causal=True,
+                                batch_axis="data").sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert text.count("tpu_custom_call") >= 3

@@ -27,6 +27,7 @@ import os
 import sys
 import time
 
+from .backends import device_entry, enable_compilation_cache
 from .cmdline import CommandLineBase, init_argparser
 from .config import root
 from .error import Bug
@@ -514,6 +515,7 @@ class Main(Logger, CommandLineBase):
             "seed": repr(prng.get(0).seed_value),
             "runtime": self.launcher.runtime,
             "units": len(self.workflow.units),
+            "device": device_entry(),
             "results": self.workflow.gather_results(),
         }
         dump_json(results, path)
@@ -591,6 +593,7 @@ class Main(Logger, CommandLineBase):
             init_argparser(prog="veles_tpu").print_help()
             return self.EXIT_FAILURE
         try:
+            enable_compilation_cache()
             self.seed_random()
             apply_config_sources(
                 list(self.args.config) + list(self.args.config_list),

@@ -25,39 +25,15 @@ state, update rule) and are applied inside the same jit.
 
 The reference's per-device compiled-program tar cache
 (accelerated_units.py:599-666) maps to XLA's persistent compilation
-cache, enabled in :func:`enable_compilation_cache`.
+cache, enabled in :func:`backends.enable_compilation_cache`.
 """
 
-import os
-
 from . import resilience
+from .backends import enable_compilation_cache
 from .config import root, get as config_get
 from .memory import Vector
 from .units import Unit
 from .workflow import Workflow
-
-_cache_enabled = [False]
-
-
-def enable_compilation_cache():
-    """Persistent XLA compile cache (replaces the reference's tar.gz
-    program cache keyed by device, accelerated_units.py:599-666)."""
-    if _cache_enabled[0]:
-        return
-    cache_dir = config_get(root.common.dirs.cache)
-    if cache_dir:
-        import jax
-        os.makedirs(cache_dir, exist_ok=True)
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              1.0)
-        except Exception as e:  # older/newer jax without the knob
-            import logging
-            logging.getLogger("StepCompiler").debug(
-                "persistent compile cache unavailable: %s", e)
-    _cache_enabled[0] = True
-
 
 class StepContext(object):
     """Per-tick traced context handed to every TracedUnit: the RNG key,
@@ -234,6 +210,9 @@ class StepCompiler(object):
         self.persist_vectors = []  # evaluator outputs etc.
         self._compiled = None
         self._fingerprint = None
+        # (blocks, key, flag) of the newest block dispatch —
+        # lower_last_block() lowers that program again.
+        self._last_block_ = None
         # Per-mode FLOP estimate for the live MFU gauge
         # (observability.attribution); 0.0 = tried, unavailable.
         self._step_flops_ = {}
@@ -613,6 +592,39 @@ class StepCompiler(object):
         self._hyper_vals_ = {}
         self._compiled = True
 
+    @staticmethod
+    def _block_sharding(vec, rank):
+        """Where a batch vector's stacked block of ``rank`` dimensions
+        lives: the vector's own sharding with the leading tick
+        dimension left whole — the dispatch splits each tick's batch
+        exactly as a single-tick step would.  A vector whose ticks
+        stack as scalars (the sample class) is replicated.  None
+        (default placement) without a mesh."""
+        sharding = vec.sharding
+        if sharding is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+        spec = (None,) + tuple(sharding.spec)
+        if len(spec) > rank:
+            spec = ()
+        return NamedSharding(sharding.mesh, PartitionSpec(*spec))
+
+    def lower_last_block(self):
+        """The block program of the newest dispatch, lowered again
+        from the very arguments it ran on (params and state are read
+        where they live now — same shapes, same layouts).
+        ``.compile().as_text()`` is then the program that dispatch
+        ran, so a caller can show what is IN it — ``chip_smoke.py``
+        looks for the flash kernel's ``tpu_custom_call`` and, on a
+        mesh, for the all-reduce and the batch split — instead of
+        inferring it from a flag.  Nothing is donated or executed."""
+        blocks, key, flag = self._last_block_
+        params = {n: v.devmem for n, v in self._param_vecs.items()}
+        states = {n: v.devmem for n, v in self._state_vecs.items()}
+        consts = {str(id(v)): v.devmem for v in self.const_vectors}
+        return self._block.lower(params, states, blocks, consts, key,
+                                 flag)
+
     def invalidate(self):
         """Drops the compiled step so the next execute re-traces.
         Needed when a Python-constant hyperparameter baked into the
@@ -795,8 +807,13 @@ class StepCompiler(object):
         ticks = next(iter(blocks.values())).shape[0] if blocks else 1
         # The stacked tick upload is EXPLICIT (device_put) so the
         # strict-step transfer guard distinguishes it from a stray
-        # host-sync inside the hot loop.
-        blocks = {k: jax.device_put(v) for k, v in blocks.items()}
+        # host-sync inside the hot loop — and it lands in the batch
+        # vector's own layout, so a data-parallel mesh splits every
+        # tick of the block instead of replicating it.
+        vecs = {str(id(v)): v for v in self.batch_vectors}
+        blocks = {k: jax.device_put(
+            v, self._block_sharding(vecs[k], v.ndim))
+            for k, v in blocks.items()}
         flag = self._training_flag(training)
         # Hyper-traced block variant (population member genes): the
         # traced training flag already gates updates, so one program
@@ -819,6 +836,9 @@ class StepCompiler(object):
             v.devmem = new_params[n]
         for n, v in self._state_vecs.items():
             v.devmem = new_states[n]
+        # What lower_last_block() re-lowers: a few small index and
+        # mask arrays, none of them donated.
+        self._last_block_ = (blocks, key, flag)
         attribution.end_step(timer,
                              leaf=self._sync_leaf(new_states))
         return {}

@@ -34,9 +34,6 @@ TRACERS = frozenset((
     "jit", "pmap", "vmap", "grad", "value_and_grad", "checkpoint",
     "remat", "custom_jvp", "custom_vjp", "shard_map",
 ))
-#: Modules that export ``shard_map`` (the pipeline schedule closures
-#: register through it — ISSUE 12).
-_SHARD_MAP_MODULES = frozenset(("jax", "jax.experimental.shard_map"))
 #: lax control-flow: every callable argument is traced.
 LAX_TRACERS = frozenset((
     "scan", "cond", "while_loop", "fori_loop", "switch", "map",
@@ -341,11 +338,6 @@ class TraceWalker(object):
                 if mod == "jax" and attr in TRACERS:
                     return True
                 if mod in ("jax.lax", "jax") and attr in LAX_TRACERS:
-                    return True
-                # ``from jax.experimental.shard_map import shard_map``
-                # (or ``from jax import shard_map``): the wrapped
-                # stage/schedule closures are traced entry points.
-                if mod in _SHARD_MAP_MODULES and attr == "shard_map":
                     return True
         return False
 

@@ -158,7 +158,7 @@ def default_targets(root):
     """The tier-1 gate's file set: the package plus the top-level
     entry scripts."""
     out = [os.path.join(root, "veles_tpu")]
-    for extra in ("bench.py", "__graft_entry__.py"):
+    for extra in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
         path = os.path.join(root, extra)
         if os.path.isfile(path):
             out.append(path)

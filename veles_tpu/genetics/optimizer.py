@@ -60,7 +60,11 @@ def evaluate_chromosome_subprocess(module_path, tunes, genes, seed,
                                    extra_argv=()):
     """Same contract via a ``python -m veles_tpu`` child process
     (reference: optimization_workflow.py:260 ``_exec`` — full issue
-    isolation at the cost of per-run startup)."""
+    isolation at the cost of per-run startup).  The child is a JAX
+    process of its own: a parent that has touched JAX holds the chip
+    and the child then fails or hangs (one process per chip), so on
+    a TPU the parent must stay off JAX, or the in-process and vmapped
+    evaluators are the ones to use."""
     overrides = ["root.%s=%r" % (path, _concrete(tune, gene))
                  for (path, tune), gene in zip(tunes, genes)]
     with tempfile.NamedTemporaryFile(

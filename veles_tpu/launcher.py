@@ -192,23 +192,11 @@ class Launcher(Logger):
             import jax
             # Idempotent across launchers in one process (genetics/
             # ensembles build a Launcher per candidate run).
-            # jax < 0.5 has no jax.distributed.is_initialized —
-            # probe when available, otherwise let the double-init
-            # RuntimeError mean "already up".
-            probe = getattr(jax.distributed, "is_initialized", None)
-            if probe is None or not probe():
-                try:
-                    jax.distributed.initialize(
-                        coordinator_address=self.coordinator_address,
-                        num_processes=self.num_processes,
-                        process_id=self.process_id)
-                except RuntimeError as e:
-                    if probe is not None or (
-                            "once" not in str(e) and
-                            "already" not in str(e).lower()):
-                        raise
-                    self.debug("jax.distributed already "
-                               "initialized: %s", e)
+            if not jax.distributed.is_initialized():
+                jax.distributed.initialize(
+                    coordinator_address=self.coordinator_address,
+                    num_processes=self.num_processes,
+                    process_id=self.process_id)
         self.device = kwargs.pop("device", None) or \
             backends.Device.create(
                 config_get(root.common.engine.backend, "auto"))
@@ -288,6 +276,13 @@ class Launcher(Logger):
         return [sys.executable, "-m", "veles_tpu"] + argv
 
     def _spawn_worker(self, node):
+        """Starts one worker process on ``node``.  A ``local`` worker
+        is a second JAX process on THIS machine, started after the
+        coordinator initialized its workflow on the device: on a
+        machine whose chip this process holds, that child cannot
+        have it (one process per chip) — ``--nodes local`` is for
+        CPU fleets and for hosts where each process is given its own
+        devices."""
         import os as os_mod
         import subprocess
         master = self._master_endpoint()

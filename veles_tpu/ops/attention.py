@@ -57,7 +57,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import resilience
 from ..config import root, get as config_get
+from .pallas_lrn import tpu_available
 
 NEG_INF = -1e30
 
@@ -70,15 +72,16 @@ KERNEL_MODES = ("xla", "pallas", "auto")
 
 #: Default attention-kernel mode — "auto" since ISSUE 13 (the r6
 #: roofline puts the flash kernel AT the bandwidth corner vs the XLA
-#: formulation's ~7.4× traffic, and dispatch degrades silently
-#: off-TPU/off-geometry, so auto is free where it cannot win).
+#: formulation's ~7.4× traffic; off a TPU or outside the kernel's
+#: geometry the XLA formulation is selected, so auto costs nothing
+#: where it cannot win).
 #: Serving surfaces pin kernel="xla" explicitly and never read this.
 DEFAULT_KERNEL_MODE = "auto"
 
 #: Default ring-kernel mode for sequence-parallel attention — the
 #: ring-flash body (per-shard Pallas flash + lse merge) engages
-#: wherever the platform/geometry supports it, with the lax scan as
-#: the silent fallback.
+#: wherever the platform/geometry supports it; elsewhere the lax
+#: scan is selected.
 DEFAULT_RING_KERNEL_MODE = "auto"
 
 
@@ -102,8 +105,8 @@ def init_parser(parser):
         help="attention fast path: 'pallas' routes attention through "
              "the geometry-tuned flash kernel "
              "(ops/pallas_attention.py) where the platform supports "
-             "it, 'auto' (default since the r9 flip) probes and "
-             "degrades silently, 'xla' keeps the fused XLA "
+             "it, 'auto' (default since the r9 flip) selects it by "
+             "platform and geometry, 'xla' keeps the fused XLA "
              "formulation")
     parser.add_argument(
         "--sp-ring-kernel", default=None, choices=KERNEL_MODES,
@@ -160,30 +163,36 @@ def _ring_kernel_mode():
     return mode
 
 
+def _selects_pallas(q_shape, k_shape, kv_len=None, mode=None):
+    """Whether attention at this geometry runs the Pallas flash
+    kernel: the knob (or the explicit ``mode`` override) asks for
+    it, the backend is a TPU and the geometry is inside the kernel's
+    contract.  A SELECTION by what the process can observe, made
+    before the kernel is touched — "pallas" and "auto" select
+    identically, so a CPU test run with the flag on still exercises
+    the reference path."""
+    from . import pallas_attention as PA
+    return (mode or _kernel_mode()) != "xla" and \
+        PA.supports(q_shape, k_shape, kv_len) and tpu_available()
+
+
 def _try_pallas(q, k, v, causal, kv_len=None, mode=None,
                 precision=None):
-    """Routes through the Pallas flash kernel when the knob (or the
-    explicit ``mode`` override) asks for it AND the platform/geometry
-    supports it; returns None (→ caller falls through to the jnp
-    formulation) otherwise.  "pallas" and "auto" behave identically —
-    both degrade silently, so a CPU test run with the flag on still
-    exercises the reference path.  The matmul operand dtype follows
-    the ``attention_dtype`` knob (or the explicit ``precision``)
-    exactly like every other formulation — f32 by default, bf16
-    under the bf16 stage.  With the kernel now engaging by DEFAULT
-    ("auto" since the r9 flip) this matters: the pre-flip behavior
-    of defaulting the operands to the kernel's bf16 MXU contract
-    would silently downgrade a default-config (or explicit
-    --attn-dtype f32) run the moment the platform supports the
-    kernel — the dtype stage must stay an explicit opt-in, as the
-    flip table documents."""
-    if (mode or _kernel_mode()) == "xla":
+    """Runs the Pallas flash kernel where :func:`_selects_pallas`
+    says so; returns None (→ caller runs the jnp formulation)
+    otherwise.  Once selected, a kernel that fails to lower raises —
+    nothing here catches it.  The matmul operand dtype follows the
+    ``attention_dtype`` knob (or the explicit ``precision``) exactly
+    like every other formulation — f32 by default, bf16 under the
+    bf16 stage: the dtype stage must stay an explicit opt-in, as the
+    flip table documents.  Each TRACE of either choice counts into
+    ``attention.kernel.pallas`` / ``attention.kernel.xla``, so a run
+    can say which formulation its programs were built from."""
+    if not _selects_pallas(q.shape, k.shape, kv_len, mode):
+        resilience.stats.incr("attention.kernel.xla")
         return None
     from . import pallas_attention as PA
-    if not PA.supports(q.shape, k.shape, kv_len):
-        return None
-    if not PA.pallas_attention_available():
-        return None
+    resilience.stats.incr("attention.kernel.pallas")
     return PA.pallas_attention(
         q, k, v, causal=causal, kv_len=kv_len,
         operand_dtype=attention_compute_dtype(precision))
@@ -270,6 +279,33 @@ def attention(q, k, v, causal=False, precision=None, kernel=None):
     return _finish(acc, l, q.dtype)
 
 
+def mesh_attention(q, k, v, mesh, causal=False, batch_axis=None,
+                   head_axis=None):
+    """:func:`attention` inside a step that GSPMD partitions over
+    ``mesh``.  The XLA formulation partitions by itself.  A Mosaic
+    kernel does not ("Mosaic kernels cannot be automatically
+    partitioned"): where the flash kernel is selected the call is
+    wrapped in ``shard_map`` over the two dimensions attention is
+    independent along — batch on ``batch_axis``, heads on
+    ``head_axis`` — so every chip runs the kernel on its own
+    (B/n, S, H/m, D) shard and no collective is needed inside.  An
+    axis the mesh lacks, or that does not divide its dimension,
+    leaves that dimension whole."""
+    if not _selects_pallas(q.shape, k.shape):
+        return attention(q, k, v, causal=causal)
+    from jax.sharding import PartitionSpec as P
+
+    def fits(axis, dim):
+        return axis if axis in mesh.axis_names and \
+            q.shape[dim] % mesh.shape[axis] == 0 else None
+
+    spec = P(fits(batch_axis, 0), None, fits(head_axis, 2), None)
+    fn = jax.shard_map(
+        functools.partial(attention, causal=causal), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
+    return fn(q, k, v)
+
+
 def blockwise_attention(q, k, v, block_size=128, causal=False,
                         kv_len=None, precision=None, kernel=None):
     """Flash-style attention: scan over key/value blocks with the
@@ -325,19 +361,19 @@ def blockwise_attention(q, k, v, block_size=128, causal=False,
 
 
 def _try_ring_flash(q, k, mode, interpret):
-    """Whether this ring call should run the Pallas flash body:
-    the knob (or explicit ``kernel`` override) asks for it AND the
-    per-shard geometry fits AND the kernel actually runs here
-    (compiled probe on TPU; ``interpret=True`` — the test/dryrun
-    path — runs the interpret kernel anywhere).  False falls through
-    to the lax streaming scan, the same silent-degrade contract as
-    ``_try_pallas``."""
+    """Whether this ring call runs the Pallas flash body: the knob
+    (or explicit ``kernel`` override) asks for it, the per-shard
+    geometry fits, and the backend is a TPU (``interpret=True`` —
+    the test/dryrun path — runs the interpret kernel anywhere).
+    False selects the lax streaming scan; like ``_try_pallas`` the
+    choice is made up front and a selected kernel's failure
+    propagates."""
     if mode == "xla":
         return False
     from . import pallas_attention as PA
     if not PA.supports_ring(q.shape, k.shape, interpret=interpret):
         return False
-    return interpret or PA.pallas_attention_available()
+    return interpret or tpu_available()
 
 
 def _ring_flash(q, k, v, axis_name, causal, od, interpret):
@@ -518,16 +554,6 @@ def sequence_parallel_attention(q, k, v, mesh, seq_axis,
     shard).  ``kernel``/``interpret`` reach the ring body only
     (:func:`ring_attention`'s ring-flash dispatch); Ulysses keeps
     its knob-driven local attention."""
-    import inspect
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    sig = inspect.signature(shard_map).parameters
-    # Disable replication/varying-axis checking: the ring's carried
-    # k/v blocks change their varying-axis type across ppermute steps.
-    _kw = {"check_vma": False} if "check_vma" in sig \
-        else {"check_rep": False}
     from jax.sharding import PartitionSpec as P
     if batch_axis is not None and batch_axis not in mesh.axis_names:
         batch_axis = None
@@ -544,8 +570,10 @@ def sequence_parallel_attention(q, k, v, mesh, seq_axis,
     if mode == "ring":
         inner_kw["kernel"] = kernel
         inner_kw["interpret"] = interpret
-    fn = shard_map(
+    # check_vma off: the ring's carried k/v blocks change their
+    # varying-axis type across ppermute steps.
+    fn = jax.shard_map(
         functools.partial(inner, **inner_kw),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        **_kw)
+        check_vma=False)
     return fn(q, k, v)

@@ -47,11 +47,15 @@ Since ISSUE 13 the kernel is RESUMABLE and MULTI-CHIP-composable:
     serving's one-token steps ride the kernel without a VMEM-whole
     sequence bound (forward-only; decode has no backward).
 
-Like pallas_lrn.py, the module ships three layers: the kernel, a
-reference-parity fallback (ops/attention.blockwise_attention — the
-parity oracle the tests pin), and availability probes so dispatch
+Like pallas_lrn.py, the module ships the kernel and its parity oracle
+(ops/attention.blockwise_attention, which the tests pin).  Dispatch
 (ops/attention._try_pallas, the ring body, export's decode gate)
-degrades silently off-TPU.
+selects the kernel by PLATFORM (``pallas_lrn.tpu_available``) and by
+the ``supports*`` geometry contracts below — off a TPU the XLA
+formulation is what runs; on a TPU a kernel that does not lower is
+an error that propagates, never a quiet switch to another path.
+tests/test_tpu_compile.py compiles every kernel here for a described
+v5e at the geometries the LM trains and serves at.
 
 HBM-traffic budget at the bench geometry (B=8, S=1024, H=16, D=128):
 q/k/v/o are 64 MB each in f32; the fwd reads q/k/v once and writes
@@ -81,10 +85,11 @@ DEFAULT_BLOCK_K = 1024
 LANE = 128
 
 #: Upper sequence bound: the kernel keeps a (batch·head) slice's
-#: whole k/v in VMEM (S × D × 4 B each) plus a (block_q, S) f32
-#: score tile — at S=2048/D=128 that is 2 × 1 MB + 4 MB, comfortable
-#: in 16 MB; past it the tiles stop fitting and dispatch must fall
-#: back to the streaming scan instead of dying in the compiler.
+#: whole k/v in VMEM (S × D × 4 B each, double-buffered) next to its
+#: f32 score tiles; the backward's dk/dv kernel also keeps q and dO
+#: whole.  S=2048/D=128 forward and backward compile inside the
+#: v5e's 16 MB scoped VMEM (tests/test_tpu_compile.py); past it the
+#: tiles stop fitting, so dispatch selects the streaming scan.
 MAX_SEQ = 2048
 
 #: Decode-kernel query bound: past this many query rows the chunk is
@@ -181,20 +186,25 @@ def supports_decode(q_shape, k_shape, interpret=False):
 # -- kernels -------------------------------------------------------------
 
 
-def _mask_tile(grows0, gcols0, lcols0, bq, bk, causal, kv_len):
-    """(bq, bk) boolean attend-mask for one score tile, or None when
-    nothing masks.  Causality is judged on GLOBAL positions (row/col
-    origins ``grows0``/``gcols0`` — possibly traced scalars: the ring
-    offsets are data-dependent), while the ``kv_len`` padding bound
-    applies to the chunk's LOCAL columns (origin ``lcols0``) — it is
-    the caller's own padding, wherever the chunk sits globally."""
+def _mask_tile(grows0, gcols0, lcols0, bq, bk, causal, kv_len,
+               transposed=False):
+    """(bq, bk) boolean attend-mask for one score tile — (bk, bq)
+    when ``transposed`` (the dk/dv kernel's key-major tile) — or
+    None when nothing masks.  Causality is judged on GLOBAL positions
+    (row/col origins ``grows0``/``gcols0`` — possibly traced scalars:
+    the ring offsets are data-dependent), while the ``kv_len``
+    padding bound applies to the chunk's LOCAL columns (origin
+    ``lcols0``) — it is the caller's own padding, wherever the chunk
+    sits globally."""
+    shape, qdim, kdim = ((bk, bq), 1, 0) if transposed else \
+        ((bq, bk), 0, 1)
     mask = None
     if causal:
-        rows = grows0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = gcols0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        rows = grows0 + jax.lax.broadcasted_iota(jnp.int32, shape, qdim)
+        cols = gcols0 + jax.lax.broadcasted_iota(jnp.int32, shape, kdim)
         mask = rows >= cols
     if kv_len is not None:
-        cols = lcols0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        cols = lcols0 + jax.lax.broadcasted_iota(jnp.int32, shape, kdim)
         kvm = cols < kv_len
         mask = kvm if mask is None else jnp.logical_and(mask, kvm)
     return mask
@@ -206,6 +216,24 @@ def _dot(a, b, od, trans_b=False):
     dims = (((1,), (1,) if trans_b else (0,)), ((), ()))
     return jax.lax.dot_general(a.astype(od), b.astype(od), dims,
                                preferred_element_type=jnp.float32)
+
+
+def _col_to_row(col):
+    """(n, 1) → (1, n).  The per-query statistics (lse, delta) are
+    kept as lane-dense ROWS — a column is padded to 128 lanes in
+    VMEM, and with q, dO, lse and delta resident as columns the
+    S=2048 backward asked for 17.7 MB of a 16 MB limit — while the
+    kernels that walk query blocks want them as COLUMNS next to the
+    (bq, bk) score tile.  The relayout is one aligned 2-d transpose
+    of the lane-broadcast column."""
+    n = col.shape[0]
+    return jnp.broadcast_to(col, (n, LANE)).T[0:1, :]
+
+
+def _row_to_col(row):
+    """(1, n) → (n, 1): the inverse of :func:`_col_to_row`."""
+    n = row.shape[1]
+    return jnp.broadcast_to(row, (LANE, n)).T[:, 0:1]
 
 
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
@@ -252,7 +280,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
     # Finite lse is also what makes the cross-chunk merge total: a
     # chunk a row attends nothing in contributes weight exp(-1e30 -
     # lse_total) = 0, never NaN.
-    lse_ref[0, :] = (m + jnp.log(l_safe))[:, 0]
+    lse_ref[0] = _col_to_row(m + jnp.log(l_safe))
 
 
 def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
@@ -264,8 +292,8 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     i = pl.program_id(1)
     q = q_ref[0]
     do = do_ref[0]
-    lse = lse_ref[0, :][:, None]
-    delta = delta_ref[0, :][:, None]
+    lse = _row_to_col(lse_ref[0])
+    delta = _row_to_col(delta_ref[0])
     grows0 = qoff_ref[0, 0] + i * bq
     koff = koff_ref[0, 0]
     nk = kv_seq_len // block_k
@@ -305,23 +333,27 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     nq = q_seq_len // block_q
 
     def body(i, carry):
+        # Key-major tiles (bk, bq): lse/delta arrive as (1, bq) rows
+        # and broadcast down the key axis as they are, and dk/dv
+        # accumulate without transposing a score-sized tile.
         dk, dv = carry
         qb = q_ref[0, pl.ds(i * block_q, block_q), :]
         dob = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(i * block_q, block_q)][:, None]
-        delta = delta_ref[0, pl.ds(i * block_q, block_q)][:, None]
-        s = _dot(qb, k, od, trans_b=True) * scale
+        lse = lse_ref[0, pl.ds(i, 1), :]
+        delta = delta_ref[0, pl.ds(i, 1), :]
+        st = _dot(k, qb, od, trans_b=True) * scale
         mask = _mask_tile(qoff + i * block_q, gcols0, lcols0,
-                          block_q, bk, causal, kv_len)
+                          block_q, bk, causal, kv_len,
+                          transposed=True)
         if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
+            st = jnp.where(mask, st, NEG_INF)
+        pt = jnp.exp(st - lse)
         if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        dv = dv + _dot(p.T, dob, od)
-        dp = _dot(dob, v, od, trans_b=True)
-        ds = p * (dp - delta) * scale
-        dk = dk + _dot(ds.T, qb, od)
+            pt = jnp.where(mask, pt, 0.0)
+        dv = dv + _dot(pt, dob, od)
+        dpt = _dot(v, dob, od, trans_b=True)
+        dst = pt * (dpt - delta) * scale
+        dk = dk + _dot(dst, qb, od)
         return dk, dv
 
     dk, dv = jax.lax.fori_loop(
@@ -345,12 +377,21 @@ def _row_spec(block, D, which):
     return pl.BlockSpec((1, block, D), lambda b, i: (b, 0, 0))
 
 
-def _vec_spec(block, which):
-    """BlockSpec over (BH, S) row vectors (lse/delta)."""
+def _stat_row_spec(block):
+    """BlockSpec over the per-query statistics (lse/delta) as a
+    lane-dense (BH, 1, S) row array, walked in ``block``-lane steps
+    along grid dim 1 — what the kernels gridded over query blocks
+    read and write."""
     from jax.experimental import pallas as pl
-    if which == "blocked":
-        return pl.BlockSpec((1, block), lambda b, i: (b, i))
-    return pl.BlockSpec((1, block), lambda b, i: (b, 0))
+    return pl.BlockSpec((1, 1, block), lambda b, i: (b, 0, i))
+
+
+def _stat_tile_spec(n_blocks, block):
+    """BlockSpec over the same bytes seen as (BH, S/block, block) —
+    one query block per sublane row, resident whole per (batch·head):
+    the dk/dv kernel picks query block ``i`` with a sublane index."""
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec((1, n_blocks, block), lambda b, i: (b, 0, 0))
 
 
 def _off_spec():
@@ -381,7 +422,7 @@ def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              kv_len=kv_len, block_k=bk,
                              kv_seq_len=Sk, od=od)
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         kern,
         grid=(BH, Sq // bq),
         in_specs=[_off_spec(), _off_spec(),
@@ -389,11 +430,12 @@ def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
                   _row_spec(Sk, D, "whole"),
                   _row_spec(Sk, D, "whole")],
         out_specs=(_row_spec(bq, D, "blocked"),
-                   _vec_spec(bq, "blocked")),
+                   _stat_row_spec(bq)),
         out_shape=(jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
-                   jax.ShapeDtypeStruct((BH, Sq), jnp.float32)),
+                   jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32)),
         interpret=interpret,
     )(_off_operand(qoff), _off_operand(koff), qf, kf, vf)
+    return out, lse.reshape(BH, Sq)
 
 
 def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
@@ -410,6 +452,9 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
              of.astype(jnp.float32)).sum(axis=-1) - \
         dlse.astype(jnp.float32)
     offs = (_off_operand(qoff), _off_operand(koff))
+    rows = (lse.reshape(BH, 1, Sq), delta.reshape(BH, 1, Sq))
+    tiles = (lse.reshape(BH, Sq // bq, bq),
+             delta.reshape(BH, Sq // bq, bq))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_k=bk, kv_seq_len=Sk,
@@ -420,12 +465,12 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
                   _row_spec(Sk, D, "whole"),
                   _row_spec(Sk, D, "whole"),
                   _row_spec(bq, D, "blocked"),
-                  _vec_spec(bq, "blocked"),
-                  _vec_spec(bq, "blocked")],
+                  _stat_row_spec(bq),
+                  _stat_row_spec(bq)],
         out_specs=_row_spec(bq, D, "blocked"),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
         interpret=interpret,
-    )(*offs, qf, kf, vf, dof, lse, delta)
+    )(*offs, qf, kf, vf, dof, *rows)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_q=bq, q_seq_len=Sq,
@@ -436,14 +481,14 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
                   _row_spec(bk, D, "blocked"),
                   _row_spec(bk, D, "blocked"),
                   _row_spec(Sq, D, "whole"),
-                  _vec_spec(Sq, "whole"),
-                  _vec_spec(Sq, "whole")],
+                  _stat_tile_spec(Sq // bq, bq),
+                  _stat_tile_spec(Sq // bq, bq)],
         out_specs=(_row_spec(bk, D, "blocked"),
                    _row_spec(bk, D, "blocked")),
         out_shape=(jax.ShapeDtypeStruct((BH, Sk, D), qf.dtype),
                    jax.ShapeDtypeStruct((BH, Sk, D), qf.dtype)),
         interpret=interpret,
-    )(*offs, qf, kf, vf, dof, lse, delta)
+    )(*offs, qf, kf, vf, dof, *tiles)
     return dq, dk, dv
 
 
@@ -633,7 +678,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
     l = p.sum(axis=1, keepdims=True)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0, 0, 0] = (_dot(p, vb, od) / l_safe).astype(o_ref.dtype)
-    lse_ref[0, 0, 0] = (m + jnp.log(l_safe))[:, 0]
+    lse_ref[0, 0, 0] = m + jnp.log(l_safe)
 
 
 def _decode_kernel_quant(q_ref, k_ref, v_ref, ks_ref, vs_ref,
@@ -643,11 +688,17 @@ def _decode_kernel_quant(q_ref, k_ref, v_ref, ks_ref, vs_ref,
     scale row, and the DEQUANT HAPPENS HERE in the gather — the
     memory traffic is the quantized bytes, never a materialized f32
     cache (the whole point of the quantized KV plane: decode is
-    bandwidth-bound, bytes are throughput)."""
+    bandwidth-bound, bytes are throughput).  A position's scale is
+    one number per key, so it factors out of both contractions:
+    ``q·(c_k·s_k) = (q·c_k)·s_k`` scales the score COLUMN and
+    ``p·(c_v·s_v) = (p·s_v)·c_v`` the probability column — the
+    (1, bk) scale rows broadcast over the few query rows as they
+    lie, and the codes reach the MXU exact (|int8| ≤ 127 is exact
+    in bf16 too)."""
     q = q_ref[0, 0]
-    kb = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-    vb = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
-    s = _dot(q, kb, od, trans_b=True) * scale
+    kb = k_ref[0, 0].astype(jnp.float32)
+    vb = v_ref[0, 0].astype(jnp.float32)
+    s = _dot(q, kb, od, trans_b=True) * ks_ref[0, 0] * scale
     mask = mask_ref[0] != 0
     s = jnp.where(mask, s, NEG_INF)
     m = s.max(axis=1, keepdims=True)
@@ -655,8 +706,9 @@ def _decode_kernel_quant(q_ref, k_ref, v_ref, ks_ref, vs_ref,
     p = jnp.where(mask, p, 0.0)
     l = p.sum(axis=1, keepdims=True)
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0, 0, 0] = (_dot(p, vb, od) / l_safe).astype(o_ref.dtype)
-    lse_ref[0, 0, 0] = (m + jnp.log(l_safe))[:, 0]
+    o_ref[0, 0, 0] = (_dot(p * vs_ref[0, 0], vb, od) /
+                      l_safe).astype(o_ref.dtype)
+    lse_ref[0, 0, 0] = m + jnp.log(l_safe)
 
 
 def pallas_decode_attention(q, k, v, key_mask, block_k=None,
@@ -700,14 +752,14 @@ def pallas_decode_attention(q, k, v, key_mask, block_k=None,
     ]
     operands = [qt, kt, vt]
     if quantized:
-        # (B, L, H) → (B, H, L): each program reads its block's
-        # per-position scale row next to the codes.
+        # (B, L, H) → (B, H, 1, L): each program reads its block's
+        # per-position scale as one lane-dense row next to the codes.
         in_specs += [
-            pl.BlockSpec((1, 1, bk), lambda b, h, j: (b, h, j)),
-            pl.BlockSpec((1, 1, bk), lambda b, h, j: (b, h, j)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, j: (b, h, 0, j)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, j: (b, h, 0, j)),
         ]
-        operands += [k_scale.transpose(0, 2, 1),
-                     v_scale.transpose(0, 2, 1)]
+        operands += [k_scale.transpose(0, 2, 1)[:, :, None, :],
+                     v_scale.transpose(0, 2, 1)[:, :, None, :]]
         kernel = functools.partial(_decode_kernel_quant,
                                    scale=scale, od=od)
     else:
@@ -723,86 +775,20 @@ def pallas_decode_attention(q, k, v, key_mask, block_k=None,
         out_specs=(
             pl.BlockSpec((1, 1, 1, Sq, D),
                          lambda b, h, j: (b, h, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Sq),
-                         lambda b, h, j: (b, h, j, 0)),
+            # lse keeps its (Sq, 1) column: a block must span the
+            # array's last two dimensions or tile them by (8, 128),
+            # and Sq is a handful of rows.
+            pl.BlockSpec((1, 1, 1, Sq, 1),
+                         lambda b, h, j: (b, h, j, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, H, nk, Sq, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, nk, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, nk, Sq, 1), jnp.float32),
         ),
         interpret=interpret,
     )(*operands)
     # Cross-block lse merge (the flash-decode combine): weights are
     # exp(lse_i − lse_total) ≤ 1, void blocks weigh 0.
-    lse = jax.nn.logsumexp(lse_part, axis=2)
-    w = jnp.exp(lse_part - lse[:, :, None, :])
-    out = (o_part * w[..., None]).sum(axis=2)
+    lse = jax.nn.logsumexp(lse_part, axis=2, keepdims=True)
+    out = (o_part * jnp.exp(lse_part - lse)).sum(axis=2)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
-
-
-# -- availability --------------------------------------------------------
-
-_available = [None]
-_decode_available = [None]
-
-
-def pallas_attention_available():
-    """True when the live backend compiles and runs the kernel (cached
-    probe, same contract as pallas_lrn.tpu_available but end-to-end:
-    a toolchain that lowers LRN but chokes on this kernel's fori_loop
-    must read as unavailable, not crash the training step)."""
-    if _available[0] is None:
-        from .pallas_lrn import tpu_available
-        if not tpu_available():
-            _available[0] = False
-        else:
-            try:
-                x = jnp.zeros((1, LANE, 1, LANE), jnp.float32)
-                jax.block_until_ready(
-                    pallas_attention(x, x, x, causal=True))
-                _available[0] = True
-            except Exception as e:
-                # The silent-fallback contract stands, but WHY the
-                # kernel is off must be discoverable.
-                import logging
-                logging.getLogger("pallas_attention").info(
-                    "flash kernel probe failed (%s) — xla fallback",
-                    e)
-                _available[0] = False
-    return _available[0]
-
-
-def pallas_decode_available():
-    """End-to-end probe for the decode-shaped kernel (its split-k/v
-    grid and 5-d output tiling are a different lowering than the
-    training kernel's, so it gets its own cached verdict)."""
-    if _decode_available[0] is None:
-        from .pallas_lrn import tpu_available
-        if not tpu_available():
-            _decode_available[0] = False
-        else:
-            try:
-                q = jnp.zeros((1, 1, 1, LANE), jnp.float32)
-                kv = jnp.zeros((1, LANE, 1, LANE), jnp.float32)
-                mask = jnp.ones((1, 1, LANE), bool)
-                # f32 operands: the probe must gate the LOWERING the
-                # serving path actually runs (export._decode_attend
-                # pins operand_dtype=f32), not the bf16 default.
-                jax.block_until_ready(
-                    pallas_decode_attention(
-                        q, kv, kv, mask,
-                        operand_dtype=jnp.float32))
-                _decode_available[0] = True
-            except Exception as e:
-                import logging
-                logging.getLogger("pallas_attention").info(
-                    "decode kernel probe failed (%s) — dense "
-                    "fallback", e)
-                _decode_available[0] = False
-    return _decode_available[0]
-
-
-def reset_probe():
-    """Clears the cached availability probes (tests, backend swaps)."""
-    _available[0] = None
-    _decode_available[0] = None

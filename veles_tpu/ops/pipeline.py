@@ -84,19 +84,6 @@ def init_parser(parser):
              "count must divide into stages x chunks)")
 
 
-def _shard_map():
-    """Version-portable shard_map + its replication-check kwarg."""
-    try:
-        from jax import shard_map
-        import inspect
-        kw = {"check_vma": False} if "check_vma" in \
-            inspect.signature(shard_map).parameters else {}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        kw = {"check_rep": False}
-    return shard_map, kw
-
-
 def _validate(x, n_microbatches, n_layers, n_stages):
     """Shared argument validation — actionable errors instead of
     silent reshape/astype surprises (ISSUE 12 satellite)."""
@@ -181,7 +168,6 @@ def gpipe(fn, stacked_params, x, mesh, stage_axis, n_microbatches):
 
     Returns y (B, ...) float32, replicated over the stage axis.
     """
-    shard_map, _kw = _shard_map()
     from jax.sharding import PartitionSpec as P
     B = x.shape[0]
     n_layers = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
@@ -202,9 +188,9 @@ def gpipe(fn, stacked_params, x, mesh, stage_axis, n_microbatches):
     pspec = jax.tree_util.tree_map(
         lambda p: P(stage_axis, *([None] * (p.ndim - 1))),
         stacked_params)
-    out = shard_map(
+    out = jax.shard_map(
         stage_fn, mesh=mesh,
-        in_specs=(pspec, P()), out_specs=P(), **_kw)(
+        in_specs=(pspec, P()), out_specs=P(), check_vma=False)(
             stacked_params, x_mb)
     return out.reshape((B,) + out.shape[2:])
 
@@ -413,7 +399,6 @@ def pipeline(fn, stacked_params, x, mesh, stage_axis, n_microbatches,
     if schedule == "gpipe":
         return gpipe(fn, stacked_params, x, mesh, stage_axis,
                      n_microbatches)
-    shard_map, _kw = _shard_map()
     from jax.sharding import PartitionSpec as P
     B = x.shape[0]
     n_layers = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
@@ -464,8 +449,8 @@ def pipeline(fn, stacked_params, x, mesh, stage_axis, n_microbatches,
 
     pspec = jax.tree_util.tree_map(
         lambda p: P(stage_axis, *([None] * (p.ndim - 1))), params)
-    out = shard_map(
+    out = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(pspec, P()) + (P(),) * len(arrays),
-        out_specs=P(), **_kw)(params, x_mb, *arrays)
+        out_specs=P(), check_vma=False)(params, x_mb, *arrays)
     return out.reshape((B,) + out.shape[2:])

@@ -257,6 +257,13 @@ def apply_dp_tp_sharding(workflow, mesh, data_axis="data",
                 unit.trainables[pname].sharding = \
                     NamedSharding(mesh, pspec)
             shard_slots_by_name(unit, gd_of.get(unit))
+            if getattr(unit, "n_heads", 0) and \
+                    unit.n_heads % n_model == 0:
+                # The column-sharded projections leave whole heads
+                # on each model shard; attention that must name its
+                # own layout (ops.attention.mesh_attention: a Mosaic
+                # kernel under shard_map) keeps them there.
+                unit.head_axis = model_axis
             sharded_layers += 1
             continue
         if not isinstance(unit, All2All):

@@ -152,7 +152,7 @@ class InjectedSnapshotCorruption(InjectedFault):
 
 class InjectedDeviceFault(InjectedFault):
     """A device failure during a serving decode call (XLA abort,
-    preemption, tunnel reset): the serving engine's supervisor
+    preemption, a lost chip): the serving engine's supervisor
     catches it on the device thread, rebuilds the KV pool, and
     re-adopts surviving streams from their request-side token
     prefixes — the exact recovery path a real device fault drives."""

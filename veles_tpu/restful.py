@@ -36,6 +36,7 @@ import base64
 
 import numpy
 
+from .backends import device_entry
 from .config import root
 from .error import Bug
 from .export import KV_DTYPES, ExportedModel, export_workflow
@@ -572,6 +573,7 @@ class ModelServer(JsonHttpServer):
         payload["queue_depth"] = self.engine.queue_depth_now()
         payload["max_batch"] = self.engine.max_batch
         payload["weight_version"] = self.engine.weight_version
+        payload["device"] = device_entry()
         cache = getattr(self.model, "compile_cache", None)
         if cache is not None:
             payload["compile_cache"] = cache.stats()

@@ -19,11 +19,16 @@ import signal
 import sys
 import threading
 
+from .backends import enable_compilation_cache
 from .export import KV_DTYPES
 from .restful import ModelServer
 
 
-def main(argv=None):
+def build_server(argv=None):
+    """Parses the command line and builds the :class:`ModelServer`
+    it describes, not yet listening — :func:`main` serves it in the
+    foreground; ``chip_smoke.py`` starts the same server on a thread
+    and talks to it over loopback."""
     parser = argparse.ArgumentParser(
         prog="veles_tpu.serve",
         description="Serve an exported veles_tpu model over HTTP "
@@ -134,13 +139,14 @@ def main(argv=None):
              "over-quota tenants get 429 + Retry-After — without "
              "shedding sibling tenants")
     args = parser.parse_args(argv)
+    enable_compilation_cache()
     if args.weight_dtype is not None:
         # export.py reads the decode weight mode from config — the
         # paged/bucketed programs re-quantize lazily on their next
         # _lm_params() look.
         from .config import root
         root.common.serving.weight_dtype = args.weight_dtype
-    server = ModelServer(
+    return ModelServer(
         args.artifact, host=args.host, port=args.port,
         token=args.token, max_batch=args.max_batch,
         queue_depth=args.queue_depth, rate_limit=args.rate_limit,
@@ -157,6 +163,10 @@ def main(argv=None):
         fabric_replicas=args.fabric_replicas,
         fabric_disagg=args.fabric_disagg,
         tenant=args.tenant)
+
+
+def main(argv=None):
+    server = build_server(argv)
     install_sigterm_drain(server)
     try:
         server.serve()

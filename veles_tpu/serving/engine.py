@@ -53,6 +53,17 @@ from .speculation import (MAX_SPEC_K, NO_DRAFTS, NGramDrafter,
                           check_draft_compat)
 
 
+#: What a device call raises when the PROGRAM is wrong rather than the
+#: device: a kernel that does not lower (Pallas raises ValueError or
+#: NotImplementedError), a shape or dtype mismatch (TypeError), a
+#: missing weight (KeyError).  They are raised while tracing or
+#: lowering — before anything ran or was donated — and they are
+#: deterministic, so supervised recovery (pool rebuild + replay)
+#: would only hide them behind a breaker trip.
+PROGRAM_ERRORS = (TypeError, ValueError, NotImplementedError,
+                  KeyError, IndexError, AttributeError)
+
+
 class _Request(object):
     """One queued unit of work.  ``key`` groups coalescible requests;
     ``rows`` is the device-batch budget it consumes."""
@@ -1306,6 +1317,9 @@ class ServingEngine(Logger):
             return
         try:
             self._run_paged_extend(rows)
+        except PROGRAM_ERRORS as e:
+            self._fail_program_error(rows, e, "paged prefill")
+            return
         except Exception as e:
             self.exception("paged prefill failed")
             self._recover_prefill_fault(rows, e)
@@ -1545,6 +1559,9 @@ class ServingEngine(Logger):
                 "serve.device_fault")
             new_tok = self.model.paged_step(pool, tables, pos, tok,
                                             gen_idx, temps, seeds)
+        except PROGRAM_ERRORS as e:
+            self._fail_program_error(rows, e, "paged decode step")
+            return False
         except Exception as e:
             self.exception("paged decode step failed")
             self._supervised_recover(rows, e)
@@ -1898,6 +1915,9 @@ class ServingEngine(Logger):
             target = self.model.paged_verify(pool, tables, pos, toks,
                                              dlens, gen_idx, temps,
                                              seeds)
+        except PROGRAM_ERRORS as e:
+            self._fail_program_error(rows, e, "speculative verify")
+            return False
         except Exception as e:
             self.exception("speculative verify failed")
             self._supervised_recover(rows, e)
@@ -2110,6 +2130,22 @@ class ServingEngine(Logger):
         if req.error is None:
             req.error = error
         req.event.set()
+
+    def _fail_program_error(self, rows, error, what):
+        """A paged device call raised one of :data:`PROGRAM_ERRORS`:
+        the program is wrong, not the device.  Nothing ran, so the
+        pool is intact and a replay would only raise the same error
+        again — the rows' requests fail now, loudly, and
+        ``errors.program`` counts them; no rebuild, no breaker."""
+        self.exception("%s failed with a program error — failing its "
+                       "requests (not a device fault: no pool "
+                       "rebuild, no replay)", what)
+        self.stats.incr("errors.program")
+        for row in rows:
+            self._release_row_blocks(row)
+            self._release_draft(row)
+        for req in dict.fromkeys(row.req for row in rows):
+            self._fail_req(req, error)
 
     # -- supervised decode recovery ----------------------------------------
 
@@ -2357,6 +2393,7 @@ class ServingEngine(Logger):
                             (b, features), numpy.float32))
                     compiles += 1
                 except Exception as e:
+                    self.stats.incr("warmup.failures")
                     self.warning("classify warmup (batch %d) "
                                  "failed: %s", b, e)
                     break
@@ -2382,6 +2419,7 @@ class ServingEngine(Logger):
                         numpy.zeros(b, numpy.int64))
                     compiles += 1
                 except Exception as e:
+                    self.stats.incr("warmup.failures")
                     self.warning("generate warmup (%d, %d, %d) "
                                  "failed: %s", b, s, m, e)
                     break
@@ -2464,6 +2502,7 @@ class ServingEngine(Logger):
                 compiles += 1
             compiles += self._warmup_spec(steps)
         except Exception as e:
+            self.stats.incr("warmup.failures")
             self.warning("paged warmup failed after %d compiles: %s",
                          compiles, e)
         return compiles

@@ -326,6 +326,11 @@ class TransformerBlock(ForwardBase):
                 head_axis=getattr(self, "head_axis", None),
                 kernel=getattr(self, "sp_kernel", None),
                 interpret=getattr(self, "sp_interpret", None))
+        if mesh is not None:
+            return A.mesh_attention(
+                q, k, v, mesh, causal=self.causal,
+                batch_axis=self.batch_axis,
+                head_axis=getattr(self, "head_axis", None))
         return A.attention(q, k, v, causal=self.causal)
 
     def tforward(self, read, write, params, ctx, state=None):
