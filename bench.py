@@ -1262,9 +1262,9 @@ def measure(wf, epochs):
 
 def trace_one_step(wf, path):
     """Enables span tracing, drives fused dispatches until one
-    ``step.dispatch`` span lands, exports the Chrome trace to
-    ``path`` and returns the dispatch-wall milliseconds of that
-    step (--trace-out; docs/observability.md)."""
+    ``step`` span lands, exports the Chrome trace to ``path`` and
+    returns the milliseconds of that step, serving to ready
+    (--trace-out; docs/observability.md)."""
     from veles_tpu.observability import tracing
     tracing.enable()
     tracing.clear()
@@ -1272,7 +1272,7 @@ def trace_one_step(wf, path):
 
     def dispatch_spans():
         return [s for s in tracing.spans()
-                if s["name"] == "step.dispatch"]
+                if s["name"] == "step"]
 
     for _ in range(4 * max(getattr(wf, "ticks_per_dispatch", 1), 1)):
         loader.run()

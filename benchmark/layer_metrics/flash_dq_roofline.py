@@ -1,0 +1,8 @@
+"""``flash_dq_roofline``: the flash dq kernel's share of its roofline
+(``scoped.kernel_roofline``)."""
+
+from benchmark.layer_metrics import scoped
+
+
+def read(record, name):
+    return scoped.kernel_roofline(record, "flash_dq", "dq")

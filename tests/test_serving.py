@@ -1086,6 +1086,8 @@ def test_paged_decode_ignores_fastpath_knobs(random_lm):
     prompt = numpy.array([[7, 3, 1, 4, 1]], numpy.int32)
     ref = model.generate_bucketed(
         numpy.pad(prompt, ((0, 0), (0, 3))), [5], 4)
+    was = (root.common.engine.attention_dtype,
+           root.common.engine.attention_kernel)
     root.common.engine.attention_dtype = "bf16"
     root.common.engine.attention_kernel = "auto"
     try:
@@ -1099,8 +1101,10 @@ def test_paged_decode_ignores_fastpath_knobs(random_lm):
         finally:
             engine.stop()
     finally:
-        root.common.engine.attention_dtype = "f32"
-        root.common.engine.attention_kernel = "xla"
+        # As found: "xla" left behind made a later file of the same
+        # xdist worker (test_tpu_compile) miss the kernel.
+        root.common.engine.attention_dtype, \
+            root.common.engine.attention_kernel = was
 
 
 # -- paged decode scheduling (fake model, no compiles) ---------------------

@@ -30,6 +30,7 @@ cache, enabled in :func:`backends.enable_compilation_cache`.
 
 from . import resilience
 from .backends import enable_compilation_cache
+from .observability.programs import STEP_SCOPES
 from .config import root, get as config_get
 from .memory import Vector
 from .units import Unit
@@ -152,6 +153,17 @@ class TracedUnit(AcceleratedUnit):
     def tstate(self):
         return {}
 
+    @property
+    def scope_name(self):
+        """The ``jax.named_scope`` the fused step wraps this unit's
+        ``tforward`` in — what the compiled program's instructions
+        carry in their ``op_name`` and ``observability.programs``
+        reads back as the instruction's unit
+        (docs/observability.md, scope vocabulary).  A STABLE name:
+        the unit's own (``embedding``, ``block3``, ``head``), or its
+        role (``loader``, ``evaluator``); never an ``id()``."""
+        return self.name
+
     def tforward(self, read, write, params, ctx, state=None):
         """Pure traced computation.  ``read(vec)``/``write(vec, val)``
         move tracers through the tensor bag; ``params`` maps this
@@ -187,6 +199,16 @@ class TracedUnit(AcceleratedUnit):
             self.tstate[a].devmem = val
 
 
+def unit_scopes(units):
+    """unit -> the ``jax.named_scope`` its ``tforward`` is traced
+    under: its ``scope_name``, made unique by the unit's position
+    where two units share one (default names are class names)."""
+    names = [u.scope_name.replace("/", "_") for u in units]
+    return {u: name if names.count(name) == 1 and
+            name not in STEP_SCOPES else "%s_%d" % (name, i)
+            for i, (u, name) in enumerate(zip(units, names))}
+
+
 class StepCompiler(object):
     """Builds the fused jitted train step for an AcceleratedWorkflow.
 
@@ -213,9 +235,10 @@ class StepCompiler(object):
         # (blocks, key, flag) of the newest block dispatch —
         # lower_last_block() lowers that program again.
         self._last_block_ = None
-        # Per-mode FLOP estimate for the live MFU gauge
-        # (observability.attribution); 0.0 = tried, unavailable.
+        # Per-program FLOP estimate for the live MFU gauge
+        # (_program_flops); 0.0 = registered, no estimate.
         self._step_flops_ = {}
+        self.scope_units = ()
 
     # -- graph analysis ----------------------------------------------------
 
@@ -318,6 +341,8 @@ class StepCompiler(object):
         persist_ids = [(str(id(v)), id(v))
                        for v in self.persist_vectors]
         pname = self.param_name
+        scope_of = unit_scopes(forward_units)
+        self.scope_units = tuple(scope_of.values())
         # Health sentinel (guardian.py): evaluators expose a
         # ``health_acc`` state row; the step accumulates per-class
         # tick finiteness (isfinite(loss) & isfinite(grad_norm)) and
@@ -345,6 +370,7 @@ class StepCompiler(object):
             self.workflow, "_zero_grad_shardings_", None) or {})
         self._note_optimizer_stats()
 
+        @jax.named_scope("health")
         def global_grad_norm(grads):
             import jax.numpy as jnp
             total = jnp.float32(0.0)
@@ -353,6 +379,7 @@ class StepCompiler(object):
                     jnp.square(g.astype(jnp.float32)))
             return jnp.sqrt(total)
 
+        @jax.named_scope("health")
         def health_update(new_states, batch, gnorm, loss,
                           valid=None):
             """Adds this tick's health row — [nonfinite, gnorm sum,
@@ -420,8 +447,9 @@ class StepCompiler(object):
                 # Units may update their own non-trainable state
                 # (e.g. epoch accumulators, batch-norm stats) by
                 # returning a dict from tforward.
-                upd = u.tforward(read, write, uparams, ctx,
-                                 state=ustate or None) or {}
+                with jax.named_scope(scope_of[u]):
+                    upd = u.tforward(read, write, uparams, ctx,
+                                     state=ustate or None) or {}
                 for a, val in upd.items():
                     new_states[pname(u, a)] = val
             outputs = {pid: bag[vid] for pid, vid in persist_ids
@@ -435,6 +463,7 @@ class StepCompiler(object):
                 loss = loss + ctx.aux_loss
             return loss, metrics, new_states, outputs
 
+        @jax.named_scope("update")
         def apply_updates(params, grads, new_states, gate,
                           hypers=None):
             """Runs every GD unit's update rule; ``gate`` (None or a
@@ -590,7 +619,9 @@ class StepCompiler(object):
         self._step_flops_ = {}
         self._hyper_progs_ = {}
         self._hyper_vals_ = {}
-        self._compiled = True
+        # Truthy, and a token of THIS compile: programs.register()
+        # lets a newer compile's program take a name over.
+        self._compiled = object()
 
     @staticmethod
     def _block_sharding(vec, rank):
@@ -650,16 +681,19 @@ class StepCompiler(object):
         _art.note_compile("step_h:%s:%s" % (mode, ",".join(names)))
         block_core, train_core = self._core_[2], self._core_[3]
         if mode == "train":
-            def fn(params, states, batch, consts, key, hvals):
+            def train_step_hyper(params, states, batch, consts, key,
+                                 hvals):
                 hypers = {n: hvals[i] for i, n in enumerate(names)}
                 return train_core(params, states, batch, consts, key,
                                   hypers)
+            fn = train_step_hyper
         else:
-            def fn(params, states, blocks, consts, key, training,
-                   hvals):
+            def block_step_hyper(params, states, blocks, consts, key,
+                                 training, hvals):
                 hypers = {n: hvals[i] for i, n in enumerate(names)}
                 return block_core(params, states, blocks, consts, key,
                                   training, hypers)
+            fn = block_step_hyper
         if config_get(root.common.engine.precision_level, 0) >= 2:
             fn = jax.default_matmul_precision("highest")(fn)
         prog = jax.jit(fn, donate_argnums=(0, 1))
@@ -696,27 +730,39 @@ class StepCompiler(object):
 
     # -- execution ---------------------------------------------------------
 
-    def _maybe_flops(self, key, fn, *args):
-        """Per-dispatch FLOP estimate for the live MFU gauge, cached
-        per compile under ``key`` — ("block", K) for block mode: a
-        remainder block (epoch length % ticks_per_dispatch) is a
-        different program with different FLOPs, and reusing the
-        first-seen estimate would skew MFU for the rest of the run.
-        Estimation re-traces the step once (XLA HLO cost analysis,
-        no recompile), so it runs only when a peak FLOP/s is known
-        for this device (the MFU denominator) — never on CPU test
-        hardware.  MUST run BEFORE the dispatch: lowering needs the
-        argument buffers donation invalidates."""
-        from .observability import attribution
-        if not attribution.enabled():
-            return None
+    def _program_flops(self, key, name, fn, ticks, *args):
+        """What is done ONCE per compiled program, cached under
+        ``key`` — ("block", K) for block mode: a remainder block
+        (epoch length % ticks_per_dispatch) is a different program
+        with different FLOPs.  Registers the program under ``name``
+        with ``observability.programs`` as a thunk that lowers it from
+        its arguments' shapes, once (so ``scopes(name)`` can answer
+        later, when the arrays are gone), and returns the
+        per-dispatch FLOP estimate for the live MFU gauge.  The
+        estimate calls that thunk (XLA HLO cost analysis, no compile)
+        and only runs when a peak FLOP/s is known for this device
+        (the MFU denominator) — never on CPU test hardware, where the
+        program is lowered only if somebody asks for its scopes."""
+        import functools
+        import jax
+        from .observability import attribution, programs
         cached = self._step_flops_.get(key)
         if cached is not None:
-            return cached or None  # 0.0 = tried, unavailable
-        if attribution.peak_flops() is None:
-            self._step_flops_[key] = 0.0
-            return None
-        flops = attribution.estimate_flops(fn, *args)
+            return cached or None  # 0.0 = no estimate
+        # A sharding only where the array is committed to one, as
+        # the dispatch sees its arguments: the dispatch then reuses
+        # the module the estimate lowered.
+        shapes = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=x.sharding if x.committed else None), args)
+        lower = functools.lru_cache(None)(lambda: fn.lower(*shapes))
+        programs.register(name, lower, self.scope_units, ticks,
+                          compiled_by=self._compiled)
+        flops = None
+        if attribution.enabled() and \
+                attribution.peak_flops() is not None:
+            flops = attribution.lowered_flops(lower())
         self._step_flops_[key] = flops or 0.0
         return flops
 
@@ -732,48 +778,56 @@ class StepCompiler(object):
 
     def execute(self, key=None, training=True, hypers=None):
         from .observability import attribution
-        from .observability import tracing
         if not self._compiled or self.fingerprint() != self._fingerprint:
             self.compile()
-        params = {n: v.devmem for n, v in self._param_vecs.items()}
-        states = {n: v.devmem for n, v in self._state_vecs.items()}
-        batch = {str(id(v)): v.devmem for v in self.batch_vectors}
-        consts = {str(id(v)): v.devmem for v in self.const_vectors}
-        if key is None:
-            from . import prng
-            key = prng.get().jax_key()
         mode = "train" if training else "infer"
-        # Hyper overrides apply to TRAIN dispatches only (inference
-        # runs no update rule, so member genes cannot matter there).
-        hyper_args = None
-        if training and hypers:
-            names, hvals = self._hyper_values(hypers)
-            train_fn = self._hyper_program("train", names)
-            hyper_args = (hvals,)
-        else:
-            train_fn = self._train
-        flops = self._maybe_flops(
-            mode, train_fn if training else self._infer,
-            params, states, batch, consts, key, *(hyper_args or ()))
-        timer = attribution.begin_step(ticks=1, flops=flops)
-        with tracing.span("step.dispatch", mode=mode):
+        with attribution.dispatch(program=mode + "_step",
+                                  ticks=1) as step:
+            with step.upload():
+                # Reading ``devmem`` uploads what the host wrote
+                # since the last tick: the minibatch vectors.
+                params = {n: v.devmem
+                          for n, v in self._param_vecs.items()}
+                states = {n: v.devmem
+                          for n, v in self._state_vecs.items()}
+                batch = {str(id(v)): v.devmem
+                         for v in self.batch_vectors}
+                consts = {str(id(v)): v.devmem
+                          for v in self.const_vectors}
+            if key is None:
+                from . import prng
+                key = prng.get().jax_key()
+            # Hyper overrides apply to TRAIN dispatches only
+            # (inference runs no update rule, so member genes cannot
+            # matter there).
+            hyper_args = ()
+            step_fn = self._train if training else self._infer
+            if training and hypers:
+                names, hvals = self._hyper_values(hypers)
+                step_fn = self._hyper_program("train", names)
+                hyper_args = (hvals,)
+                step.program = "train_step_hyper"
+            step.flops = self._program_flops(
+                mode, step.program, step_fn, 1,
+                params, states, batch, consts, key, *hyper_args)
+            with step.enqueue():
+                if training:
+                    new_params, new_states, outputs, metrics = \
+                        step_fn(params, states, batch, consts, key,
+                                *hyper_args)
+                else:
+                    new_states, outputs, metrics = step_fn(
+                        params, states, batch, consts, key)
             if training:
-                new_params, new_states, outputs, metrics = \
-                    train_fn(params, states, batch, consts, key,
-                             *(hyper_args or ()))
                 for n, v in self._param_vecs.items():
                     v.devmem = new_params[n]
-            else:
-                new_states, outputs, metrics = self._infer(
-                    params, states, batch, consts, key)
-        for n, v in self._state_vecs.items():
-            v.devmem = new_states[n]
-        for vec in self.persist_vectors:
-            pid = str(id(vec))
-            if pid in outputs:
-                vec.devmem = outputs[pid]
-        attribution.end_step(timer,
-                             leaf=self._sync_leaf(metrics, new_states))
+            for n, v in self._state_vecs.items():
+                v.devmem = new_states[n]
+            for vec in self.persist_vectors:
+                pid = str(id(vec))
+                if pid in outputs:
+                    vec.devmem = outputs[pid]
+            step.wait(self._sync_leaf(metrics, new_states))
         return metrics
 
     def _training_flag(self, training):
@@ -795,52 +849,56 @@ class StepCompiler(object):
         vector id → (K, ...) numpy/jax array."""
         import jax
         from .observability import attribution
-        from .observability import tracing
         if not self._compiled or self.fingerprint() != self._fingerprint:
             self.compile()
-        params = {n: v.devmem for n, v in self._param_vecs.items()}
-        states = {n: v.devmem for n, v in self._state_vecs.items()}
-        consts = {str(id(v)): v.devmem for v in self.const_vectors}
-        if key is None:
-            from . import prng
-            key = prng.get().jax_key()
         ticks = next(iter(blocks.values())).shape[0] if blocks else 1
-        # The stacked tick upload is EXPLICIT (device_put) so the
-        # strict-step transfer guard distinguishes it from a stray
-        # host-sync inside the hot loop — and it lands in the batch
-        # vector's own layout, so a data-parallel mesh splits every
-        # tick of the block instead of replicating it.
-        vecs = {str(id(v)): v for v in self.batch_vectors}
-        blocks = {k: jax.device_put(
-            v, self._block_sharding(vecs[k], v.ndim))
-            for k, v in blocks.items()}
-        flag = self._training_flag(training)
-        # Hyper-traced block variant (population member genes): the
-        # traced training flag already gates updates, so one program
-        # serves train and validation blocks alike.
-        hyper_args = None
-        block_fn = self._block
-        if hypers:
-            names, hvals = self._hyper_values(hypers)
-            block_fn = self._hyper_program("block", names)
-            hyper_args = (hvals,)
-        flops = self._maybe_flops(("block", ticks), block_fn,
-                                  params, states, blocks, consts,
-                                  key, flag, *(hyper_args or ()))
-        timer = attribution.begin_step(ticks=ticks, flops=flops)
-        with tracing.span("step.dispatch", mode="block", ticks=ticks):
-            new_params, new_states = block_fn(
+        with attribution.dispatch(program="block_step",
+                                  ticks=ticks) as step:
+            params = {n: v.devmem for n, v in self._param_vecs.items()}
+            states = {n: v.devmem for n, v in self._state_vecs.items()}
+            consts = {str(id(v)): v.devmem
+                      for v in self.const_vectors}
+            if key is None:
+                from . import prng
+                key = prng.get().jax_key()
+            # The stacked tick upload is EXPLICIT (device_put) so the
+            # strict-step transfer guard distinguishes it from a
+            # stray host-sync inside the hot loop — and it lands in
+            # the batch vector's own layout, so a data-parallel mesh
+            # splits every tick of the block instead of replicating
+            # it.
+            vecs = {str(id(v)): v for v in self.batch_vectors}
+            with step.upload():
+                blocks = {k: jax.device_put(
+                    v, self._block_sharding(vecs[k], v.ndim))
+                    for k, v in blocks.items()}
+            flag = self._training_flag(training)
+            # Hyper-traced block variant (population member genes):
+            # the traced training flag already gates updates, so one
+            # program serves train and validation blocks alike.
+            hyper_args = ()
+            block_fn = self._block
+            if hypers:
+                names, hvals = self._hyper_values(hypers)
+                block_fn = self._hyper_program("block", names)
+                hyper_args = (hvals,)
+                step.program = "block_step_hyper"
+            step.flops = self._program_flops(
+                ("block", ticks), step.program, block_fn, ticks,
                 params, states, blocks, consts, key, flag,
-                *(hyper_args or ()))
-        for n, v in self._param_vecs.items():
-            v.devmem = new_params[n]
-        for n, v in self._state_vecs.items():
-            v.devmem = new_states[n]
-        # What lower_last_block() re-lowers: a few small index and
-        # mask arrays, none of them donated.
-        self._last_block_ = (blocks, key, flag)
-        attribution.end_step(timer,
-                             leaf=self._sync_leaf(new_states))
+                *hyper_args)
+            with step.enqueue():
+                new_params, new_states = block_fn(
+                    params, states, blocks, consts, key, flag,
+                    *hyper_args)
+            for n, v in self._param_vecs.items():
+                v.devmem = new_params[n]
+            for n, v in self._state_vecs.items():
+                v.devmem = new_states[n]
+            # What lower_last_block() re-lowers: a few small index
+            # and mask arrays, none of them donated.
+            self._last_block_ = (blocks, key, flag)
+            step.wait(self._sync_leaf(new_states))
         return {}
 
     # -- population mode (vmapped hyperparameter sweeps) -------------------

@@ -7,7 +7,8 @@ if call sites actually pass literals.  This pass closes the loop:
 the name argument of ``stats.incr``, ``set_gauge``,
 ``observe_latency`` / ``observe_batch`` / ``observe_request``,
 registry ``counter``/``gauge``/``histogram``, ``tracing.span`` /
-``tracing.begin``, and injector ``check``/``tick`` must be a string
+``tracing.begin`` / ``tracing.annotated``, and injector
+``check``/``tick`` must be a string
 literal or a ``"prefix.%s" % …`` format with a literal left side.
 A bare ``Name`` is accepted only when it is a parameter of the
 enclosing function (the pass-through idiom: ``RetryPolicy.call(...,
@@ -59,7 +60,7 @@ def dotted_source_literals(project):
 _NAME_SINKS = frozenset((
     "incr", "set_gauge", "observe_latency", "observe_batch",
     "observe_request", "counter", "gauge", "histogram", "span",
-    "begin", "check", "tick",
+    "begin", "annotated", "check", "tick",
 ))
 
 #: Receiver spellings that make an attribute call a registry sink.
@@ -95,7 +96,7 @@ def _is_sink(call):
         return False
     recv = _recv_text(func.value)
     last = recv.split(".")[-1] if recv else ""
-    if func.attr in ("span", "begin"):
+    if func.attr in ("span", "begin", "annotated"):
         return last in ("tracing", "trace")
     if func.attr in ("check", "tick"):
         return ("injector" in recv or last in ("inj",) or

@@ -1229,8 +1229,9 @@ class ExportedModel(object):
         the compiled executable)."""
         import jax
         if self._jit_forward is None:
-            self._jit_forward = jax.jit(
-                lambda weights, x: self._jax_chain(x, weights))
+            def model_forward(weights, x):
+                return self._jax_chain(x, weights)
+            self._jit_forward = jax.jit(model_forward)
         return numpy.asarray(self._jit_forward(
             self._device_weights(),
             numpy.asarray(x, dtype=numpy.float32)))
@@ -1368,7 +1369,7 @@ class ExportedModel(object):
                 from .znicz.attention import transformer_block_apply
                 p = {n: par(entry, n) for n in entry["params"]}
                 x = transformer_block_apply(
-                    p, x, int(cfg["n_heads"]),
+                    p, x, int(cfg["n_heads"]),  # lint-ok: VL101 manifest int
                     bool(cfg.get("causal", 1)), jnp.float32,
                     attend=self._serving_attend(
                         bool(cfg.get("causal", 1))))
@@ -1377,6 +1378,7 @@ class ExportedModel(object):
                 from .ops.moe import moe_ffn
                 p = {n: jnp.asarray(par(entry, n))
                      for n in entry["params"]}
+                # lint-ok: VL101 manifest number, not a tracer
                 cf = float(cfg.get("capacity_factor", 1.25))
 
                 def moe_mlp(h, p=p, cf=cf):
@@ -1388,7 +1390,7 @@ class ExportedModel(object):
                     return y.reshape(B_, S_, E_)
 
                 x = transformer_block_apply(
-                    p, x, int(cfg["n_heads"]),
+                    p, x, int(cfg["n_heads"]),  # lint-ok: VL101 manifest int
                     bool(cfg.get("causal", 1)), jnp.float32,
                     attend=self._serving_attend(
                         bool(cfg.get("causal", 1))),
@@ -1425,10 +1427,11 @@ class ExportedModel(object):
                 x = self._jax_pool(t, cfg, x)
             elif t == "norm":
                 c = x.shape[-1]
-                half = int(cfg["n"]) // 2
+                half = int(cfg["n"]) // 2  # lint-ok: VL101 manifest int
                 i = jnp.arange(c)
                 d = i[:, None] - i[None, :]
                 band = ((d >= -half) &
+                        # lint-ok: VL101 manifest int
                         (d <= int(cfg["n"]) - 1 - half)
                         ).astype(jnp.float32)
                 ssum = jnp.einsum("...c,cd->...d", x * x, band)
@@ -1585,7 +1588,7 @@ class ExportedModel(object):
 
         att = self._decode_attend()
 
-        def run(params, prompt, key, temperature):
+        def lm_generate(params, prompt, key, temperature):
             B = prompt.shape[0]
             block_params = params["blocks"]
             x = embed(params, prompt, 0)
@@ -1628,7 +1631,7 @@ class ExportedModel(object):
                 all_logits = first_logits[:, None]
             return tokens, all_logits
 
-        return jax.jit(run)
+        return jax.jit(lm_generate)
 
     def generate(self, prompt, max_new_tokens, temperature=0.0,
                  seed=0, return_logits=False):
@@ -1747,7 +1750,7 @@ class ExportedModel(object):
         sample_rows = _sample_rows
         att = self._decode_attend()
 
-        def run(params, prompts, lengths, seeds, temps):
+        def lm_generate_bucketed(params, prompts, lengths, seeds, temps):
             B = prompts.shape[0]
             emb_w = params["emb_w"]
             emb_pos = params["emb_pos"]
@@ -1806,7 +1809,7 @@ class ExportedModel(object):
                     [toks.swapaxes(0, 1), last_tok[:, None]], axis=1)
             return tok0[:, None]
 
-        return jax.jit(run)
+        return jax.jit(lm_generate_bucketed)
 
     def generate_bucketed(self, prompts, lengths, max_new_tokens,
                           temperatures=0.0, seeds=0):
@@ -1924,19 +1927,19 @@ class ExportedModel(object):
 
         def build():
             if sks is None:
-                def run(ks, vs, src, dst):
+                def kv_copy(ks, vs, src, dst):
                     ks = [k.at[dst].set(k[src]) for k in ks]
                     vs = [v.at[dst].set(v[src]) for v in vs]
                     return ks, vs
-                return jax.jit(run, donate_argnums=(0, 1))
+                return jax.jit(kv_copy, donate_argnums=(0, 1))
 
-            def run(ks, vs, sks, svs, src, dst):
+            def kv_copy_quant(ks, vs, sks, svs, src, dst):
                 ks = [k.at[dst].set(k[src]) for k in ks]
                 vs = [v.at[dst].set(v[src]) for v in vs]
                 sks = [s.at[dst].set(s[src]) for s in sks]
                 svs = [s.at[dst].set(s[src]) for s in svs]
                 return ks, vs, sks, svs
-            return jax.jit(run, donate_argnums=(0, 1, 2, 3))
+            return jax.jit(kv_copy_quant, donate_argnums=(0, 1, 2, 3))
 
         fn = self.compile_cache.get_or_build(key, build)
         src_dst = jax.device_put((numpy.int32(src),
@@ -2191,8 +2194,8 @@ class ExportedModel(object):
         att = self._decode_attend()
         quantized = _KV_QMAX[kv_dtype] is not None
 
-        def run(params, pks, pvs, sks, svs, tables, tokens, prior,
-                chunk_len, temps, seeds):
+        def paged_extend(params, pks, pvs, sks, svs, tables, tokens, prior,
+                         chunk_len, temps, seeds):
             B = tables.shape[0]
             keys0 = jax.vmap(jax.random.PRNGKey)(seeds)
             offs = jnp.arange(Sc)
@@ -2237,7 +2240,7 @@ class ExportedModel(object):
                 temps)
             return new_pks, new_pvs, new_sks, new_svs, tok0
 
-        return jax.jit(run, donate_argnums=(1, 2, 3, 4))
+        return jax.jit(paged_extend, donate_argnums=(1, 2, 3, 4))
 
     def _build_paged_step(self, T, block_size, kv_dtype="f32"):
         """Jitted one-token decode step over the block pool: each
@@ -2263,8 +2266,8 @@ class ExportedModel(object):
         att = self._decode_attend()
         quantized = _KV_QMAX[kv_dtype] is not None
 
-        def run(params, pks, pvs, sks, svs, tables, pos, tok,
-                gen_idx, temps, seeds):
+        def paged_step(params, pks, pvs, sks, svs, tables, pos, tok,
+                       gen_idx, temps, seeds):
             keys0 = jax.vmap(jax.random.PRNGKey)(seeds)
             posn = jnp.clip(pos, 0, P - 1)
             x = params["emb_w"][jnp.clip(tok, 0, V - 1)][:, None] + \
@@ -2293,7 +2296,7 @@ class ExportedModel(object):
                 temps)
             return new_pks, new_pvs, new_sks, new_svs, tok_new
 
-        return jax.jit(run, donate_argnums=(1, 2, 3, 4))
+        return jax.jit(paged_step, donate_argnums=(1, 2, 3, 4))
 
     def _build_paged_verify(self, K, T, block_size,
                             kv_dtype="f32"):
@@ -2336,8 +2339,8 @@ class ExportedModel(object):
         att = self._decode_attend()
         quantized = _KV_QMAX[kv_dtype] is not None
 
-        def run(params, pks, pvs, sks, svs, tables, pos, toks,
-                dlens, gen_idx, temps, seeds):
+        def paged_verify(params, pks, pvs, sks, svs, tables, pos, toks,
+                         dlens, gen_idx, temps, seeds):
             keys0 = jax.vmap(jax.random.PRNGKey)(seeds)
             offs = jnp.arange(Sq)
             posn = jnp.clip(pos[:, None] + offs[None, :], 0, P - 1)
@@ -2390,7 +2393,7 @@ class ExportedModel(object):
                            lambda _: greedy, None)
             return new_pks, new_pvs, new_sks, new_svs, out
 
-        return jax.jit(run, donate_argnums=(1, 2, 3, 4))
+        return jax.jit(paged_verify, donate_argnums=(1, 2, 3, 4))
 
     def paged_verify(self, pool, tables, pos, toks, draft_lens,
                      gen_idx, temps, seeds):
@@ -2505,7 +2508,7 @@ class ExportedModel(object):
     def _jax_pool(t, cfg, x):
         import jax.numpy as jnp
         from jax import lax
-        ky, kx = int(cfg["ky"]), int(cfg["kx"])
+        ky, kx = int(cfg["ky"]), int(cfg["kx"])  # lint-ok: VL101 manifest int
         sh, sw = cfg["sliding"]
         (pt, pb), (pl, pr) = cfg["padding"]
         H, W = x.shape[1], x.shape[2]

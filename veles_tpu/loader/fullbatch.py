@@ -28,6 +28,8 @@ class FullBatchLoader(Loader, TracedUnit):
 
     hide_from_registry = True
 
+    scope_name = "loader"
+
     def __init__(self, workflow, **kwargs):
         super(FullBatchLoader, self).__init__(workflow, **kwargs)
         self.original_data = Vector()
@@ -120,9 +122,15 @@ class FullBatchLoader(Loader, TracedUnit):
         wf = self.workflow
         ticks = getattr(wf, "ticks_per_dispatch", 1)
         if ticks > 1 and getattr(wf, "fused", False):
-            blocks = self.serve_block(ticks)
-            wf.begin_tick()
-            wf.execute_block(blocks)
+            from ..observability import attribution
+            # One ``step`` span a dispatch, from the host's index
+            # serving to the device's last output
+            # (docs/observability.md); the compiler joins it.
+            with attribution.dispatch(ticks=ticks) as step:
+                with step.serve():
+                    blocks = self.serve_block(ticks)
+                wf.begin_tick()
+                wf.execute_block(blocks)
             return
         self.serve_next_minibatch()
         if wf is not None and hasattr(wf, "begin_tick"):

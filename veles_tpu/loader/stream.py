@@ -55,6 +55,8 @@ class StreamLoader(Loader, TracedUnit):
 
     hide_from_registry = True
 
+    scope_name = "loader"
+
     #: Published epoch_number of the dispatched block (class-level
     #: default so the property works before/without publication).
     _pub_ = None

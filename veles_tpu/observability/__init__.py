@@ -1,7 +1,7 @@
 """Unified observability: span tracing, typed metrics, device/MFU
 attribution (docs/observability.md).
 
-Three legs over one substrate:
+Four legs over one substrate:
 
 * :mod:`.tracing` — ``span("net.send")`` context managers feeding a
   bounded ring collector, cross-node clock alignment, and Chrome
@@ -10,10 +10,15 @@ Three legs over one substrate:
   registry with Prometheus text exposition (``GET /metrics`` on
   web_status and the serving ModelServer); ``resilience.stats`` is
   a thin shim over it, so every PR-1 counter is scrapeable;
-* :mod:`.attribution` — ``block_until_ready`` device-time deltas +
-  ``cost_analysis()`` FLOPs around the fused step → a live MFU
-  gauge (heartbeat ``perf`` section, web_status row), and the
-  ``--xprof DIR`` capture window.
+* :mod:`.attribution` — one record per dispatch of the fused step
+  (``step`` span and children on the profiler's clock,
+  ``block_until_ready`` device time, the host's split, gc seconds)
+  + ``cost_analysis()`` FLOPs → a live MFU gauge (heartbeat ``perf``
+  section, web_status row), and the ``--xprof DIR`` capture window,
+  which :mod:`.profile` reduces and prints;
+* :mod:`.programs` — the scope table of each compiled step program:
+  instruction name → (phase, unit, inner scope), read back from the
+  ``jax.named_scope``s the step is traced under.
 
 Tracing defaults OFF and compiles to a near-zero no-op; metrics are
 passive counters; attribution adds one host sync per dispatched
@@ -38,8 +43,10 @@ def init_parser(parser):
     parser.add_argument(
         "--xprof", default=None, metavar="DIR",
         help="open a jax.profiler capture window around the next "
-             "--xprof-steps fused step dispatches and write the "
-             "trace into DIR (inspect with tensorboard/xprof)")
+             "--xprof-steps fused step dispatches, write the "
+             "trace into DIR (inspect with tensorboard/xprof) and "
+             "print device seconds by phase and unit, the kernels "
+             "and the idle gaps when the window closes")
     parser.add_argument(
         "--xprof-steps", type=int, default=4, metavar="N",
         help="fused dispatches inside the --xprof capture window "

@@ -2,26 +2,31 @@
 
 ``StepCompiler`` dispatches are asynchronous — ``time.perf_counter``
 around the call measures Python dispatch, not the chip.  This module
-closes the gap: after each dispatch the compiler hands a small output
-leaf to :func:`end_step`, which ``block_until_ready``s it (waiting,
-not transferring — all outputs of one XLA computation complete
-together) and records the true wall→ready delta.  Combined with a
-``cost_analysis()``-derived FLOP estimate per compiled step (one
-extra trace per geometry, no extra compile — ``Lowered
-.cost_analysis()`` runs XLA's HLO cost model), that yields a **live
-MFU gauge** published into the process metrics registry, the
-launcher heartbeat's ``perf`` section, and the web_status dashboard.
+closes the gap: every dispatch runs inside a :class:`Dispatch`
+(``with attribution.dispatch(...) as step``), which opens the ``step``
+span and its children (``loader.serve_block``, ``step.upload``,
+``step.enqueue``, ``step.wait``) on the profiler's clock, waits for a
+small output leaf (``block_until_ready``: waiting, not transferring —
+all outputs of one XLA computation complete together) and keeps ONE
+record per dispatch: ordinal, program, ticks and the host's split
+(``serve_s``, ``upload_s``, ``enqueue_s``, ``wait_s``, ``gc_s``,
+``gc_full``).  The last 64 records are :func:`recent`; the newest
+one's split rides :func:`perf_summary` (launcher heartbeat ``perf``
+section, web_status row).  Combined with a FLOP count per compiled
+step from XLA's HLO cost analysis (the ``Lowered`` is made once per
+program and kept for ``observability.programs``), that yields the
+live ``device.mfu`` gauge: XLA's count — recomputation included —
+over host-timed dispatches, NOT the benchmark's ``train_mfu_pct``.
 
 Also owns the ``--xprof DIR`` capture window: a ``jax.profiler``
-trace opened at the first fused dispatch and closed after N of them
-— the "give me a profile of exactly the steady-state step" operator
-workflow, without bracketing the whole run like ``--profile`` does.
+trace opened at the first fused dispatch and closed after N of them,
+then reduced and printed by ``observability.profile``.
 
 Knobs (``root.common.observability``):
 
 * ``attribution`` (default True) — the per-dispatch sync costs one
   host round-trip per *block* of ticks; flip off for maximally
-  async dispatch chains;
+  async dispatch chains (spans still open; no record is kept);
 * ``peak_tflops`` — the MFU denominator; defaults from the device
   kind table below (v5e bf16 = 197), None on unknown hardware
   (device time still publishes; the MFU gauge just stays silent).
@@ -31,9 +36,15 @@ computation: bits on device are identical with attribution on, off,
 or absent.
 """
 
+import collections
+import gc
+import itertools
 import logging
 import threading
 import time
+
+from . import metrics, tracing
+from ..config import root, get as config_get
 
 #: device_kind substring → peak dense bf16 TFLOP/s (the MFU
 #: denominator).  Substring match: jax reports kinds like
@@ -55,11 +66,17 @@ _state = {
     "last_ms": None,       # the newest dispatch, unsmoothed
     "device_ms": None,     # EWMA ms per dispatch
     "mfu": None,           # EWMA model-flop utilization
-    "flops": None,         # last per-dispatch FLOP estimate
     "dispatches": 0,
     "ticks": 0,
     "device_s_total": 0.0,
 }
+#: How many dispatch records :func:`recent` keeps.
+RECENT = 64
+_recent = collections.deque(maxlen=RECENT)
+_local = threading.local()
+#: The open outermost dispatch the gc hook charges collections to,
+#: and when the collection under way began.
+_gc = {"target": None, "t0": None}
 _xprof = {"dir": None, "steps": 0, "done": 0, "started": False}
 #: Optimizer observability (StepCompiler.compile publishes once per
 #: compile): the configured kind(s), total slot bytes, and the ZeRO
@@ -71,6 +88,7 @@ _optimizer = {"kind": None, "state_bytes": None, "shard_frac": None}
 #: collapsed).
 _moe = {"aux_loss": None, "max_load_frac": None, "n_experts": None}
 _timer = time.perf_counter  # injectable for tests
+_ordinals = itertools.count(1)
 #: configured-peak-value -> resolved FLOP/s (the device probe and
 #: config walk are constant per process; never pay them per
 #: dispatch).
@@ -78,7 +96,6 @@ _peak_cache = {}
 
 
 def _config(name, default):
-    from ..config import root, get as config_get
     return config_get(getattr(root.common.observability, name),
                       default)
 
@@ -94,17 +111,19 @@ def reset():
     ``device.*`` series in the process registry (test isolation) —
     attribution owns its gauges; the resilience shim's reset only
     touches counters created through it."""
+    global _ordinals
+    _ordinals = itertools.count(1)
     with _lock:
         _state.update(last_ms=None, device_ms=None, mfu=None,
-                      flops=None,
                       dispatches=0, ticks=0, device_s_total=0.0)
+        _recent.clear()
         _optimizer.update(kind=None, state_bytes=None,
                           shard_frac=None)
         _moe.update(aux_loss=None, max_load_frac=None,
                     n_experts=None)
     _xprof.update(dir=None, steps=0, done=0, started=False)
+    _local.dispatch = _gc["target"] = _gc["t0"] = None
     _peak_cache.clear()
-    from . import metrics
     metrics.registry.remove_prefix("device.")
     metrics.registry.remove_prefix("optimizer.")
     metrics.registry.remove_prefix("moe.")
@@ -156,7 +175,9 @@ def _xprof_step_begin():
         return
     try:
         import jax
-        jax.profiler.start_trace(_xprof["dir"])
+        from . import profile
+        jax.profiler.start_trace(
+            _xprof["dir"], profiler_options=profile.profile_options())
         _xprof["started"] = True
     except Exception:
         # The operator explicitly asked for a capture (--xprof):
@@ -165,6 +186,7 @@ def _xprof_step_begin():
             "xprof capture could not start — disarming")
         _xprof["dir"] = None  # unusable; disarm rather than retrying
 
+
 def _xprof_step_end(leaf):
     if not _xprof["started"]:
         return
@@ -172,14 +194,25 @@ def _xprof_step_end(leaf):
     if _xprof["done"] < _xprof["steps"]:
         return
     _device_sync(leaf)
+    directory = _xprof["dir"]
+    _xprof["started"] = False
+    _xprof["dir"] = None
     try:
         import jax
         jax.profiler.stop_trace()
     except Exception as e:
         logging.getLogger("attribution").debug(
             "xprof stop_trace failed: %s", e)
-    _xprof["started"] = False
-    _xprof["dir"] = None
+        return
+    try:
+        from . import profile
+        print(profile.report(profile.reduce_dir(directory)),
+              flush=True)
+    except Exception:
+        # The trace is on disk either way; the reduction is a
+        # convenience and must not take the training run down.
+        logging.getLogger("attribution").exception(
+            "could not reduce the xprof trace under %s", directory)
 
 
 def _device_sync(leaf):
@@ -192,43 +225,157 @@ def _device_sync(leaf):
         leaf.block_until_ready()
 
 
-# -- per-dispatch hooks (called by StepCompiler) ---------------------------
+# -- per-dispatch hooks (called by StepCompiler and the loader) ------------
 
-class _StepTimer(object):
-    __slots__ = ("t0", "ticks", "flops")
-
-    def __init__(self, ticks, flops):
-        self.t0 = _timer()
-        self.ticks = ticks
-        self.flops = flops
-
-
-def begin_step(ticks=1, flops=None):
-    """Called right before a fused dispatch.  Returns a timer token
-    for :func:`end_step`, or None when nothing here is active."""
-    _xprof_step_begin()
-    if not enabled():
-        return None
-    return _StepTimer(ticks, flops)
+def _on_gc(phase, info):
+    """``gc.callbacks`` hook: seconds and generation of every
+    collection that falls inside an open dispatch (a collection
+    stops every Python thread, whichever thread set it off)."""
+    target = _gc["target"]
+    if target is None:
+        return
+    if phase == "start":
+        _gc["t0"] = _timer()
+    elif _gc["t0"] is not None:
+        target.gc_s += _timer() - _gc["t0"]
+        target.gc_full += info.get("generation") == 2
+        _gc["t0"] = None
 
 
-def end_step(timer, leaf=None):
-    """Called right after the dispatch returns.  Syncs on ``leaf``
-    (when given) so the delta covers device execution, then folds the
-    measurement into the live gauges."""
-    _xprof_step_end(leaf)
-    if timer is None:
-        return None
-    _device_sync(leaf)
-    return record_step(_timer() - timer.t0, flops=timer.flops,
-                       ticks=timer.ticks)
+def _seconds(span):
+    return span.seconds if span is not None else 0.0
 
 
-def record_step(device_seconds, flops=None, ticks=1):
-    """Folds one measured dispatch into the attribution state and the
-    metrics registry — separated from :func:`end_step` so tests can
-    drive the MFU plumbing with a fake device timer."""
-    from . import metrics
+class Dispatch(object):
+    """The host's side of ONE dispatch of a step program: the
+    ``step`` span, its children, and the record :func:`recent`
+    keeps.  Re-entrant on a thread: the loader opens it around
+    ``serve_block`` and ``StepCompiler.execute_block`` joins the
+    same one."""
+
+    __slots__ = ("ordinal", "program", "ticks", "flops", "gc_s",
+                 "gc_full", "_span", "_serve", "_upload", "_enqueue",
+                 "_wait", "_depth", "_t0", "_leaf", "_timed")
+
+    def __init__(self):
+        self.ordinal = next(_ordinals)
+        self.program = None
+        self.ticks = 1
+        self.flops = None
+        self.gc_s = 0.0
+        self.gc_full = 0
+        self._span = self._t0 = self._leaf = None
+        self._serve = self._upload = self._enqueue = self._wait = None
+        self._depth = 0
+        self._timed = False
+
+    def __enter__(self):
+        self._depth += 1
+        if self._depth == 1:
+            if _on_gc not in gc.callbacks:
+                gc.callbacks.append(_on_gc)
+            # Before the span: an annotation opened ahead of the
+            # profiler session is not in its trace.
+            _xprof_step_begin()
+            self._span = tracing.annotated(
+                "step", ordinal=self.ordinal, ticks=self.ticks)
+            _local.dispatch = _gc["target"] = self
+        return self
+
+    # The children: each opens once a dispatch, and its seconds go
+    # into the dispatch's record.
+
+    def serve(self):
+        """``loader.serve_block``: host index serving and stacking."""
+        self._serve = tracing.annotated("loader.serve_block")
+        return self._serve
+
+    def upload(self):
+        """``step.upload``: the host→device put of the dispatch's
+        inputs."""
+        self._upload = tracing.annotated("step.upload")
+        return self._upload
+
+    def enqueue(self):
+        """``step.enqueue``: the jitted call until it returns."""
+        self._t0 = _timer()
+        self._enqueue = tracing.annotated("step.enqueue")
+        return self._enqueue
+
+    def wait(self, leaf):
+        """``step.wait``: ``block_until_ready`` on one output leaf,
+        where attribution is on (else the leaf is only kept for the
+        xprof window's last dispatch, and no record is made)."""
+        self._leaf = leaf
+        self._timed = enabled()
+        if self._timed:
+            self._wait = tracing.annotated("step.wait")
+            with self._wait:
+                _device_sync(leaf)
+
+    def __exit__(self, exc_type, exc, tb):
+        self._depth -= 1
+        if self._depth:
+            return False
+        _local.dispatch = None
+        if _gc["target"] is self:
+            _gc["target"] = None
+        self._span.set(program=self.program, ticks=self.ticks,
+                       gc_s=self.gc_s, gc_full=self.gc_full)
+        self._span.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            if self._timed and self._t0 is not None:
+                device_s = _timer() - self._t0
+                record_step(device_s, flops=self.flops,
+                            ticks=self.ticks, record={
+                                "ordinal": self.ordinal,
+                                "program": self.program,
+                                "ticks": self.ticks,
+                                "device_s": device_s,
+                                "serve_s": _seconds(self._serve),
+                                "upload_s": _seconds(self._upload),
+                                "enqueue_s": _seconds(self._enqueue),
+                                "wait_s": _seconds(self._wait),
+                                "gc_s": self.gc_s,
+                                "gc_full": int(self.gc_full)})
+            # After the record: closing the window reduces the trace
+            # (and may compile for the scope table), which is none of
+            # this dispatch's time.
+            _xprof_step_end(self._leaf)
+        self._leaf = None
+        return False
+
+
+def dispatch(program=None, ticks=None):
+    """The :class:`Dispatch` this thread is inside of, or a new one;
+    enter it with ``with``.  Arguments that are given are set on
+    it."""
+    step = getattr(_local, "dispatch", None)
+    if step is None:
+        step = Dispatch()
+    if program is not None:
+        step.program = program
+    if ticks is not None:
+        step.ticks = int(ticks)
+    return step
+
+
+def recent():
+    """The last :data:`RECENT` dispatch records, oldest first: dicts
+    of ``ordinal``, ``program``, ``ticks``, ``device_s`` (enqueue to
+    ready) and the host's split ``serve_s``, ``upload_s``,
+    ``enqueue_s``, ``wait_s``, ``gc_s`` (seconds of garbage
+    collections inside the dispatch) and ``gc_full`` (how many of
+    them were full ones)."""
+    with _lock:
+        return list(_recent)
+
+
+def record_step(device_seconds, flops=None, ticks=1, record=None):
+    """Folds one measured dispatch into the attribution state, the
+    metrics registry and, given the dispatch's ``record``, the deque
+    behind :func:`recent` — callable on its own so tests can drive
+    the MFU plumbing with a fake device timer."""
     device_seconds = max(float(device_seconds), 1e-9)
     mfu = None
     peak = peak_flops() if flops else None
@@ -244,11 +391,11 @@ def record_step(device_seconds, flops=None, ticks=1):
             prev = _state["mfu"]
             _state["mfu"] = mfu if prev is None else \
                 prev + EWMA_ALPHA * (mfu - prev)
-        if flops:
-            _state["flops"] = float(flops)
         _state["dispatches"] += 1
         _state["ticks"] += int(ticks)
         _state["device_s_total"] += device_seconds
+        if record is not None:
+            _recent.append(record)
         snap = dict(_state)
     reg = metrics.registry
     reg.counter("device.dispatches").inc()
@@ -256,8 +403,6 @@ def record_step(device_seconds, flops=None, ticks=1):
     reg.gauge("device.step_ms").set(round(snap["device_ms"], 3))
     if snap["mfu"] is not None:
         reg.gauge("device.mfu").set(round(snap["mfu"], 4))
-    if snap["flops"] is not None:
-        reg.gauge("device.flops_per_dispatch").set(snap["flops"])
     return snap
 
 
@@ -271,7 +416,6 @@ def note_optimizer(kind, state_bytes, shard_frac=1.0):
         _optimizer.update(kind=str(kind),
                           state_bytes=int(state_bytes),
                           shard_frac=float(shard_frac))
-    from . import metrics
     reg = metrics.registry
     labels = {"kind": str(kind)}
     reg.gauge("optimizer.state_bytes",
@@ -292,7 +436,6 @@ def note_moe(aux_loss, max_load_frac, n_experts, expert_shares=None):
         _moe.update(aux_loss=float(aux_loss),
                     max_load_frac=float(max_load_frac),
                     n_experts=int(n_experts))
-    from . import metrics
     reg = metrics.registry
     reg.gauge("moe.aux_loss").set(round(float(aux_loss), 6))
     reg.gauge("moe.max_load_frac").set(
@@ -322,12 +465,13 @@ def optimizer_summary():
         return dict(_optimizer)
 
 
-def estimate_flops(jitted, *args):
-    """Per-dispatch FLOP count from XLA's HLO cost analysis of the
-    jitted step (``Lowered.cost_analysis()`` — a re-trace, NOT a
-    recompile), or None when the backend/version can't say."""
+def lowered_flops(lowered):
+    """Per-dispatch FLOP count from XLA's HLO cost analysis of a
+    lowered step (``Lowered.cost_analysis()`` — no compile), or None
+    when the backend/version can't say.  XLA's count of the program
+    as written: a rematerialized block's second forward is in it."""
     try:
-        cost = jitted.lower(*args).cost_analysis()
+        cost = lowered.cost_analysis()
         if isinstance(cost, (list, tuple)):
             cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0))
@@ -355,8 +499,15 @@ def perf_summary():
         }
         if _state["mfu"] is not None:
             out["mfu"] = round(_state["mfu"], 4)
-        if _state["flops"] is not None:
-            out["flops_per_dispatch"] = _state["flops"]
+        if _recent:
+            # Where the host's time around the newest dispatch went:
+            # what an operator looks at when a dispatch runs late.
+            last = _recent[-1]
+            for field in ("serve_s", "upload_s", "enqueue_s",
+                          "wait_s", "gc_s"):
+                out["last_" + field[:-2] + "_ms"] = round(
+                    last[field] * 1e3, 3)
+            out["last_gc_full"] = last["gc_full"]
         if _optimizer["kind"] is not None:
             out["optimizer"] = _optimizer["kind"]
             out["optimizer_state_bytes"] = _optimizer["state_bytes"]

@@ -215,6 +215,62 @@ def span(name, **attrs):
     return Span(name, attrs)
 
 
+#: ``jax.profiler.TraceAnnotation``, once a span on the dispatch path
+#: has asked for it (this module imports without JAX).
+_TraceAnnotation = None
+
+
+def _trace_annotation():
+    global _TraceAnnotation
+    import jax
+    _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation
+
+
+class AnnotatedSpan(object):
+    """A span on the dispatch path (see :func:`annotated`)."""
+
+    __slots__ = ("seconds", "_annotation", "_span", "_t0")
+
+    def __init__(self, name, attrs):
+        self.seconds = None
+        self._annotation = (_TraceAnnotation or _trace_annotation())(
+            "veles." + name, **attrs)
+        self._annotation.__enter__()
+        self._span = Span(name, attrs) if _enabled else None
+        self._t0 = time.perf_counter()
+
+    def set(self, **attrs):
+        """Adds attributes to the ring's span (the profiler's
+        annotation took its own when it opened)."""
+        if self._span is not None:
+            self._span.set(**attrs)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self._t0
+        if self._span is not None:
+            self._span.finish()
+        self._annotation.__exit__(*exc)
+        return False
+
+
+def annotated(name, **attrs):
+    """A span on the fused step's dispatch path: besides the ring's
+    span (recorded only while tracing is on, like :func:`span`) it
+    ALWAYS opens ``jax.profiler.TraceAnnotation("veles.<name>")`` —
+    free while no profiler session runs — so an ``--xprof`` trace
+    holds the program's spans on the device trace's own clock, and
+    it leaves its duration in ``.seconds`` for attribution's
+    dispatch record.  Opens when called; use as ``with
+    tracing.annotated("step.enqueue") as span:``.  Not for the
+    per-frame wire path: that stays :func:`span`, a no-op when
+    off."""
+    return AnnotatedSpan(name, attrs)
+
+
 def begin(name, detached=False, **attrs):
     """Manually-closed span (pair with ``span.finish()``); returns
     the no-op singleton when disabled, so callers need no branch.

@@ -434,6 +434,7 @@ def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
         out_shape=(jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
                    jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32)),
         interpret=interpret,
+        name="flash_fwd",
     )(_off_operand(qoff), _off_operand(koff), qf, kf, vf)
     return out, lse.reshape(BH, Sq)
 
@@ -470,6 +471,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
         out_specs=_row_spec(bq, D, "blocked"),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(*offs, qf, kf, vf, dof, *rows)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
@@ -488,6 +490,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
         out_shape=(jax.ShapeDtypeStruct((BH, Sk, D), qf.dtype),
                    jax.ShapeDtypeStruct((BH, Sk, D), qf.dtype)),
         interpret=interpret,
+        name="flash_dkv",
     )(*offs, qf, kf, vf, dof, *tiles)
     return dq, dk, dv
 
@@ -786,6 +789,7 @@ def pallas_decode_attention(q, k, v, key_mask, block_k=None,
             jax.ShapeDtypeStruct((B, H, nk, Sq, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="flash_decode",
     )(*operands)
     # Cross-block lse merge (the flash-decode combine): weights are
     # exp(lse_i − lse_total) ≤ 1, void blocks weigh 0.

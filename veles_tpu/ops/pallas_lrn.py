@@ -114,6 +114,7 @@ def _call(kernel, args, c, out_dtype, interpret):
         in_specs=specs,
         out_specs=row_spec,
         interpret=interpret,
+        name="lrn",
     )(*args)
 
 

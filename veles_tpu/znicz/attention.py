@@ -116,7 +116,14 @@ def transformer_block_apply(params, x, n_heads, causal, cdt,
     expert FFN via ``mlp``), and the pipelined stack (the pipeline
     stages must be a pure (params, x) → y function).  ``mlp``
     receives the post-LN activations (B, S, E) and returns the FFN
-    output to be residual-added; None → the dense w1/w2 MLP."""
+    output to be residual-added; None → the dense w1/w2 MLP.
+
+    The inner ``jax.named_scope``s are the scope vocabulary
+    ``observability.programs`` reads back from the compiled program
+    (docs/observability.md): ``ln1``, ``attention`` (q, k, v → o:
+    scores, softmax, value matmul or the kernel — NOT the
+    projections), ``ln2``, ``mlp``."""
+    import jax
     import jax.numpy as jnp
     from ..ops import attention as A
     B, S, E = x.shape
@@ -125,7 +132,8 @@ def transformer_block_apply(params, x, n_heads, causal, cdt,
         return jnp.dot(a.astype(cdt), w.astype(cdt),
                        preferred_element_type=jnp.float32) + b
 
-    h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
+    with jax.named_scope("ln1"):
+        h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
     if "wqkv" in params:
         # Fast path stage (a): one (E, 3E) matmul; the head-major
         # column layout makes the q/k/v split a reshape + index on a
@@ -143,15 +151,17 @@ def transformer_block_apply(params, x, n_heads, causal, cdt,
             B, S, n_heads, -1)
     if attend is None:
         attend = functools.partial(A.attention, causal=causal)
-    attn = attend(q.astype(cdt), k.astype(cdt),
-                  v.astype(cdt)).reshape(B, S, E)
-    x = x + dot(attn, params["wo"], params["bo"])
-    h = _layer_norm(x, params["ln2_g"], params["ln2_b"])
-    if mlp is not None:
-        x = x + mlp(h)
-    else:
-        h = jnp.maximum(dot(h, params["w1"], params["b1"]), 0.0)
-        x = x + dot(h, params["w2"], params["b2"])
+    with jax.named_scope("attention"):
+        attn = attend(q.astype(cdt), k.astype(cdt), v.astype(cdt))
+    x = x + dot(attn.reshape(B, S, E), params["wo"], params["bo"])
+    with jax.named_scope("ln2"):
+        h = _layer_norm(x, params["ln2_g"], params["ln2_b"])
+    with jax.named_scope("mlp"):
+        if mlp is not None:
+            x = x + mlp(h)
+        else:
+            h = jnp.maximum(dot(h, params["w1"], params["b1"]), 0.0)
+            x = x + dot(h, params["w2"], params["b2"])
     return x.astype(jnp.float32)
 
 

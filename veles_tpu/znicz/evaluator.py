@@ -27,6 +27,8 @@ class EvaluatorBase(TracedUnit):
 
     hide_from_registry = True
 
+    scope_name = "evaluator"
+
     ACC_ERR, ACC_VALID, ACC_LOSS, ACC_TICKS = range(4)
 
     #: health_acc columns: per-class [non-finite ticks, grad-norm sum
