@@ -354,16 +354,23 @@ def test_pallas_gradients_match_blockwise():
             "pallas %s diverged from the reference" % name
 
 
-def test_pallas_minimal_geometry_parity_tier1():
-    """Tier-1 kernel gate at the contract's smallest geometry
-    (B=1, H=1, S=D=128 — one lane tile): forward and backward match
-    the blockwise reference in interpret mode."""
+@pytest.mark.parametrize("shape,block,kv_len", [
+    ((1, 128, 1, 128), None, None),
+    ((1, 256, 2, 64), 128, None),
+    ((1, 256, 2, 64), 128, 200),
+], ids=["lane-tile", "head64", "head64+kv_len"])
+def test_pallas_minimal_geometry_parity_tier1(shape, block, kv_len):
+    """Tier-1 kernel gate: forward and backward match the blockwise
+    reference in interpret mode at the contract's smallest geometry
+    (B=1, H=1, S=D=128 — one lane tile) and at head size 64 (half a
+    lane row: OPT-1.3B, GPT-2, BERT), there over two query and two
+    key blocks so the online softmax and both backward kernels walk
+    more than one tile, causal and with the ``kv_len`` bound."""
     import jax
     import jax.numpy as jnp
     from veles_tpu.ops import attention as A
     from veles_tpu.ops import pallas_attention as PA
-    q, k, v = (_rand((1, 128, 1, 128), seed=50 + i)
-               for i in range(3))
+    q, k, v = (_rand(shape, seed=50 + i) for i in range(3))
 
     def run(fn):
         def loss(q, k, v):
@@ -372,10 +379,10 @@ def test_pallas_minimal_geometry_parity_tier1():
         return out, jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     out_p, g_p = run(lambda q, k, v: PA.pallas_attention(
-        q, k, v, causal=True, operand_dtype=jnp.float32,
-        interpret=True))
+        q, k, v, causal=True, kv_len=kv_len, block_q=block,
+        block_k=block, operand_dtype=jnp.float32, interpret=True))
     out_r, g_r = run(lambda q, k, v: A.blockwise_attention(
-        q, k, v, block_size=128, causal=True))
+        q, k, v, block_size=128, causal=True, kv_len=kv_len))
     numpy.testing.assert_allclose(
         numpy.asarray(out_p), numpy.asarray(out_r), rtol=2e-5,
         atol=2e-5)
@@ -386,10 +393,23 @@ def test_pallas_minimal_geometry_parity_tier1():
 
 
 def test_pallas_supports_contract():
-    from veles_tpu.ops.pallas_attention import supports
+    from veles_tpu.ops.pallas_attention import (
+        supports, supports_decode, supports_ring)
     good = (2, 256, 2, 128)
     assert supports(good, good)
-    assert not supports((2, 256, 2, 64), (2, 256, 2, 64))  # D < lane
+    # Head size: 64, or a multiple of 128 up to 512 — nothing else.
+    half = (2, 256, 2, 64)
+    assert supports(half, half)
+    assert supports(half, half, kv_len=200)
+    assert supports((2, 256, 2, 512), (2, 256, 2, 512))
+    for D in (32, 96, 192, 640):
+        assert not supports((2, 256, 2, D), (2, 256, 2, D)), D
+    assert not supports((2, 100, 2, 64), (2, 100, 2, 64))  # S%128
+    assert not supports(half, (2, 512, 2, 64))  # cross-attention
+    # The ring and decode contracts stay lane-native: no cell runs
+    # them at head 64 yet.
+    assert not supports_ring(half, half)
+    assert not supports_decode((2, 1, 2, 64), half)
     assert not supports((2, 100, 2, 128), (2, 100, 2, 128))  # S%128
     assert not supports(good, (2, 512, 2, 128))  # cross-attention
     assert not supports((2, 256, 128), (2, 256, 128))  # rank
@@ -406,6 +426,21 @@ def test_kernel_not_selected_off_tpu():
     assert tpu_available() is False
     assert A._selects_pallas(PALLAS_GEOM, PALLAS_GEOM,
                              mode="pallas") is False
+
+
+@pytest.mark.parametrize("head,selected", [(64, True), (128, True),
+                                           (32, False), (96, False)])
+def test_selects_pallas_by_head_size_on_tpu(monkeypatch, head,
+                                            selected):
+    """On a TPU (the platform is stubbed here) the dispatch's one
+    decision follows ``supports()``: head 64 takes the kernel through
+    ``attention`` like head 128, other sub-lane heads the XLA
+    formulation — and "xla" pins the formulation whatever the head."""
+    from veles_tpu.ops import attention as A
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    shape = (4, 2048, 32, head)
+    assert A._selects_pallas(shape, shape) is selected
+    assert A._selects_pallas(shape, shape, mode="xla") is False
 
 
 def test_kernel_knob_dispatch(engine_knobs, monkeypatch):

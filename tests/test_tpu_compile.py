@@ -26,6 +26,8 @@ import jax.numpy as jnp
 
 #: The LM bench geometry (bench.py LM_*, chip_smoke.LM_GEOMETRY).
 LM = (8, 1024, 16, 128)  # B, S, H, D
+#: ``opt-1.3b.train`` (benchmark/): 4 x 2048 tokens, 32 heads of 64.
+HEAD_64 = (4, 2048, 32, 64)
 #: Decode: one new token per row over a 2048-slot gathered table.
 DECODE_L = 2048
 #: Ring shard: S=1024 over a 2-way seq axis.
@@ -65,17 +67,25 @@ def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("seq", [LM[1], 2048])
+@pytest.mark.parametrize("shape,inputs", [
+    (LM, "float32"),
+    ((LM[0], 2048) + LM[2:], "float32"),
+    (HEAD_64, "bfloat16"),
+], ids=["1024", "2048", "head64"])
 @pytest.mark.parametrize("operands", ["float32", "bfloat16"])
 @pytest.mark.parametrize("grad", [False, True],
                          ids=["fwd", "fwd+bwd"])
-def test_flash_attention_compiles(one_chip, grad, operands, seq):
-    """The training kernel at the LM geometry, and at ``MAX_SEQ`` —
-    the longest sequence ``supports()`` admits, where the backward's
-    dk/dv kernel (q, dO, lse, delta resident) must still fit VMEM."""
+def test_flash_attention_compiles(one_chip, grad, operands, shape,
+                                  inputs):
+    """The training kernel at the LM geometry; at ``MAX_SEQ`` — the
+    longest sequence ``supports()`` admits, where the backward's
+    dk/dv kernel (q, dO, lse, delta resident) must still fit VMEM;
+    and at ``opt-1.3b.train``'s own geometry, head size 64 (half a
+    lane row) with bf16 inputs as the block hands them over: a
+    (1, block, 64) block spans the array's whole last dimension,
+    which Mosaic takes as it is."""
     from veles_tpu.ops import pallas_attention as PA
-    shape = (LM[0], seq) + LM[2:]
-    assert seq <= PA.MAX_SEQ and PA.supports(shape, shape)
+    assert shape[1] <= PA.MAX_SEQ and PA.supports(shape, shape)
     od = jnp.dtype(operands).type
 
     def fwd(q, k, v):
@@ -84,9 +94,10 @@ def test_flash_attention_compiles(one_chip, grad, operands, seq):
 
     fn = fwd
     if grad:
-        fn = jax.grad(lambda q, k, v: fwd(q, k, v).sum(),
-                      argnums=(0, 1, 2))
-    x = _struct(shape, jnp.float32, one_chip)
+        fn = jax.grad(
+            lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))
+    x = _struct(shape, jnp.dtype(inputs), one_chip)
     text = _compiled_text(fn, x, x, x)
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
 

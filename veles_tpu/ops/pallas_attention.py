@@ -7,8 +7,9 @@ LOSING to XLA's fused full attention at the bench geometry
 not a 128-lane head dim at batch 8.  This kernel makes the opposite
 choices, for exactly one geometry family:
 
-  * D is the FULL lane width (D % 128 == 0) — one q/k/v row is one
-    (or a few) native (8, 128) tiles, no head-dim blocking ever;
+  * D is the FULL lane width (D % 128 == 0, up to 512) — one q/k/v
+    row is one (or a few) native (8, 128) tiles, no head-dim blocking
+    ever — or HALF of it, D = 64 (below);
   * k/v for a (batch·head) slice live WHOLE in VMEM (S ≤ 2048 ×
     D=128 × 4 B = 1 MB each — a fraction of 16 MB), so the only
     streaming dimension is the query block: grid (B·H, S/block_q),
@@ -21,6 +22,24 @@ choices, for exactly one geometry family:
     not): one kernel produces dq gridded over query blocks, one
     produces dk/dv gridded over key blocks — no atomics, no
     cross-block races.
+
+Head size 64 (OPT-125M…1.3B, GPT-2, BERT, T5; ISSUE 27) runs the
+SAME three training kernels with the same block shapes: a
+(1, block, 64) block spans the array's whole last dimension, which
+Mosaic takes as it is.  What a 64-wide block costs: the operands
+reach the call as ``bf16[B·H, S, 64]{T(8,128)(2,1)}``, each row
+padded to a 128-lane tile in HBM and in VMEM, so the DMA moves and
+VMEM holds what head 128 does (not twice the rows); a contraction
+over 64 (q·kᵀ) and an output 64 wide (p·v) each fill half of the
+128 × 128 MXU and take head 128's passes; the (block_q, block_k)
+score-tile work does not depend on D.  Measured in ``opt-1.3b.train``
+against ``opt-6.7b.train`` (B·H = 128, S = 2048 in both; one v5e, ms
+a call, PERF.md §5): ``flash_fwd`` 2.433 at head 64 / 2.421 at head
+128, ``flash_dq`` 2.524 / 2.524, ``flash_dkv`` 3.342 / 3.279 — half
+the matmul work in the same time, and a third of what XLA's
+materialised S × S scores took.  Only :func:`supports` admits head
+64; the ring and decode contracts stay lane-native until a cell runs
+them.
 
 Since ISSUE 13 the kernel is RESUMABLE and MULTI-CHIP-composable:
 
@@ -83,6 +102,8 @@ DEFAULT_BLOCK_K = 1024
 
 #: Geometry contract: lane-native head dim, tile-aligned sequence.
 LANE = 128
+#: The one head dim below a lane row that :func:`supports` admits.
+HALF_LANE = LANE // 2
 
 #: Upper sequence bound: the kernel keeps a (batch·head) slice's
 #: whole k/v in VMEM (S × D × 4 B each, double-buffered) next to its
@@ -112,15 +133,16 @@ def _pick_block(n, want):
 
 def supports(q_shape, k_shape, kv_len=None):
     """Whether the kernel's geometry contract holds: self-attention
-    ((B, S, H, D) with equal q/k sequence), D lane-native, S
-    tile-aligned.  ``kv_len`` (the blockwise padding contract) is
-    supported as a static mask bound."""
+    ((B, S, H, D) with equal q/k sequence), D lane-native (a multiple
+    of 128 up to 512) or 64, S tile-aligned up to ``MAX_SEQ``.
+    ``kv_len`` (the blockwise padding contract) is supported as a
+    static mask bound."""
     if len(q_shape) != 4 or len(k_shape) != 4:
         return False
     B, S, H, D = q_shape
     if k_shape[1] != S:
         return False
-    if D % LANE or D > 4 * LANE:
+    if D != HALF_LANE and (D % LANE or D > 4 * LANE):
         return False
     if S % LANE or S < LANE or S > MAX_SEQ:
         return False
