@@ -22,6 +22,9 @@ from veles_tpu.observability import (attribution, profile, programs,
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 UNITS = ("loader", "embedding", "block0", "block1", "head",
          "evaluator")
+#: What the OPT block opens (``programs.INNER_SCOPES`` holds the
+#: spec-built layers' scopes too).
+OPT_INNER_SCOPES = ("ln1", "attention", "ln2", "mlp")
 STEP_CHILDREN = ["loader.serve_block", "step.upload", "step.enqueue",
                  "step.wait"]
 
@@ -101,11 +104,43 @@ def test_block_program_names_every_unit_and_the_update(block_run):
     text = block_run["compiled"]
     for unit in UNITS:
         assert re.search(r"jvp\(%s\)" % unit, text), unit
-    for inner in programs.INNER_SCOPES:
+    for inner in OPT_INNER_SCOPES:
         assert "jvp(block1)/%s/" % inner in text, inner
     for scope in programs.STEP_SCOPES:
         assert "/%s/" % scope in text, scope
     assert "rematted_computation" in text
+
+
+def test_spec_built_layers_name_their_inner_scopes():
+    """Every inner scope the vocabulary holds beyond the OPT block's
+    is opened by a layer built from a spec (``samples/lfm2.py``:
+    convolution, attention with rotary positions, dense gated MLP,
+    experts), and the scope table places each in every phase."""
+    from veles_tpu.znicz.samples.lfm2 import lfm2_layers
+    root.common.engine.remat = True
+    launcher, wf = _tiny_lm(
+        ticks_per_dispatch=2,
+        layers=lfm2_layers(["conv", "full_attention", "conv"], n_heads=2,
+                           kv_heads=1, intermediate_size=32,
+                           moe_intermediate_size=16, n_experts=4,
+                           top_k=2, num_dense_layers=1, held=(0, 2)))
+    wf.loader.run()
+    launcher.stop()
+    placed = set(programs.scopes("block_step").values())
+    inner = {"block0": ("ln1", "shortconv", "ln2", "mlp"),
+             "block1": ("ln1", "rope", "attention", "ln2", "moe_route",
+                        "moe_dispatch", "moe_experts", "moe_combine"),
+             "block2": ("shortconv", "moe_experts")}
+    for unit, scopes in inner.items():
+        for scope in scopes:
+            for phase in ("forward", "recompute", "backward"):
+                if (scope, phase) == ("moe_route", "backward"):
+                    continue        # its ordering has no gradient
+                assert (phase, unit, scope) in placed, (phase, unit,
+                                                        scope)
+    assert set(s for scopes in inner.values() for s in scopes) == \
+        set(programs.INNER_SCOPES)
+    assert ("forward", "final_norm", None) in placed
 
 
 def test_scopes_answer_after_stop_and_array_deletion(block_run):

@@ -102,6 +102,46 @@ def test_flash_attention_compiles(one_chip, grad, operands, shape,
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
 
 
+@pytest.mark.parametrize("rows", [10240, 65536],
+                         ids=["common path", "every chunk"])
+def test_dropless_expert_share_compiles(one_chip, monkeypatch, rows):
+    """``lfm2-24b-a2b.train``'s expert layer, forward and gradients:
+    16,384 tokens, top 4 of 64, 8 experts of 2048 x 1536 held.  On a
+    TPU the grouped products are the megablox kernels (the selection
+    is by platform: steered here, the process sees a CPU); their
+    (512, 1024, 768 | 1024) tiles must fit VMEM.  ``rows``: the
+    grouped product alone at the common path's 10,240 rows, and the
+    whole layer, which holds the walk over all 65,536."""
+    from veles_tpu.ops import moe as M
+    monkeypatch.setattr(M, "tpu_available", lambda: True)
+    T, D, F, E, k, held = 16384, 2048, 1536, 64, 4, 8
+    assert M.dropless_rows(T, k, E, held) == (10240, 7)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    if rows == 10240:
+        def loss(lhs, rhs, sizes):
+            return M.grouped_dot(lhs, rhs, sizes).sum()
+        text = _compiled_text(
+            jax.grad(loss, argnums=(0, 1)),
+            _struct((rows, D), bf16, one_chip),
+            _struct((held, D, F), bf16, one_chip),
+            _struct((held,), jnp.int32, one_chip))
+        # the forward product is dead under a sum: dlhs (gmm), drhs (tgmm)
+        assert text.count("tpu_custom_call") >= 2
+        return
+
+    def loss(x, gate, bias, w1, w3, w2):
+        y, stats = M.moe_dropless(x, gate, bias, w1, w3, w2, top_k=k,
+                                  held=(0, held))
+        return (y * y).sum() + stats["landed"]
+    text = _compiled_text(
+        jax.grad(loss, argnums=(0, 1, 3, 4, 5)),
+        _struct((T, D), f32, one_chip), _struct((D, E), f32, one_chip),
+        _struct((E,), f32, one_chip), _struct((held, D, F), f32, one_chip),
+        _struct((held, D, F), f32, one_chip),
+        _struct((held, F, D), f32, one_chip))
+    assert text.count("tpu_custom_call") >= 9 and "conditional" in text
+
+
 @pytest.mark.parametrize("grad", [False, True],
                          ids=["fwd", "fwd+bwd"])
 def test_flash_chunk_compiles_at_ring_shard(one_chip, grad):

@@ -73,6 +73,12 @@ EXPORTABLE = {
     "lm_head": (),
 }
 
+#: Unit kinds that train but that no serving program knows yet: the
+#: spec-built LM layer (rotary positions, grouped keys/values, the
+#: short convolution's state, a held share of experts) and the RMS
+#: norm before its head.  Refused by name, not as "unknown".
+TRAIN_ONLY = ("lm_layer", "rms_norm")
+
 TANH_A, TANH_B = 1.7159, 0.6666
 
 
@@ -82,6 +88,11 @@ def _unit_entry(unit):
     from .mean_disp_normalizer import MeanDispNormalizer
     if isinstance(unit, MeanDispNormalizer):
         mapping = "mean_disp"
+    if mapping in TRAIN_ONLY:
+        raise Bug("unit %s: %s units train but are not served yet — "
+                  "export has no forward, cached or paged program "
+                  "for a layer built from a spec (docs/attention.md, "
+                  "\"Layers from a spec\")" % (unit.name, mapping))
     if mapping not in EXPORTABLE:
         raise Bug("unit %s (type %s, MAPPING %r) is not exportable" %
                   (unit.name, type(unit).__name__, mapping))

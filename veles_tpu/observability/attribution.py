@@ -447,6 +447,19 @@ def note_moe(aux_loss, max_load_frac, n_experts, expert_shares=None):
             round(float(share), 6))
 
 
+def note_moe_share(assignments_made, assignments_landed,
+                   max_load_frac):
+    """Publishes what DecisionGD read from the layers that hold a
+    share of a dropless expert layer: ``moe.assignments_made`` and
+    ``moe.assignments_landed`` (means a tick, summed over those
+    layers; landed / made is the share of the routing that fell on
+    the experts held here) and ``moe.max_load_frac`` (docs/moe.md)."""
+    reg = metrics.registry
+    reg.gauge("moe.assignments_made").set(float(assignments_made))
+    reg.gauge("moe.assignments_landed").set(float(assignments_landed))
+    reg.gauge("moe.max_load_frac").set(round(float(max_load_frac), 6))
+
+
 def moe_summary():
     """The last published MoE router stats, or None when no MoE
     epoch has completed."""

@@ -5,8 +5,9 @@ A device trace names an operation by its HLO instruction
 The program put a name there: ``StepCompiler`` traces every unit
 under ``jax.named_scope(unit.scope_name)``, the update rules under
 ``update``, the health sentinel under ``health``, and the block
-function opens ``ln1`` / ``attention`` / ``ln2`` / ``mlp`` inside a
-unit; JAX adds ``jvp(...)``, ``transpose(jvp(...))`` and the
+function opens ``ln1`` / ``attention`` / ``ln2`` / ``mlp`` (and
+``rope``, ``shortconv``, ``moe_*`` where a spec asks for them) inside
+a unit; JAX adds ``jvp(...)``, ``transpose(jvp(...))`` and the
 checkpoint's ``rematted_computation`` by itself.  This module keeps,
 per program name (``block_step``, ``train_step``, ``infer_step``),
 what it takes to read that back::
@@ -34,8 +35,11 @@ PHASES = ("forward", "recompute", "backward", "update")
 #: are phase ``update``.
 STEP_SCOPES = ("update", "health")
 
-#: Scopes a unit may open inside its own (``transformer_block_apply``).
-INNER_SCOPES = ("ln1", "attention", "ln2", "mlp")
+#: Scopes a unit may open inside its own (``znicz.attention.
+#: layer_apply``, ``ops.moe.moe_dropless``).
+INNER_SCOPES = ("ln1", "attention", "ln2", "mlp", "rope", "shortconv",
+                "moe_route", "moe_dispatch", "moe_experts",
+                "moe_combine")
 
 _lock = threading.Lock()
 _programs = {}

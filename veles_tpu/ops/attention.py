@@ -261,7 +261,22 @@ def attention(q, k, v, causal=False, precision=None, kernel=None):
     "pallas"/"auto" the call routes through the Pallas flash kernel
     when the platform supports the geometry (the kernel never
     materializes the S×S scores, so the precision knob is moot
-    there beyond the matmul operand dtype)."""
+    there beyond the matmul operand dtype).
+
+    Grouped-query attention: where k and v carry fewer heads than q
+    (H a multiple of theirs) each key/value head is broadcast over
+    its group of consecutive query heads first, so both formulations
+    see equal head counts and the flash kernels are selected by the
+    same shapes as for full multi-head attention; autodiff sums dk
+    and dv over the group."""
+    if k.shape[2] != q.shape[2]:
+        group = q.shape[2] // k.shape[2]
+        if group * k.shape[2] != q.shape[2] or v.shape[2] != k.shape[2]:
+            raise ValueError("%d query heads over %d key and %d value "
+                             "heads" % (q.shape[2], k.shape[2],
+                                        v.shape[2]))
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
     out = _try_pallas(q, k, v, causal, mode=kernel,
                       precision=precision)
     if out is not None:

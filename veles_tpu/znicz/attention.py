@@ -109,88 +109,236 @@ def _layer_norm(x, gamma, beta, eps=1e-5):
             beta).astype(x.dtype)
 
 
-def transformer_block_apply(params, x, n_heads, causal, cdt,
-                            attend=None, mlp=None):
-    """Pure pre-LN block: x + MHA(LN(x)), then + MLP(LN(·)).  Shared
-    by TransformerBlock.tforward, the MoE block (which passes its
-    expert FFN via ``mlp``), and the pipelined stack (the pipeline
-    stages must be a pure (params, x) → y function).  ``mlp``
-    receives the post-LN activations (B, S, E) and returns the FFN
-    output to be residual-added; None → the dense w1/w2 MLP.
+#: What a layer spec may say (:func:`layer_spec`).
+NORMS = ("layer", "rms")
+OPERATORS = ("attention", "shortconv")
+FFNS = ("relu-mlp", "gated-mlp", "experts")
+
+
+def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
+               n_heads=4, kv_heads=None, qk_norm=False,
+               rope_theta=None, bias=True, ffn_dim=None,
+               conv_kernel=3, n_experts=0, top_k=1, held=None,
+               norm_topk=True, routed_scaling=1.0, norm_eps=1e-5):
+    """One decoder layer, said as data: ``h + operator(norm(h))`` then
+    ``+ ffn(norm(·))``.  A plain dict (it rides snapshots).
+
+    ``norm``: ``layer`` (LayerNorm, gain and bias) | ``rms`` (gain).
+    ``operator``: ``attention`` — ``n_heads`` query heads over
+    ``kv_heads`` key/value heads (None: as many), ``qk_norm`` a
+    per-head RMS norm of q and k, ``rope_theta`` rotary positions
+    (None: the positions are the embedding's) — | ``shortconv``, the
+    gated short convolution of ``conv_kernel`` taps
+    (``ops/shortconv.py``).  ``ffn``: ``relu-mlp`` | ``gated-mlp``
+    (``silu(u W1) ⊙ (u W3)) W2``), both ``ffn_dim`` wide (None: 4 ×
+    the layer's width), | ``experts``: ``top_k`` of ``n_experts``
+    gated experts ``ffn_dim`` wide, dropless, of which this layer
+    HOLDS ``held = (first, count)`` (None: all) —
+    ``ops.moe.moe_dropless``.  ``bias``: whether the projections and
+    the MLP carry biases."""
+    if norm not in NORMS or operator not in OPERATORS or \
+            ffn not in FFNS:
+        raise ValueError("layer spec: norm %r of %s, operator %r of "
+                         "%s, ffn %r of %s" % (norm, NORMS, operator,
+                                               OPERATORS, ffn, FFNS))
+    kv_heads = kv_heads or n_heads
+    if n_heads % kv_heads:
+        raise ValueError("%d query heads over %d key/value heads"
+                         % (n_heads, kv_heads))
+    if ffn == "experts":
+        held = tuple(held or (0, n_experts))
+        if not (1 <= top_k <= n_experts and held[1] >= 1 and
+                0 <= held[0] <= n_experts - held[1]):
+            raise ValueError("top %d of %d experts, held %r"
+                             % (top_k, n_experts, held))
+    return {"norm": norm, "operator": operator, "ffn": ffn,
+            "n_heads": n_heads, "kv_heads": kv_heads,
+            "qk_norm": bool(qk_norm), "rope_theta": rope_theta,
+            "bias": bool(bias), "ffn_dim": ffn_dim,
+            "conv_kernel": conv_kernel, "n_experts": n_experts,
+            "top_k": top_k, "held": held, "norm_topk": bool(norm_topk),
+            "routed_scaling": routed_scaling, "norm_eps": norm_eps}
+
+
+def layer_param_shapes(spec, embed, fused_qkv=False):
+    """Parameter geometry of one layer — single source of truth for
+    TransformerBlock, the pipelined stack (which prepends a stage
+    dim) and LMLayer.  ``fused_qkv`` swaps the three (E, E)
+    projections for the single (E, 3E) fused weight.
+
+    Dict ORDER is load-bearing: initialization draws from the seeded
+    prng in iteration order, so the unfused OPT layout keeps the
+    historical ordering bit-for-bit (seeded trajectories — and the
+    tests pinning them — depend on it)."""
+    bias = spec["bias"]
+
+    def norm(name):
+        out = {name + "_g": (embed,)}
+        if spec["norm"] == "layer":
+            out[name + "_b"] = (embed,)
+        return out
+
+    shapes = norm("ln1")
+    if spec["operator"] == "attention":
+        head = embed // spec["n_heads"]
+        kv = spec["kv_heads"] * head
+        if fused_qkv:
+            shapes.update({"wqkv": (embed, 3 * embed),
+                           "wo": (embed, embed)})
+            if bias:
+                shapes.update({"bqkv": (3 * embed,), "bo": (embed,)})
+        else:
+            shapes.update({"wq": (embed, embed), "wk": (embed, kv),
+                           "wv": (embed, kv), "wo": (embed, embed)})
+            if bias:
+                shapes.update({"bq": (embed,), "bk": (kv,),
+                               "bv": (kv,), "bo": (embed,)})
+        if spec["qk_norm"]:
+            shapes.update({"q_norm_g": (head,), "k_norm_g": (head,)})
+    else:
+        shapes.update({"w_in": (embed, 3 * embed),
+                       "w_conv": (embed, spec["conv_kernel"]),
+                       "w_out": (embed, embed)})
+    shapes.update(norm("ln2"))
+    hidden = spec["ffn_dim"] or 4 * embed
+    if spec["ffn"] == "experts":
+        count = spec["held"][1]
+        shapes.update({"router": (embed, spec["n_experts"]),
+                       "w1": (count, embed, hidden),
+                       "w3": (count, embed, hidden),
+                       "w2": (count, hidden, embed)})
+    elif spec["ffn"] == "gated-mlp":
+        shapes.update({"w1": (embed, hidden), "w3": (embed, hidden),
+                       "w2": (hidden, embed)})
+    else:
+        shapes["w1"] = (embed, hidden)
+        if bias:
+            shapes["b1"] = (hidden,)
+        shapes["w2"] = (hidden, embed)
+        if bias:
+            shapes["b2"] = (embed,)
+    return shapes
+
+
+def layer_apply(spec, params, x, cdt, causal=True, attend=None,
+                mlp=None, buffers=None):
+    """Pure decoder layer from its spec (:func:`layer_spec`):
+    ``x + operator(norm(x))``, then ``+ ffn(norm(·))``.  Shared by
+    TransformerBlock.tforward, the MoE block (which passes its
+    expert FFN via ``mlp``), the pipelined stack (whose stages must
+    be a pure (params, x) → y function) and LMLayer.  ``mlp``
+    receives the post-norm activations (B, S, E) and returns the FFN
+    output to be residual-added; None → the spec's own.  ``buffers``
+    holds what the layer reads and no gradient reaches
+    (``expert_bias``).  Returns ``(y float32, stats)``; ``stats`` is
+    ``ops.moe.moe_dropless``'s for an ``experts`` layer, else None.
 
     The inner ``jax.named_scope``s are the scope vocabulary
     ``observability.programs`` reads back from the compiled program
     (docs/observability.md): ``ln1``, ``attention`` (q, k, v → o:
     scores, softmax, value matmul or the kernel — NOT the
-    projections), ``ln2``, ``mlp``."""
+    projections), ``rope`` (per-head norm and rotary positions),
+    ``shortconv`` (gates and taps, not the projections), ``ln2``,
+    ``mlp``, and the expert layer's ``moe_*``."""
     import jax
     import jax.numpy as jnp
     from ..ops import attention as A
+    from ..ops.rotary import rms_norm, rotary
     B, S, E = x.shape
 
-    def dot(a, w, b):
-        return jnp.dot(a.astype(cdt), w.astype(cdt),
-                       preferred_element_type=jnp.float32) + b
+    def dot(a, w, b=None):
+        y = jnp.dot(a.astype(cdt), w.astype(cdt),
+                    preferred_element_type=jnp.float32)
+        return y if b is None else y + b
 
-    with jax.named_scope("ln1"):
-        h = _layer_norm(x, params["ln1_g"], params["ln1_b"])
-    if "wqkv" in params:
-        # Fast path stage (a): one (E, 3E) matmul; the head-major
-        # column layout makes the q/k/v split a reshape + index on a
-        # replicated axis (tensor-parallel-safe, see
-        # fused_qkv_enabled).
-        qkv = dot(h, params["wqkv"], params["bqkv"]).reshape(
-            B, S, n_heads, 3, -1)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    def norm(name, x):
+        with jax.named_scope(name):
+            if spec["norm"] == "layer":
+                return _layer_norm(x, params[name + "_g"],
+                                   params[name + "_b"])
+            return rms_norm(x, params[name + "_g"], spec["norm_eps"])
+
+    h = norm("ln1", x)
+    if spec["operator"] == "shortconv":
+        from ..ops.shortconv import gated_short_conv
+        b_, c_, x_ = jnp.split(dot(h, params["w_in"]).astype(cdt), 3,
+                               axis=-1)
+        with jax.named_scope("shortconv"):
+            mixed = gated_short_conv(b_, c_, x_, params["w_conv"])
+        x = x + dot(mixed, params["w_out"])
     else:
-        q = dot(h, params["wq"], params["bq"]).reshape(
-            B, S, n_heads, -1)
-        k = dot(h, params["wk"], params["bk"]).reshape(
-            B, S, n_heads, -1)
-        v = dot(h, params["wv"], params["bv"]).reshape(
-            B, S, n_heads, -1)
-    if attend is None:
-        attend = functools.partial(A.attention, causal=causal)
-    with jax.named_scope("attention"):
-        attn = attend(q.astype(cdt), k.astype(cdt), v.astype(cdt))
-    x = x + dot(attn.reshape(B, S, E), params["wo"], params["bo"])
-    with jax.named_scope("ln2"):
-        h = _layer_norm(x, params["ln2_g"], params["ln2_b"])
-    with jax.named_scope("mlp"):
-        if mlp is not None:
-            x = x + mlp(h)
+        n_heads, kv_heads = spec["n_heads"], spec["kv_heads"]
+        if "wqkv" in params:
+            # Fast path stage (a): one (E, 3E) matmul; the head-major
+            # column layout makes the q/k/v split a reshape + index
+            # on a replicated axis (tensor-parallel-safe, see
+            # fused_qkv_enabled).
+            qkv = dot(h, params["wqkv"], params.get("bqkv")).reshape(
+                B, S, n_heads, 3, -1)
+            q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
         else:
-            h = jnp.maximum(dot(h, params["w1"], params["b1"]), 0.0)
-            x = x + dot(h, params["w2"], params["b2"])
-    return x.astype(jnp.float32)
+            q = dot(h, params["wq"], params.get("bq")).reshape(
+                B, S, n_heads, -1)
+            k = dot(h, params["wk"], params.get("bk")).reshape(
+                B, S, kv_heads, -1)
+            v = dot(h, params["wv"], params.get("bv")).reshape(
+                B, S, kv_heads, -1)
+        if spec["qk_norm"] or spec["rope_theta"]:
+            with jax.named_scope("rope"):
+                if spec["qk_norm"]:
+                    q = rms_norm(q, params["q_norm_g"],
+                                 spec["norm_eps"])
+                    k = rms_norm(k, params["k_norm_g"],
+                                 spec["norm_eps"])
+                if spec["rope_theta"]:
+                    q = rotary(q, spec["rope_theta"])
+                    k = rotary(k, spec["rope_theta"])
+        if attend is None:
+            attend = functools.partial(A.attention, causal=causal)
+        with jax.named_scope("attention"):
+            attn = attend(q.astype(cdt), k.astype(cdt), v.astype(cdt))
+        x = x + dot(attn.reshape(B, S, E), params["wo"],
+                    params.get("bo"))
+    h = norm("ln2", x)
+    stats = None
+    if mlp is None and spec["ffn"] == "experts":
+        from ..ops.moe import moe_dropless
+        y, stats = moe_dropless(
+            h.reshape(B * S, E), params["router"],
+            buffers["expert_bias"], params["w1"], params["w3"],
+            params["w2"], top_k=spec["top_k"], held=spec["held"],
+            norm_topk=spec["norm_topk"],
+            scaling=spec["routed_scaling"], cdt=cdt)
+        x = x + y.reshape(B, S, E)
+    else:
+        with jax.named_scope("mlp"):
+            if mlp is not None:
+                x = x + mlp(h)
+            elif spec["ffn"] == "gated-mlp":
+                h = jax.nn.silu(dot(h, params["w1"])) * \
+                    dot(h, params["w3"])
+                x = x + dot(h, params["w2"])
+            else:
+                h = jnp.maximum(
+                    dot(h, params["w1"], params.get("b1")), 0.0)
+                x = x + dot(h, params["w2"], params.get("b2"))
+    return x.astype(jnp.float32), stats
+
+
+def transformer_block_apply(params, x, n_heads, causal, cdt,
+                            attend=None, mlp=None):
+    """The OPT block — pre-LN LayerNorm, full multi-head attention,
+    ReLU MLP, biases — as :func:`layer_apply` traces it: what
+    TransformerBlock, the MoE block, the pipelined stack and the
+    serving forward all run."""
+    return layer_apply(layer_spec(n_heads=n_heads), params, x, cdt,
+                       causal=causal, attend=attend, mlp=mlp)[0]
 
 
 def _block_param_shapes(embed, hidden, fused_qkv=False):
-    """Parameter geometry of one dense pre-LN block — single source
-    of truth for TransformerBlock and the pipelined stack (which
-    prepends a stage dim).  ``fused_qkv`` swaps the three (E, E)
-    projections for the single (E, 3E) fused weight.
-
-    Dict ORDER is load-bearing: initialization draws from the seeded
-    prng in iteration order, so the unfused layout must keep the
-    historical ordering bit-for-bit (seeded trajectories — and the
-    tests pinning them — depend on it)."""
-    if fused_qkv:
-        proj = {"wqkv": (embed, 3 * embed), "wo": (embed, embed),
-                "bqkv": (3 * embed,), "bo": (embed,)}
-    else:
-        proj = {"wq": (embed, embed), "wk": (embed, embed),
-                "wv": (embed, embed), "wo": (embed, embed),
-                "bq": (embed,), "bk": (embed,), "bv": (embed,),
-                "bo": (embed,)}
-    shapes = {"ln1_g": (embed,), "ln1_b": (embed,)}
-    shapes.update(proj)
-    shapes.update({
-        "ln2_g": (embed,), "ln2_b": (embed,),
-        "w1": (embed, hidden), "b1": (hidden,),
-        "w2": (hidden, embed), "b2": (embed,),
-    })
-    return shapes
+    """Parameter geometry of one dense pre-LN OPT block."""
+    return layer_param_shapes(
+        layer_spec(ffn_dim=hidden), embed, fused_qkv=fused_qkv)
 
 
 class Embedding(ForwardBase):
@@ -204,6 +352,9 @@ class Embedding(ForwardBase):
         self.vocab_size = kwargs["vocab_size"]
         self.embed_dim = kwargs["embed_dim"]
         self.max_len = kwargs.get("max_len")
+        #: False: no learned position table (the layers carry rotary
+        #: positions, or none).
+        self.positions = kwargs.get("positions", True)
         self.include_bias = False
         self.pos = Vector()
 
@@ -225,7 +376,7 @@ class Embedding(ForwardBase):
             self.rand().fill_normal(w, stddev=stddev)
             self.weights.mem = w
             self.weights.initialize(self.device)
-        if not self.pos:
+        if not self.pos and getattr(self, "positions", True):
             p = numpy.zeros((max_len, self.embed_dim),
                             dtype=numpy.float32)
             self.rand().fill_normal(p, stddev=0.02)
@@ -239,7 +390,9 @@ class Embedding(ForwardBase):
         tokens = read(self.input).astype("int32")
         w = params["weights"]
         seq = tokens.shape[1]
-        out = w[tokens] + params["pos"][:seq]
+        out = w[tokens]
+        if "pos" in params:
+            out = out + params["pos"][:seq]
         write(self.output, out.astype(self.compute_dtype))
 
 
@@ -535,6 +688,164 @@ class MoETransformerBlock(TransformerBlock):
             return {"moe_acc": state["moe_acc"].at[cls].add(row)}
 
 
+class LMLayer(ForwardBase):
+    """One decoder layer built from a spec (:func:`layer_spec`): the
+    norm kind, the operator (attention with grouped keys/values,
+    per-head norm and rotary positions, or the gated short
+    convolution) and the FFN (ReLU MLP, gated MLP, or a held share
+    of a dropless expert layer) are data, not classes
+    (docs/attention.md, "Layers from a spec").
+
+    kwargs: ``spec`` (a :func:`layer_spec` dict, or its keyword
+    arguments as a dict); ``causal`` (default True); ``remat``; and
+    for an ``experts`` layer the loader's ``minibatch_class_vec`` /
+    ``minibatch_mask``, which bucket and gate its accumulator.
+
+    An ``experts`` layer carries two buffers beside its trainables:
+    ``expert_bias`` (n_experts,), the router's selection bias, which
+    no gradient reaches and no update rule touches (zeros unless
+    put), and ``moe_acc``, a (3 classes × 3 + count) on-device
+    accumulator — [assignments made, assignments landed on the held
+    experts, ticks, load of each held expert] — added to inside the
+    fused step and fetched by DecisionGD at epoch boundaries
+    (``moe.assignments_made`` / ``moe.assignments_landed`` /
+    ``moe.max_load_frac``, docs/moe.md)."""
+
+    MAPPING = "lm_layer"
+
+    def __init__(self, workflow, **kwargs):
+        super(LMLayer, self).__init__(workflow, **kwargs)
+        self.spec = layer_spec(**kwargs["spec"])
+        self.causal = kwargs.get("causal", True)
+        #: None → follow root.common.engine.remat; True/False forces.
+        self.remat = kwargs.get("remat")
+        self.minibatch_class_vec = kwargs.get("minibatch_class_vec")
+        self.minibatch_mask = kwargs.get("minibatch_mask")
+        self.params = {name: Vector()
+                       for name in layer_param_shapes(self.spec, 1)}
+        self.expert_bias = Vector()
+        self.moe_acc = Vector()
+
+    @property
+    def has_experts(self):
+        return self.spec["ffn"] == "experts"
+
+    @property
+    def trainables(self):
+        return {n: v for n, v in self.params.items() if v}
+
+    @property
+    def tstate(self):
+        if not self.has_experts:
+            return {}
+        return {"expert_bias": self.expert_bias,
+                "moe_acc": self.moe_acc}
+
+    def read_moe_share(self, cls):
+        """Host fetch of one class's row — [made, landed, ticks,
+        per-held-expert load] (rides the Decision's epoch-boundary
+        sync like the evaluator accumulators)."""
+        self.moe_acc.map_read()
+        return numpy.array(self.moe_acc.mem[cls])
+
+    def reset_moe_share(self, cls):
+        self.moe_acc.map_write()
+        self.moe_acc.mem[cls] = 0.0
+
+    def initialize(self, device=None, **kwargs):
+        super(LMLayer, self).initialize(device=device, **kwargs)
+        batch, seq, embed = self.input.shape
+        spec = self.spec
+        if embed % spec["n_heads"]:
+            raise ValueError("embed dim %d not divisible by %d heads"
+                             % (embed, spec["n_heads"]))
+        stddev = self.weights_stddev or (1.0 / numpy.sqrt(embed))
+        for name, shape in layer_param_shapes(spec, embed).items():
+            vec = self.params[name]
+            if vec:
+                continue
+            arr = numpy.zeros(shape, dtype=numpy.float32)
+            if name == "w_conv":
+                self.rand().fill_normal(
+                    arr, stddev=1.0 / numpy.sqrt(shape[-1]))
+            elif name.startswith("w") or name == "router":
+                self.rand().fill_normal(arr, stddev=stddev)
+            elif name.endswith("_g"):
+                arr[...] = 1.0
+            vec.mem = arr
+            vec.initialize(self.device)
+        if self.has_experts:
+            if not self.expert_bias:
+                self.expert_bias.mem = numpy.zeros(
+                    spec["n_experts"], dtype=numpy.float32)
+            if not self.moe_acc:
+                self.moe_acc.mem = numpy.zeros(
+                    (3, 3 + spec["held"][1]), dtype=numpy.float32)
+            self.expert_bias.initialize(self.device)
+            self.moe_acc.initialize(self.device)
+        self.output.mem = numpy.zeros((batch, seq, embed),
+                                      dtype=numpy.float32)
+        self.output.initialize(self.device)
+
+    def tforward(self, read, write, params, ctx, state=None):
+        import jax
+        import jax.numpy as jnp
+        x = read(self.input)
+        buffers = {"expert_bias": state["expert_bias"]} \
+            if self.has_experts else {}
+
+        def apply(p, b, h):
+            return layer_apply(self.spec, p, h, self.compute_dtype,
+                               causal=self.causal, buffers=b)
+
+        if remat_enabled(self.remat):
+            apply = jax.checkpoint(apply)
+        out, stats = apply(params, buffers, x)
+        write(self.output, out)
+        if stats is None:
+            return None
+        # Bucketed by the minibatch class (TRAIN when no loader
+        # link); padded block ticks (all-zero mask) are gated out
+        # whole, as the evaluator's epoch row gates its own.
+        cvec, mvec = self.minibatch_class_vec, self.minibatch_mask
+        cls = read(cvec).astype(jnp.int32) if cvec is not None \
+            else jnp.int32(2)
+        valid = (read(mvec).sum() > 0).astype(jnp.float32) \
+            if mvec is not None else jnp.float32(1.0)
+        row = jnp.concatenate([
+            jnp.stack([stats["made"], stats["landed"],
+                       jnp.float32(1.0)]), stats["load"]]) * valid
+        return {"moe_acc": state["moe_acc"].at[cls].add(row)}
+
+
+class RMSNorm(ForwardBase):
+    """``x · rsqrt(mean(x², -1) + eps) · gain`` over the last axis:
+    the norm a spec-built LM puts before its head (``weights`` is
+    the gain)."""
+
+    MAPPING = "rms_norm"
+
+    def __init__(self, workflow, **kwargs):
+        super(RMSNorm, self).__init__(workflow, **kwargs)
+        self.eps = kwargs.get("eps", 1e-5)
+        self.include_bias = False
+
+    def initialize(self, device=None, **kwargs):
+        super(RMSNorm, self).initialize(device=device, **kwargs)
+        if not self.weights:
+            self.weights.mem = numpy.ones(self.input.shape[-1],
+                                          dtype=numpy.float32)
+            self.weights.initialize(self.device)
+        self.output.mem = numpy.zeros(self.input.shape,
+                                      dtype=numpy.float32)
+        self.output.initialize(self.device)
+
+    def tforward(self, read, write, params, ctx, state=None):
+        from ..ops.rotary import rms_norm
+        write(self.output, rms_norm(read(self.input),
+                                    params["weights"], self.eps))
+
+
 class PipelinedTransformerStack(ForwardBase):
     """N homogeneous transformer blocks as ONE unit with stage-
     stacked parameters (leading ``n_blocks`` dim) — the pipeline-
@@ -769,3 +1080,11 @@ class GDMoETransformerBlock(GradientDescentBase):
 
 class GDLMHead(GradientDescentBase):
     MAPPING = "lm_head"
+
+
+class GDLMLayer(GradientDescentBase):
+    MAPPING = "lm_layer"
+
+
+class GDRMSNorm(GradientDescentBase):
+    MAPPING = "rms_norm"

@@ -119,6 +119,36 @@ class DecisionGD(DecisionBase, IResultProvider):
         self.epoch_grad_norm_max[cls] = float(health[2])
         self.evaluator.reset_health_acc(cls)
         self._fetch_moe_metrics(cls)
+        self._fetch_moe_share(cls)
+
+    def _fetch_moe_share(self, cls):
+        """Folds the accumulators of the layers that hold a SHARE of a
+        dropless expert layer (``LMLayer.moe_acc``) into the gauges
+        ``moe.assignments_made`` / ``moe.assignments_landed`` (means a
+        tick, summed over the layers) and ``moe.max_load_frac`` (the
+        fullest held expert's share of what landed on its layer)."""
+        layers = [u for u in getattr(self.workflow, "forwards", ())
+                  if getattr(u, "has_experts", False)]
+        made = landed = ticks = max_share = 0.0
+        for layer in layers:
+            row = layer.read_moe_share(cls)
+            layer.reset_moe_share(cls)
+            made += float(row[0])
+            landed += float(row[1])
+            ticks = max(ticks, float(row[2]))
+            max_share = max(max_share, float(row[3:].max()) /
+                            max(float(row[1]), 1.0))
+        if not ticks:
+            return
+        share = {"assignments_made": made / ticks,
+                 "assignments_landed": landed / ticks,
+                 "max_load_frac": max_share}
+        # beside what _fetch_moe_metrics wrote, where a workflow holds
+        # capacity blocks too
+        self.epoch_moe[cls] = dict(self.epoch_moe[cls] or {}, **share)
+        if cls == TRAIN:
+            from ..observability import attribution
+            attribution.note_moe_share(**share)
 
     def _fetch_moe_metrics(self, cls):
         """Folds every MoE block's router accumulator into the epoch

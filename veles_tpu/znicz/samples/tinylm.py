@@ -23,10 +23,11 @@ from ...loader.fullbatch import FullBatchLoader
 from ...plumbing import Repeater
 from ...accelerated_units import AcceleratedWorkflow
 from ..attention import (Embedding, EvaluatorLM, GDEmbedding,
-                         GDLMHead, GDMoETransformerBlock,
-                         GDPipelinedStack, GDTransformerBlock,
-                         LMHead, MoETransformerBlock,
-                         PipelinedTransformerStack,
+                         GDLMHead, GDLMLayer, GDMoETransformerBlock,
+                         GDPipelinedStack, GDRMSNorm,
+                         GDTransformerBlock, LMHead, LMLayer,
+                         MoETransformerBlock,
+                         PipelinedTransformerStack, RMSNorm,
                          TransformerBlock)
 from ..decision import DecisionGD
 
@@ -55,7 +56,13 @@ class FirstTokenLoader(FullBatchLoader):
 
 
 class TinyLMWorkflow(AcceleratedWorkflow):
-    """The LM training workflow (long-context capability sample)."""
+    """The LM training workflow (long-context capability sample).
+
+    ``layers``: a list of ``znicz.attention.layer_spec`` dicts builds
+    the body from them instead of OPT blocks — one ``LMLayer`` a spec
+    (``block<i>``), no learned positions in the embedding, an RMS
+    norm (``final_norm``) before the tied head: the shape of the
+    hybrid LMs (``samples/lfm2.py``)."""
 
     def __init__(self, workflow, vocab_size=16, seq_len=32,
                  embed_dim=32, n_heads=4, n_blocks=1,
@@ -65,7 +72,7 @@ class TinyLMWorkflow(AcceleratedWorkflow):
                  n_experts=0, expert_axis=None, top_k=None,
                  router_z_weight=None, pipelined=False,
                  stage_axis=None, n_microbatches=4, schedule=None,
-                 n_chunks=None, fused_qkv=None,
+                 n_chunks=None, fused_qkv=None, layers=None,
                  loader_cls=FirstTokenLoader, loader_config=None,
                  **kwargs):
         super(TinyLMWorkflow, self).__init__(workflow, **kwargs)
@@ -80,12 +87,16 @@ class TinyLMWorkflow(AcceleratedWorkflow):
 
         self.embedding = Embedding(
             self, vocab_size=vocab_size, embed_dim=embed_dim,
-            name="embedding")
+            positions=layers is None, name="embedding")
         self.embedding.link_from(self.loader)
         self.embedding.input = self.loader.minibatch_data
 
         self.forwards = [self.embedding]
         prev = self.embedding
+        if layers is not None and (pipelined or n_experts):
+            raise ValueError(
+                "layers=[specs] builds the whole body: it goes with "
+                "neither pipelined=True nor n_experts>0")
         if pipelined and n_experts:
             raise ValueError(
                 "pipelined=True with n_experts>0 is not supported — "
@@ -101,6 +112,23 @@ class TinyLMWorkflow(AcceleratedWorkflow):
             stack.input = prev.output
             self.forwards.append(stack)
             prev = stack
+            n_blocks = 0
+        for i, spec in enumerate(layers or ()):
+            block = LMLayer(
+                self, spec=spec,
+                minibatch_class_vec=self.loader.minibatch_class_vec,
+                minibatch_mask=self.loader.minibatch_mask,
+                name="block%d" % i)
+            block.link_from(prev)
+            block.input = prev.output
+            self.forwards.append(block)
+            prev = block
+        if layers is not None:
+            norm = RMSNorm(self, name="final_norm")
+            norm.link_from(prev)
+            norm.input = prev.output
+            self.forwards.append(norm)
+            prev = norm
             n_blocks = 0
         for i in range(n_blocks):
             if n_experts:
@@ -159,6 +187,7 @@ class TinyLMWorkflow(AcceleratedWorkflow):
                    TransformerBlock: GDTransformerBlock,
                    MoETransformerBlock: GDMoETransformerBlock,
                    PipelinedTransformerStack: GDPipelinedStack,
+                   LMLayer: GDLMLayer, RMSNorm: GDRMSNorm,
                    LMHead: GDLMHead}[type(unit)]
             gd = cls(self, target=unit, **gd_kw)
             gd.link_from(prev_gd)
