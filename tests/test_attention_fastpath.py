@@ -392,6 +392,157 @@ def test_pallas_minimal_geometry_parity_tier1(shape, block, kv_len):
             atol=2e-5)
 
 
+def _all_tiles(causal, *_origins_and_blocks):
+    """The schedule before the causal walk: every block of the range,
+    the causal mask built on each."""
+    return ((0, _origins_and_blocks[-1], causal),)
+
+
+@pytest.mark.parametrize("kv_len", [None, "last tile",
+                                    "earlier tile"])
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "full"])
+@pytest.mark.parametrize("S", [1024, 2048])
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 512),
+                                    (512, 1024)],
+                         ids=lambda b: "%dx%d" % b)
+def test_causal_tile_schedule_is_bit_equal_to_all_tiles(
+        monkeypatch, blocks, S, causal, kv_len):
+    """The kernels walk only the tiles a causal call can see and mask
+    only those the diagonal crosses; what they skip contributed
+    exact zeros, so ``out``, ``lse``, ``dq``, ``dk`` and ``dv`` are
+    the SAME BITS as the all-tiles schedule gives (the bounds forced
+    to the full range, the causal mask on every tile).  Interpret
+    mode, f32 operands, one head of 64; ``kv_len`` keeps its own
+    mask, in the last key tile and in an earlier one."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.ops import pallas_attention as PA
+    bq, bk = blocks
+    if kv_len is not None:
+        kv_len = S - 37 if kv_len == "last tile" else S // 2 - 37
+    q, k, v, do = (_rand((1, S, 1, 64), seed=70 + i)
+                   for i in range(4))
+    dlse = _rand((1, S, 1), seed=75)
+
+    def run():
+        (out, lse), vjp = jax.vjp(
+            lambda q, k, v: PA.flash_chunk(
+                q, k, v, causal=causal, kv_len=kv_len, block_q=bq,
+                block_k=bk, operand_dtype=jnp.float32,
+                interpret=True), q, k, v)
+        return (out, lse) + vjp((do, dlse))
+
+    got = run()
+    monkeypatch.setattr(PA, "_key_stretches", _all_tiles)
+    monkeypatch.setattr(PA, "_query_stretches", _all_tiles)
+    want = run()
+    for a, b, name in zip(got, want, ("out", "lse", "dq", "dk",
+                                      "dv")):
+        a, b = numpy.asarray(a), numpy.asarray(b)
+        assert numpy.isfinite(a).all(), name
+        assert numpy.array_equal(a, b), \
+            "%s: %d elements differ from the all-tiles schedule" % (
+                name, (a != b).sum())
+
+
+@pytest.mark.parametrize(
+    "S,bq,bk,qoff,koff,causal,visited,total", [
+        (2048, 512, 1024, 0, 0, True, 6, 8),
+        (2048, 512, 512, 0, 0, True, 10, 16),
+        (2048, 256, 256, 0, 0, True, 36, 64),
+        (2048, 256, 512, 0, 0, True, 20, 32),
+        (2048, 128, 128, 0, 0, True, 136, 256),
+        (2048, 1024, 1024, 0, 0, True, 3, 4),
+        (1024, 512, 1024, 0, 0, True, 2, 2),
+        (1024, 512, 512, 0, 0, True, 3, 4),
+        (2048, 512, 512, 0, 0, False, 16, 16),
+        # a ring step's chunk: wholly before the queries, across the
+        # diagonal one block up, wholly after them
+        (512, 128, 128, 512, 0, True, 16, 16),
+        (512, 128, 128, 0, 0, True, 10, 16),
+        (512, 128, 128, 64, 0, True, 13, 16),
+        (512, 128, 128, 0, 64, True, 10, 16),
+        (512, 128, 128, 0, 512, True, 0, 16),
+        (512, 128, 128, 0, 511, True, 1, 16),
+    ])
+def test_flash_tiles_table(S, bq, bk, qoff, koff, causal, visited,
+                           total):
+    """The pure tile count, pinned: what one (batch·head) slice's
+    forward visits and what its grid holds."""
+    from veles_tpu.ops.pallas_attention import flash_tiles
+    assert flash_tiles(S, S, bq, bk, qoff, koff, causal) == \
+        (visited, total)
+
+
+def test_flash_tiles_are_exactly_the_visible_ones():
+    """Against the definition, for every query and key block of
+    unequal extents and origins either side of the diagonal: a tile
+    is visited iff it holds a column ≤ one of its rows, masked iff
+    it also holds a column > one of its rows; the dk/dv kernel's
+    walk visits the same tiles from the other side."""
+    from veles_tpu.ops import pallas_attention as PA
+    bq, bk, nq, nk = 32, 64, 6, 5
+    for qoff in (0, 17, 64, 200, 400):
+        for koff in (0, 31, 64, 192, 330, 1000):
+            seen_q = set()
+            for i in range(nq):
+                r0 = qoff + i * bq
+                walked = {}
+                for lo, hi, diagonal in PA._key_stretches(
+                        True, r0, bq, koff, bk, nk):
+                    walked.update((j, diagonal)
+                                  for j in range(lo, hi))
+                for j in range(nk):
+                    c0 = koff + j * bk
+                    visible = c0 <= r0 + bq - 1
+                    crossed = visible and c0 + bk - 1 > r0
+                    assert (j in walked) == visible, (qoff, koff, i, j)
+                    if visible:
+                        assert walked[j] == crossed
+                        seen_q.add((i, j))
+            seen_k = set()
+            for j in range(nk):
+                for lo, hi, diagonal in PA._query_stretches(
+                        True, koff + j * bk, bk, qoff, bq, nq):
+                    for i in range(lo, hi):
+                        assert diagonal == (
+                            koff + (j + 1) * bk - 1 > qoff + i * bq)
+                        seen_k.add((i, j))
+            assert seen_k == seen_q
+            assert PA.flash_tiles(nq * bq, nk * bk, bq, bk, qoff,
+                                  koff) == (len(seen_q), nq * nk)
+
+
+def test_flash_tile_counters_after_a_trace():
+    """Each trace of ``pallas_attention`` counts one (batch·head)
+    slice's forward schedule into ``attention.flash.tiles_visited``
+    / ``.tiles_total`` — what the tile-count function says for the
+    call's geometry and the block shape it chose."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu import resilience
+    from veles_tpu.ops import pallas_attention as PA
+
+    def counts():
+        return tuple(resilience.stats.get("attention.flash.tiles_" + n)
+                     for n in ("visited", "total"))
+
+    x = jax.ShapeDtypeStruct((4, 2048, 32, 64), jnp.bfloat16)
+    for causal, kwargs in ((True, {}), (False, {}),
+                           (True, {"block_q": 512, "block_k": 512})):
+        was = counts()
+        jax.eval_shape(lambda q, k, v: PA.pallas_attention(
+            q, k, v, causal=causal, interpret=True, **kwargs),
+            x, x, x)
+        want = PA.flash_tiles(
+            2048, 2048, kwargs.get("block_q", PA.DEFAULT_BLOCK_Q),
+            kwargs.get("block_k", PA.DEFAULT_BLOCK_K), causal=causal)
+        assert tuple(b - a for a, b in zip(was, counts())) == want
+        assert want[0] < want[1] if causal else want[0] == want[1]
+    assert want == (10, 16)
+
+
 def test_pallas_supports_contract():
     from veles_tpu.ops.pallas_attention import (
         supports, supports_decode, supports_ring)

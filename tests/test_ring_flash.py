@@ -119,6 +119,143 @@ def test_merge_partials_handles_void_chunk():
                                   numpy.asarray(lse), atol=1e-6)
 
 
+# -- the causal tile schedule under traced offsets -----------------------
+
+#: Queries at global rows 28..43 against three 24-key chunks: columns
+#: 0..23 lie wholly before them, 24..47 cross the diagonal, 48..71
+#: lie wholly after.  Sq (16) ≠ Sk (24); 8 x 8 tiles, so a chunk is
+#: 2 x 3 of them.
+_Q_OFF, _SQ, _SK = 28, 16, 24
+_CHUNKS = {"before": 0, "across": 24, "after": 48}
+
+
+def _chunk_operands(seed=11):
+    q = _rand((2, _SQ, 2, 4), seed=seed)
+    k, v = (_rand((2, 3 * _SK, 2, 4), seed=seed + i) for i in (1, 2))
+    return q, k, v
+
+
+def _dense_partial(q, k, v, q_off, k_off):
+    """One chunk's partial by the definition: (out, lse) over the
+    columns a row may see; a row that sees none reads out 0."""
+    import jax
+    import jax.numpy as jnp
+    s = jnp.einsum("bqhd,bkhd->bqhk", q, k) / (q.shape[-1] ** 0.5)
+    rows = q_off + jnp.arange(q.shape[1])[:, None]
+    cols = k_off + jnp.arange(k.shape[1])[None, :]
+    mask = (rows >= cols)[None, :, None, :]
+    s = jnp.where(mask, s, -1e30)
+    p = jnp.where(mask, jax.nn.softmax(s, axis=-1), 0.0)
+    return (jnp.einsum("bqhk,bkhd->bqhd", p, v),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+def _jit_chunk():
+    """A fresh jitted chunk (a new trace each time: the schedule is
+    read when the kernels are traced) whose offsets are TRACED."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.ops import pallas_attention as PA
+
+    def chunk(q, k, v, q_off, k_off):
+        return PA.flash_chunk(q, k, v, causal=True, q_offset=q_off,
+                              k_offset=k_off, block_q=8, block_k=8,
+                              operand_dtype=jnp.float32,
+                              interpret=True)
+
+    def with_grads(q, k, v, do, dlse, q_off, k_off):
+        part, vjp = jax.vjp(
+            lambda q, k, v: chunk(q, k, v, q_off, k_off), q, k, v)
+        return part + vjp((do, dlse))
+
+    return jax.jit(with_grads)
+
+
+@pytest.mark.parametrize("where", list(_CHUNKS))
+def test_flash_chunk_walks_by_traced_offsets(monkeypatch, where):
+    """The kernels read their loop bounds from the TRACED origins: a
+    chunk wholly before the queries is walked whole and unmasked, one
+    across the diagonal up to it, one wholly after not at all — and
+    that last one still writes ``out`` 0, a finite ``lse`` ≈ −1e30
+    and zero gradients.  Each equals the dense partial, and is the
+    same bits as the all-tiles schedule."""
+    import jax.numpy as jnp
+    from veles_tpu.ops import pallas_attention as PA
+    q, k, v = _chunk_operands()
+    k_off = _CHUNKS[where]
+    kc, vc = (x[:, k_off:k_off + _SK] for x in (k, v))
+    do, dlse = _rand(q.shape, seed=21), _rand(q.shape[:3], seed=22)
+    offs = (jnp.float32(_Q_OFF), jnp.float32(k_off))
+    got = _jit_chunk()(q, kc, vc, do, dlse, *offs)
+    assert PA.flash_tiles(_SQ, _SK, 8, 8, _Q_OFF, k_off) == {
+        "before": (6, 6), "across": (5, 6), "after": (0, 6)}[where]
+    out, lse = _dense_partial(q, kc, vc, _Q_OFF, k_off)
+    numpy.testing.assert_allclose(numpy.asarray(got[0]),
+                                  numpy.asarray(out), rtol=2e-5,
+                                  atol=2e-5)
+    numpy.testing.assert_allclose(numpy.asarray(got[1]),
+                                  numpy.asarray(lse), rtol=2e-5,
+                                  atol=2e-5)
+    assert all(numpy.isfinite(numpy.asarray(x)).all() for x in got)
+    if where == "after":
+        assert float(jnp.abs(got[0]).max()) == 0.0
+        assert float(got[1].max()) < -1e29
+        for grad in got[2:]:
+            assert float(jnp.abs(grad).max()) == 0.0
+
+    def all_tiles(causal, *origins_and_blocks):
+        return ((0, origins_and_blocks[-1], causal),)
+
+    monkeypatch.setattr(PA, "_key_stretches", all_tiles)
+    monkeypatch.setattr(PA, "_query_stretches", all_tiles)
+    for a, b, name in zip(got, _jit_chunk()(q, kc, vc, do, dlse, *offs),
+                          ("out", "lse", "dq", "dk", "dv")):
+        assert numpy.array_equal(numpy.asarray(a), numpy.asarray(b)), \
+            name
+
+
+def test_merge_over_walked_chunks_equals_whole_attention():
+    """``merge_partials`` over the three chunks — one of them void —
+    is attention over all 72 keys, forward and gradients, with every
+    offset traced."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.ops import pallas_attention as PA
+    q, k, v = _chunk_operands(seed=31)
+    w = _rand(q.shape, seed=41)
+
+    @jax.jit
+    def merged(q, k, v, q_off, k_offs):
+        carry = None
+        for c in range(3):
+            carry = PA.flash_resume(
+                carry, q, k[:, c * _SK:(c + 1) * _SK],
+                v[:, c * _SK:(c + 1) * _SK], causal=True,
+                q_offset=q_off, k_offset=k_offs[c], block_q=8,
+                block_k=8, operand_dtype=jnp.float32, interpret=True)
+        return carry[0]
+
+    def whole(q, k, v):
+        return _dense_partial(q, k, v, _Q_OFF, 0)[0]
+
+    offs = (jnp.float32(_Q_OFF),
+            jnp.asarray(sorted(_CHUNKS.values()), jnp.float32))
+    numpy.testing.assert_allclose(
+        numpy.asarray(merged(q, k, v, *offs)),
+        numpy.asarray(whole(q, k, v)), rtol=2e-5, atol=2e-5)
+    gm = jax.grad(lambda *o: (merged(*o, *offs) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    gw = jax.grad(lambda *o: (whole(*o) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gm, gw, ("dq", "dk", "dv")):
+        numpy.testing.assert_allclose(
+            numpy.asarray(a), numpy.asarray(b), rtol=2e-4, atol=2e-5,
+            err_msg="merged %s diverged" % name)
+    # the keys no query may see get no gradient at all
+    assert float(jnp.abs(gm[1][:, 2 * _SK:]).max()) == 0.0
+    assert float(jnp.abs(gm[2][:, 2 * _SK:]).max()) == 0.0
+
+
 # -- ring-flash through shard_map ---------------------------------------
 
 

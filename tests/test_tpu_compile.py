@@ -28,6 +28,11 @@ import jax.numpy as jnp
 LM = (8, 1024, 16, 128)  # B, S, H, D
 #: ``opt-1.3b.train`` (benchmark/): 4 x 2048 tokens, 32 heads of 64.
 HEAD_64 = (4, 2048, 32, 64)
+#: ``opt-6.7b.train``: 4 x 2048 tokens, 32 heads of 128.
+OPT_6_7B = (4, 2048, 32, 128)
+#: ``lfm2-24b-a2b.train``: 8 x 2048 tokens, the 8 kv heads broadcast
+#: to the 32 query heads of 64 before the call.
+LFM2 = (8, 2048, 32, 64)
 #: Decode: one new token per row over a 2048-slot gathered table.
 DECODE_L = 2048
 #: Ring shard: S=1024 over a 2-way seq axis.
@@ -71,7 +76,9 @@ def _struct(shape, dtype, sharding):
     (LM, "float32"),
     ((LM[0], 2048) + LM[2:], "float32"),
     (HEAD_64, "bfloat16"),
-], ids=["1024", "2048", "head64"])
+    (OPT_6_7B, "bfloat16"),
+    (LFM2, "bfloat16"),
+], ids=["1024", "2048", "head64", "opt-6.7b", "lfm2"])
 @pytest.mark.parametrize("operands", ["float32", "bfloat16"])
 @pytest.mark.parametrize("grad", [False, True],
                          ids=["fwd", "fwd+bwd"])
@@ -83,7 +90,10 @@ def test_flash_attention_compiles(one_chip, grad, operands, shape,
     and at ``opt-1.3b.train``'s own geometry, head size 64 (half a
     lane row) with bf16 inputs as the block hands them over: a
     (1, block, 64) block spans the array's whole last dimension,
-    which Mosaic takes as it is."""
+    which Mosaic takes as it is; and at the other two cells' calls.
+    Every case is causal: the kernels' loop bounds are scalars
+    computed from the offset operands and ``program_id``, which
+    Mosaic must take as the bounds of an ``scf.for``."""
     from veles_tpu.ops import pallas_attention as PA
     assert shape[1] <= PA.MAX_SEQ and PA.supports(shape, shape)
     od = jnp.dtype(operands).type

@@ -14,6 +14,20 @@ choices, for exactly one geometry family:
     D=128 × 4 B = 1 MB each — a fraction of 16 MB), so the only
     streaming dimension is the query block: grid (B·H, S/block_q),
     with the key loop a ``fori_loop`` over VMEM, never HBM;
+  * a CAUSAL call walks only the score tiles it can see (ISSUE 30):
+    each query block's key loop ends at the last key block that
+    holds a column ≤ its last row, and the dk/dv kernel's query loop
+    starts at the first query block that holds a row ≥ its first
+    column — the tiles past the diagonal are exact zeros (``p = 0``,
+    the carry unchanged), so leaving them out gives the same bits.
+    The walk is two stretches: the tiles wholly below the diagonal,
+    with no causal mask at all, and the tiles the diagonal crosses.
+    The bounds are computed IN the kernel from the global origins
+    and ``program_id`` (:func:`_key_stretches`,
+    :func:`_query_stretches`) because the ring's origins are traced;
+    :func:`flash_tiles` is the same arithmetic on Python ints, and
+    what a trace counts.  ``kv_len`` keeps its own mask on every
+    tile and skips nothing;
   * matmul operands are bf16 (MXU-native), accumulation f32
     (``preferred_element_type``), the online-softmax statistics f32 —
     the same contract as ops/attention's bf16 mode;
@@ -34,12 +48,17 @@ over 64 (q·kᵀ) and an output 64 wide (p·v) each fill half of the
 128 × 128 MXU and take head 128's passes; the (block_q, block_k)
 score-tile work does not depend on D.  Measured in ``opt-1.3b.train``
 against ``opt-6.7b.train`` (B·H = 128, S = 2048 in both; one v5e, ms
-a call, PERF.md §5): ``flash_fwd`` 2.433 at head 64 / 2.421 at head
-128, ``flash_dq`` 2.524 / 2.524, ``flash_dkv`` 3.342 / 3.279 — half
-the matmul work in the same time, and a third of what XLA's
-materialised S × S scores took.  Only :func:`supports` admits head
-64; the ring and decode contracts stay lane-native until a cell runs
-them.
+a call, PERF.md §5): before the causal walk ``flash_fwd`` 2.433 at
+head 64 / 2.421 at head 128, ``flash_dq`` 2.524 / 2.524,
+``flash_dkv`` 3.342 / 3.279 — half the matmul work in the same time,
+and a third of what XLA's materialised S × S scores took; with it
+1.917 / 1.818 / 2.408 at head 128 and the four calls of a block 8.05
+against 8.06 ms.  The tile work is MXU-bound (a ``dot_general`` on
+f32 operands is ONE bf16 pass in Mosaic, so the operand dtype does
+not show either), and 0.67–0.92 ms of a call is operand DMA and
+epilogue that no tile accounts for (PERF.md §6, PR 30).  Only
+:func:`supports` admits head 64; the ring and decode contracts stay
+lane-native until a cell runs them.
 
 Since ISSUE 13 the kernel is RESUMABLE and MULTI-CHIP-composable:
 
@@ -90,15 +109,26 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import resilience
+
 NEG_INF = -1e30
 
-#: Default query block: 512 rows × 128 lanes × 4 B = 256 KB of q per
-#: grid step; the (block_q, S) score tile peaks at 512×2048×4 = 4 MB
-#: f32 — comfortable VMEM at both target sequence lengths.
+#: Default query block (the forward's and dq's grid step, the dk/dv
+#: kernel's loop step) and key block (their loop step, its grid
+#: step).  The pair sets how fine the causal walk is — a call at
+#: S = 2048 visits 10 of its 16 (512, 512) tiles, 6 of 8 at (512,
+#: 1024), 36 of 64 at (256, 256) — against what a loop step costs
+#: (the trip count is traced, so steps are not overlapped): a key
+#: block under 512 costs more than it skips, and a block of 1024
+#: skips too little.  Measured on one v5e,
+#: forward twice + dq + dk/dv, ms, bf16 inputs (PERF.md §6, PR 30):
+#: (512, 512) 8.75 at (B·H, S, D) = (128, 2048, 128), 10.95 at (128,
+#: 2048, 64), 22.69 at (256, 2048, 64) — the least of the sixteen
+#: pairs of 128…1024 at all three, in each kernel; (512, 1024) reads
+#: 9.71 / 11.92 / 24.49, (256, 256) 12.76 / 15.01 / 30.74.  One
+#: shape for every head size.
 DEFAULT_BLOCK_Q = 512
-#: Key loop step inside the kernel (VMEM-resident, so this only sets
-#: the score-tile width): S=1024 runs the loop once, S=2048 twice.
-DEFAULT_BLOCK_K = 1024
+DEFAULT_BLOCK_K = 512
 
 #: Geometry contract: lane-native head dim, tile-aligned sequence.
 LANE = 128
@@ -258,6 +288,71 @@ def _row_to_col(row):
     return jnp.broadcast_to(row, (LANE, n)).T[:, 0:1]
 
 
+def _clip(x, lo, hi):
+    """``min(max(x, lo), hi)`` for Python ints (the tile count, made
+    at trace time) and for traced scalars (the kernels) alike."""
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+def _key_stretches(causal, grows0, bq, gcols0, bk, nk):
+    """The key blocks ONE query block walks, as ``(lo, hi, diagonal)``
+    stretches of block indices, in ascending order.  The query block
+    holds global rows ``grows0 … grows0 + bq − 1``; key block ``j``
+    holds global columns ``gcols0 + j·bk … gcols0 + (j + 1)·bk − 1``.
+    A causal call visits the blocks that hold a column ≤ the last
+    row, ``j < cdiv(grows0 + bq − gcols0, bk)``: first those wholly
+    below the diagonal (last column ≤ first row: no causal mask),
+    then those the diagonal crosses.  The blocks past them are all
+    zeros and are not visited.  The origins may be traced scalars
+    (the ring's are data-dependent), so the bounds may be too."""
+    if not causal:
+        return ((0, nk, False),)
+    hi = _clip((grows0 + bq - gcols0 + bk - 1) // bk, 0, nk)
+    below = _clip((grows0 - gcols0 + 1) // bk, 0, hi)
+    return ((0, below, False), (below, hi, True))
+
+
+def _query_stretches(causal, gcols0, bk, grows0, bq, nq):
+    """The query blocks ONE key block walks (the dk/dv kernel's
+    loop): the mirror of :func:`_key_stretches`.  Query block ``i``
+    is visited if it holds a row ≥ the key block's first column,
+    ``i ≥ (gcols0 − grows0) // bq``: first the blocks the diagonal
+    crosses, then those wholly below it (first row ≥ last column)."""
+    if not causal:
+        return ((0, nq, False),)
+    lo = _clip((gcols0 - grows0) // bq, 0, nq)
+    below = _clip((gcols0 + bk - 1 - grows0 + bq - 1) // bq, lo, nq)
+    return ((lo, below, True), (below, nq, False))
+
+
+def _walk(stretches, tile, carry):
+    """Folds ``tile(index, carry, diagonal)`` over the stretches."""
+    for lo, hi, diagonal in stretches:
+        carry = jax.lax.fori_loop(
+            lo, hi, functools.partial(tile, diagonal=diagonal), carry)
+    return carry
+
+
+def flash_tiles(q_len, k_len, block_q, block_k, q_offset=0,
+                k_offset=0, causal=True):
+    """``(visited, total)`` score tiles of ONE (batch·head) slice of
+    a forward call: what the key loops of its ``q_len // block_q``
+    programs visit, and what the grid holds (the dq kernel walks the
+    same tiles, the dk/dv kernel the same set from the other side).
+    Static offsets only — it is the count a trace records
+    (``attention.flash.tiles_visited`` / ``.tiles_total``) and the
+    tests pin; the kernels get their bounds from the same
+    :func:`_key_stretches`."""
+    nq, nk = q_len // block_q, k_len // block_k
+    visited = sum(
+        hi - lo for i in range(nq) for lo, hi, _ in _key_stretches(
+            causal, q_offset + i * block_q, block_q, k_offset,
+            block_k, nk))
+    return visited, nq * nk
+
+
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
                 lse_ref, *, scale, causal, kv_len, block_k,
                 kv_seq_len, od):
@@ -268,15 +363,14 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0]
     grows0 = qoff_ref[0, 0] + i * bq
     koff = koff_ref[0, 0]
-    nk = kv_seq_len // block_k
 
-    def body(j, carry):
+    def tile(j, carry, diagonal):
         acc, m, l = carry
         kb = k_ref[0, pl.ds(j * block_k, block_k), :]
         vb = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot(q, kb, od, trans_b=True) * scale
         mask = _mask_tile(grows0, koff + j * block_k, j * block_k,
-                          bq, block_k, causal, kv_len)
+                          bq, block_k, diagonal, kv_len)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         bm = s.max(axis=1, keepdims=True)
@@ -289,8 +383,12 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
         acc = acc * corr + _dot(p, vb, od)
         return acc, new_m, new_l
 
-    acc, m, l = jax.lax.fori_loop(
-        0, nk, body,
+    # A chunk wholly after the queries is walked zero times and
+    # leaves the carry as it starts: out 0 and lse ≈ -1e30 below.
+    acc, m, l = _walk(
+        _key_stretches(causal, grows0, bq, koff, block_k,
+                       kv_seq_len // block_k),
+        tile,
         (jnp.zeros((bq, D), jnp.float32),
          jnp.full((bq, 1), NEG_INF, jnp.float32),
          jnp.zeros((bq, 1), jnp.float32)))
@@ -318,14 +416,13 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     delta = _row_to_col(delta_ref[0])
     grows0 = qoff_ref[0, 0] + i * bq
     koff = koff_ref[0, 0]
-    nk = kv_seq_len // block_k
 
-    def body(j, dq):
+    def tile(j, dq, diagonal):
         kb = k_ref[0, pl.ds(j * block_k, block_k), :]
         vb = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot(q, kb, od, trans_b=True) * scale
         mask = _mask_tile(grows0, koff + j * block_k, j * block_k,
-                          bq, block_k, causal, kv_len)
+                          bq, block_k, diagonal, kv_len)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse)
@@ -335,9 +432,10 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         ds = p * (dp - delta) * scale
         return dq + _dot(ds, kb, od)
 
-    dq_ref[0] = jax.lax.fori_loop(
-        0, nk, body,
-        jnp.zeros((bq, D), jnp.float32)).astype(dq_ref.dtype)
+    dq_ref[0] = _walk(
+        _key_stretches(causal, grows0, bq, koff, block_k,
+                       kv_seq_len // block_k),
+        tile, jnp.zeros((bq, D), jnp.float32)).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
@@ -352,9 +450,8 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     qoff = qoff_ref[0, 0]
     gcols0 = koff_ref[0, 0] + j * bk
     lcols0 = j * bk
-    nq = q_seq_len // block_q
 
-    def body(i, carry):
+    def tile(i, carry, diagonal):
         # Key-major tiles (bk, bq): lse/delta arrive as (1, bq) rows
         # and broadcast down the key axis as they are, and dk/dv
         # accumulate without transposing a score-sized tile.
@@ -365,7 +462,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         delta = delta_ref[0, pl.ds(i, 1), :]
         st = _dot(k, qb, od, trans_b=True) * scale
         mask = _mask_tile(qoff + i * block_q, gcols0, lcols0,
-                          block_q, bk, causal, kv_len,
+                          block_q, bk, diagonal, kv_len,
                           transposed=True)
         if mask is not None:
             st = jnp.where(mask, st, NEG_INF)
@@ -378,8 +475,10 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dk = dk + _dot(dst, qb, od)
         return dk, dv
 
-    dk, dv = jax.lax.fori_loop(
-        0, nq, body,
+    dk, dv = _walk(
+        _query_stretches(causal, gcols0, bk, qoff, block_q,
+                         q_seq_len // block_q),
+        tile,
         (jnp.zeros((bk, D), jnp.float32),
          jnp.zeros((bk, D), jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -602,6 +701,12 @@ def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
     if kv_len is not None:
         # Static by the supports() contract (isinstance(int) gate).
         kv_len = int(kv_len)  # lint-ok: VL101 static config int
+    # Each TRACE counts what one (batch·head) slice of the forward
+    # visits and what its grid holds, beside attention.kernel.pallas:
+    # how far the causal schedule engages at this call's geometry.
+    visited, total = flash_tiles(S, S, bq, bk, causal=bool(causal))
+    resilience.stats.incr("attention.flash.tiles_visited", visited)
+    resilience.stats.incr("attention.flash.tiles_total", total)
     zero = jnp.zeros((1, 1), jnp.float32)
     out, _lse = _flash_lse(q, k, v, zero, zero, bool(causal),
                            kv_len, bq, bk, od, bool(interpret))
