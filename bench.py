@@ -101,8 +101,8 @@ BENCH_FLAGS = ("--mlp", "--lm", "--lm-toy", "--serve", "--streamed",
                "--streamed-jpeg", "--attn-stages", "--attn-ladder",
                "--serve-streams", "--serve-seconds", "--spec",
                "--trace-out", "--optimizer", "--pp-schedule",
-               "--moe-topk", "--moe-experts", "--population",
-               "--population-members", "--population-epochs",
+               "--population", "--population-members",
+               "--population-epochs",
                "--population-ticks", "--elastic", "--elastic-jobs",
                "--replicas", "--fabric-disagg", "--kv-dtype",
                "--net-dtype")
@@ -901,8 +901,7 @@ def apply_attn_stages(stages):
 
 def build_lm(vocab=LM_VOCAB, seq=LM_SEQ, embed=LM_EMBED,
              heads=LM_HEADS, blocks=LM_BLOCKS, batch=LM_BATCH,
-             n_train=LM_N_TRAIN, n_valid=LM_N_VALID, remat=True,
-             n_experts=0, top_k=None):
+             n_train=LM_N_TRAIN, n_valid=LM_N_VALID, remat=True):
     import numpy
     import veles_tpu.prng as prng
     from veles_tpu.config import root
@@ -927,7 +926,6 @@ def build_lm(vocab=LM_VOCAB, seq=LM_SEQ, embed=LM_EMBED,
     wf = TinyLMWorkflow(
         launcher, vocab_size=vocab, seq_len=seq,
         embed_dim=embed, n_heads=heads, n_blocks=blocks,
-        n_experts=n_experts, top_k=top_k,
         minibatch_size=batch,
         ticks_per_dispatch=LM_TICKS_PER_DISPATCH,
         max_epochs=1000, loader_cls=SyntheticCorpus,
@@ -1294,56 +1292,6 @@ PP_MICRO = 8
 PP_LAYERS = 8
 PP_WIDTH = 256
 PP_MB_ROWS = 8
-
-
-def parse_moe(argv):
-    """``--moe-topk=K`` (and optional ``--moe-experts=E``, default 8
-    when top-k is set) → the LM bench builds its blocks as top-k MoE
-    instead of dense; returns (top_k, n_experts) — (None, 0) when
-    absent."""
-    topk = experts = None
-    for arg in argv:
-        if arg.startswith("--moe-topk="):
-            topk = int(arg.split("=", 1)[1])
-        if arg.startswith("--moe-experts="):
-            experts = int(arg.split("=", 1)[1])
-    if topk is None and experts is None:
-        return None, 0
-    return (topk or 1), (experts or 8)
-
-
-def moe_fields(wf, topk, n_experts):
-    """MoE columns for the bench JSON line: the configured routing
-    plus the run's accumulated router health (mean aux per tick and
-    the worst expert-load share) straight from the blocks'
-    ``moe_acc`` rows.  The bench loop drives only the loader, so the
-    Decision never drains the accumulator here — but if a future
-    bench mode runs the full workflow graph, fall back to the last
-    DecisionGD-published epoch stats (attribution.moe_summary)."""
-    blocks = [u for u in getattr(wf, "forwards", ())
-              if hasattr(u, "read_moe_acc")]
-    if not blocks:
-        return {}
-    from veles_tpu.loader.base import TRAIN
-    aux = ticks = 0.0
-    max_share = 0.0
-    for blk in blocks:
-        row = blk.read_moe_acc(TRAIN)
-        aux += float(row[0])
-        ticks += float(row[1])
-        load = row[2:]
-        max_share = max(max_share,
-                        float(load.max()) / max(float(load.sum()),
-                                                1.0))
-    if not ticks:
-        from veles_tpu.observability import attribution
-        summary = attribution.moe_summary()
-        if summary:
-            aux, ticks = summary["aux_loss"], 1.0
-            max_share = summary["max_load_frac"]
-    return {"moe_topk": topk, "moe_experts": n_experts,
-            "moe_aux_loss": round(aux / max(ticks, 1.0), 4),
-            "moe_max_load_frac": round(max_share, 4)}
 
 
 def pipeline_bench(argv):
@@ -1920,9 +1868,6 @@ def main():
         apply_attn_stages(stages)
         opt_name = parse_optimizer(sys.argv)
         net_dtype = parse_net_dtype(sys.argv)
-        # --moe-topk=K [--moe-experts=E]: the LM's blocks become
-        # top-k MoE; router health rides the JSON line (moe_fields).
-        moe_topk, moe_experts = parse_moe(sys.argv)
         # Both MFU figures on the JSON line — the analytic one below
         # and the live attribution gauge — divide by this device's
         # peak from the one device_kind table; resolved BEFORE the
@@ -1934,8 +1879,7 @@ def main():
                         blocks=LM_TOY_BLOCKS, batch=LM_TOY_BATCH,
                         n_train=LM_TOY_N_TRAIN,
                         n_valid=LM_TOY_N_VALID, remat=False)
-            _, wf = build_lm(n_experts=moe_experts, top_k=moe_topk,
-                             **geom)
+            _, wf = build_lm(**geom)
         else:
             # The default geometry lives ONCE in build_lm's defaults
             # (the LM_* constants); geom here only feeds the FLOP
@@ -1943,7 +1887,7 @@ def main():
             geom = dict(vocab=LM_VOCAB, seq=LM_SEQ, embed=LM_EMBED,
                         blocks=LM_BLOCKS, n_train=LM_N_TRAIN,
                         n_valid=LM_N_VALID)
-            _, wf = build_lm(n_experts=moe_experts, top_k=moe_topk)
+            _, wf = build_lm()
         ips = measure(wf, epochs=2)
         trace_out = next(
             (a.split("=", 1)[1] for a in sys.argv
@@ -1983,7 +1927,6 @@ def main():
             "trace_out": trace_out,
             **attribution_fields(),
             **optimizer_fields(wf, opt_name),
-            **moe_fields(wf, moe_topk, moe_experts),
             **net_dtype_fields(wf, net_dtype),
         }))
         return

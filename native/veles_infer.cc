@@ -356,8 +356,7 @@ struct AttnScratch {
 };
 
 /* One sample's pre-LN attention with residual:
- * res = x + attn(LN1(x)) @ wo + bo.  Shared by the dense and MoE
- * transformer blocks (the MoE block differs only in its FFN). */
+ * res = x + attn(LN1(x)) @ wo + bo. */
 void transformer_attention(const UnitDesc &u, const float *x,
                            float *res, int seq, int embed,
                            AttnScratch &ws) {
@@ -458,88 +457,6 @@ void run_transformer_block(const UnitDesc &u, const float *in,
                 embed);
     for (size_t i = 0; i < (size_t)seq * embed; ++i)
       y[i] += res[i];
-  }
-}
-
-/* Mixture-of-Experts transformer block: same pre-LN attention, but
- * the FFN routes each token to its argmax expert under a GShard
- * top-1 capacity limit computed over the WHOLE batch's tokens in
- * order (mirror of ops/moe.py moe_ffn: capacity = cf·T/E, overflow
- * tokens ride the residual with a zero FFN contribution). */
-void run_moe_transformer_block(const UnitDesc &u, const float *in,
-                               float *out, int batch, int seq,
-                               int embed) {
-  auto P = [&](const char *n) {
-    return u.params.at(n).data.data();
-  };
-  const int nexp = (int)u.cfgv("n_experts", 1);
-  /* Capacity truncation must match the Python paths BIT-wise: they
-   * compute int(cf * T / E) in double, and a float intermediate can
-   * round the quotient across the integer boundary. */
-  const double cf = u.cfgv("capacity_factor", 1.25);
-  const int hidden = (int)u.params.at("w1").dims[2];
-  const int T = batch * seq;
-  int capacity = (int)(cf * (double)T / (double)nexp);
-  if (capacity < 1) capacity = 1;
-  const float *router = P("router");
-  const float *w1 = P("w1"), *b1 = P("b1");
-  const float *w2 = P("w2"), *b2 = P("b2");
-  /* Phase 1: attention + residual + LN2 for every sample. */
-  std::vector<float> res((size_t)T * embed), ln2((size_t)T * embed);
-  AttnScratch ws(seq, embed);
-  for (int s = 0; s < batch; ++s) {
-    const float *x = in + (size_t)s * seq * embed;
-    transformer_attention(u, x,
-                          res.data() + (size_t)s * seq * embed, seq,
-                          embed, ws);
-  }
-  for (int t = 0; t < T; ++t)
-    layer_norm(res.data() + (size_t)t * embed, P("ln2_g"),
-               P("ln2_b"), ln2.data() + (size_t)t * embed, embed);
-  /* Phase 2: route + expert FFN per token, batch-major order. */
-  std::vector<int> count(nexp, 0);
-  std::vector<float> logits((size_t)nexp), h1((size_t)hidden);
-  for (int t = 0; t < T; ++t) {
-    const float *tok = ln2.data() + (size_t)t * embed;
-    float *y = out + (size_t)t * embed;
-    const float *r = res.data() + (size_t)t * embed;
-    for (int i = 0; i < embed; ++i) y[i] = r[i];
-    /* softmax over router logits; first maximal index wins (the
-     * argmax convention of numpy/jax). */
-    float mx = -1e30f;
-    for (int e = 0; e < nexp; ++e) {
-      double dot = 0.0;
-      for (int i = 0; i < embed; ++i)
-        dot += (double)tok[i] * router[(size_t)i * nexp + e];
-      logits[e] = (float)dot;
-      mx = std::max(mx, logits[e]);
-    }
-    double sum = 0.0;
-    for (int e = 0; e < nexp; ++e) {
-      logits[e] = std::exp(logits[e] - mx);
-      sum += logits[e];
-    }
-    int best = 0;
-    for (int e = 1; e < nexp; ++e)
-      if (logits[e] > logits[best]) best = e;
-    const float gate = (float)(logits[best] / sum);
-    if (count[best]++ >= capacity) continue;  /* dropped: residual */
-    const float *we1 = w1 + (size_t)best * embed * hidden;
-    const float *be1 = b1 + (size_t)best * hidden;
-    const float *we2 = w2 + (size_t)best * hidden * embed;
-    const float *be2 = b2 + (size_t)best * embed;
-    for (int j = 0; j < hidden; ++j) {
-      double acc = be1[j];
-      for (int i = 0; i < embed; ++i)
-        acc += (double)tok[i] * we1[(size_t)i * hidden + j];
-      h1[j] = std::max((float)acc, 0.0f);
-    }
-    for (int i = 0; i < embed; ++i) {
-      double acc = be2[i];
-      for (int j = 0; j < hidden; ++j)
-        acc += (double)h1[j] * we2[(size_t)j * embed + i];
-      y[i] += gate * (float)acc;
-    }
   }
 }
 
@@ -740,39 +657,6 @@ bool infer_shapes(VtModel *m) {
         return false;
       if (!checked_param(u, "b1", (size_t)hidden) ||
           !checked_param(u, "w2", (size_t)hidden * embed))
-        return false;
-      /* shape-preserving */
-    } else if (t == "moe_transformer_block") {
-      const int seq = si.h, embed = si.c;
-      const int heads = (int)u.cfgv("n_heads", 1);
-      const int nexp = (int)u.cfgv("n_experts");
-      if (si.w != 1 || seq <= 0 || embed <= 0 || heads <= 0 ||
-          embed % heads || nexp <= 0) {
-        set_error("unit " + u.name + ": bad MoE geometry");
-        return false;
-      }
-      auto w1it = u.params.find("w1");
-      if (w1it == u.params.end() || w1it->second.dims.size() != 3 ||
-          (int)w1it->second.dims[0] != nexp ||
-          (int)w1it->second.dims[1] != embed) {
-        set_error("unit " + u.name +
-                  ": w1 must be (n_experts, embed, hidden)");
-        return false;
-      }
-      const int hidden = (int)w1it->second.dims[2];
-      const size_t E = (size_t)embed;
-      const char *vecs_e[] = {"ln1_g", "ln1_b", "bo", "ln2_g",
-                              "ln2_b"};
-      for (const char *n : vecs_e)
-        if (!checked_param(u, n, E)) return false;
-      if (!check_attention_proj(u, E) ||
-          !checked_param(u, "wo", E * E))
-        return false;
-      if (!checked_param(u, "router", E * nexp) ||
-          !checked_param(u, "b1", (size_t)nexp * hidden) ||
-          !checked_param(u, "w2",
-                         (size_t)nexp * hidden * embed) ||
-          !checked_param(u, "b2", (size_t)nexp * embed))
         return false;
       /* shape-preserving */
     } else if (t == "lm_head") {
@@ -1006,9 +890,6 @@ int vt_forward(const VtModel *m, const float *input, int batch,
     } else if (t == "transformer_block") {
       run_transformer_block(u, a.data(), b.data(), batch, si.h,
                             si.c);
-    } else if (t == "moe_transformer_block") {
-      run_moe_transformer_block(u, a.data(), b.data(), batch, si.h,
-                                si.c);
     } else if (t == "lm_head") {
       /* per-position dense: rows = batch × seq */
       run_dense(u, a.data(), b.data(), batch * si.h, si.c, so.c);

@@ -92,6 +92,14 @@ def test_fully_masked_rows_are_finite():
     numpy.testing.assert_array_equal(out, numpy.zeros_like(out))
 
 
+def _expert_layers():
+    """A two-layer spec-built body whose FFNs are dropless expert
+    layers (top 2 of 4, all held)."""
+    from veles_tpu.znicz.attention import layer_spec
+    return [layer_spec(ffn="experts", n_experts=4, top_k=2)
+            for _ in range(2)]
+
+
 def _train_tinylm(**kwargs):
     from veles_tpu.znicz.samples.tinylm import TinyLMWorkflow
     kwargs.setdefault("max_epochs", 8)
@@ -127,17 +135,33 @@ def test_tinylm_sequence_parallel_training():
 @pytest.mark.parametrize("variant,kwargs,param,lead", [
     ("dense", {}, "wq", None),
     ("fused", {"fused_qkv": True}, "wqkv", None),
-    ("moe", {"n_experts": 4}, "w1", 4),
+    ("moe", {"layers": "experts"}, "w1", 4),
     ("pipelined", {"pipelined": True, "n_blocks": 4}, "w1", 4),
 ])
 def test_lm_snapshot_roundtrip(variant, kwargs, param, lead):
     """Every transformer variant pickles/resumes like every other
     workflow (params — incl. expert/stage-stacked — ride Vectors;
-    the ring/pipeline is rebuilt from config)."""
+    the ring/pipeline is rebuilt from config; an expert layer's
+    selection bias and accumulator ride too)."""
     import pickle
+    if kwargs.get("layers") == "experts":
+        kwargs = {"layers": _expert_layers()}
     launcher, wf = _train_tinylm(max_epochs=2, **kwargs)
+    if variant == "moe":
+        bias = wf.forwards[1].expert_bias
+        bias.map_write()
+        bias.mem[:] = [0.5, -0.5, 0.25, 0.0]
     launcher.run()
     wf2 = pickle.loads(pickle.dumps(wf))
+    if variant == "moe":
+        for name in ("expert_bias", "moe_acc"):
+            a, b = (getattr(w.forwards[1], name) for w in (wf, wf2))
+            a.map_read()
+            b.map_read()
+            numpy.testing.assert_array_equal(numpy.array(a.mem),
+                                             numpy.array(b.mem))
+        assert list(wf2.forwards[1].expert_bias.mem[:2]) == [0.5, -0.5]
+        assert wf2.forwards[1].spec == wf.forwards[1].spec
     a = wf.forwards[1].params[param]
     a.map_read()
     b = wf2.forwards[1].params[param]
@@ -146,63 +170,6 @@ def test_lm_snapshot_roundtrip(variant, kwargs, param, lead):
                                      numpy.array(b.mem))
     if lead is not None:
         assert b.shape[0] == lead  # expert/stage stacking survived
-
-
-# -- expert parallelism (MoE) -------------------------------------------
-
-
-def test_top1_routing_respects_capacity():
-    import jax.numpy as jnp
-    from veles_tpu.ops.moe import top1_routing
-    rng = numpy.random.RandomState(0)
-    # All tokens prefer expert 0 — capacity must cap its queue.
-    logits = numpy.zeros((16, 4), numpy.float32)
-    logits[:, 0] = 5.0
-    dispatch, combine, aux, load = top1_routing(
-        jnp.asarray(logits), capacity=4)
-    d = numpy.asarray(dispatch)
-    assert d[:, 0].sum() == 4.0          # only 4 tokens kept
-    assert d[:, 1:].sum() == 0.0
-    # Each occupied slot holds exactly one token.
-    assert (d.sum(axis=0) <= 1.0 + 1e-6).all()
-    assert float(load[0]) == 16.0        # pre-capacity load
-    assert float(aux) > 1.0              # imbalance penalized
-
-
-def test_moe_ffn_matches_dense_when_one_expert():
-    """With E=1 and ample capacity, MoE degenerates to the dense FFN
-    (gate=1) — pins the dispatch/combine algebra."""
-    import jax.numpy as jnp
-    from veles_tpu.ops.moe import moe_ffn
-    rng = numpy.random.RandomState(1)
-    T, D, H = 12, 8, 16
-    x = rng.normal(0, 1, (T, D)).astype(numpy.float32)
-    router = rng.normal(0, 1, (D, 1)).astype(numpy.float32)
-    w1 = rng.normal(0, 0.3, (1, D, H)).astype(numpy.float32)
-    b1 = rng.normal(0, 0.1, (1, H)).astype(numpy.float32)
-    w2 = rng.normal(0, 0.3, (1, H, D)).astype(numpy.float32)
-    b2 = rng.normal(0, 0.1, (1, D)).astype(numpy.float32)
-    y, aux, load = moe_ffn(jnp.asarray(x), router, w1, b1, w2, b2,
-                           capacity_factor=2.0)
-    want = numpy.maximum(x @ w1[0] + b1[0], 0.0) @ w2[0] + b2[0]
-    numpy.testing.assert_allclose(numpy.asarray(y), want, rtol=1e-4,
-                                  atol=1e-5)
-    assert float(load[0]) == T
-
-
-def test_tinylm_moe_expert_parallel_training():
-    """dp(2) × ep(4): the MoE variant trains to the gate with expert
-    params sharded one-expert-per-device."""
-    from veles_tpu.parallel import apply_dp_ep_sharding
-    launcher, wf = _train_tinylm(n_experts=4, learning_rate=0.02,
-                                 max_epochs=10)
-    mesh = make_mesh(axes={"data": 2, "expert": 4})
-    apply_dp_ep_sharding(wf, mesh)
-    assert wf._parallel_style_[0] == "dp_ep"
-    block = wf.forwards[1]
-    assert block.params["w1"].sharding.spec[0] == "expert"
-    launcher.run()
-    assert wf.decision.min_validation_err < 0.1
 
 
 # -- pipeline parallelism -----------------------------------------------
@@ -331,9 +298,12 @@ def test_pipelined_stack_falls_back_when_indivisible():
 
 
 def test_tinylm_rejects_pipelined_moe():
+    """The pipelined stack holds OPT blocks only: a spec-built body
+    (here expert layers) does not go with it."""
     from veles_tpu.znicz.samples.tinylm import TinyLMWorkflow
-    with pytest.raises(ValueError):
-        TinyLMWorkflow(Launcher(), pipelined=True, n_experts=4)
+    with pytest.raises(ValueError, match="pipelined"):
+        TinyLMWorkflow(Launcher(), pipelined=True,
+                       layers=_expert_layers())
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -475,11 +445,16 @@ def test_lm_variant_snapshot_roundtrip(variant):
     """MoE and pipelined LM variants pickle/resume like every other
     workflow (expert/stage-stacked params ride Vectors)."""
     import pickle
-    kwargs = {"n_experts": 4} if variant == "moe" else \
+    kwargs = {"layers": _expert_layers()} if variant == "moe" else \
         {"pipelined": True, "n_blocks": 4}
     launcher, wf = _train_tinylm(max_epochs=2, **kwargs)
     launcher.run()
     wf2 = pickle.loads(pickle.dumps(wf))
+    if variant == "moe":
+        layer = wf2.forwards[1]
+        assert layer.has_experts and layer.expert_bias.shape == (4,)
+        # made, landed, ticks + the load of each of the 4 held
+        assert layer.moe_acc.shape == (3, 7)
     name = "w1"
     a = wf.forwards[1].params[name]
     a.map_read()
@@ -556,30 +531,6 @@ def test_lm_elastic_rebuild_on_chip_loss():
     assert wf.decision.min_validation_err < 0.05
     some_param = wf.forwards[1].params["wq"]
     assert len(some_param.devmem.sharding.device_set) == 4
-
-
-def test_moe_capacity_one_drops_overflow_to_residual():
-    """capacity=1 with every token preferring one expert: exactly one
-    token computes, the rest emit zeros (the residual path carries
-    them) — the documented top-1 overflow behavior."""
-    import jax.numpy as jnp
-    from veles_tpu.ops.moe import moe_ffn
-    rng = numpy.random.RandomState(0)
-    T, D, H = 8, 4, 8
-    x = rng.normal(0, 1, (T, D)).astype(numpy.float32)
-    router = numpy.zeros((D, 2), numpy.float32)
-    router[0, 0] = 100.0  # everyone routes to expert 0
-    x[:, 0] = 1.0
-    w1 = rng.normal(0, 0.3, (2, D, H)).astype(numpy.float32)
-    b1 = numpy.zeros((2, H), numpy.float32)
-    w2 = rng.normal(0, 0.3, (2, H, D)).astype(numpy.float32)
-    b2 = numpy.zeros((2, D), numpy.float32)
-    y, aux, load = moe_ffn(jnp.asarray(x), router, w1, b1, w2, b2,
-                           capacity_factor=0.25)  # cap = 0.25*8/2 = 1
-    y = numpy.asarray(y)
-    nonzero_rows = (numpy.abs(y).sum(axis=1) > 1e-6).sum()
-    assert nonzero_rows == 1  # exactly capacity tokens computed
-    assert float(load[0]) == T  # pre-capacity demand recorded
 
 
 def test_gpipe_single_stage_degenerates_to_plain_apply():

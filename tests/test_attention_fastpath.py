@@ -89,12 +89,17 @@ def test_fused_layout_is_head_major():
     assert (per_head[:, :, 2, :] == 3.0).all()
 
 
-def test_qkv_param_names_rewrite():
-    from veles_tpu.znicz.attention import qkv_param_names
-    names = ("ln1_g", "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo")
-    assert qkv_param_names(names, False) == names
-    assert qkv_param_names(names, True) == \
-        ("ln1_g", "wqkv", "wo", "bqkv", "bo")
+def test_fused_layout_rewrites_the_leaf_names():
+    """One source of the leaf names, in the order initialization
+    draws them: the fused layout puts wqkv / bqkv where wq / bq
+    stood and leaves the rest where it was."""
+    from veles_tpu.znicz.attention import (TransformerBlock,
+                                           _block_param_shapes)
+    assert tuple(_block_param_shapes(8, 32)) == \
+        TransformerBlock.PARAM_NAMES
+    assert tuple(_block_param_shapes(8, 32, fused_qkv=True)) == (
+        "ln1_g", "ln1_b", "wqkv", "wo", "bqkv", "bo",
+        "ln2_g", "ln2_b", "w1", "b1", "w2", "b2")
 
 
 def test_fused_block_apply_matches_unfused():
@@ -573,10 +578,21 @@ def test_kernel_not_selected_off_tpu():
     kernel is never selected, whatever the knob and however well the
     geometry fits — the XLA formulation is what runs."""
     from veles_tpu.ops import attention as A
-    from veles_tpu.ops.pallas_lrn import tpu_available
+    from veles_tpu.backends import tpu_available
     assert tpu_available() is False
     assert A._selects_pallas(PALLAS_GEOM, PALLAS_GEOM,
                              mode="pallas") is False
+
+
+def test_platform_check_has_one_home():
+    """Every kernel dispatch asks ``backends.tpu_available``: each
+    module that selects a kernel holds that one function under the
+    name the tests steer (``monkeypatch.setattr(A, "tpu_available",
+    …)``), and none keeps a copy of its own."""
+    from veles_tpu import backends
+    from veles_tpu.ops import attention as A, moe as M, pallas_lrn as L
+    for module in (A, M, L):
+        assert module.tpu_available is backends.tpu_available
 
 
 @pytest.mark.parametrize("head,selected", [(64, True), (128, True),

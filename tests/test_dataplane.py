@@ -728,7 +728,11 @@ def _loopback_run(seed, epochs, legacy, job_ticks=1):
         assert not server.is_running
         # Departed workers stay reportable: every worker has said bye
         # by now, yet the exit throughput report must still see them.
-        assert len(server.all_slaves) == 2
+        # Counted by the sessions that trained: at an orderly finish
+        # only the handler that sees it says bye, the other worker
+        # redials and is a third session that is handed nothing.
+        assert sum(1 for d in server.all_slaves.values()
+                   if d.jobs_done) == 2
         assert sum(d.jobs_done
                    for d in server.all_slaves.values()) == \
             sum(c.jobs_done for c in clients)

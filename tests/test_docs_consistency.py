@@ -153,3 +153,22 @@ def test_heartbeat_sections_match_dashboard_rows():
         assert 'info.get("%s"' % section in src, (
             "heartbeat section %r is scraped on /metrics but never "
             "rendered by render_page" % section)
+
+
+def test_cli_reference_lists_what_the_parser_has():
+    """``docs/cli.md`` is generated from the aggregated parser: its
+    rows are the parser's options, no more (a flag that was removed —
+    ``--moe-topk``, ``--moe-router-z`` with the capacity-routed
+    expert block — is gone from both) and no fewer."""
+    from veles_tpu.cmdline import init_argparser
+    parser = init_argparser(prog="veles_tpu")
+    options = {o for action in parser._actions
+               for o in action.option_strings if o.startswith("--")}
+    assert not {"--moe-topk", "--moe-router-z"} & options
+    with open(os.path.join(REPO, "docs", "cli.md")) as fin:
+        rows = [line.split("|")[1] for line in fin
+                if line.startswith("| `")]
+    documented = {flag for row in rows
+                  for flag in _FLAG_RE.findall(row)}
+    assert documented == options - {"--help"}, (
+        sorted(documented ^ (options - {"--help"})))

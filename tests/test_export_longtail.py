@@ -194,35 +194,3 @@ def test_lm_export_clamps_oov_tokens(lm_artifact):
     numpy.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3)
     numpy.testing.assert_allclose(c, a.reshape(1, -1), rtol=1e-4,
                                   atol=1e-4)
-
-
-def test_moe_lm_export_all_paths_agree(tmp_path):
-    """Mixture-of-Experts LM artifact: numpy mirror == jitted jax
-    chain == native C++ runtime (the routing — argmax expert with
-    batch-cumulative capacity — must agree BIT-wise across runtimes
-    or outputs diverge sharply), and the deployed model still solves
-    its task."""
-    from veles_tpu.znicz.samples.tinylm import TinyLMWorkflow
-    prng.reset()
-    prng.get(0).seed(3)
-    launcher = Launcher()
-    wf = TinyLMWorkflow(launcher, n_experts=4, max_epochs=8)
-    launcher.initialize()
-    launcher.run()
-    assert wf.decision.min_validation_err < 0.05
-    path = str(tmp_path / "moe.veles.tgz")
-    export_workflow(wf, path)
-    model = ExportedModel(path)
-    assert [u["type"] for u in model.units] == \
-        ["embedding", "moe_transformer_block", "lm_head"]
-    x = numpy.random.RandomState(0).randint(
-        0, 16, (6, 32)).astype(numpy.float32)
-    a = model.forward_numpy(x)
-    b = numpy.asarray(model.forward(x))
-    numpy.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3)
-    nat = NativeModel(path)
-    c = nat.forward(x)
-    numpy.testing.assert_allclose(c, a.reshape(6, -1), rtol=1e-3,
-                                  atol=1e-3)
-    pred = numpy.argmax(a, -1)
-    assert (pred == x[:, :1].astype(int)).mean() == 1.0

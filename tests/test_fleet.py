@@ -117,6 +117,18 @@ def test_least_loaded_stable_ties():
 # -- preemption is a drain, not a crash (real sockets) ---------------------
 
 
+def _sessions_that_worked(server):
+    """How many of the sessions the fleet admitted did a job — after
+    checking that every join the ledger minted IS an admitted session.
+    At an orderly finish a worker whose socket closes before its bye
+    redials, and now and then gets in before the port shuts: one more
+    join, handed nothing.  So a test counts the sessions that worked,
+    not the joins."""
+    sessions = server.all_slaves
+    assert server.fleet.snapshot()["joins"] == len(sessions)
+    return sum(1 for desc in sessions.values() if desc.jobs_done)
+
+
 def test_preempt_retires_clean_goodbye_not_drop():
     """Deterministic ``worker.preempt`` chaos: the noticed worker
     finishes its in-flight job, ships the update, says bye, and the
@@ -128,12 +140,16 @@ def test_preempt_retires_clean_goodbye_not_drop():
     addr = "127.0.0.1:%d" % server.port
     injector = FaultInjector("worker.preempt@job:2")
     preempted, t1, _ = _start_client(addr, injector=injector)
+    # The survivor dials only once the noticed worker is gone: two
+    # workers racing for eight jobs can leave the noticed one fewer
+    # than the two its notice waits for, and then nothing fires.
+    t1.join(timeout=10)
+    assert not t1.is_alive(), "preempted worker failed to exit"
+    _await_retires(1)  # ...and its handler on the server has too
     _survivor, t2, _ = _start_client(addr)
     server.wait(timeout=30)
     assert not server.is_running
-    t1.join(timeout=10)
     t2.join(timeout=10)
-    assert not t1.is_alive(), "preempted worker failed to exit"
     assert injector.fired == [("worker.preempt", "job", 2)]
     assert len(master.done) == 8
     assert all(v == 1 for v in master.done.values())
@@ -144,8 +160,8 @@ def test_preempt_retires_clean_goodbye_not_drop():
     assert resilience.stats.get("server.goodbye") >= 1
     assert resilience.stats.get("server.drop") == 0
     assert resilience.stats.get("server.requeue") == 0
-    snap = server.fleet.snapshot()
-    assert snap["joins"] == 2 and snap["drains"] >= 1
+    assert _sessions_that_worked(server) == 2
+    assert server.fleet.snapshot()["drains"] >= 1
 
 
 def test_fleet_join_fault_rides_dead_peer_path():
@@ -163,8 +179,8 @@ def test_fleet_join_fault_rides_dead_peer_path():
     assert len(master.done) == 4
     assert all(v == 1 for v in master.done.values())
     assert injector.fired == [("fleet.join", "fleet.join", 1)]
-    snap = server.fleet.snapshot()
-    assert snap["joins"] == 1, snap
+    # the admission that died minted no join and left no session
+    assert _sessions_that_worked(server) == 1
 
 
 def test_clean_bye_during_probation_grants_parole():
@@ -182,11 +198,14 @@ def test_clean_bye_during_probation_grants_parole():
             server._slaves["s1"] = desc
             server._blacklist["mach1"] = time.time()
         server.fleet.join("s1", "mach1")
+        # The counter is the process's: a session of the test before
+        # this one may say its bye late, so count this drop's own.
+        byes = resilience.stats.get("server.goodbye")
         server._drop(desc, clean=True)
         assert not desc.probation
         assert "mach1" not in server._blacklist
         assert resilience.stats.get("server.parole") == 1
-        assert resilience.stats.get("server.goodbye") == 1
+        assert resilience.stats.get("server.goodbye") == byes + 1
         assert server.fleet.snapshot()["drains"] == 1
 
         # Dirty drop: cooldown stays armed.

@@ -378,3 +378,236 @@ def test_export_refuses_the_new_kinds_by_name(trainer, tmp_path):
     with pytest.raises(Bug, match="lm_layer units train but are not "
                                   "served yet"):
         export_workflow(t.wf, str(tmp_path / "model.veles.tgz"))
+
+
+# -- one unit: TransformerBlock is LMLayer at the OPT spec; placement --------
+
+def _bare_unit(cls, mesh=None, shape=(2, 16, 32), **kwargs):
+    """A decoder-layer unit on a workflow of its own (whose ``mesh``
+    is the one given), initialised over an input of ``shape``."""
+    from veles_tpu.dummy import DummyWorkflow
+    from veles_tpu.memory import Vector
+    import veles_tpu.prng as prng
+    prng.reset()
+    prng.get(0).seed(11)
+    wf = DummyWorkflow()
+    if mesh is not None:
+        wf.mesh = mesh
+    unit = cls(wf, **kwargs)
+    unit.input = Vector(numpy.zeros(shape, numpy.float32))
+    unit.initialize()
+    return unit
+
+
+def _unit_fn(unit):
+    """``(params, x) -> y``: the unit's ``tforward`` as a pure
+    function of its trainables and its input."""
+    def fn(params, x):
+        out = []
+        unit.tforward(lambda vec: x, lambda vec, val: out.append(val),
+                      params, None)
+        return out[0]
+    return fn
+
+
+def _unit_params(unit):
+    return {n: jnp.asarray(v.mem) for n, v in unit.trainables.items()}
+
+
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("remat", [False, True])
+def test_transformer_block_is_lm_layer_at_the_opt_spec(remat, fused):
+    """``TransformerBlock(n_heads=…)`` and ``LMLayer(spec=layer_spec(
+    n_heads=…))`` are one unit: the same leaves from the same seed,
+    and forward + gradient lower to the same text, checkpointed or
+    not, with three projections or the fused one."""
+    kwargs = {"remat": remat, "fused_qkv": fused}
+    block = _bare_unit(Z.TransformerBlock, n_heads=4, **kwargs)
+    layer = _bare_unit(Z.LMLayer, spec=Z.layer_spec(n_heads=4), **kwargs)
+    assert type(block).tforward is Z.LMLayer.tforward
+    assert type(block).initialize is Z.LMLayer.initialize
+    assert block.fused_qkv is layer.fused_qkv is fused
+    assert ("wqkv" in block.params) is fused
+    if not fused:
+        assert tuple(block.params) == Z.TransformerBlock.PARAM_NAMES
+    pb, pl = _unit_params(block), _unit_params(layer)
+    assert set(pb) == set(pl)
+    for name in pb:
+        numpy.testing.assert_array_equal(pb[name], pl[name])
+    x = jnp.zeros((2, 16, 32), jnp.float32)
+
+    def text(unit, params):
+        fn = _unit_fn(unit)
+
+        def both(p, x):
+            return fn(p, x), jax.grad(lambda p: fn(p, x).sum())(p)
+        return jax.jit(both).lower(params, x).as_text()
+
+    assert text(block, pb) == text(layer, pl)
+
+
+GROUPED = dict(norm="rms", n_heads=4, kv_heads=2, qk_norm=True,
+               rope_theta=1e4, bias=False, ffn="gated-mlp", ffn_dim=96)
+
+
+@pytest.mark.parametrize("kind", ["opt", "grouped-rotary-gated"])
+def test_spec_built_body_under_a_data_mesh_is_the_replicated_step(
+        kind, f32_precision):
+    """One fused step of a spec-built body under ``apply_dp_sharding``
+    on the 8-device CPU mesh (``LMLayer._attend`` goes through
+    ``mesh_attention`` there) equals the step with no mesh."""
+    from veles_tpu.launcher import Launcher
+    from veles_tpu.parallel import apply_dp_sharding, make_mesh
+    from veles_tpu.znicz.samples.tinylm import TinyLMWorkflow
+    import veles_tpu.prng as prng
+    spec = Z.layer_spec(n_heads=4) if kind == "opt" \
+        else Z.layer_spec(**GROUPED)
+
+    def one_step(shard):
+        prng.reset()
+        prng.get(0).seed(42)
+        launcher = Launcher()
+        wf = TinyLMWorkflow(launcher, layers=[spec, spec],
+                            minibatch_size=32, max_epochs=1)
+        launcher.initialize()
+        if shard:
+            apply_dp_sharding(wf, make_mesh(jax.devices(), {"data": 8}))
+            assert wf.forwards[1].workflow.mesh is not None
+        wf.loader.serve_next_minibatch()
+        wf.begin_tick()
+        wf.compiler.execute(key=jax.random.PRNGKey(0), training=True)
+        out = {n: numpy.asarray(jax.device_get(v.devmem))
+               for n, v in wf.compiler._param_vecs.items()}
+        launcher.stop()
+        return out
+
+    ref, got = one_step(False), one_step(True)
+    assert set(ref) == set(got)
+    for name in ref:
+        numpy.testing.assert_allclose(
+            ref[name], got[name], rtol=2e-4, atol=2e-5,
+            err_msg="param %s diverged under the data mesh" % name)
+
+
+@pytest.mark.parametrize("sp_mode", ["ring", "ulysses"])
+def test_rotary_layer_sequence_parallel_matches_one_device(
+        sp_mode, f32_precision):
+    """An ``LMLayer`` of RMS norms, rotary positions (each token's
+    own, whichever sequence shard holds it) and a gated MLP with
+    ``seq_axis`` on a dp × sp mesh: forward and gradients are the
+    single-device layer's."""
+    from veles_tpu.parallel import make_mesh
+    spec = Z.layer_spec(**dict(GROUPED, kv_heads=4))
+    mesh = make_mesh(jax.devices(), {"data": 2, "seq": 4})
+    kwargs = {"spec": spec, "shape": (4, 32, 32)}
+    one = _bare_unit(Z.LMLayer, **kwargs)
+    sp = _bare_unit(Z.LMLayer, mesh=mesh, seq_axis="seq",
+                    sp_mode=sp_mode, sp_kernel="xla", **kwargs)
+    params = _unit_params(one)
+    x = jnp.asarray(numpy.random.RandomState(3).normal(
+        0, 1, (4, 32, 32)).astype(numpy.float32))
+
+    def run(unit):
+        fn = _unit_fn(unit)
+        return jax.jit(lambda p, x: (
+            fn(p, x), jax.grad(lambda p: (fn(p, x) ** 2).sum())(p)))(
+                params, x)
+
+    (y1, g1), (y2, g2) = run(one), run(sp)
+    numpy.testing.assert_allclose(y1, y2, rtol=2e-4, atol=2e-4)
+    for name in g1:
+        numpy.testing.assert_allclose(
+            g1[name], g2[name], rtol=2e-3, atol=2e-3, err_msg=name)
+    # ...and the positions matter: without them the output differs
+    flat = _bare_unit(Z.LMLayer, spec=dict(spec, rope_theta=None),
+                      shape=(4, 32, 32))
+    assert float(jnp.abs(_unit_fn(flat)(params, x) - y1).max()) > 1e-2
+
+
+def test_grouped_heads_refuse_a_sequence_axis():
+    """The ring does not broadcast key/value groups: said at
+    construction, not inside a shard_map three layers down."""
+    from veles_tpu.dummy import DummyWorkflow
+    with pytest.raises(ValueError, match="key/value heads"):
+        Z.LMLayer(DummyWorkflow(), spec=Z.layer_spec(**GROUPED),
+                  seq_axis="seq")
+    # the same spec without the axis is fine, and is never fused
+    layer = Z.LMLayer(DummyWorkflow(), spec=Z.layer_spec(**GROUPED),
+                      fused_qkv=True)
+    assert not layer.fused_qkv and "wk" in layer.params
+
+
+@pytest.mark.parametrize("cls,what", [
+    ("TransformerBlock", {"n_heads": 4}),
+    ("LMLayer", {"spec": {"n_heads": 4, "norm": "rms"}})])
+def test_unknown_sp_mode_is_refused_at_construction(cls, what):
+    """One unit takes the placement arguments, so one place checks
+    them: a sequence-parallel mode the ops do not know is a
+    ``ValueError`` from either name (a spec-built layer used to take
+    no ``sp_mode`` at all and ignore the word)."""
+    from veles_tpu.dummy import DummyWorkflow
+    with pytest.raises(ValueError, match="sp_mode"):
+        getattr(Z, cls)(DummyWorkflow(), seq_axis="seq",
+                        sp_mode="spiral", **what)
+
+
+# -- the accumulator's rows --------------------------------------------------
+
+def _expert_workflow():
+    from veles_tpu.launcher import Launcher
+    from veles_tpu.znicz.samples.tinylm import TinyLMWorkflow
+    import veles_tpu.prng as prng
+    prng.reset()
+    prng.get(0).seed(3)
+    launcher = Launcher()
+    wf = TinyLMWorkflow(
+        launcher, max_epochs=1, seq_len=16, minibatch_size=16,
+        embed_dim=16,
+        layers=[Z.layer_spec(n_heads=2, ffn="experts", n_experts=4,
+                             top_k=2)],
+        loader_config={"n_train": 64, "n_valid": 16})
+    launcher.initialize()
+    return launcher, wf
+
+
+def test_moe_acc_buckets_ticks_by_minibatch_class():
+    """One epoch of 64 train / 16 validation samples at minibatch 16,
+    tick by tick: the validation row holds 1 tick and the train row
+    4, each with its own assignments; the test row stays empty."""
+    from veles_tpu.loader.base import TEST, TRAIN, VALID
+    launcher, wf = _expert_workflow()
+    layer = wf.forwards[1]
+    while True:
+        wf.loader.serve_next_minibatch()
+        wf.begin_tick()
+        wf.compiler.execute(
+            key=jax.random.PRNGKey(0),
+            training=wf.loader.minibatch_class == TRAIN)
+        if wf.loader.epoch_ended:
+            break
+    a_tick = 16 * 16 * 2               # tokens x top 2, all 4 held
+    for cls, ticks in ((TEST, 0), (VALID, 1), (TRAIN, 4)):
+        row = layer.read_moe_share(cls)
+        assert list(row[:3]) == [ticks * a_tick, ticks * a_tick, ticks]
+        assert float(row[3:].sum()) == ticks * a_tick
+    launcher.stop()
+
+
+def test_moe_acc_leaves_padded_ticks_out():
+    """A block whose last tick is padding (an all-zero mask) adds
+    three ticks' counts to the train row, not four."""
+    from veles_tpu.loader.base import TRAIN, VALID
+    launcher, wf = _expert_workflow()
+    layer, loader = wf.forwards[1], wf.loader
+    block = loader.serve_block(4)
+    assert loader.minibatch_class == VALID   # served first: 1 tick
+    block = loader.serve_block(4)
+    assert loader.minibatch_class == TRAIN
+    mask = block[str(id(loader.minibatch_mask))]
+    assert mask.shape[0] == 4
+    mask[-1] = 0.0
+    wf.compiler.execute_block(block, True, key=jax.random.PRNGKey(0))
+    row = layer.read_moe_share(TRAIN)
+    assert list(row[:3]) == [3 * 16 * 16 * 2, 3 * 16 * 16 * 2, 3]
+    assert float(layer.read_moe_share(VALID).sum()) == 0.0
+    launcher.stop()

@@ -139,12 +139,17 @@ def test_remat_step_matches_plain(family, f32_precision):
     """Checkpointing must not change the math — the recompute is the
     same computation, so any difference is only XLA re-fusing around
     the checkpoint boundary (float-noise level).  (The MoE case also
-    proves the aux-loss/metric plumbing survives the checkpoint
+    proves the expert layer's counts survive the checkpoint
     boundary: side outputs ride the return value, not ctx closure
     mutation.)"""
     kwargs = {"n_blocks": 2, "seq_len": 32, "minibatch_size": 32}
     if family == "moe":
-        kwargs["n_experts"] = 4
+        from veles_tpu.znicz.attention import layer_spec
+        kwargs["layers"] = [
+            layer_spec(ffn="experts", n_experts=4, top_k=2),
+            layer_spec(norm="rms", ffn="experts", n_experts=4,
+                       top_k=2, held=(1, 2), bias=False,
+                       rope_theta=1e4)]
     elif family == "pipelined":
         kwargs.update(pipelined=True, n_microbatches=2)
     ref = _one_step_params(False, **kwargs)

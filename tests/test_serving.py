@@ -831,11 +831,10 @@ def test_moe_artifact_generate_has_precise_refusal(tmp_path):
                "e__p": numpy.zeros((8, 4), numpy.float32),
                "h__w": numpy.zeros((4, 4), numpy.float32)}
     path = _write_artifact(tmp_path / "moe.veles.tgz", units, weights)
-    model = ExportedModel(path)
-    with pytest.raises(Bug, match="MoE blocks are not yet supported"):
-        model.generate([[1, 2]], 2)
-    # Not an LM for serving-limit purposes either.
-    assert model.max_position is None
+    # An artifact of the removed capacity-routed block is refused at
+    # LOAD, by the kind's name, not as an unknown unit three calls in.
+    with pytest.raises(Bug, match="moe_transformer_block.*removed"):
+        ExportedModel(path)
 
 
 def test_tp_plan_degrades_on_uninitialized_unit():

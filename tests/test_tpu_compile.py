@@ -232,3 +232,46 @@ def test_flash_attention_partitions_over_a_dp_mesh(topo, monkeypatch):
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_grouped_query_layer_partitions_over_a_dp_mesh(topo,
+                                                       monkeypatch):
+    """A spec-built layer — ``lfm2-24b-a2b.train``'s attention: 32
+    query heads of 64 over 8 key/value heads, per-head norm, rotary
+    positions — forward + backward through ``LMLayer``'s own
+    placement for FOUR described chips, batch over ``data``: the unit
+    hands ``layer_apply`` an ``attend`` that wraps the kernels in
+    ``shard_map``, so the partitioned program compiles and holds
+    them.  (A unit that passes no ``attend`` traces a bare Mosaic
+    call into the GSPMD program, which the compiler refuses.)"""
+    import numpy
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from veles_tpu.dummy import DummyWorkflow
+    from veles_tpu.ops import attention as A
+    from veles_tpu.znicz import attention as Z
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    B, S, H, D = LFM2
+    spec = Z.layer_spec(norm="rms", n_heads=H, kv_heads=8,
+                        qk_norm=True, rope_theta=1e6, bias=False,
+                        ffn="gated-mlp", ffn_dim=256)
+    mesh = Mesh(numpy.array(topo.devices[:4]), ("data",))
+    wf = DummyWorkflow()
+    wf.mesh = mesh
+    layer = Z.LMLayer(wf, spec=spec, remat=True)
+    replicated = NamedSharding(mesh, P())
+    params = {name: _struct(shape, jnp.float32, replicated)
+              for name, shape in
+              Z.layer_param_shapes(spec, H * D).items()}
+    x = _struct((B, S, H * D), jnp.float32,
+                NamedSharding(mesh, P("data", None, None)))
+
+    def loss(p, x):
+        out = []
+        layer.tforward(lambda vec: x, lambda vec, val: out.append(val),
+                       p, None)
+        return out[0].sum()
+
+    text = _compiled_text(jax.grad(loss), params, x)
+    # the recompute's forward, dq, dk/dv (the loss's own forward is
+    # dead code under ``grad``)
+    assert text.count("tpu_custom_call") >= 3

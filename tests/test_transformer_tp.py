@@ -4,10 +4,11 @@ The reference's only engine was master–slave data parallelism
 (reference: veles/server.py:659, veles/client.py:405); SURVEY §2.3
 sets tensor parallelism as the TPU build's natural-XLA obligation.
 These tests pin the column/row weight layout per parameter family
-(attention qkv/o, MLP up/down, MoE experts, pipelined stacks, LM
-head, embedding), verify ONE fused training step under dp×tp is
-numerically the same step as fully-replicated dp, and exercise the
-composed 3-axis dp×tp×sp layout end-to-end.
+(attention qkv/o, MLP up/down, pipelined stacks, LM head,
+embedding), chosen by the unit's parameter NAMES; verify ONE fused
+training step under dp×tp is numerically the same step as
+fully-replicated dp, and exercise the composed 3-axis dp×tp×sp
+layout end-to-end.
 """
 
 import numpy
@@ -96,16 +97,14 @@ def test_indivisible_heads_stay_replicated():
     assert blk.params["wq"].devmem.sharding.spec == P()
 
 
-@pytest.mark.parametrize("family", ["dense", "moe", "pipelined"])
+@pytest.mark.parametrize("family", ["dense", "pipelined"])
 def test_tp_step_parity_vs_replicated(family, f32_precision):
     """ONE fused training step under dp×tp(2×4) == the same step
     fully replicated, per sharded parameter family — the annotation
     must never change the math, only the layout."""
     import jax
     kwargs = {}
-    if family == "moe":
-        kwargs = {"n_experts": 4}
-    elif family == "pipelined":
+    if family == "pipelined":
         kwargs = {"pipelined": True, "n_blocks": 2,
                   "n_microbatches": 2}
     devices = jax.devices()
@@ -126,23 +125,56 @@ def test_tp_step_parity_vs_replicated(family, f32_precision):
             err_msg="param %s diverged under tp" % name)
 
 
-def test_moe_expert_param_tp_shardings():
-    """MoE experts: per-expert column/row pairing on the TRAILING
-    dims, leading expert dim left for the expert axis, router
-    replicated."""
+@pytest.mark.parametrize("kind", ["opt-spec", "shortconv", "experts"])
+def test_tp_plan_is_by_name(kind, f32_precision):
+    """The plan is chosen by the layer's parameter NAMES, not by its
+    class: a spec-built layer with the OPT block's leaves gets
+    TransformerBlock's specs leaf for leaf; a layer holding a name
+    the plan does not know (the short convolution's ``w_in`` /
+    ``w_conv``, an expert layer's ``router`` / ``w3``) stays
+    replicated whole — and either way ONE dp×tp step is the
+    replicated step."""
     import jax
     from jax.sharding import PartitionSpec as P
-    _, wf = _build_tinylm(max_epochs=1, n_experts=4)
-    mesh = make_mesh(jax.devices(), {"data": 2, "model": 4})
-    apply_dp_tp_sharding(wf, mesh)
-    blk = _block_unit(wf)
+    from veles_tpu.znicz.attention import layer_spec
+    spec = {"opt-spec": layer_spec(n_heads=4),
+            "shortconv": layer_spec(norm="rms", operator="shortconv",
+                                    ffn="gated-mlp", bias=False),
+            "experts": layer_spec(norm="rms", ffn="experts",
+                                  n_experts=4, top_k=2, bias=False,
+                                  rope_theta=1e4)}[kind]
+    devices = jax.devices()
+    axes = {"data": 2, "model": 4}
     spec_of = lambda v: v.devmem.sharding.spec  # noqa: E731
-    assert spec_of(blk.params["w1"]) == P(None, None, "model")
-    assert spec_of(blk.params["w2"]) == P(None, "model", None)
-    assert spec_of(blk.params["b1"]) == P(None, "model")
-    assert spec_of(blk.params["b2"]) == P(None)
-    assert spec_of(blk.params["router"]) == P()
-    assert spec_of(blk.params["wq"]) == P(None, "model")
+    _, wf = _build_tinylm(max_epochs=1, layers=[spec])
+    apply_dp_tp_sharding(wf, make_mesh(devices, axes))
+    layer = wf.forwards[1]
+    if kind == "opt-spec":
+        _, opt = _build_tinylm(max_epochs=1)
+        apply_dp_tp_sharding(opt, make_mesh(devices, axes))
+        blk = _block_unit(opt)
+        assert set(layer.params) == set(blk.params)
+        for name, vec in blk.params.items():
+            assert spec_of(layer.params[name]) == spec_of(vec), name
+        assert spec_of(layer.params["wq"]) == P(None, "model")
+        assert layer.head_axis == blk.head_axis == "model"
+    else:
+        assert all(spec_of(v) == P() for v in layer.params.values())
+        assert layer.head_axis is None
+
+    def dp(wf):
+        apply_dp_sharding(wf, make_mesh(devices, {"data": 8}))
+
+    def tp(wf):
+        apply_dp_tp_sharding(wf, make_mesh(devices, axes))
+
+    ref = _one_step_params(dp, layers=[spec])
+    got = _one_step_params(tp, layers=[spec])
+    assert set(ref) == set(got)
+    for name in ref:
+        numpy.testing.assert_allclose(
+            ref[name], got[name], rtol=2e-4, atol=2e-5,
+            err_msg="param %s diverged under tp" % name)
 
 
 def test_pipelined_stack_tp_shardings():
@@ -236,24 +268,21 @@ def test_three_axis_dp_tp_sp(sp_mode):
 
 def _rebuild_case(style):
     """(lm kwargs, mesh axes, applier) per parallelism style."""
-    from veles_tpu.parallel import (apply_dp_ep_sharding,
-                                    apply_dp_pp_sharding,
+    from veles_tpu.parallel import (apply_dp_pp_sharding,
                                     apply_dp_sp_sharding)
     return {
         "dp_sp": ({"seq_axis": "seq"}, {"data": 2, "seq": 4},
                   apply_dp_sp_sharding),
-        "dp_ep": ({"n_experts": 4}, {"data": 2, "expert": 4},
-                  apply_dp_ep_sharding),
         "dp_pp": ({"pipelined": True, "n_blocks": 4,
                    "n_microbatches": 2},
                   {"data": 2, "stage": 4}, apply_dp_pp_sharding),
     }[style]
 
 
-@pytest.mark.parametrize("style", ["dp_sp", "dp_ep", "dp_pp"])
+@pytest.mark.parametrize("style", ["dp_sp", "dp_pp"])
 def test_rebuild_preserves_style(style):
-    """8→4 chip loss must RE-FORM the sp/ep/pp layout over the
-    survivors (pre-round-5 all three silently degraded to plain DP;
+    """8→4 chip loss must RE-FORM the sp/pp layout over the
+    survivors (pre-round-5 both silently degraded to plain DP;
     only dp_tp was preserved), and training must continue."""
     import jax
     kwargs, axes, applier = _rebuild_case(style)

@@ -357,17 +357,12 @@ class Main(Logger, CommandLineBase):
         if args.attn_decode_kernel is not None:
             root.common.engine.decode_kernel = \
                 args.attn_decode_kernel
-        # Pipeline-schedule / MoE-routing knobs (ops/pipeline.py and
-        # ops/moe.py init_parser; docs/pipeline.md, docs/moe.md) —
-        # read back at unit construction.
+        # Pipeline-schedule knobs (ops/pipeline.py init_parser;
+        # docs/pipeline.md) — read back at unit construction.
         if args.pp_schedule is not None:
             root.common.engine.pp_schedule = args.pp_schedule
         if args.pp_chunks is not None:
             root.common.engine.pp_chunks = args.pp_chunks
-        if args.moe_topk is not None:
-            root.common.engine.moe_top_k = args.moe_topk
-        if args.moe_router_z is not None:
-            root.common.engine.moe_router_z = args.moe_router_z
         # Distributed data-plane knobs (network_common.init_parser;
         # docs/distributed.md) — read back by the handshake
         # negotiation and the channels.

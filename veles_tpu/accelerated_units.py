@@ -44,7 +44,6 @@ class StepContext(object):
         self.key = key
         self.training = training
         self.loss = None
-        self.aux_loss = 0.0
         self.metrics = {}
         self._key_uses = 0
 
@@ -60,12 +59,6 @@ class StepContext(object):
 
     def set_loss(self, value):
         self.loss = value
-
-    def add_aux_loss(self, value):
-        """Accumulates an auxiliary objective (e.g. MoE load-balance)
-        that is ADDED to the evaluator's loss for differentiation but
-        kept out of the reported metrics."""
-        self.aux_loss = self.aux_loss + value
 
 
 def step_compute_dtype():
@@ -458,9 +451,6 @@ class StepCompiler(object):
             loss = ctx.loss
             if loss is not None:
                 metrics["loss"] = loss
-                # Auxiliary objectives (MoE load balance) ride the
-                # differentiated total but not the reported loss.
-                loss = loss + ctx.aux_loss
             return loss, metrics, new_states, outputs
 
         @jax.named_scope("update")

@@ -54,6 +54,16 @@ def enable_compilation_cache():
     return COMPILE_CACHE_DIR
 
 
+def tpu_available():
+    """Whether the default JAX backend is a TPU — the platform half
+    of every kernel-dispatch decision in ``ops`` (the geometry half
+    is the kernel's own ``supports*``).  A backend that fails to
+    initialize raises here: a chip that cannot be reached is not a
+    reason to run on another device."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
 def device_entry():
     """``{"platform", "kind", "count"}`` of the devices JAX runs this
     process on — the one entry the CLI's result file, the server's

@@ -175,7 +175,6 @@ CONTRIBUTING_MODULES = (
     "veles_tpu.network_common",
     "veles_tpu.observability",
     "veles_tpu.ops.attention",
-    "veles_tpu.ops.moe",
     "veles_tpu.ops.pipeline",
     "veles_tpu.population",
     "veles_tpu.restful",

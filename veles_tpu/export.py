@@ -68,8 +68,6 @@ EXPORTABLE = {
     # long-context extension, deployable like everything else).
     "embedding": ("vocab_size", "embed_dim"),
     "transformer_block": ("n_heads",),
-    "moe_transformer_block": ("n_heads", "n_experts",
-                              "capacity_factor"),
     "lm_head": (),
 }
 
@@ -150,7 +148,7 @@ def _unit_entry(unit):
             vec.map_read()
             params[pname] = numpy.asarray(vec.mem,
                                           dtype=numpy.float32)
-    elif mapping in ("transformer_block", "moe_transformer_block"):
+    elif mapping == "transformer_block":
         config["causal"] = int(unit.causal)
         for pname, vec in unit.trainables.items():
             vec.map_read()
@@ -800,6 +798,16 @@ class ExportedModel(object):
                                         FORMAT_VERSION))
         self.weights = dict(numpy.load(io.BytesIO(weights_blob)))
         self.units = self.manifest["units"]
+        for entry in self.units:
+            # An artifact is input from outside the program: a kind
+            # an older tree wrote is refused by name, not as unknown.
+            if entry["type"] == "moe_transformer_block":
+                raise Bug("%s: unit %s is a moe_transformer_block — "
+                          "the capacity-routed expert block was "
+                          "removed; expert layers are dropless "
+                          "LMLayer specs (docs/moe.md), which train "
+                          "but are not served yet"
+                          % (path, entry["name"]))
         self.input_shape = tuple(
             self.manifest["input"]["sample_shape"])
         self._jit_forward = None
@@ -1037,10 +1045,6 @@ class ExportedModel(object):
                     ).astype(numpy.float32)
         if t == "transformer_block":
             return self._transformer_numpy(entry, x)
-        if t == "moe_transformer_block":
-            return self._transformer_numpy(
-                entry, x,
-                mlp=lambda h, p: self._moe_ffn_numpy(entry, h, p))
         if t == "lm_head":
             w = self._param(entry, "weights")
             y = x @ w
@@ -1055,10 +1059,9 @@ class ExportedModel(object):
             return self._lrn_numpy(cfg, x)
         raise Bug("unknown unit type %r in artifact" % t)
 
-    def _transformer_numpy(self, entry, x, mlp=None):
+    def _transformer_numpy(self, entry, x):
         """Pre-LN block, numpy mirror of znicz/attention.py
-        ``transformer_block_apply``.  ``mlp(h, p)`` overrides the
-        dense FFN (the MoE variant passes its routed experts)."""
+        ``transformer_block_apply``."""
         cfg = entry["config"]
         H = int(cfg["n_heads"])
         causal = bool(cfg.get("causal", 1))
@@ -1093,41 +1096,8 @@ class ExportedModel(object):
             .reshape(B, S, E)
         x = x + attn @ p["wo"] + p["bo"]
         h = ln(x, p["ln2_g"], p["ln2_b"])
-        if mlp is not None:
-            return (x + mlp(h, p)).astype(numpy.float32)
         h = numpy.maximum(h @ p["w1"] + p["b1"], 0.0)
         return (x + h @ p["w2"] + p["b2"]).astype(numpy.float32)
-
-    def _moe_ffn_numpy(self, entry, h, p):
-        """Top-1 capacity routing, numpy mirror of ops/moe.py
-        ``moe_ffn``: tokens flatten batch-major, each goes to its
-        argmax expert while the expert has queue slots left
-        (capacity = cf·T/E over the WHOLE batch, cumulative in token
-        order); overflow tokens contribute zero (the residual path
-        carries them)."""
-        cfg = entry["config"]
-        nexp = int(cfg["n_experts"])
-        cf = float(cfg.get("capacity_factor", 1.25))
-        B, S, E = h.shape
-        tok = h.reshape(B * S, E).astype(numpy.float32)
-        T = tok.shape[0]
-        capacity = max(1, int(cf * T / nexp))
-        logits = tok @ p["router"]
-        logits -= logits.max(axis=-1, keepdims=True)
-        probs = numpy.exp(logits)
-        probs /= probs.sum(axis=-1, keepdims=True)
-        gate = probs.max(axis=-1)
-        expert = probs.argmax(axis=-1)
-        y = numpy.zeros_like(tok)
-        count = numpy.zeros(nexp, dtype=numpy.int64)
-        for t in range(T):
-            e = int(expert[t])
-            if count[e] < capacity:
-                h1 = numpy.maximum(tok[t] @ p["w1"][e] + p["b1"][e],
-                                   0.0)
-                y[t] = gate[t] * (h1 @ p["w2"][e] + p["b2"][e])
-            count[e] += 1
-        return y.reshape(B, S, E)
 
     def _kohonen_numpy(self, entry, x):
         # Squared distance to each SOM neuron (KohonenForward emits
@@ -1314,7 +1284,7 @@ class ExportedModel(object):
             return None
         import jax.numpy as jnp
         from .ops import pallas_attention as PA
-        from .ops.pallas_lrn import tpu_available
+        from .backends import tpu_available
         interpret = mode == "interpret"
 
         def attend(q, kc, vc, key_mask, k_scale=None, v_scale=None):
@@ -1384,28 +1354,6 @@ class ExportedModel(object):
                     bool(cfg.get("causal", 1)), jnp.float32,
                     attend=self._serving_attend(
                         bool(cfg.get("causal", 1))))
-            elif t == "moe_transformer_block":
-                from .znicz.attention import transformer_block_apply
-                from .ops.moe import moe_ffn
-                p = {n: jnp.asarray(par(entry, n))
-                     for n in entry["params"]}
-                # lint-ok: VL101 manifest number, not a tracer
-                cf = float(cfg.get("capacity_factor", 1.25))
-
-                def moe_mlp(h, p=p, cf=cf):
-                    B_, S_, E_ = h.shape
-                    y, _aux, _load = moe_ffn(
-                        h.reshape(B_ * S_, E_), p["router"],
-                        p["w1"], p["b1"], p["w2"], p["b2"],
-                        capacity_factor=cf)
-                    return y.reshape(B_, S_, E_)
-
-                x = transformer_block_apply(
-                    p, x, int(cfg["n_heads"]),  # lint-ok: VL101 manifest int
-                    bool(cfg.get("causal", 1)), jnp.float32,
-                    attend=self._serving_attend(
-                        bool(cfg.get("causal", 1))),
-                    mlp=moe_mlp)
             elif t == "lm_head":
                 w = par(entry, "weights")
                 y = x @ w
@@ -1459,13 +1407,6 @@ class ExportedModel(object):
         artifact is not a causal LM.  Dropout entries are inert at
         inference and skipped."""
         entries = [e for e in self.units if e["type"] != "dropout"]
-        if any(e["type"] == "moe_transformer_block" for e in entries):
-            # A precise refusal: the routed-expert FFN has no cached
-            # decode path yet, and the generic chain-shape message
-            # would mislead (the chain IS embedding→blocks→head).
-            raise Bug("MoE blocks are not yet supported by "
-                      "generate() — serve moe_transformer_block "
-                      "artifacts through forward()")
         if len(entries) < 3 or entries[0]["type"] != "embedding" or \
                 entries[-1]["type"] != "lm_head" or \
                 any(e["type"] != "transformer_block"

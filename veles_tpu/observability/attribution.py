@@ -82,11 +82,6 @@ _xprof = {"dir": None, "steps": 0, "done": 0, "started": False}
 #: compile): the configured kind(s), total slot bytes, and the ZeRO
 #: shard fraction each dp rank persistently stores (1.0 = replicated).
 _optimizer = {"kind": None, "state_bytes": None, "shard_frac": None}
-#: MoE router observability (DecisionGD publishes per class-epoch
-#: from the blocks' moe_acc accumulators): mean load-balance aux per
-#: tick and the worst expert-load share (1/E = balanced, 1.0 =
-#: collapsed).
-_moe = {"aux_loss": None, "max_load_frac": None, "n_experts": None}
 _timer = time.perf_counter  # injectable for tests
 _ordinals = itertools.count(1)
 #: configured-peak-value -> resolved FLOP/s (the device probe and
@@ -119,8 +114,6 @@ def reset():
         _recent.clear()
         _optimizer.update(kind=None, state_bytes=None,
                           shard_frac=None)
-        _moe.update(aux_loss=None, max_load_frac=None,
-                    n_experts=None)
     _xprof.update(dir=None, steps=0, done=0, started=False)
     _local.dispatch = _gc["target"] = _gc["t0"] = None
     _peak_cache.clear()
@@ -424,29 +417,6 @@ def note_optimizer(kind, state_bytes, shard_frac=1.0):
               labels=labels).set(round(float(shard_frac), 6))
 
 
-def note_moe(aux_loss, max_load_frac, n_experts, expert_shares=None):
-    """Publishes the MoE router gauges (called by DecisionGD at
-    epoch boundaries from the blocks' ``moe_acc`` rows):
-    ``moe.aux_loss`` (mean load-balance aux per tick) and
-    ``moe.expert_load`` (per-expert share, labeled by block and
-    expert index) in the process metrics registry, plus the heartbeat
-    ``perf`` section fields (→ web_status perf row, /metrics) — the
-    live router-collapse signal."""
-    with _lock:
-        _moe.update(aux_loss=float(aux_loss),
-                    max_load_frac=float(max_load_frac),
-                    n_experts=int(n_experts))
-    reg = metrics.registry
-    reg.gauge("moe.aux_loss").set(round(float(aux_loss), 6))
-    reg.gauge("moe.max_load_frac").set(
-        round(float(max_load_frac), 6))
-    for (block, idx), share in (expert_shares or {}).items():
-        reg.gauge("moe.expert_load",
-                  labels={"block": str(block),
-                          "expert": str(idx)}).set(
-            round(float(share), 6))
-
-
 def note_moe_share(assignments_made, assignments_landed,
                    max_load_frac):
     """Publishes what DecisionGD read from the layers that hold a
@@ -458,15 +428,6 @@ def note_moe_share(assignments_made, assignments_landed,
     reg.gauge("moe.assignments_made").set(float(assignments_made))
     reg.gauge("moe.assignments_landed").set(float(assignments_landed))
     reg.gauge("moe.max_load_frac").set(round(float(max_load_frac), 6))
-
-
-def moe_summary():
-    """The last published MoE router stats, or None when no MoE
-    epoch has completed."""
-    with _lock:
-        if _moe["aux_loss"] is None:
-            return None
-        return dict(_moe)
 
 
 def optimizer_summary():
@@ -525,8 +486,4 @@ def perf_summary():
             out["optimizer"] = _optimizer["kind"]
             out["optimizer_state_bytes"] = _optimizer["state_bytes"]
             out["optimizer_shard_frac"] = _optimizer["shard_frac"]
-        if _moe["aux_loss"] is not None:
-            out["moe_aux_loss"] = round(_moe["aux_loss"], 6)
-            out["moe_max_load_frac"] = round(_moe["max_load_frac"],
-                                             6)
     return out

@@ -59,7 +59,7 @@ from jax import lax
 
 from .. import resilience
 from ..config import root, get as config_get
-from .pallas_lrn import tpu_available
+from ..backends import tpu_available
 
 NEG_INF = -1e30
 

@@ -88,7 +88,7 @@ Since ISSUE 13 the kernel is RESUMABLE and MULTI-CHIP-composable:
 Like pallas_lrn.py, the module ships the kernel and its parity oracle
 (ops/attention.blockwise_attention, which the tests pin).  Dispatch
 (ops/attention._try_pallas, the ring body, export's decode gate)
-selects the kernel by PLATFORM (``pallas_lrn.tpu_available``) and by
+selects the kernel by PLATFORM (``backends.tpu_available``) and by
 the ``supports*`` geometry contracts below — off a TPU the XLA
 formulation is what runs; on a TPU a kernel that does not lower is
 an error that propagates, never a quiet switch to another path.

@@ -29,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backends import tpu_available
+
 #: Rows per grid step.  f32 working set ≈ 5 tiles × BP × 128 lanes
 #: × 4 B ≈ 5 MB at 2048 — comfortably inside 16 MB VMEM.
 _BLOCK_ROWS = 2048
@@ -149,15 +151,6 @@ def _lrn_bwd(n, alpha, beta, k, interpret, res, dy):
 
 
 lrn_pallas.defvjp(_lrn_fwd, _lrn_bwd)
-
-
-def tpu_available():
-    """Whether the default JAX backend is a TPU — the platform half
-    of every kernel-dispatch decision in ``ops`` (the geometry half
-    is the kernel's own ``supports*``).  A backend that fails to
-    initialize raises here: a chip that cannot be reached is not a
-    reason to run on another device."""
-    return jax.default_backend() == "tpu"
 
 
 def lrn(x, n, alpha, beta, k):

@@ -91,13 +91,27 @@ def apply_dp_sharding(workflow, mesh, axis="data"):
     return workflow
 
 
+#: Megatron column/row pairing of the OPT block's leaves, by NAME
+#: (fused layout included): how :func:`_transformer_tp_plan` lays
+#: the trailing dims of each on the model axis.
+_OPT_TP_LEAVES = {
+    "wq": "col", "wk": "col", "wv": "col", "wo": "row",
+    "bq": "vec", "bk": "vec", "bv": "vec", "bo": "rep",
+    "wqkv": "col", "bqkv": "vec",
+    "ln1_g": "rep", "ln1_b": "rep", "ln2_g": "rep", "ln2_b": "rep",
+    "w1": "col", "b1": "vec", "w2": "row", "b2": "rep",
+}
+
+
 def _transformer_tp_plan(unit, n_model, model_axis):
     """Megatron-style PartitionSpecs for one transformer-family unit,
     or None when its geometry does not divide the model axis.
 
     The layout is the standard column→row pairing, expressed as
     GSPMD annotations instead of manual collectives (XLA inserts the
-    all-reduce after each row-parallel matmul):
+    all-reduce after each row-parallel matmul), and it is chosen by
+    the unit's parameter NAMES (:data:`_OPT_TP_LEAVES`), not by its
+    class:
 
       * attention: wq/wk/wv COLUMN-sharded (each model shard computes
         E/n output features = H/n whole heads; the (B,S,H,D) reshape
@@ -110,9 +124,11 @@ def _transformer_tp_plan(unit, n_model, model_axis):
         split indexes a replicated axis;
       * MLP: w1 column, w2 row — the hidden dim lives sharded, the
         residual stream stays replicated;
-      * MoE experts: same column/row pairing on the per-expert
-        matrices (trailing dims; the leading expert dim is the
-        EXPERT axis's business, composable);
+      * a layer holding a name the plan does not know — the short
+        convolution's ``w_in`` / ``w_conv``, a gated MLP's ``w3``, an
+        expert layer's ``router``, grouped key/value heads' narrower
+        ``wk`` — stays replicated whole (correct, merely not
+        tensor-parallel);
       * pipelined stacks: same specs with the leading stage dim left
         to the STAGE axis;
       * LMHead: vocab (output) column-sharded — the loss's
@@ -122,68 +138,34 @@ def _transformer_tp_plan(unit, n_model, model_axis):
         local per shard); a TIED head then contracts over the sharded
         embed dim — a row-parallel linear ending in a psum.
     """
-    from ..znicz.attention import (Embedding, LMHead,
-                                   MoETransformerBlock,
-                                   PipelinedTransformerStack,
-                                   TransformerBlock)
+    from ..znicz.attention import Embedding, LMHead
 
     def spec(*axes):
         return PartitionSpec(*axes)
 
-    if isinstance(unit, (TransformerBlock, PipelinedTransformerStack)):
-        inp = getattr(unit, "input", None)
-        if inp is None or inp.shape is None:
-            # Pre-initialize sharding (no linked input yet): degrade
-            # to replicated instead of raising AttributeError.
+    names = set(getattr(unit, "params", ()))
+    if "wo" in names:
+        trainables = unit.trainables
+        if not trainables:
+            # Pre-initialize sharding (no parameter allocated yet):
+            # degrade to replicated instead of raising.
             return None
-        embed = inp.shape[-1]
-        hidden = embed * unit.mlp_ratio
+        wk = trainables.get("wk")
+        if not names <= set(_OPT_TP_LEAVES) or \
+                (wk is not None and
+                 wk.shape != trainables["wo"].shape):
+            return None
+        # A stage-stacked unit's leaves carry a leading dim the
+        # STAGE axis owns.
+        lead = (None,) * (len(trainables["wo"].shape) - 2)
+        embed, hidden = trainables["w1"].shape[-2:]
         if embed % n_model or hidden % n_model or \
                 unit.n_heads % n_model:
             return None
-        col, row, vec, rep = ((None, model_axis),
-                              (model_axis, None),
-                              (model_axis,), ())
-        if isinstance(unit, MoETransformerBlock):
-            plan = {
-                "wq": col, "wk": col, "wv": col, "wo": row,
-                "bq": vec, "bk": vec, "bv": vec, "bo": rep,
-                # Fused layout: the 3E column dim is head-major, so a
-                # column shard is whole heads' q/k/v (see the wqkv
-                # note above).
-                "wqkv": col, "bqkv": vec,
-                "ln1_g": rep, "ln1_b": rep,
-                "ln2_g": rep, "ln2_b": rep,
-                "router": rep,
-                # Per-expert column/row pairing on the TRAILING dims;
-                # the leading expert dim stays None here (the expert
-                # axis shards it, composably).
-                "w1": (None,) + col, "b1": (None,) + vec,
-                "w2": (None,) + row, "b2": (None,) + rep,
-            }
-        elif isinstance(unit, PipelinedTransformerStack):
-            plan = {
-                "wq": (None,) + col, "wk": (None,) + col,
-                "wv": (None,) + col, "wo": (None,) + row,
-                "bq": (None,) + vec, "bk": (None,) + vec,
-                "bv": (None,) + vec, "bo": (None,) + rep,
-                "wqkv": (None,) + col, "bqkv": (None,) + vec,
-                "ln1_g": (None,) + rep, "ln1_b": (None,) + rep,
-                "ln2_g": (None,) + rep, "ln2_b": (None,) + rep,
-                "w1": (None,) + col, "b1": (None,) + vec,
-                "w2": (None,) + row, "b2": (None,) + rep,
-            }
-        else:
-            plan = {
-                "wq": col, "wk": col, "wv": col, "wo": row,
-                "bq": vec, "bk": vec, "bv": vec, "bo": rep,
-                "wqkv": col, "bqkv": vec,
-                "ln1_g": rep, "ln1_b": rep,
-                "ln2_g": rep, "ln2_b": rep,
-                "w1": col, "b1": vec, "w2": row, "b2": rep,
-            }
-        return {name: spec(*axes) for name, axes in plan.items()
-                if name in unit.trainables}
+        axes = {"col": (None, model_axis), "row": (model_axis, None),
+                "vec": (model_axis,), "rep": ()}
+        return {name: spec(*(lead + axes[_OPT_TP_LEAVES[name]]))
+                for name in trainables}
     if isinstance(unit, LMHead):
         plan = {}
         w = unit.trainables.get("weights")
@@ -350,7 +332,7 @@ def apply_dp_sp_sharding(workflow, mesh, data_axis="data",
     """Data × sequence parallelism — the long-context layout
     (SURVEY §5: absent in the 2013-15 reference; first-class here):
     batches shard on ``data_axis`` exactly as in DP, and every
-    TransformerBlock whose ``seq_axis`` names a mesh axis runs its
+    decoder layer whose ``seq_axis`` names a mesh axis runs its
     attention as a ``shard_map`` ring over that axis
     (ops/attention.py ``ring_attention`` — k/v shards rotate over ICI
     with a streaming-softmax accumulator, so per-device activation
@@ -375,35 +357,6 @@ def apply_dp_sp_sharding(workflow, mesh, data_axis="data",
     return workflow
 
 
-def apply_dp_ep_sharding(workflow, mesh, data_axis="data",
-                         expert_axis="expert"):
-    """Data × EXPERT parallelism for Mixture-of-Experts blocks
-    (znicz/attention.py MoETransformerBlock): each MoE block's
-    expert-stacked parameters (leading ``n_experts`` dimension) and
-    their mirroring optimizer slots shard along ``expert_axis``; the
-    GShard dispatch/combine einsums (ops/moe.py) then contract a
-    sharded expert dimension against replicated tokens, and XLA
-    lowers them to the all-to-all pattern of expert-parallel
-    frameworks over ICI.  Everything else follows DP.
-
-    Blocks whose ``n_experts`` does not divide the expert-axis size
-    stay replicated (correct, merely not expert-parallel).
-    """
-    apply_dp_sharding(workflow, mesh, axis=data_axis)
-    # Optimizer slots match their parameter BY NAME inside the
-    # shared overlay (any registered slot prefix — velocity_/
-    # adam_m_/…) — shape matching would mis-shard e.g.
-    # velocity_router when router (D, E) collides with b2 (E, D).
-    if _overlay_leading_axis(workflow, mesh, "expert_params",
-                             "n_experts", expert_axis) == 0:
-        workflow.warning(
-            "apply_dp_ep_sharding: no MoE block's n_experts divides "
-            "the expert axis (%d) — the workflow runs data-parallel "
-            "only" % mesh.shape[expert_axis])
-    workflow._parallel_style_ = ("dp_ep", data_axis, expert_axis)
-    return workflow
-
-
 def apply_dp_pp_sharding(workflow, mesh, data_axis="data",
                          stage_axis="stage"):
     """Data × PIPELINE parallelism (znicz/attention.py
@@ -419,8 +372,7 @@ def apply_dp_pp_sharding(workflow, mesh, data_axis="data",
     merely not pipelined).
     """
     apply_dp_sharding(workflow, mesh, axis=data_axis)
-    if _overlay_leading_axis(workflow, mesh, "stage_params",
-                             "n_blocks", stage_axis) == 0:
+    if _overlay_stage_axis(workflow, mesh, stage_axis) == 0:
         workflow.warning(
             "apply_dp_pp_sharding: no pipelined stack's n_blocks "
             "divides the stage axis (%d) — the workflow runs "
@@ -429,36 +381,32 @@ def apply_dp_pp_sharding(workflow, mesh, data_axis="data",
     return workflow
 
 
-def _overlay_leading_axis(workflow, mesh, params_attr, count_attr,
-                          lead_axis):
-    """The shared ep/pp leading-dim overlay (used by the plain
-    dp×ep / dp×pp appliers AND the ×tp compositions): for every unit
-    exposing ``params_attr`` (stage_params / expert_params) whose
-    ``count_attr`` (n_blocks / n_experts) divides the ``lead_axis``
-    size, put ``lead_axis`` on dim 0 ON TOP of whatever trailing
-    axes are already assigned (all-None after plain dp, the Megatron
-    column/row pairing after :func:`apply_dp_tp_sharding`), then
-    re-point the mirroring optimizer slots by name
-    (``znicz.optimizers.param_of_slot`` — shape matching alone could
-    collide).  Returns the number of units overlaid."""
+def _overlay_stage_axis(workflow, mesh, stage_axis):
+    """The leading-dim overlay of the dp×pp applier AND its ×tp
+    composition: for every unit exposing ``stage_params`` whose
+    ``n_blocks`` divides the ``stage_axis`` size, put ``stage_axis``
+    on dim 0 ON TOP of whatever trailing axes are already assigned
+    (all-None after plain dp, the Megatron column/row pairing after
+    :func:`apply_dp_tp_sharding`), then re-point the mirroring
+    optimizer slots by name (``znicz.optimizers.param_of_slot`` —
+    shape matching alone could collide).  Returns the number of
+    units overlaid."""
     from ..znicz.optimizers import param_of_slot
-    n_lead = mesh.shape[lead_axis]
+    n_lead = mesh.shape[stage_axis]
     gd_of = {gd.target: gd
              for gd in getattr(workflow, "gds", [])
              if getattr(gd, "target", None) is not None}
     overlaid = 0
     for unit in getattr(workflow, "forwards", []):
-        stacked = getattr(unit, params_attr, None)
-        if stacked is None:
-            continue
-        if getattr(unit, count_attr) % n_lead:
+        stacked = getattr(unit, "stage_params", None)
+        if stacked is None or unit.n_blocks % n_lead:
             continue
         for vec in stacked.values():
             cur = ()
             if isinstance(vec.sharding, NamedSharding):
                 cur = tuple(vec.sharding.spec)
             axes = list(cur) + [None] * (len(vec.shape) - len(cur))
-            axes[0] = lead_axis
+            axes[0] = stage_axis
             vec.sharding = NamedSharding(mesh, PartitionSpec(*axes))
         overlaid += 1
         gd = gd_of.get(unit)
@@ -488,41 +436,12 @@ def apply_dp_pp_tp_sharding(workflow, mesh, data_axis="data",
     1-device step."""
     apply_dp_tp_sharding(workflow, mesh, data_axis=data_axis,
                          model_axis=model_axis)
-    n = _overlay_leading_axis(workflow, mesh, "stage_params",
-                              "n_blocks", stage_axis)
-    if n == 0:
+    if _overlay_stage_axis(workflow, mesh, stage_axis) == 0:
         workflow.warning(
             "apply_dp_pp_tp_sharding: no pipelined stack's n_blocks "
             "divides the stage axis (%d) — the workflow runs dp×tp "
             "only" % mesh.shape[stage_axis])
     workflow._parallel_style_ = ("dp_pp_tp", data_axis, stage_axis,
-                                 model_axis)
-    return workflow
-
-
-def apply_dp_ep_tp_sharding(workflow, mesh, data_axis="data",
-                            expert_axis="expert",
-                            model_axis="model"):
-    """COMPOSED 3-axis layout: data × expert × tensor parallelism
-    (ISSUE 12).  The Megatron trailing column/row pairing on each
-    expert's matrices comes from :func:`apply_dp_tp_sharding` (the
-    MoE plan shards w1/w2's TRAILING dims, leaving the expert dim
-    alone); the expert axis then overlays dim 0.  The GShard
-    dispatch/combine einsums are plain GSPMD — no shard_map — so
-    both axes propagate: XLA lowers the dispatch to all-to-alls over
-    the expert axis while each expert's FFN einsums keep the hidden
-    dim sharded over the model axis.  ``dryrun_multichip``
-    self-verifies the composition against the 1-device step."""
-    apply_dp_tp_sharding(workflow, mesh, data_axis=data_axis,
-                         model_axis=model_axis)
-    n = _overlay_leading_axis(workflow, mesh, "expert_params",
-                              "n_experts", expert_axis)
-    if n == 0:
-        workflow.warning(
-            "apply_dp_ep_tp_sharding: no MoE block's n_experts "
-            "divides the expert axis (%d) — the workflow runs dp×tp "
-            "only" % mesh.shape[expert_axis])
-    workflow._parallel_style_ = ("dp_ep_tp", data_axis, expert_axis,
                                  model_axis)
     return workflow
 
@@ -535,7 +454,7 @@ def apply_zero_sharding(workflow, mesh=None, data_axis="data",
 
     * **Level 1** re-annotates every GD unit's optimizer slot whose
       leading dimension divides the data-axis size: dim 0 gains the
-      ``data`` axis ON TOP of whatever model/expert/stage axes the
+      ``data`` axis ON TOP of whatever model/stage axes the
       style applier put on the other dims, so each dp rank
       persistently stores 1/dp of the optimizer state in HBM.  XLA's
       sharding propagation then computes the slot update shard-local
@@ -552,7 +471,7 @@ def apply_zero_sharding(workflow, mesh=None, data_axis="data",
       grad-shard variant.
 
     Slots whose geometry does not divide the axis — or whose dim 0
-    is already owned by an expert/stage axis — stay as the style
+    is already owned by the stage axis — stay as the style
     applier left them (correct, merely not ZeRO-sharded); scalar
     slots (Adam's step counters) always stay replicated.
 
@@ -590,7 +509,7 @@ def apply_zero_sharding(workflow, mesh=None, data_axis="data",
                 cur = tuple(vec.sharding.spec)
             axes = list(cur) + [None] * (len(vec.shape) - len(cur))
             if axes[0] is not None:
-                continue  # dim 0 already owned (expert/stage axis)
+                continue  # dim 0 already owned (stage axis)
             axes[0] = data_axis
             spec = NamedSharding(mesh, PartitionSpec(*axes))
             vec.sharding = spec
@@ -626,7 +545,6 @@ def _style_appliers():
     return {
         "dp_tp": apply_dp_tp_sharding,
         "dp_sp": apply_dp_sp_sharding,
-        "dp_ep": apply_dp_ep_sharding,
         "dp_pp": apply_dp_pp_sharding,
     }
 
@@ -634,7 +552,7 @@ def _style_appliers():
 def _seq_axis_fits(workflow, n_seq):
     """Whether every sequence-parallel unit can run over an n_seq-wide
     seq axis: the shard_map specs need S % n_seq == 0, and Ulysses
-    additionally needs heads % n_seq == 0.  Unlike tp/ep/pp (whose
+    additionally needs heads % n_seq == 0.  Unlike tp/pp (whose
     appliers degrade to replicated), an sp unit runs its shard_map
     unconditionally once the mesh carries the axis — an unvalidated
     rebuild would crash the next step instead of degrading."""
@@ -654,7 +572,7 @@ def _rebuild_styled_mesh(workflow, surviving_devices, n, style):
     """Re-forms the workflow's non-DP layout over the survivors when
     divisibility allows; returns the new mesh or None (→ dp
     fallback).  On a shrink, every style preserves the OLD data-axis
-    size first (so the model/seq/expert/stage axis — which layer
+    size first (so the model/seq/stage axis — which layer
     geometry was validated against — shrinks as little as possible),
     then tries data=2; the non-data axis must keep >= 2 devices or
     the style is meaningless.  On GROWTH the preference inverts: the
@@ -679,7 +597,7 @@ def _rebuild_styled_mesh(workflow, surviving_devices, n, style):
             # axis keeps its exact old size — layer geometry was
             # validated against that size, and the new capacity
             # belongs to batch throughput, not to an unvalidated
-            # re-split of the model/seq/expert/stage plane.
+            # re-split of the model/seq/stage plane.
             candidates.insert(0, n // old_other)
         seen = set()
         for candidate in candidates:
@@ -696,7 +614,6 @@ def _rebuild_styled_mesh(workflow, surviving_devices, n, style):
                 kwargs = {"data_axis": data_axis,
                           {"dp_tp": "model_axis",
                            "dp_sp": "seq_axis",
-                           "dp_ep": "expert_axis",
                            "dp_pp": "stage_axis"}[name]: other_axis}
                 _style_appliers()[name](workflow, mesh, **kwargs)
                 return mesh

@@ -40,8 +40,8 @@ from .gd import (GradientDescent, GDTanh, GDRelu,  # noqa: F401
                  GDActivationMul, GDDropout, GDLRNormalizer)
 from .rbm import (RBM, GDRBM, EvaluatorRBM, All2AllDeconv,  # noqa: F401
                   All2AllDeconvSigmoid, All2AllDeconvTanh)
-from .attention import (Embedding, TransformerBlock,  # noqa: F401
-                        MoETransformerBlock,
+from .attention import (Embedding, LMLayer,  # noqa: F401
+                        TransformerBlock,
                         PipelinedTransformerStack, LMHead,
                         EvaluatorLM)
 from .kohonen import (KohonenForward, KohonenTrainer,  # noqa: F401

@@ -80,26 +80,6 @@ def split_qkv_arrays(wqkv, n_heads):
         for t in range(3))
 
 
-#: The per-projection parameter names the fused layout replaces.
-_QKV_NAMES = ("wq", "wk", "wv", "bq", "bk", "bv")
-
-
-def qkv_param_names(names, fused):
-    """Rewrites a canonical PARAM_NAMES tuple for the fused layout:
-    wq/wk/wv → wqkv, bq/bk/bv → bqkv (order of first occurrence)."""
-    if not fused:
-        return tuple(names)
-    out = []
-    for n in names:
-        if n in _QKV_NAMES:
-            repl = "wqkv" if n.startswith("w") else "bqkv"
-            if repl not in out:
-                out.append(repl)
-        else:
-            out.append(n)
-    return tuple(out)
-
-
 def _layer_norm(x, gamma, beta, eps=1e-5):
     import jax.numpy as jnp
     xf = x.astype(jnp.float32)
@@ -162,9 +142,9 @@ def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
 
 def layer_param_shapes(spec, embed, fused_qkv=False):
     """Parameter geometry of one layer — single source of truth for
-    TransformerBlock, the pipelined stack (which prepends a stage
-    dim) and LMLayer.  ``fused_qkv`` swaps the three (E, E)
-    projections for the single (E, 3E) fused weight.
+    LMLayer and the pipelined stack (which prepends a stage dim).
+    ``fused_qkv`` swaps the three (E, E) projections for the single
+    (E, 3E) fused weight.
 
     Dict ORDER is load-bearing: initialization draws from the seeded
     prng in iteration order, so the unfused OPT layout keeps the
@@ -221,17 +201,16 @@ def layer_param_shapes(spec, embed, fused_qkv=False):
 
 
 def layer_apply(spec, params, x, cdt, causal=True, attend=None,
-                mlp=None, buffers=None):
+                buffers=None):
     """Pure decoder layer from its spec (:func:`layer_spec`):
     ``x + operator(norm(x))``, then ``+ ffn(norm(·))``.  Shared by
-    TransformerBlock.tforward, the MoE block (which passes its
-    expert FFN via ``mlp``), the pipelined stack (whose stages must
-    be a pure (params, x) → y function) and LMLayer.  ``mlp``
-    receives the post-norm activations (B, S, E) and returns the FFN
-    output to be residual-added; None → the spec's own.  ``buffers``
-    holds what the layer reads and no gradient reaches
-    (``expert_bias``).  Returns ``(y float32, stats)``; ``stats`` is
-    ``ops.moe.moe_dropless``'s for an ``experts`` layer, else None.
+    LMLayer.tforward, the pipelined stack (whose stages must be a
+    pure (params, x) → y function) and the serving forward.
+    ``attend(q, k, v)`` is where the caller places attention (None:
+    ``ops.attention.attention``).  ``buffers`` holds what the layer
+    reads and no gradient reaches (``expert_bias``).  Returns
+    ``(y float32, stats)``; ``stats`` is ``ops.moe.moe_dropless``'s
+    for an ``experts`` layer, else None.
 
     The inner ``jax.named_scope``s are the scope vocabulary
     ``observability.programs`` reads back from the compiled program
@@ -301,7 +280,7 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
                     params.get("bo"))
     h = norm("ln2", x)
     stats = None
-    if mlp is None and spec["ffn"] == "experts":
+    if spec["ffn"] == "experts":
         from ..ops.moe import moe_dropless
         y, stats = moe_dropless(
             h.reshape(B * S, E), params["router"],
@@ -312,9 +291,7 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
         x = x + y.reshape(B, S, E)
     else:
         with jax.named_scope("mlp"):
-            if mlp is not None:
-                x = x + mlp(h)
-            elif spec["ffn"] == "gated-mlp":
+            if spec["ffn"] == "gated-mlp":
                 h = jax.nn.silu(dot(h, params["w1"])) * \
                     dot(h, params["w3"])
                 x = x + dot(h, params["w2"])
@@ -326,13 +303,12 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
 
 
 def transformer_block_apply(params, x, n_heads, causal, cdt,
-                            attend=None, mlp=None):
+                            attend=None):
     """The OPT block — pre-LN LayerNorm, full multi-head attention,
-    ReLU MLP, biases — as :func:`layer_apply` traces it: what
-    TransformerBlock, the MoE block, the pipelined stack and the
-    serving forward all run."""
+    ReLU MLP, biases — as :func:`layer_apply` traces it: what the
+    pipelined stack and the serving forward run."""
     return layer_apply(layer_spec(n_heads=n_heads), params, x, cdt,
-                       causal=causal, attend=attend, mlp=mlp)[0]
+                       causal=causal, attend=attend)[0]
 
 
 def _block_param_shapes(embed, hidden, fused_qkv=False):
@@ -396,310 +372,32 @@ class Embedding(ForwardBase):
         write(self.output, out.astype(self.compute_dtype))
 
 
-class TransformerBlock(ForwardBase):
-    """Pre-LN transformer block: x + MHA(LN(x)), then + MLP(LN(·)).
-
-    kwargs: ``n_heads``; ``mlp_ratio`` (default 4); ``causal``
-    (default True); ``seq_axis`` — when set AND the workflow's mesh
-    carries that axis, attention runs ring sequence-parallel
-    (``ops.attention.sequence_parallel_attention``); otherwise
-    blockwise/full attention on-device.
-    """
-
-    MAPPING = "transformer_block"
-
-    PARAM_NAMES = ("ln1_g", "ln1_b", "wq", "wk", "wv", "wo",
-                   "bq", "bk", "bv", "bo",
-                   "ln2_g", "ln2_b", "w1", "b1", "w2", "b2")
-
-    def __init__(self, workflow, **kwargs):
-        super(TransformerBlock, self).__init__(workflow, **kwargs)
-        self.n_heads = kwargs.get("n_heads", 4)
-        self.mlp_ratio = kwargs.get("mlp_ratio", 4)
-        self.causal = kwargs.get("causal", True)
-        self.seq_axis = kwargs.get("seq_axis")
-        #: "ring" (ppermute k/v streaming, O(S/N) memory) or
-        #: "ulysses" (two all-to-alls, dense local attention).
-        self.sp_mode = kwargs.get("sp_mode", "ring")
-        from ..ops.attention import SP_MODES
-        if self.sp_mode not in SP_MODES:
-            raise ValueError("unknown sp_mode %r — valid: %s" %
-                             (self.sp_mode, list(SP_MODES)))
-        self.batch_axis = kwargs.get("batch_axis", "data")
-        #: When set (apply_dp_tp_sp_sharding), attention keeps the
-        #: head dim sharded on this mesh axis inside the shard_map —
-        #: the tp × sp composition.
-        self.head_axis = kwargs.get("head_axis")
-        #: Ring-kernel override for the sequence-parallel path:
-        #: None → the ``sp_ring_kernel`` knob ("auto" default —
-        #: ring-flash where the platform supports it); "xla" forces
-        #: the lax streaming scan; "pallas" forces the flash body.
-        self.sp_kernel = kwargs.get("sp_kernel")
-        #: Forces the interpret-mode flash kernel inside the ring —
-        #: the CPU parity/dryrun path (tests only; never on a chip).
-        self.sp_interpret = kwargs.get("sp_interpret")
-        #: None → follow root.common.engine.remat; True/False forces.
-        self.remat = kwargs.get("remat")
-        #: Resolved at construction (None → the engine knob) so the
-        #: parameter LAYOUT is frozen into the unit — a snapshot
-        #: trained fused restores fused whatever the config says.
-        self.fused_qkv = fused_qkv_enabled(kwargs.get("fused_qkv"))
-        self.params = {name: Vector()
-                       for name in qkv_param_names(self.PARAM_NAMES,
-                                                   self.fused_qkv)}
-
-    @property
-    def trainables(self):
-        return {n: v for n, v in self.params.items() if v}
-
-    def initialize(self, device=None, **kwargs):
-        super(TransformerBlock, self).initialize(device=device,
-                                                 **kwargs)
-        batch, seq, embed = self.input.shape
-        if embed % self.n_heads:
-            raise ValueError("embed dim %d not divisible by %d heads"
-                             % (embed, self.n_heads))
-        hidden = embed * self.mlp_ratio
-        stddev = self.weights_stddev or (1.0 / numpy.sqrt(embed))
-        shapes = _block_param_shapes(embed, hidden,
-                                     fused_qkv=self.fused_qkv)
-        for name, shape in shapes.items():
-            vec = self.params[name]
-            if vec:
-                continue
-            arr = numpy.zeros(shape, dtype=numpy.float32)
-            if name.startswith("w"):
-                self.rand().fill_normal(arr, stddev=stddev)
-            elif name.endswith("_g"):
-                arr[...] = 1.0
-            vec.mem = arr
-            vec.initialize(self.device)
-        self.output.mem = numpy.zeros((batch, seq, embed),
-                                      dtype=numpy.float32)
-        self.output.initialize(self.device)
-
-    def _attend(self, q, k, v):
-        from ..ops import attention as A
-        mesh = getattr(self.workflow, "mesh", None)
-        if self.seq_axis and mesh is not None and \
-                self.seq_axis in mesh.axis_names:
-            return A.sequence_parallel_attention(
-                q, k, v, mesh, self.seq_axis, causal=self.causal,
-                batch_axis=self.batch_axis, mode=self.sp_mode,
-                head_axis=getattr(self, "head_axis", None),
-                kernel=getattr(self, "sp_kernel", None),
-                interpret=getattr(self, "sp_interpret", None))
-        if mesh is not None:
-            return A.mesh_attention(
-                q, k, v, mesh, causal=self.causal,
-                batch_axis=self.batch_axis,
-                head_axis=getattr(self, "head_axis", None))
-        return A.attention(q, k, v, causal=self.causal)
-
-    def tforward(self, read, write, params, ctx, state=None):
-        x = read(self.input)
-
-        def apply(p, h):
-            return transformer_block_apply(
-                p, h, self.n_heads, self.causal, self.compute_dtype,
-                attend=lambda q, k, v: self._attend(q, k, v))
-
-        if remat_enabled(getattr(self, "remat", None)):
-            import jax
-            apply = jax.checkpoint(apply)
-        write(self.output, apply(params, x))
-
-
-class MoETransformerBlock(TransformerBlock):
-    """Transformer block whose MLP is a top-1 Mixture-of-Experts
-    (ops/moe.py — GShard dispatch/combine einsums).  Expert parameters
-    carry a leading ``n_experts`` dimension; under a mesh with an
-    ``expert`` axis (apply_dp_ep_sharding) that dimension shards there
-    and XLA lowers the dispatch einsums to all-to-alls over ICI.
-
-    kwargs beyond TransformerBlock: ``n_experts``;
-    ``capacity_factor`` (default 1.25); ``aux_weight`` — load-balance
-    loss weight (default 0.01); ``top_k`` — experts per token
-    (default: ``root.common.engine.moe_top_k`` or 1 — the Switch/
-    GShard top-1 path; k ≥ 2 routes through ``ops.moe.topk_routing``
-    with rank-major capacity priority); ``router_z_weight`` — ST-MoE
-    router z-loss weight (default: ``root.common.engine.
-    moe_router_z`` or 0); ``expert_axis`` — recorded so the sharding
-    helper can find MoE blocks.
-
-    Router health rides the epoch accounting: ``moe_acc`` is a
-    (3 classes × 2 + n_experts) on-device accumulator —
-    [aux_sum, ticks, load_0 … load_{E−1}] — added to inside the
-    fused step and fetched by DecisionGD at epoch boundaries (the
-    ``moe.aux_loss`` / ``moe.expert_load`` gauges; router collapse
-    is visible live on the heartbeat perf section / web_status).
-    """
-
-    MAPPING = "moe_transformer_block"
-
-    PARAM_NAMES = ("ln1_g", "ln1_b", "wq", "wk", "wv", "wo",
-                   "bq", "bk", "bv", "bo",
-                   "ln2_g", "ln2_b", "router",
-                   "w1", "b1", "w2", "b2")
-
-    def __init__(self, workflow, **kwargs):
-        self.n_experts = kwargs.get("n_experts", 4)
-        self.capacity_factor = kwargs.get("capacity_factor", 1.25)
-        self.aux_weight = kwargs.get("aux_weight", 0.01)
-        top_k = kwargs.get("top_k")
-        if top_k is None:
-            top_k = config_get(root.common.engine.moe_top_k, 1)
-        self.top_k = int(top_k)
-        if not 1 <= self.top_k <= self.n_experts:
-            raise ValueError(
-                "top_k=%d must satisfy 1 <= k <= n_experts=%d"
-                % (self.top_k, self.n_experts))
-        z_weight = kwargs.get("router_z_weight")
-        if z_weight is None:
-            z_weight = config_get(root.common.engine.moe_router_z,
-                                  0.0)
-        self.router_z_weight = float(z_weight)
-        self.expert_axis = kwargs.get("expert_axis")
-        #: Optional link to the loader's class vector — buckets the
-        #: moe_acc rows per sample class (TRAIN row when unlinked).
-        self.minibatch_class_vec = kwargs.get("minibatch_class_vec")
-        #: Optional link to the loader's mask — gates padded block
-        #: ticks (all-zero mask) out of the router-health row, the
-        #: same validity treatment the evaluator accumulator applies.
-        self.minibatch_mask = kwargs.get("minibatch_mask")
-        self.moe_acc = Vector()
-        super(MoETransformerBlock, self).__init__(workflow, **kwargs)
-
-    @property
-    def tstate(self):
-        state = dict(super(MoETransformerBlock, self).tstate)
-        acc = getattr(self, "moe_acc", None)
-        if acc is None:  # block from a pre-top-k snapshot
-            acc = self.moe_acc = Vector()
-        if not acc:
-            acc.mem = numpy.zeros((3, 2 + self.n_experts),
-                                  dtype=numpy.float32)
-        state["moe_acc"] = acc
-        return state
-
-    def read_moe_acc(self, cls):
-        """Host fetch of one class's router row — [aux_sum, ticks,
-        per-expert load] (rides the Decision's epoch-boundary sync
-        like the evaluator accumulators)."""
-        acc = self.tstate["moe_acc"]
-        acc.map_read()
-        return numpy.array(acc.mem[cls])
-
-    def reset_moe_acc(self, cls):
-        acc = self.tstate["moe_acc"]
-        acc.map_write()
-        acc.mem[cls] = 0.0
-
-    def initialize(self, device=None, **kwargs):
-        batch, seq, embed = self.input.shape
-        hidden = embed * self.mlp_ratio
-        stddev = self.weights_stddev or (1.0 / numpy.sqrt(embed))
-        E = self.n_experts
-        moe_shapes = {
-            "router": (embed, E),
-            "w1": (E, embed, hidden), "b1": (E, hidden),
-            "w2": (E, hidden, embed), "b2": (E, embed),
-        }
-        for name, shape in moe_shapes.items():
-            vec = self.params[name]
-            if vec:
-                continue
-            arr = numpy.zeros(shape, dtype=numpy.float32)
-            if name in ("router", "w1", "w2"):
-                self.rand().fill_normal(arr, stddev=stddev)
-            vec.mem = arr
-            vec.initialize(self.device)
-        acc = self.tstate["moe_acc"]  # allocates when absent
-        acc.initialize(device)
-        super(MoETransformerBlock, self).initialize(device=device,
-                                                    **kwargs)
-
-    @property
-    def expert_params(self):
-        """The expert-stacked Vectors (leading n_experts dim) — what
-        apply_dp_ep_sharding shards."""
-        return {n: self.params[n] for n in ("w1", "b1", "w2", "b2")}
-
-    def tforward(self, read, write, params, ctx, state=None):
-        import jax.numpy as jnp
-        from ..ops.moe import moe_ffn_topk
-        x = read(self.input)
-        B, S, E = x.shape
-
-        def apply(p, h0):
-            """Pure (params, x) → (out, aux, z, load): the MoE side
-            outputs RIDE the return value (not ctx closure mutation),
-            so the whole block is checkpointable — a tracer born
-            inside jax.checkpoint must not leak out through ctx."""
-            box = {}
-
-            def mlp(h):
-                y, aux, z, load = moe_ffn_topk(
-                    h.reshape(B * S, E), p["router"], p["w1"],
-                    p["b1"], p["w2"], p["b2"],
-                    capacity_factor=self.capacity_factor,
-                    top_k=getattr(self, "top_k", 1))
-                box["aux"], box["z"], box["load"] = aux, z, load
-                return y.reshape(B, S, E)
-
-            out = transformer_block_apply(
-                p, h0, self.n_heads, self.causal,
-                self.compute_dtype,
-                attend=lambda q, k, v: self._attend(q, k, v),
-                mlp=mlp)
-            return out, box["aux"], box["z"], box["load"]
-
-        if remat_enabled(getattr(self, "remat", None)):
-            import jax
-            apply = jax.checkpoint(apply)
-        out, aux, z, load = apply(params, x)
-        total_aux = self.aux_weight * aux
-        z_weight = getattr(self, "router_z_weight", 0.0)
-        if z_weight:
-            # Static-zero skip keeps the pre-z traced graph (and its
-            # seeded trajectories) bit-identical when disabled.
-            total_aux = total_aux + z_weight * z
-        ctx.add_aux_loss(total_aux)
-        ctx.add_metric("%s_max_expert_load" % self.name,
-                       load.max() / jnp.maximum(load.sum(), 1.0))
-        write(self.output, out)
-        if state is not None and "moe_acc" in state:
-            # Router-health epoch row: aux + per-expert load bucketed
-            # by the minibatch class (TRAIN when no loader link) —
-            # fetched by DecisionGD with the epoch accumulators.
-            # Padded block ticks (all-zero mask) are gated out whole,
-            # like the evaluator's epoch row: filler dispatches must
-            # not dilute the mean aux or skew the load shares.
-            cvec = getattr(self, "minibatch_class_vec", None)
-            cls = read(cvec).astype(jnp.int32) if cvec is not None \
-                else jnp.int32(2)
-            mvec = getattr(self, "minibatch_mask", None)
-            valid = (read(mvec).sum() > 0).astype(jnp.float32) \
-                if mvec is not None else jnp.float32(1.0)
-            row = jnp.concatenate([
-                jnp.stack([aux.astype(jnp.float32),
-                           jnp.float32(1.0)]),
-                load.astype(jnp.float32)]) * valid
-            return {"moe_acc": state["moe_acc"].at[cls].add(row)}
-
-
 class LMLayer(ForwardBase):
-    """One decoder layer built from a spec (:func:`layer_spec`): the
-    norm kind, the operator (attention with grouped keys/values,
+    """THE decoder-layer unit, built from a spec (:func:`layer_spec`):
+    the norm kind, the operator (attention with grouped keys/values,
     per-head norm and rotary positions, or the gated short
     convolution) and the FFN (ReLU MLP, gated MLP, or a held share
     of a dropless expert layer) are data, not classes
     (docs/attention.md, "Layers from a spec").
 
     kwargs: ``spec`` (a :func:`layer_spec` dict, or its keyword
-    arguments as a dict); ``causal`` (default True); ``remat``; and
-    for an ``experts`` layer the loader's ``minibatch_class_vec`` /
+    arguments as a dict); ``causal`` (default True); ``remat``;
+    ``fused_qkv`` (one (E, 3E) projection where the spec's attention
+    has as many key/value heads as query heads); and for an
+    ``experts`` layer the loader's ``minibatch_class_vec`` /
     ``minibatch_mask``, which bucket and gate its accumulator.
+
+    Placement on a mesh is :meth:`_attend`'s alone.  ``seq_axis`` —
+    when set AND the workflow's mesh carries that axis, attention
+    runs sequence-parallel (``sp_mode``: "ring", ppermute k/v
+    streaming at O(S/N) memory, or "ulysses", two all-to-alls around
+    dense local attention; ``sp_kernel`` / ``sp_interpret`` pick the
+    ring's body, the latter for the CPU's tests only); under any
+    other mesh ``ops.attention.mesh_attention`` keeps the batch on
+    ``batch_axis`` and, where ``apply_dp_tp_sharding`` set it, the
+    heads on ``head_axis``; without a mesh ``ops.attention.
+    attention``.  The ring does not broadcast grouped key/value
+    heads: such a spec with ``seq_axis`` is refused here.
 
     An ``experts`` layer carries two buffers beside its trainables:
     ``expert_bias`` (n_experts,), the router's selection bias, which
@@ -713,18 +411,51 @@ class LMLayer(ForwardBase):
 
     MAPPING = "lm_layer"
 
+    #: What ``ffn_dim=None`` in a spec means, in layer widths.
+    mlp_ratio = 4
+
     def __init__(self, workflow, **kwargs):
         super(LMLayer, self).__init__(workflow, **kwargs)
-        self.spec = layer_spec(**kwargs["spec"])
+        self.spec = spec = layer_spec(**kwargs["spec"])
         self.causal = kwargs.get("causal", True)
+        self.seq_axis = kwargs.get("seq_axis")
+        self.sp_mode = kwargs.get("sp_mode", "ring")
+        from ..ops.attention import SP_MODES
+        if self.sp_mode not in SP_MODES:
+            raise ValueError("unknown sp_mode %r — valid: %s" %
+                             (self.sp_mode, list(SP_MODES)))
+        grouped = spec["kv_heads"] != spec["n_heads"]
+        if self.seq_axis and grouped:
+            raise ValueError(
+                "seq_axis=%r with %d key/value heads under %d query "
+                "heads: sequence-parallel attention does not "
+                "broadcast groups" % (self.seq_axis, spec["kv_heads"],
+                                      spec["n_heads"]))
+        self.batch_axis = kwargs.get("batch_axis", "data")
+        #: Set by apply_dp_tp_sharding: attention keeps the head dim
+        #: on this mesh axis inside its shard_map (tp, tp × sp).
+        self.head_axis = kwargs.get("head_axis")
+        #: None → the ``sp_ring_kernel`` knob; "xla" forces the lax
+        #: streaming scan, "pallas" the flash body.
+        self.sp_kernel = kwargs.get("sp_kernel")
+        self.sp_interpret = kwargs.get("sp_interpret")
         #: None → follow root.common.engine.remat; True/False forces.
         self.remat = kwargs.get("remat")
+        #: Resolved at construction (None → the engine knob) so the
+        #: parameter LAYOUT is frozen into the unit — a snapshot
+        #: trained fused restores fused whatever the config says.
+        self.fused_qkv = spec["operator"] == "attention" and \
+            not grouped and fused_qkv_enabled(kwargs.get("fused_qkv"))
         self.minibatch_class_vec = kwargs.get("minibatch_class_vec")
         self.minibatch_mask = kwargs.get("minibatch_mask")
-        self.params = {name: Vector()
-                       for name in layer_param_shapes(self.spec, 1)}
+        self.params = {name: Vector() for name in layer_param_shapes(
+            spec, 1, fused_qkv=self.fused_qkv)}
         self.expert_bias = Vector()
         self.moe_acc = Vector()
+
+    @property
+    def n_heads(self):
+        return self.spec["n_heads"]
 
     @property
     def has_experts(self):
@@ -760,7 +491,11 @@ class LMLayer(ForwardBase):
             raise ValueError("embed dim %d not divisible by %d heads"
                              % (embed, spec["n_heads"]))
         stddev = self.weights_stddev or (1.0 / numpy.sqrt(embed))
-        for name, shape in layer_param_shapes(spec, embed).items():
+        shapes = layer_param_shapes(
+            dict(spec, ffn_dim=spec["ffn_dim"] or
+                 self.mlp_ratio * embed),
+            embed, fused_qkv=self.fused_qkv)
+        for name, shape in shapes.items():
             vec = self.params[name]
             if vec:
                 continue
@@ -787,6 +522,24 @@ class LMLayer(ForwardBase):
                                       dtype=numpy.float32)
         self.output.initialize(self.device)
 
+    def _attend(self, q, k, v):
+        """WHERE attention runs — the one placement decision of the
+        one decoder-layer unit (class docstring)."""
+        from ..ops import attention as A
+        mesh = getattr(self.workflow, "mesh", None)
+        if self.seq_axis and mesh is not None and \
+                self.seq_axis in mesh.axis_names:
+            return A.sequence_parallel_attention(
+                q, k, v, mesh, self.seq_axis, causal=self.causal,
+                batch_axis=self.batch_axis, mode=self.sp_mode,
+                head_axis=self.head_axis, kernel=self.sp_kernel,
+                interpret=self.sp_interpret)
+        if mesh is not None:
+            return A.mesh_attention(
+                q, k, v, mesh, causal=self.causal,
+                batch_axis=self.batch_axis, head_axis=self.head_axis)
+        return A.attention(q, k, v, causal=self.causal)
+
     def tforward(self, read, write, params, ctx, state=None):
         import jax
         import jax.numpy as jnp
@@ -796,7 +549,8 @@ class LMLayer(ForwardBase):
 
         def apply(p, b, h):
             return layer_apply(self.spec, p, h, self.compute_dtype,
-                               causal=self.causal, buffers=b)
+                               causal=self.causal,
+                               attend=self._attend, buffers=b)
 
         if remat_enabled(self.remat):
             apply = jax.checkpoint(apply)
@@ -816,6 +570,26 @@ class LMLayer(ForwardBase):
             jnp.stack([stats["made"], stats["landed"],
                        jnp.float32(1.0)]), stats["load"]]) * valid
         return {"moe_acc": state["moe_acc"].at[cls].add(row)}
+
+
+class TransformerBlock(LMLayer):
+    """:class:`LMLayer` at the OPT spec — pre-LN LayerNorm, full
+    multi-head attention, ReLU MLP ``mlp_ratio`` × the width, biases
+    — under the name artifacts and snapshots know.  kwargs:
+    ``n_heads`` (default 4), ``mlp_ratio`` (default 4), and
+    LMLayer's but ``spec``."""
+
+    MAPPING = "transformer_block"
+
+    PARAM_NAMES = ("ln1_g", "ln1_b", "wq", "wk", "wv", "wo",
+                   "bq", "bk", "bv", "bo",
+                   "ln2_g", "ln2_b", "w1", "b1", "w2", "b2")
+
+    def __init__(self, workflow, **kwargs):
+        self.mlp_ratio = kwargs.get("mlp_ratio", 4)
+        super(TransformerBlock, self).__init__(
+            workflow, **dict(kwargs, spec={
+                "n_heads": kwargs.get("n_heads", 4)}))
 
 
 class RMSNorm(ForwardBase):
@@ -893,10 +667,8 @@ class PipelinedTransformerStack(ForwardBase):
         #: Fused-QKV layout, frozen at construction like
         #: TransformerBlock's.
         self.fused_qkv = fused_qkv_enabled(kwargs.get("fused_qkv"))
-        self.params = {name: Vector()
-                       for name in qkv_param_names(
-                           TransformerBlock.PARAM_NAMES,
-                           self.fused_qkv)}
+        self.params = {name: Vector() for name in _block_param_shapes(
+            1, 1, fused_qkv=self.fused_qkv)}
 
     @property
     def trainables(self):
@@ -1072,10 +844,6 @@ class GDEmbedding(GradientDescentBase):
 
 class GDTransformerBlock(GradientDescentBase):
     MAPPING = "transformer_block"
-
-
-class GDMoETransformerBlock(GradientDescentBase):
-    MAPPING = "moe_transformer_block"
 
 
 class GDLMHead(GradientDescentBase):

@@ -80,10 +80,11 @@ class DecisionGD(DecisionBase, IResultProvider):
         self.epoch_nonfinite = [0.0, 0.0, 0.0]
         self.epoch_grad_norm = [0.0, 0.0, 0.0]
         self.epoch_grad_norm_max = [0.0, 0.0, 0.0]
-        # MoE router health (ISSUE 12): per class-epoch dict with
-        # mean aux loss per tick and the max expert-load share,
-        # fetched from every MoE block's moe_acc with the same
-        # epoch-boundary sync and published as moe.* gauges.
+        # The expert layers' share (docs/moe.md): per class-epoch
+        # dict of assignments made / landed a tick and the fullest
+        # held expert's share, fetched from every expert layer's
+        # moe_acc with the same epoch-boundary sync and published as
+        # moe.* gauges.
         self.epoch_moe = [None, None, None]
         self.min_validation_err = 1.0e30
         self.min_validation_epoch = 0
@@ -118,7 +119,6 @@ class DecisionGD(DecisionBase, IResultProvider):
         self.epoch_grad_norm[cls] = float(health[1]) / finite_ticks
         self.epoch_grad_norm_max[cls] = float(health[2])
         self.evaluator.reset_health_acc(cls)
-        self._fetch_moe_metrics(cls)
         self._fetch_moe_share(cls)
 
     def _fetch_moe_share(self, cls):
@@ -143,46 +143,10 @@ class DecisionGD(DecisionBase, IResultProvider):
         share = {"assignments_made": made / ticks,
                  "assignments_landed": landed / ticks,
                  "max_load_frac": max_share}
-        # beside what _fetch_moe_metrics wrote, where a workflow holds
-        # capacity blocks too
-        self.epoch_moe[cls] = dict(self.epoch_moe[cls] or {}, **share)
+        self.epoch_moe[cls] = share
         if cls == TRAIN:
             from ..observability import attribution
             attribution.note_moe_share(**share)
-
-    def _fetch_moe_metrics(self, cls):
-        """Folds every MoE block's router accumulator into the epoch
-        bucket and the live ``moe.aux_loss`` / ``moe.expert_load``
-        gauges (heartbeat perf section + web_status) — router
-        collapse is visible the epoch it happens."""
-        blocks = [u for u in getattr(self.workflow, "forwards", ())
-                  if hasattr(u, "read_moe_acc")]
-        if not blocks:
-            return
-        aux_sum = ticks = 0.0
-        shares = {}
-        max_share = 0.0
-        for blk in blocks:
-            row = blk.read_moe_acc(cls)
-            blk.reset_moe_acc(cls)
-            aux_sum += float(row[0])
-            ticks += float(row[1])
-            load = row[2:]
-            total = max(float(load.sum()), 1.0)
-            for i, v in enumerate(load):
-                share = float(v) / total
-                shares[(blk.name, i)] = share
-                max_share = max(max_share, share)
-        if not ticks:
-            return
-        moe = {"aux_loss": aux_sum / ticks,
-               "max_load_frac": max_share,
-               "n_experts": sum(b.n_experts for b in blocks)}
-        self.epoch_moe[cls] = moe
-        if cls == TRAIN:  # the training router is the live signal
-            from ..observability import attribution
-            attribution.note_moe(moe["aux_loss"], max_share,
-                                 moe["n_experts"], shares)
 
     # -- remote (master-side) accumulation: per-tick metrics arrive in
     # worker updates instead of the on-device epoch accumulator
@@ -198,8 +162,6 @@ class DecisionGD(DecisionBase, IResultProvider):
                      "epoch_grad_norm_max"):
             if not hasattr(self, attr):
                 setattr(self, attr, [0.0, 0.0, 0.0])
-        if not hasattr(self, "epoch_moe"):  # pre-top-k snapshot
-            self.epoch_moe = [None, None, None]
 
     def accumulate_remote(self, cls, metrics, epoch=None):
         """Buckets are keyed by (epoch, cls): with several workers,

@@ -2,10 +2,11 @@
 
 The reference has no attention models (SURVEY §5: long-context absent
 in the 2013-15 framework); this sample exercises the TPU build's
-long-context stack end-to-end: Embedding → N × TransformerBlock
-(optionally ring sequence-parallel over a mesh ``seq`` axis) →
-LMHead (tied weights) → EvaluatorLM → DecisionGD → per-unit GD, the
-whole tick one fused XLA computation like every other workflow.
+long-context stack end-to-end: Embedding → N decoder layers
+(TransformerBlock, or LMLayer from a spec; optionally ring
+sequence-parallel over a mesh ``seq`` axis) → LMHead (tied weights)
+→ EvaluatorLM → DecisionGD → per-unit GD, the whole tick one fused
+XLA computation like every other workflow.
 
 The bundled dataset is the **first-token recall** task: every label
 equals the sequence's FIRST token, so the model cannot succeed
@@ -23,11 +24,9 @@ from ...loader.fullbatch import FullBatchLoader
 from ...plumbing import Repeater
 from ...accelerated_units import AcceleratedWorkflow
 from ..attention import (Embedding, EvaluatorLM, GDEmbedding,
-                         GDLMHead, GDLMLayer, GDMoETransformerBlock,
-                         GDPipelinedStack, GDRMSNorm,
-                         GDTransformerBlock, LMHead, LMLayer,
-                         MoETransformerBlock,
-                         PipelinedTransformerStack, RMSNorm,
+                         GDLMHead, GDLMLayer, GDPipelinedStack,
+                         GDRMSNorm, GDTransformerBlock, LMHead,
+                         LMLayer, PipelinedTransformerStack, RMSNorm,
                          TransformerBlock)
 from ..decision import DecisionGD
 
@@ -58,21 +57,21 @@ class FirstTokenLoader(FullBatchLoader):
 class TinyLMWorkflow(AcceleratedWorkflow):
     """The LM training workflow (long-context capability sample).
 
-    ``layers``: a list of ``znicz.attention.layer_spec`` dicts builds
-    the body from them instead of OPT blocks — one ``LMLayer`` a spec
-    (``block<i>``), no learned positions in the embedding, an RMS
-    norm (``final_norm``) before the tied head: the shape of the
-    hybrid LMs (``samples/lfm2.py``)."""
+    The body is ``n_blocks`` OPT blocks (``TransformerBlock``), or —
+    ``layers``: a list of ``znicz.attention.layer_spec`` dicts — one
+    ``LMLayer`` a spec, with no learned positions in the embedding
+    and an RMS norm (``final_norm``) before the tied head: the shape
+    of the hybrid LMs (``samples/lfm2.py``).  Either way the units
+    are ``block<i>`` and take the same placement arguments."""
 
     def __init__(self, workflow, vocab_size=16, seq_len=32,
                  embed_dim=32, n_heads=4, n_blocks=1,
                  minibatch_size=64, learning_rate=0.01,
                  gradient_moment=0.9, max_epochs=8, seq_axis=None,
                  sp_mode="ring", sp_kernel=None, sp_interpret=None,
-                 n_experts=0, expert_axis=None, top_k=None,
-                 router_z_weight=None, pipelined=False,
-                 stage_axis=None, n_microbatches=4, schedule=None,
-                 n_chunks=None, fused_qkv=None, layers=None,
+                 pipelined=False, stage_axis=None, n_microbatches=4,
+                 schedule=None, n_chunks=None, fused_qkv=None,
+                 layers=None,
                  loader_cls=FirstTokenLoader, loader_config=None,
                  **kwargs):
         super(TinyLMWorkflow, self).__init__(workflow, **kwargs)
@@ -93,14 +92,11 @@ class TinyLMWorkflow(AcceleratedWorkflow):
 
         self.forwards = [self.embedding]
         prev = self.embedding
-        if layers is not None and (pipelined or n_experts):
+        if layers is not None and pipelined:
             raise ValueError(
-                "layers=[specs] builds the whole body: it goes with "
-                "neither pipelined=True nor n_experts>0")
-        if pipelined and n_experts:
-            raise ValueError(
-                "pipelined=True with n_experts>0 is not supported — "
-                "the pipelined stack holds dense blocks only")
+                "layers=[specs] builds the whole body: it does not "
+                "go with pipelined=True (the pipelined stack holds "
+                "OPT blocks only)")
         if pipelined:
             stack = PipelinedTransformerStack(
                 self, n_blocks=n_blocks, n_heads=n_heads,
@@ -113,12 +109,19 @@ class TinyLMWorkflow(AcceleratedWorkflow):
             self.forwards.append(stack)
             prev = stack
             n_blocks = 0
-        for i, spec in enumerate(layers or ()):
-            block = LMLayer(
-                self, spec=spec,
+        bodies = [(LMLayer, {"spec": spec}) for spec in layers] \
+            if layers is not None else \
+            [(TransformerBlock, {"n_heads": n_heads})] * n_blocks
+        for i, (cls, what) in enumerate(bodies):
+            block = cls(
+                self, causal=True, seq_axis=seq_axis, sp_mode=sp_mode,
+                sp_kernel=sp_kernel, sp_interpret=sp_interpret,
+                fused_qkv=fused_qkv,
+                # Bucket an expert layer's accumulator rows by
+                # sample class and gate padded ticks out of them.
                 minibatch_class_vec=self.loader.minibatch_class_vec,
                 minibatch_mask=self.loader.minibatch_mask,
-                name="block%d" % i)
+                name="block%d" % i, **what)
             block.link_from(prev)
             block.input = prev.output
             self.forwards.append(block)
@@ -129,33 +132,6 @@ class TinyLMWorkflow(AcceleratedWorkflow):
             norm.input = prev.output
             self.forwards.append(norm)
             prev = norm
-            n_blocks = 0
-        for i in range(n_blocks):
-            if n_experts:
-                block = MoETransformerBlock(
-                    self, n_heads=n_heads, causal=True,
-                    seq_axis=seq_axis, sp_mode=sp_mode,
-                    sp_kernel=sp_kernel, sp_interpret=sp_interpret,
-                    n_experts=n_experts, top_k=top_k,
-                    router_z_weight=router_z_weight,
-                    fused_qkv=fused_qkv, expert_axis=expert_axis,
-                    # Buckets the router-health accumulator rows by
-                    # sample class and gates padded ticks
-                    # (moe.aux_loss / moe.expert_load).
-                    minibatch_class_vec=(
-                        self.loader.minibatch_class_vec),
-                    minibatch_mask=self.loader.minibatch_mask,
-                    name="block%d" % i)
-            else:
-                block = TransformerBlock(
-                    self, n_heads=n_heads, causal=True,
-                    seq_axis=seq_axis, sp_mode=sp_mode,
-                    sp_kernel=sp_kernel, sp_interpret=sp_interpret,
-                    fused_qkv=fused_qkv, name="block%d" % i)
-            block.link_from(prev)
-            block.input = prev.output
-            self.forwards.append(block)
-            prev = block
 
         self.head = LMHead(self, vocab_size=vocab_size,
                            tie_to=self.embedding, name="head")
@@ -185,7 +161,6 @@ class TinyLMWorkflow(AcceleratedWorkflow):
         for unit in reversed(self.forwards):
             cls = {Embedding: GDEmbedding,
                    TransformerBlock: GDTransformerBlock,
-                   MoETransformerBlock: GDMoETransformerBlock,
                    PipelinedTransformerStack: GDPipelinedStack,
                    LMLayer: GDLMLayer, RMSNorm: GDRMSNorm,
                    LMHead: GDLMHead}[type(unit)]
@@ -208,9 +183,6 @@ def run(load, main):
          embed_dim=config_get(cfg.embed_dim, 32),
          n_heads=config_get(cfg.n_heads, 4),
          n_blocks=config_get(cfg.n_blocks, 1),
-         n_experts=config_get(cfg.n_experts, 0),
-         top_k=config_get(cfg.top_k, None),
-         router_z_weight=config_get(cfg.router_z_weight, None),
          pipelined=config_get(cfg.pipelined, False),
          schedule=config_get(cfg.schedule, None),
          n_chunks=config_get(cfg.n_chunks, None),
