@@ -9,7 +9,10 @@ the math is bit-for-bit the same step (checkpointing only re-runs the
 forward inside the backward).
 """
 
+import collections
 import contextlib
+import functools
+import re
 
 import numpy
 import pytest
@@ -134,15 +137,39 @@ def _one_step_params(remat, **kwargs):
                 for n, v in wf.compiler._param_vecs.items()}
 
 
-@pytest.mark.parametrize("family", ["dense", "moe", "pipelined"])
-def test_remat_step_matches_plain(family, f32_precision):
+@pytest.fixture
+def flash_interpret(monkeypatch):
+    """Attention dispatch selects the flash kernels, as on a TPU, and
+    they run in interpret mode: the custom VJP, its residuals and the
+    two names are the chip's."""
+    from veles_tpu.ops import attention as A
+    from veles_tpu.ops import pallas_attention as PA
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    monkeypatch.setattr(PA, "pallas_attention", functools.partial(
+        PA.pallas_attention, interpret=True))
+
+
+#: A geometry inside ``pallas_attention.supports``: 2 heads of 64
+#: over 128 positions.
+_FLASH = dict(embed_dim=128, n_heads=2, seq_len=128, minibatch_size=4,
+              loader_config={"n_train": 64, "n_valid": 16})
+
+
+@pytest.mark.parametrize("family", ["dense", "moe", "pipelined",
+                                    "dense-flash", "pipelined-flash"])
+def test_remat_step_matches_plain(family, f32_precision, request):
     """Checkpointing must not change the math — the recompute is the
     same computation, so any difference is only XLA re-fusing around
     the checkpoint boundary (float-noise level).  (The MoE case also
     proves the expert layer's counts survive the checkpoint
     boundary: side outputs ride the return value, not ctx closure
-    mutation.)"""
+    mutation.)  The ``-flash`` families take the kernel path, where
+    the checkpoint keeps the forward kernel's output."""
     kwargs = {"n_blocks": 2, "seq_len": 32, "minibatch_size": 32}
+    if family.endswith("-flash"):
+        request.getfixturevalue("flash_interpret")
+        kwargs.update(_FLASH)
+        family = family[:-len("-flash")]
     if family == "moe":
         from veles_tpu.znicz.attention import layer_spec
         kwargs["layers"] = [
@@ -178,3 +205,187 @@ def test_unit_kwarg_overrides_config():
     with _remat_config(False):
         assert remat_enabled(None) is False
         assert remat_enabled(True) is True
+
+
+# -- what the layers' checkpoint keeps (ISSUE 32) ------------------------
+
+
+def _layer_loss(wrap, interpret, n_layers=2):
+    """``(loss, params, x)``: ``n_layers`` OPT layers (2 heads of 64,
+    128 positions, bfloat16 operands), each under ``wrap``; attention
+    through the flash kernels in interpret mode, or XLA's."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.ops import pallas_attention as PA
+    from veles_tpu.znicz import attention as Z
+    B, S, H, D = 2, 128, 2, 64
+    spec = Z.layer_spec(n_heads=H, ffn_dim=64)
+    key = jax.random.PRNGKey(0)
+    params = {
+        name: 0.02 * jax.random.normal(jax.random.fold_in(key, i), shape)
+        for i, (name, shape) in enumerate(
+            Z.layer_param_shapes(spec, H * D).items())}
+    x = jax.random.normal(key, (B, S, H * D))
+    attend = functools.partial(PA.pallas_attention, causal=True,
+                               interpret=True) if interpret else None
+
+    def layer(p, h):
+        return Z.layer_apply(spec, p, h, jnp.bfloat16,
+                             attend=attend)[0]
+
+    layer = wrap(layer)
+
+    def loss(p, h):
+        for _ in range(n_layers):
+            h = layer(p, h)
+        return (h * h).sum()
+
+    return loss, params, x
+
+
+def _wrap(name):
+    """What a case wraps each layer in, by the case's name."""
+    import jax
+    from veles_tpu.znicz.attention import checkpointed
+    return {"none": lambda fn: fn, "bare": jax.checkpoint,
+            "checkpointed": checkpointed}[name]
+
+
+def _residuals(loss, *args):
+    """What autodiff stores for the backward pass, arguments left
+    out: a list of (shape, dtype name, where it came from)."""
+    from jax._src.ad_checkpoint import saved_residuals
+    return [(tuple(a.shape), str(a.dtype), why)
+            for a, why in saved_residuals(loss, *args)
+            if not why.startswith("from the argument")]
+
+
+def _kernel_calls(loss, *args):
+    import jax
+    text = str(jax.make_jaxpr(jax.grad(loss))(*args))
+    return {kernel: len(re.findall(r"\bname=%s\b" % kernel, text))
+            for kernel in ("flash_fwd", "flash_dq", "flash_dkv")}
+
+
+def test_checkpoint_keeps_what_the_flash_forward_produced():
+    """Beside what a bare ``jax.checkpoint`` stores (each layer's
+    input), a layer keeps exactly the kernel's output — one
+    compute-dtype activation in the backward's (B·H, S, D) layout —
+    and its float32 log-sum-exp rows: no q, k, v, no projection, no
+    MLP activation."""
+    from veles_tpu.ops import pallas_attention as PA
+    ours = _residuals(*_layer_loss(_wrap("checkpointed"), True))
+    kept = collections.Counter(r[:2] for r in ours)
+    bare = collections.Counter(
+        r[:2] for r in _residuals(*_layer_loss(_wrap("bare"), True)))
+    assert kept - bare == {((4, 128, 64), "bfloat16"): 2,
+                           ((4, 128), "float32"): 2}
+    assert not bare - kept
+    named = [why for _, _, why in ours if "named" in why]
+    assert len(named) == 2 and all(PA.FLASH_LSE in w for w in named)
+
+
+@pytest.mark.parametrize("wrap,forwards", [
+    ("none", 2), ("bare", 4), ("checkpointed", 2)])
+def test_recompute_holds_no_flash_forward(wrap, forwards):
+    """Two attention layers: the gradient holds one ``flash_dq`` and
+    one ``flash_dkv`` a layer whatever wraps it, and ONE
+    ``flash_fwd`` a layer under the layers' checkpoint — as without
+    any; a checkpoint with no policy runs the kernel again in every
+    recompute."""
+    assert _kernel_calls(*_layer_loss(_wrap(wrap), True)) == {
+        "flash_fwd": forwards, "flash_dq": 2, "flash_dkv": 2}
+
+
+@pytest.mark.parametrize("wrap,forwards", [("bare", 4),
+                                           ("checkpointed", 2)])
+def test_ring_keeps_every_chunks_partial(wrap, forwards):
+    """The ring reaches the same custom VJP through ``flash_chunk``:
+    over two sequence shards a checkpointed layer keeps each of its
+    two chunks' output and rows (the names are given inside
+    ``shard_map``), and the recompute runs no forward kernel."""
+    import jax
+    from veles_tpu.ops import attention as A
+    from veles_tpu.parallel import make_mesh
+    mesh = make_mesh(axes={"seq": 2})
+    key = jax.random.PRNGKey(3)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                                 (2, 32, 3, 5)) for i in range(3))
+
+    @_wrap(wrap)
+    def attend(q, k, v):
+        return A.sequence_parallel_attention(
+            q, k, v, mesh, "seq", causal=True, kernel="pallas",
+            interpret=True)
+
+    def loss(q, k, v):
+        return (attend(q, k, v) ** 2).sum()
+
+    assert _kernel_calls(loss, q, k, v) == {
+        "flash_fwd": forwards, "flash_dq": 2, "flash_dkv": 2}
+
+
+def test_checkpoint_over_xla_attention_is_the_bare_one():
+    """Where no value came out of the flash kernel the policy finds
+    nothing to save: the stored residuals are the bare
+    ``jax.checkpoint``'s one for one, the gradient's program lowers
+    to the same text, and the gradients are the same bits."""
+    import jax
+    ours, params, x = _layer_loss(_wrap("checkpointed"), False)
+    bare, _, _ = _layer_loss(_wrap("bare"), False)
+    assert _residuals(ours, params, x) == _residuals(bare, params, x)
+    assert _kernel_calls(ours, params, x)["flash_fwd"] == 0
+    ours, bare = (jax.jit(jax.grad(f)) for f in (ours, bare))
+    assert ours.lower(params, x).as_text() == \
+        bare.lower(params, x).as_text()
+    for name, grad in ours(params, x).items():
+        numpy.testing.assert_array_equal(grad, bare(params, x)[name])
+
+
+def test_kept_output_gives_the_second_calls_bits():
+    """A kept value is the value the recompute's kernel call would
+    have produced: gradients under the layers' checkpoint equal a
+    bare ``jax.checkpoint``'s bit for bit.  (Here, where the recompute
+    reproduces the forward pass; on the chip the parent's did not, to
+    the last bit — PERF.md §6, PR 32.)"""
+    import jax
+    ours, params, x = _layer_loss(_wrap("checkpointed"), True)
+    bare, _, _ = _layer_loss(_wrap("bare"), True)
+    ours = jax.jit(jax.grad(ours))(params, x)
+    bare = jax.jit(jax.grad(bare))(params, x)
+    for name in ours:
+        numpy.testing.assert_array_equal(ours[name], bare[name])
+
+
+@pytest.mark.parametrize("pipelined", [False, True],
+                         ids=["LMLayer", "stack"])
+def test_both_checkpoint_sites_use_the_one_helper(pipelined,
+                                                  monkeypatch,
+                                                  flash_interpret,
+                                                  f32_precision):
+    """``LMLayer.tforward`` and the pipelined stack's block function
+    go through ``checkpointed`` — so the one-tick step on the kernel
+    path, with a bare ``jax.checkpoint`` swapped in under them (the
+    forward kernel then runs twice a block), lands on the same
+    parameters, to the re-fusing of the interpreted kernel around the
+    checkpoint's boundary."""
+    import jax
+    from veles_tpu.znicz import attention as Z
+    kwargs = dict(_FLASH, n_blocks=2)
+    if pipelined:
+        kwargs.update(pipelined=True, n_microbatches=2)
+    wrapped = []
+
+    def recording(fn):
+        wrapped.append(fn)
+        return jax.checkpoint(fn)
+
+    ours = _one_step_params(True, **kwargs)
+    monkeypatch.setattr(Z, "checkpointed", recording)
+    bare = _one_step_params(True, **kwargs)
+    assert wrapped
+    for name in ours:
+        numpy.testing.assert_allclose(
+            ours[name], bare[name], rtol=1e-5, atol=1e-7,
+            err_msg="param %s differs from the bare checkpoint's"
+            % name)

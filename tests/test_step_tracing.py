@@ -206,6 +206,40 @@ def test_parse_hlo_gives_a_nameless_fusion_its_bodys_placing():
     assert table["copy.1"] == (None, None, None)
 
 
+@pytest.mark.parametrize("forwards", [2, 4], ids=["kept", "twice"])
+def test_scopes_count_the_flash_calls_of_the_program(forwards):
+    """``scopes()`` sets ``attention.flash.fwd_calls`` / ``.dq_calls``
+    (labelled with the program) from the parse it makes anyway: by
+    instruction name, ``flash_fwd`` alone or ``flash_fwd.<n>``."""
+    from veles_tpu.observability.metrics import registry
+
+    class Lowered(object):
+        def compile(self, compiler_options=None):
+            return self
+
+        def as_text(self):
+            calls = ["flash_fwd"] + ["flash_fwd.%d" % i
+                                     for i in range(1, forwards)]
+            calls += ["flash_dq", "flash_dq.7", "flash_dkv.1",
+                      "flash_dkv.2", "flash_fwdish.3"]
+            return "ENTRY %main () -> f32[] {\n" + "".join(
+                "  %%%s = f32[] custom-call(), custom_call_target="
+                '"tpu_custom_call"\n' % name for name in calls) + "}"
+
+    programs.reset()
+    programs.register("block_step", Lowered, ())
+    try:
+        table = programs.scopes("block_step")
+    finally:
+        programs.reset()
+    label = {"program": "block_step"}
+    assert registry.peek("attention.flash.fwd_calls",
+                         label).value == forwards
+    assert registry.peek("attention.flash.dq_calls", label).value == 2
+    assert programs.kernel_calls(table, "flash_dkv") == 2
+    assert registry.peek("attention.flash.fwd_calls") is None
+
+
 def test_a_newer_compiles_program_takes_the_name_over():
     """Within one compile the program with more ticks a dispatch
     keeps the name (a remainder block does not take it); a program of

@@ -18,6 +18,7 @@ be read back without one.
 """
 
 import os
+import re
 
 import pytest
 
@@ -72,6 +73,47 @@ def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _flash_calls(text):
+    """(``flash_fwd``, ``flash_dq``, ``flash_dkv``) instructions of a
+    compiled program's text."""
+    return tuple(
+        len(re.findall(r"%%%s[.\d]* = [^\n]*tpu_custom_call" % kernel,
+                       text))
+        for kernel in ("flash_fwd", "flash_dq", "flash_dkv"))
+
+
+#: ``lfm2-24b-a2b.train``'s attention layer (the MLP cut narrow: the
+#: compile is of the attention path).
+LFM2_LAYER = dict(norm="rms", kv_heads=8, qk_norm=True, rope_theta=1e6,
+                  bias=False, ffn="gated-mlp")
+
+
+def _layer_step(wrap, shape, sharding, **spec):
+    """Loss and gradients of two decoder layers, each under the
+    layers' checkpoint (``wrap`` "checkpointed") or a ``jax.checkpoint``
+    with no policy ("bare"), at a cell's attention geometry, and the
+    shapes to lower it from.  The loss is not linear, so its own
+    forward pass stays in the program beside the backward's
+    recompute."""
+    from veles_tpu.znicz import attention as Z
+    B, S, H, D = shape
+    spec = Z.layer_spec(n_heads=H, ffn_dim=256, **spec)
+    wrap = {"checkpointed": Z.checkpointed, "bare": jax.checkpoint}[wrap]
+    layer = wrap(lambda p, h: Z.layer_apply(spec, p, h,
+                                            jnp.bfloat16)[0])
+
+    def loss(params, x):
+        for p in params:
+            x = layer(p, x)
+        return (x * x).sum()
+
+    params = [{name: _struct(shape, jnp.float32, sharding)
+               for name, shape in
+               Z.layer_param_shapes(spec, H * D).items()}] * 2
+    return jax.jit(jax.value_and_grad(loss)), \
+        (params, _struct((B, S, H * D), jnp.float32, sharding))
+
+
 @pytest.mark.parametrize("shape,inputs", [
     (LM, "float32"),
     ((LM[0], 2048) + LM[2:], "float32"),
@@ -110,6 +152,52 @@ def test_flash_attention_compiles(one_chip, grad, operands, shape,
     x = _struct(shape, jnp.dtype(inputs), one_chip)
     text = _compiled_text(fn, x, x, x)
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("shape,spec,wrap,calls", [
+    (HEAD_64, {}, "checkpointed", (2, 2, 2)),
+    (OPT_6_7B, {}, "checkpointed", (2, 2, 2)),
+    (LFM2, LFM2_LAYER, "checkpointed", (2, 2, 2)),
+    (HEAD_64, {}, "bare", (4, 2, 2)),
+], ids=["head64", "opt-6.7b", "lfm2", "head64, no policy"])
+def test_checkpointed_layer_runs_the_flash_forward_once(
+        one_chip, monkeypatch, shape, spec, wrap, calls):
+    """The three cells' attention layers under the layers'
+    checkpoint, forward + backward: the compiled program holds
+    ``flash_fwd`` : ``flash_dq`` : ``flash_dkv`` = 1 : 1 : 1 — the
+    recompute reads the forward kernel's kept output.  A bare
+    ``jax.checkpoint`` runs the kernel again in every recompute."""
+    from veles_tpu.ops import attention as A
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    step, structs = _layer_step(wrap, shape, one_chip, **spec)
+    assert _flash_calls(step.lower(*structs).compile().as_text()) \
+        == calls
+
+
+@pytest.mark.parametrize("wrap,fwd_calls", [("checkpointed", 2),
+                                            ("bare", 4)])
+def test_scopes_publish_the_flash_call_counts(one_chip, monkeypatch,
+                                              wrap, fwd_calls):
+    """``programs.scopes()`` sets ``attention.flash.fwd_calls`` /
+    ``.dq_calls`` from its parse of the program: equal where every
+    layer kept its forward kernel's output, two to one where the
+    recompute runs it again."""
+    from veles_tpu.observability import programs
+    from veles_tpu.observability.metrics import registry
+    from veles_tpu.ops import attention as A
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    step, structs = _layer_step(wrap, HEAD_64, one_chip)
+    programs.reset()
+    programs.register("block_step", lambda: step.lower(*structs), ())
+    try:
+        table = programs.scopes("block_step")
+    finally:
+        programs.reset()
+    label = {"program": "block_step"}
+    assert registry.peek("attention.flash.fwd_calls",
+                         label).value == fwd_calls
+    assert registry.peek("attention.flash.dq_calls", label).value == 2
+    assert programs.kernel_calls(table, "flash_dkv") == 2
 
 
 @pytest.mark.parametrize("rows", [10240, 65536],
@@ -242,7 +330,8 @@ def test_grouped_query_layer_partitions_over_a_dp_mesh(topo,
     placement for FOUR described chips, batch over ``data``: the unit
     hands ``layer_apply`` an ``attend`` that wraps the kernels in
     ``shard_map``, so the partitioned program compiles and holds
-    them.  (A unit that passes no ``attend`` traces a bare Mosaic
+    them — one call of each kernel, under the unit's checkpoint.  (A
+    unit that passes no ``attend`` traces a bare Mosaic
     call into the GSPMD program, which the compiler refuses.)"""
     import numpy
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -269,9 +358,10 @@ def test_grouped_query_layer_partitions_over_a_dp_mesh(topo,
         out = []
         layer.tforward(lambda vec: x, lambda vec, val: out.append(val),
                        p, None)
-        return out[0].sum()
+        return (out[0] * out[0]).sum()
 
-    text = _compiled_text(jax.grad(loss), params, x)
-    # the recompute's forward, dq, dk/dv (the loss's own forward is
-    # dead code under ``grad``)
-    assert text.count("tpu_custom_call") >= 3
+    text = _compiled_text(jax.value_and_grad(loss), params, x)
+    # The names are given inside ``shard_map``: the unit's checkpoint
+    # keeps each shard's forward output, so the recompute holds dq
+    # and dk/dv and no second forward.
+    assert _flash_calls(text) == (1, 1, 1)

@@ -27,6 +27,8 @@ the workflow has stopped and every device array is deleted.
 import re
 import threading
 
+from .metrics import registry
+
 #: What an instruction's phase can be; ``None`` is "unscoped" (scan
 #: bookkeeping, copies, an instruction without ``op_name``).
 PHASES = ("forward", "recompute", "backward", "update")
@@ -152,6 +154,13 @@ def parse_hlo(text, units):
     return table
 
 
+def kernel_calls(table, kernel):
+    """How many instructions of a scope table are calls of the Pallas
+    kernel ``kernel`` (``pallas_call(name=…)`` → ``flash_fwd.28``)."""
+    return sum(1 for name in table
+               if name.split(".")[0] == kernel)
+
+
 #: A compiler option at its default.  ``Lowered.compile()`` hands
 #: back the executable it already has, and the lowering is the one
 #: the dispatch ran from; with an option it compiles anew.
@@ -187,7 +196,9 @@ def scopes(program):
     instruction of the compiled ``program``, or None where no such
     program was dispatched.  Instruction names are as the device
     trace gives them, without the ``%``.  The first call compiles
-    (:func:`_compiled_text`) and parses; later calls return the same
+    (:func:`_compiled_text`) and parses, and sets the gauges
+    ``attention.flash.fwd_calls`` / ``.dq_calls`` (labelled with the
+    program's name) from that parse; later calls return the same
     table."""
     with _lock:
         entry = _programs.get(program)
@@ -197,4 +208,12 @@ def scopes(program):
         entry.table = parse_hlo(_compiled_text(entry.lower()),
                                 entry.units)
         entry.lower = None
+        # Equal counts: every checkpointed layer kept its forward
+        # kernel's output (``znicz.attention.checkpointed``); two to
+        # one: each recompute runs the kernel again.
+        label = {"program": program}
+        registry.gauge("attention.flash.fwd_calls", label).set(
+            kernel_calls(entry.table, "flash_fwd"))
+        registry.gauge("attention.flash.dq_calls", label).set(
+            kernel_calls(entry.table, "flash_dq"))
     return entry.table

@@ -35,7 +35,11 @@ choices, for exactly one geometry family:
     (FLOPs are free at this arithmetic intensity; HBM traffic is
     not): one kernel produces dq gridded over query blocks, one
     produces dk/dv gridded over key blocks — no atomics, no
-    cross-block races.
+    cross-block races;
+  * the forward rule NAMES what only the kernel can rebuild, its
+    output and logsumexp rows (``FLASH_OUT``, ``FLASH_LSE``): the
+    layers' checkpoint (``znicz.attention.checkpointed``) keeps the
+    two and its recompute calls no kernel (ISSUE 32).
 
 Head size 64 (OPT-125M…1.3B, GPT-2, BERT, T5; ISSUE 27) runs the
 SAME three training kernels with the same block shapes: a
@@ -108,6 +112,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import resilience
 
@@ -129,6 +134,16 @@ NEG_INF = -1e30
 #: shape for every head size.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
+
+#: ``jax.ad_checkpoint.checkpoint_name``s of what only the forward
+#: kernel can rebuild: its output and its log-sum-exp rows, as the
+#: (B·H, S, D) and (B·H, S) arrays the backward kernels read.  A
+#: ``jax.checkpoint`` whose policy saves these names
+#: (``znicz.attention.checkpointed``) keeps them from the forward
+#: pass, and its recompute holds no ``flash_fwd`` (ISSUE 32); under
+#: any other checkpoint, or none, a name is an identity.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 
 #: Geometry contract: lane-native head dim, tile-aligned sequence.
 LANE = 128
@@ -658,19 +673,25 @@ def _flash_lse_fwd(q, k, v, qoff, koff, causal, kv_len, bq, bk, od,
     of, lsef = _flash_fwd_flat(_to_flat(q), _to_flat(k), _to_flat(v),
                                qoff, koff, causal, kv_len, bq, bk,
                                od, interpret)
+    # Named in the layout the backward reads, and everything below
+    # derives from the named values: a checkpoint that saves the two
+    # names has no use left for the kernel in its recompute.  q, k
+    # and v are not named — their projections are recomputed.
+    of = checkpoint_name(of, FLASH_OUT)
+    lsef = checkpoint_name(lsef, FLASH_LSE)
     out = _from_flat(of, B, H)
     lse = _lse_from_flat(lsef, B, H)
-    return (out, lse), (q, k, v, out, lsef, qoff, koff)
+    return (out, lse), (q, k, v, of, lsef, qoff, koff)
 
 
 def _flash_lse_bwd(causal, kv_len, bq, bk, od, interpret, res, ct):
-    q, k, v, out, lsef, qoff, koff = res
+    q, k, v, of, lsef, qoff, koff = res
     do, dlse = ct
     B, Sq, H, D = q.shape
     dqf, dkf, dvf = _flash_bwd_flat(
-        _to_flat(q), _to_flat(k), _to_flat(v), _to_flat(out),
-        _to_flat(do), lsef, _lse_to_flat(dlse), qoff, koff, causal,
-        kv_len, bq, bk, od, interpret)
+        _to_flat(q), _to_flat(k), _to_flat(v), of, _to_flat(do),
+        lsef, _lse_to_flat(dlse), qoff, koff, causal, kv_len, bq, bk,
+        od, interpret)
     return (_from_flat(dqf, B, H), _from_flat(dkf, B, H),
             _from_flat(dvf, B, H), jnp.zeros((1, 1), jnp.float32),
             jnp.zeros((1, 1), jnp.float32))

@@ -25,17 +25,45 @@ from .evaluator import EvaluatorBase
 
 
 def remat_enabled(unit_flag):
-    """Whether a transformer unit should rematerialize (jax.checkpoint)
-    its block application: the unit kwarg wins when set, otherwise
-    ``root.common.engine.remat`` (default off).  Remat trades ~1/3 more
-    FLOPs (forward re-run in backward) for O(layers) → O(1) residual
-    activation memory per block — THE long-context/deep-stack enabler:
-    ring attention already gives O(S/N) attention memory, but without
-    remat the backward still stores every block's full residual
-    stream."""
+    """Whether a transformer unit should rematerialize its block
+    application (:func:`checkpointed`): the unit kwarg wins when
+    set, otherwise ``root.common.engine.remat`` (default off).  Remat
+    trades ~1/3 more FLOPs (forward re-run in backward) for
+    O(layers) → O(1) residual activation memory per block — THE
+    long-context/deep-stack enabler: ring attention already gives
+    O(S/N) attention memory, but without remat the backward still
+    stores every block's full residual stream.
+
+    What a checkpointed layer KEEPS from its forward pass: its input,
+    and — where its attention ran the flash kernel — that kernel's
+    output and log-sum-exp rows (one compute-dtype activation of the
+    layer, B·S·E elements, plus B·H·S float32: 64 MB + 1 MB at 4 ×
+    2048 tokens of 4096 in bfloat16; as much at 2048 wide with heads
+    of 64, whose rows are padded to a lane tile).  Everything else is
+    computed again in the backward pass."""
     if unit_flag is not None:
         return bool(unit_flag)
     return bool(config_get(root.common.engine.remat, False))
+
+
+def checkpointed(fn):
+    """``fn`` under THE layers' checkpoint — both sites
+    (``LMLayer.tforward``, the pipelined stack's block function) go
+    through here.  ``jax.checkpoint`` with one rule: save what came
+    out of the flash forward kernel (the two names
+    ``ops/pallas_attention.py`` gives inside its forward rule),
+    recompute the rest.  The kernel runs at a fifth of its roofline,
+    so its second call was the dearest part of the recompute for the
+    bytes it costs to keep (:func:`remat_enabled`); q, k and v are
+    XLA projections and are rebuilt as before.  The rule observes
+    only that a value came out of the kernel: where none did — XLA's
+    attention, a short-convolution layer — nothing is saved and the
+    program is a bare ``jax.checkpoint``'s."""
+    import jax
+    from ..ops.pallas_attention import FLASH_OUT, FLASH_LSE
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT, FLASH_LSE))
 
 
 def fused_qkv_enabled(unit_flag):
@@ -541,7 +569,6 @@ class LMLayer(ForwardBase):
         return A.attention(q, k, v, causal=self.causal)
 
     def tforward(self, read, write, params, ctx, state=None):
-        import jax
         import jax.numpy as jnp
         x = read(self.input)
         buffers = {"expert_bias": state["expert_bias"]} \
@@ -553,7 +580,7 @@ class LMLayer(ForwardBase):
                                attend=self._attend, buffers=b)
 
         if remat_enabled(self.remat):
-            apply = jax.checkpoint(apply)
+            apply = checkpointed(apply)
         out, stats = apply(params, buffers, x)
         write(self.output, out)
         if stats is None:
@@ -722,8 +749,7 @@ class PipelinedTransformerStack(ForwardBase):
             # its backward instead of storing every block's
             # residuals — per-stage activation memory drops from
             # O(blocks/stage) to O(1) per microbatch in flight.
-            import jax
-            block_fn = jax.checkpoint(block_fn)
+            block_fn = checkpointed(block_fn)
 
         mesh = getattr(self.workflow, "mesh", None)
         if self.stage_axis and mesh is not None and \
