@@ -397,7 +397,7 @@ def test_pallas_minimal_geometry_parity_tier1(shape, block, kv_len):
             atol=2e-5)
 
 
-def _all_tiles(causal, *_origins_and_blocks):
+def _all_tiles(causal, *_origins_and_blocks, window=None):
     """The schedule before the causal walk: every block of the range,
     the causal mask built on each."""
     return ((0, _origins_and_blocks[-1], causal),)
@@ -625,10 +625,11 @@ def test_kernel_knob_dispatch(engine_knobs, monkeypatch):
     real = PA.pallas_attention
 
     def fake_kernel(q, k, v, causal=False, kv_len=None,
-                    operand_dtype=None):
+                    operand_dtype=None, window=None):
         calls.append(q.shape)
         return real(q, k, v, causal=causal, kv_len=kv_len,
-                    operand_dtype=jnp.float32, interpret=True)
+                    window=window, operand_dtype=jnp.float32,
+                    interpret=True)
 
     monkeypatch.setattr(PA, "pallas_attention", fake_kernel)
     monkeypatch.setattr(A, "tpu_available", lambda: True)

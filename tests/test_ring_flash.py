@@ -203,7 +203,7 @@ def test_flash_chunk_walks_by_traced_offsets(monkeypatch, where):
         for grad in got[2:]:
             assert float(jnp.abs(grad).max()) == 0.0
 
-    def all_tiles(causal, *origins_and_blocks):
+    def all_tiles(causal, *origins_and_blocks, window=None):
         return ((0, origins_and_blocks[-1], causal),)
 
     monkeypatch.setattr(PA, "_key_stretches", all_tiles)

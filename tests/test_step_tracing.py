@@ -111,26 +111,53 @@ def test_block_program_names_every_unit_and_the_update(block_run):
     assert "rematted_computation" in text
 
 
-def test_spec_built_layers_name_their_inner_scopes():
+#: sample -> (its layers, unit -> inner scopes it must open)
+def _lfm2_body():
+    from veles_tpu.znicz.samples.lfm2 import lfm2_layers
+    return lfm2_layers(["conv", "full_attention", "conv"], n_heads=2,
+                       kv_heads=1, intermediate_size=32,
+                       moe_intermediate_size=16, n_experts=4, top_k=2,
+                       num_dense_layers=1, held=(0, 2))
+
+
+def _trinity_body():
+    from veles_tpu.znicz.samples.trinity import trinity_layers
+    return trinity_layers(
+        ["sliding_attention", "sliding_attention", "full_attention"],
+        n_heads=2, kv_heads=1, head_dim=8, intermediate_size=32,
+        moe_intermediate_size=16, n_experts=4, top_k=2, sliding_window=4,
+        num_dense_layers=1, held=(0, 2))
+
+
+SPEC_BODIES = {
+    "lfm2": (_lfm2_body, {
+        "block0": ("ln1", "shortconv", "ln2", "mlp"),
+        "block1": ("ln1", "rope", "attention", "ln2", "moe_route",
+                   "moe_dispatch", "moe_experts", "moe_combine"),
+        "block2": ("shortconv", "moe_experts")}),
+    "trinity": (_trinity_body, {
+        "block0": ("ln1", "rope", "attention", "attn_gate", "ln1_post",
+                   "ln2", "mlp", "ln2_post"),
+        "block1": ("attention", "attn_gate", "ln1_post", "moe_route",
+                   "moe_experts", "moe_shared", "ln2_post"),
+        "block2": ("attention", "attn_gate", "moe_shared", "ln2_post")}),
+}
+
+
+@pytest.mark.parametrize("sample", sorted(SPEC_BODIES))
+def test_spec_built_layers_name_their_inner_scopes(sample):
     """Every inner scope the vocabulary holds beyond the OPT block's
     is opened by a layer built from a spec (``samples/lfm2.py``:
     convolution, attention with rotary positions, dense gated MLP,
-    experts), and the scope table places each in every phase."""
-    from veles_tpu.znicz.samples.lfm2 import lfm2_layers
+    experts; ``samples/trinity.py``: the output gate, the sandwich's
+    second norms, the shared expert), and the scope table places each
+    in every phase."""
+    body, inner = SPEC_BODIES[sample]
     root.common.engine.remat = True
-    launcher, wf = _tiny_lm(
-        ticks_per_dispatch=2,
-        layers=lfm2_layers(["conv", "full_attention", "conv"], n_heads=2,
-                           kv_heads=1, intermediate_size=32,
-                           moe_intermediate_size=16, n_experts=4,
-                           top_k=2, num_dense_layers=1, held=(0, 2)))
+    launcher, wf = _tiny_lm(ticks_per_dispatch=2, layers=body())
     wf.loader.run()
     launcher.stop()
     placed = set(programs.scopes("block_step").values())
-    inner = {"block0": ("ln1", "shortconv", "ln2", "mlp"),
-             "block1": ("ln1", "rope", "attention", "ln2", "moe_route",
-                        "moe_dispatch", "moe_experts", "moe_combine"),
-             "block2": ("shortconv", "moe_experts")}
     for unit, scopes in inner.items():
         for scope in scopes:
             for phase in ("forward", "recompute", "backward"):
@@ -138,7 +165,8 @@ def test_spec_built_layers_name_their_inner_scopes():
                     continue        # its ordering has no gradient
                 assert (phase, unit, scope) in placed, (phase, unit,
                                                         scope)
-    assert set(s for scopes in inner.values() for s in scopes) == \
+    assert set(s for _body, units in SPEC_BODIES.values()
+               for scopes in units.values() for s in scopes) == \
         set(programs.INNER_SCOPES)
     assert ("forward", "final_norm", None) in placed
 

@@ -34,6 +34,10 @@ OPT_6_7B = (4, 2048, 32, 128)
 #: ``lfm2-24b-a2b.train``: 8 x 2048 tokens, the 8 kv heads broadcast
 #: to the 32 query heads of 64 before the call.
 LFM2 = (8, 2048, 32, 64)
+#: ``trinity-mini.train-8k``: 1 x 8192 tokens, the 4 kv heads
+#: broadcast to the 32 query heads of 128; four chunks of ``MAX_SEQ``.
+TRINITY = (1, 8192, 32, 128)
+TRINITY_WINDOW = 2048
 #: Decode: one new token per row over a 2048-slot gathered table.
 DECODE_L = 2048
 #: Ring shard: S=1024 over a 2-way seq axis.
@@ -198,6 +202,107 @@ def test_scopes_publish_the_flash_call_counts(one_chip, monkeypatch,
                          label).value == fwd_calls
     assert registry.peek("attention.flash.dq_calls", label).value == 2
     assert programs.kernel_calls(table, "flash_dkv") == 2
+
+
+@pytest.mark.parametrize("window,pairs", [(TRINITY_WINDOW, 7),
+                                          (None, 10)],
+                         ids=["window", "full"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_past_max_seq_compiles(one_chip, grad, window,
+                                               pairs):
+    """``trinity-mini.train-8k``'s two calls: 8,192 tokens in four
+    chunks of ``MAX_SEQ``, one call of each kernel a chunk pair the
+    mask leaves anything of — 7 under the window of 2,048 (the walk's
+    lower bound, computed in the kernel from the pair's global
+    origins, is one more scalar Mosaic must take as an ``scf.for``
+    bound), 10 under the causal mask alone; no S x S array, so the
+    program's temporaries stay far under one (268 MB a head)."""
+    from veles_tpu.ops import pallas_attention as PA
+    assert TRINITY[1] == 4 * PA.MAX_SEQ and PA.supports(TRINITY, TRINITY)
+
+    def fwd(q, k, v):
+        return PA.pallas_attention(q, k, v, causal=True, window=window,
+                                   operand_dtype=jnp.bfloat16)
+
+    fn = fwd
+    if grad:
+        fn = jax.grad(
+            lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))
+    x = _struct(TRINITY, jnp.bfloat16, one_chip)
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == \
+        pairs * (3 if grad else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+#: ``trinity-mini.train-8k``'s attention (the FFN cut narrow: the
+#: compile is of the attention path): 32 gated heads of 128 over 4
+#: key/value heads on a 2,048-wide stream, sandwich norms.
+TRINITY_LAYER = dict(norm="rms", post_norm=True, kv_heads=4,
+                     head_dim=128, qk_norm=True, attn_gate=True,
+                     bias=False, ffn="gated-mlp")
+
+
+@pytest.mark.parametrize("kind,spec,calls", [
+    ("sliding", dict(TRINITY_LAYER, window=TRINITY_WINDOW,
+                     rope_theta=1e4), 14),
+    ("full", TRINITY_LAYER, 20)])
+def test_trinity_layers_run_each_flash_kernel_once_a_pair(
+        one_chip, monkeypatch, kind, spec, calls):
+    """The cell's two kinds of spec-built layer, two of each under the
+    layers' checkpoint, forward + backward: every chunk pair's forward
+    output is kept, so the program holds as many ``flash_fwd`` as
+    ``flash_dq`` as ``flash_dkv`` — 7 a sliding layer, 10 a full one."""
+    from veles_tpu.ops import attention as A
+    from veles_tpu.znicz import attention as Z
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    B, S, H, _ = TRINITY
+    spec = Z.layer_spec(n_heads=H, ffn_dim=256, **spec)
+    layer = Z.checkpointed(lambda p, h: Z.layer_apply(
+        spec, p, h, jnp.bfloat16)[0])
+
+    def loss(params, x):
+        for p in params:
+            x = layer(p, x)
+        return (x * x).sum()
+
+    params = [{name: _struct(shape, jnp.float32, one_chip)
+               for name, shape in
+               Z.layer_param_shapes(spec, 2048).items()}] * 2
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        params, _struct((B, S, 2048), jnp.float32, one_chip)
+    ).compile().as_text()
+    assert _flash_calls(text) == (calls, calls, calls)
+
+
+def test_trinity_expert_share_compiles(one_chip, monkeypatch):
+    """``trinity-mini.train-8k``'s routed experts, forward and
+    gradients: 8,192 tokens, top 8 of 128, 16 experts of 2048 x 1024
+    held — ``lfm2-24b-a2b.train``'s even share, the common path
+    compiled for twice it (``trinity_layers``' ``slack``: 16,384
+    rows, 4 chunks).  A (1024, 1024) tile of the
+    weights' gradient does not fit VMEM; ``grouped_dot`` keeps the
+    tile's elements at 1024 x 768 (``TILE_ELEMENTS``)."""
+    from veles_tpu.ops import moe as M
+    monkeypatch.setattr(M, "tpu_available", lambda: True)
+    T, D, F, E, k, held = 8192, 2048, 1024, 128, 8, 16
+    assert M.dropless_rows(T, k, E, held) == (10240, 7)
+    assert M.dropless_rows(T, k, E, held, (2, 1)) == (16384, 4)
+    f32 = jnp.float32
+
+    def loss(x, gate, bias, w1, w3, w2):
+        y, stats = M.moe_dropless(x, gate, bias, w1, w3, w2, top_k=k,
+                                  held=(0, held), scaling=2.826,
+                                  eps=1e-20, slack=(2, 1))
+        return (y * y).sum() + stats["landed"]
+    text = _compiled_text(
+        jax.grad(loss, argnums=(0, 1, 3, 4, 5)),
+        _struct((T, D), f32, one_chip), _struct((D, E), f32, one_chip),
+        _struct((E,), f32, one_chip), _struct((held, D, F), f32, one_chip),
+        _struct((held, D, F), f32, one_chip),
+        _struct((held, F, D), f32, one_chip))
+    assert text.count("tpu_custom_call") >= 9 and "conditional" in text
 
 
 @pytest.mark.parametrize("rows", [10240, 65536],

@@ -73,8 +73,10 @@ EXPORTABLE = {
 
 #: Unit kinds that train but that no serving program knows yet: the
 #: spec-built LM layer (rotary positions, grouped keys/values, the
-#: short convolution's state, a held share of experts) and the RMS
-#: norm before its head.  Refused by name, not as "unknown".
+#: short convolution's state, a held share of experts, a window, an
+#: output gate, sandwich norms, a shared expert) and the RMS norm
+#: before its head.  Refused by name, not as "unknown"; so is an
+#: embedding whose output is scaled.
 TRAIN_ONLY = ("lm_layer", "rms_norm")
 
 TANH_A, TANH_B = 1.7159, 0.6666
@@ -91,6 +93,10 @@ def _unit_entry(unit):
                   "export has no forward, cached or paged program "
                   "for a layer built from a spec (docs/attention.md, "
                   "\"Layers from a spec\")" % (unit.name, mapping))
+    if mapping == "embedding" and getattr(unit, "scale", 1.0) != 1.0:
+        raise Bug("unit %s: an embedding scaled by %r trains but is "
+                  "not served yet (samples/trinity.py)" %
+                  (unit.name, unit.scale))
     if mapping not in EXPORTABLE:
         raise Bug("unit %s (type %s, MAPPING %r) is not exportable" %
                   (unit.name, type(unit).__name__, mapping))

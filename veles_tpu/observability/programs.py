@@ -6,7 +6,8 @@ The program put a name there: ``StepCompiler`` traces every unit
 under ``jax.named_scope(unit.scope_name)``, the update rules under
 ``update``, the health sentinel under ``health``, and the block
 function opens ``ln1`` / ``attention`` / ``ln2`` / ``mlp`` (and
-``rope``, ``shortconv``, ``moe_*`` where a spec asks for them) inside
+``rope``, ``shortconv``, ``moe_*``, ``attn_gate``, ``ln1_post`` /
+``ln2_post`` where a spec asks for them) inside
 a unit; JAX adds ``jvp(...)``, ``transpose(jvp(...))`` and the
 checkpoint's ``rematted_computation`` by itself.  This module keeps,
 per program name (``block_step``, ``train_step``, ``infer_step``),
@@ -41,7 +42,8 @@ STEP_SCOPES = ("update", "health")
 #: layer_apply``, ``ops.moe.moe_dropless``).
 INNER_SCOPES = ("ln1", "attention", "ln2", "mlp", "rope", "shortconv",
                 "moe_route", "moe_dispatch", "moe_experts",
-                "moe_combine")
+                "moe_combine", "attn_gate", "moe_shared", "ln1_post",
+                "ln2_post")
 
 _lock = threading.Lock()
 _programs = {}

@@ -60,6 +60,7 @@ from jax import lax
 from .. import resilience
 from ..config import root, get as config_get
 from ..backends import tpu_available
+from . import pallas_attention as PA
 
 NEG_INF = -1e30
 
@@ -171,13 +172,12 @@ def _selects_pallas(q_shape, k_shape, kv_len=None, mode=None):
     before the kernel is touched — "pallas" and "auto" select
     identically, so a CPU test run with the flag on still exercises
     the reference path."""
-    from . import pallas_attention as PA
     return (mode or _kernel_mode()) != "xla" and \
         PA.supports(q_shape, k_shape, kv_len) and tpu_available()
 
 
 def _try_pallas(q, k, v, causal, kv_len=None, mode=None,
-                precision=None):
+                precision=None, window=None):
     """Runs the Pallas flash kernel where :func:`_selects_pallas`
     says so; returns None (→ caller runs the jnp formulation)
     otherwise.  Once selected, a kernel that fails to lower raises —
@@ -191,10 +191,9 @@ def _try_pallas(q, k, v, causal, kv_len=None, mode=None,
     if not _selects_pallas(q.shape, k.shape, kv_len, mode):
         resilience.stats.incr("attention.kernel.xla")
         return None
-    from . import pallas_attention as PA
     resilience.stats.incr("attention.kernel.pallas")
     return PA.pallas_attention(
-        q, k, v, causal=causal, kv_len=kv_len,
+        q, k, v, causal=causal, kv_len=kv_len, window=window,
         operand_dtype=attention_compute_dtype(precision))
 
 
@@ -244,13 +243,19 @@ def _finish(acc, l, dtype):
             jnp.maximum(l, 1e-30)[..., None]).astype(dtype)
 
 
-def _causal_mask(sq, sk, q_offset, k_offset):
+def _causal_mask(sq, sk, q_offset, k_offset, window=None):
+    """(sq, sk) True = attend: a row sees the columns up to its own
+    global position, and with ``window`` only the last ``window`` of
+    them (``0 ≤ row − col < window``)."""
     qpos = q_offset + jnp.arange(sq)[:, None]
     kpos = k_offset + jnp.arange(sk)[None, :]
-    return qpos >= kpos
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def attention(q, k, v, causal=False, precision=None, kernel=None):
+def attention(q, k, v, causal=False, precision=None, kernel=None,
+              window=None):
     """Full O(S²)-memory attention (B, S, H, D) — the reference
     formulation the streaming variants are tested against.
 
@@ -261,7 +266,11 @@ def attention(q, k, v, causal=False, precision=None, kernel=None):
     "pallas"/"auto" the call routes through the Pallas flash kernel
     when the platform supports the geometry (the kernel never
     materializes the S×S scores, so the precision knob is moot
-    there beyond the matmul operand dtype).
+    there beyond the matmul operand dtype).  ``window`` (a static
+    int, beside ``causal``): row ``i`` sees key ``j`` iff ``0 ≤ i − j
+    < window`` — sliding-window attention; the kernel's tile walk
+    follows it, and past ``pallas_attention.MAX_SEQ`` the kernel is
+    called a visible pair of k/v and query chunks at a time.
 
     Grouped-query attention: where k and v carry fewer heads than q
     (H a multiple of theirs) each key/value head is broadcast over
@@ -277,14 +286,15 @@ def attention(q, k, v, causal=False, precision=None, kernel=None):
                                         v.shape[2]))
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
+    PA.check_window(causal, window)
     out = _try_pallas(q, k, v, causal, mode=kernel,
-                      precision=precision)
+                      precision=precision, window=window)
     if out is not None:
         return out
     dt = attention_compute_dtype(precision)
     scale = 1.0 / (q.shape[-1] ** 0.5)
-    mask = _causal_mask(q.shape[1], k.shape[1], 0, 0) if causal \
-        else None
+    mask = _causal_mask(q.shape[1], k.shape[1], 0, 0, window) \
+        if causal else None
     B, Sq, H, D = q.shape
     acc = jnp.zeros((B, Sq, H, D), dt)
     m = jnp.full((B, Sq, H), NEG_INF, jnp.float32)
@@ -295,7 +305,7 @@ def attention(q, k, v, causal=False, precision=None, kernel=None):
 
 
 def mesh_attention(q, k, v, mesh, causal=False, batch_axis=None,
-                   head_axis=None):
+                   head_axis=None, window=None):
     """:func:`attention` inside a step that GSPMD partitions over
     ``mesh``.  The XLA formulation partitions by itself.  A Mosaic
     kernel does not ("Mosaic kernels cannot be automatically
@@ -307,7 +317,7 @@ def mesh_attention(q, k, v, mesh, causal=False, batch_axis=None,
     axis the mesh lacks, or that does not divide its dimension,
     leaves that dimension whole."""
     if not _selects_pallas(q.shape, k.shape):
-        return attention(q, k, v, causal=causal)
+        return attention(q, k, v, causal=causal, window=window)
     from jax.sharding import PartitionSpec as P
 
     def fits(axis, dim):
@@ -316,28 +326,31 @@ def mesh_attention(q, k, v, mesh, causal=False, batch_axis=None,
 
     spec = P(fits(batch_axis, 0), None, fits(head_axis, 2), None)
     fn = jax.shard_map(
-        functools.partial(attention, causal=causal), mesh=mesh,
+        functools.partial(attention, causal=causal, window=window),
+        mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 
 def blockwise_attention(q, k, v, block_size=128, causal=False,
-                        kv_len=None, precision=None, kernel=None):
+                        kv_len=None, precision=None, kernel=None,
+                        window=None):
     """Flash-style attention: scan over key/value blocks with the
     streaming accumulator — O(S·block) memory on one device.
 
     ``kv_len``: when set, keys at global positions >= kv_len are
     masked out — the padding contract for callers that padded k/v up
     to a block multiple (non-causal attention would otherwise attend
-    the zero padding).
+    the zero padding).  ``window``: as :func:`attention`'s.
 
     ``precision``/``kernel``: None → the ``attention_dtype`` /
     ``attention_kernel`` knobs (explicit values force, as in
     :func:`attention`).  Under "pallas"/"auto" the scan is replaced
     wholesale by the Pallas flash kernel when the platform supports
     the geometry."""
+    PA.check_window(causal, window)
     out = _try_pallas(q, k, v, causal, kv_len=kv_len, mode=kernel,
-                      precision=precision)
+                      precision=precision, window=window)
     if out is not None:
         return out
     dt = attention_compute_dtype(precision)
@@ -354,7 +367,7 @@ def blockwise_attention(q, k, v, block_size=128, causal=False,
         acc, m, l = carry
         kblk, vblk, idx = xs
         k_off = idx * block_size
-        mask = _causal_mask(S, block_size, 0, k_off) \
+        mask = _causal_mask(S, block_size, 0, k_off, window) \
             if causal else None
         if kv_len is not None:
             kvalid = jnp.broadcast_to(
@@ -385,7 +398,6 @@ def _try_ring_flash(q, k, mode, interpret):
     propagates."""
     if mode == "xla":
         return False
-    from . import pallas_attention as PA
     if not PA.supports_ring(q.shape, k.shape, interpret=interpret):
         return False
     return interpret or tpu_available()
@@ -403,7 +415,6 @@ def _ring_flash(q, k, v, axis_name, causal, od, interpret):
     the reversed ppermutes differentiate as plain jax — recompute-
     from-lse per ring step, exactly the single-chip kernel's
     contract stretched across the ring."""
-    from . import pallas_attention as PA
     n = lax.psum(1, axis_name)
     rank = lax.axis_index(axis_name)
     B, Sq, H, D = q.shape

@@ -26,9 +26,16 @@ from ..backends import tpu_available
 #: Rows of a grouped product's row tile on the TPU; the row counts
 #: :func:`moe_dropless` compiles for are multiples of it.
 ROW_TILE = 512
-#: Room over the even share that the common path is compiled for:
-#: 5 / 4.
+#: Room over the even share that the common path is compiled for,
+#: unless a caller says otherwise (``layer_spec(slack=…)``): 5 / 4.
 DROPLESS_SLACK = (5, 4)
+
+
+#: Elements of the (K tile × M tile) block a grouped product's kernels
+#: keep in VMEM — the weights' gradient (``tgmm``) accumulates one in
+#: float32 beside its double-buffered copies: 1024 × 768 compiles for
+#: a v5e's 16 MB, 1024 × 1024 does not (tests/test_tpu_compile.py).
+TILE_ELEMENTS = 1024 * 768
 
 
 def _tile(n, most=1024, lane=128):
@@ -59,7 +66,9 @@ def grouped_dot(lhs, rhs, group_sizes, interpret=False):
                                   preferred_element_type=jnp.float32)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     N, K = lhs.shape
-    tile = (min(ROW_TILE, N), _tile(K), _tile(rhs.shape[2]))
+    tile_k = _tile(K)
+    tile = (min(ROW_TILE, N), tile_k,
+            _tile(rhs.shape[2], min(1024, TILE_ELEMENTS // tile_k)))
     pad = -N % tile[0]
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
@@ -68,24 +77,27 @@ def grouped_dot(lhs, rhs, group_sizes, interpret=False):
     return out[:N] if pad else out
 
 
-def dropless_rows(n_tokens, top_k, n_experts, count):
+def dropless_rows(n_tokens, top_k, n_experts, count,
+                  slack=DROPLESS_SLACK):
     """``(chunk, n_chunks)``: the assignment rows the common path of
-    :func:`moe_dropless` is compiled for — :data:`DROPLESS_SLACK`
-    times the even share ``T · k · count / E``, up to a row tile —
-    and how many such chunks cover all ``T · k`` assignments."""
+    :func:`moe_dropless` is compiled for — ``slack`` (a ratio ``(more,
+    than)``, :data:`DROPLESS_SLACK` by default) times the even share
+    ``T · k · count / E``, up to a row tile — and how many such chunks
+    cover all ``T · k`` assignments."""
     worst = n_tokens * top_k
-    more, than = DROPLESS_SLACK
+    more, than = slack
     tiles = -(-worst * count * more // (n_experts * than * ROW_TILE))
     chunk = min(ROW_TILE * tiles, worst)
     return chunk, -(-worst // chunk)
 
 
 def sigmoid_route(x, gate_w, expert_bias, top_k, norm_topk=True,
-                  scaling=1.0):
+                  scaling=1.0, eps=1e-6):
     """The LFM2 / DeepSeek-V3 router: ``s = sigmoid(x @ W_gate)`` over
     all E experts, the CHOICE by ``s + expert_bias`` (a buffer that
     balances loads and that no gradient reaches), the WEIGHTS from
-    ``s`` alone, normalised over the chosen k.  Scores and choice are
+    ``s`` alone, normalised over the chosen k (their sum + ``eps``)
+    and scaled by ``scaling``.  Scores and choice are
     float32 from float32 operands at ``highest``: a flipped choice is
     a discrete error, not a rounding.  Returns (idx (T, k) int32,
     weights (T, k) float32)."""
@@ -97,12 +109,13 @@ def sigmoid_route(x, gate_w, expert_bias, top_k, norm_topk=True,
         top_k)
     weights = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
     return idx, weights * scaling
 
 
 def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
-                 norm_topk=True, scaling=1.0, cdt=jnp.bfloat16):
+                 norm_topk=True, scaling=1.0, cdt=jnp.bfloat16,
+                 eps=1e-6, slack=DROPLESS_SLACK):
     """A share of a dropless top-k expert layer with gated experts.
 
     Args:
@@ -112,7 +125,10 @@ def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
         here, ``silu(x @ w1) * (x @ w3) @ w2`` each;
       held: ``(first, count)`` — experts ``first … first + count − 1``
         of the E are the ones held (static Python ints);
-      cdt: type of the products' operands (accumulation is float32).
+      cdt: type of the products' operands (accumulation is float32);
+      scaling, eps: :func:`sigmoid_route`'s;
+      slack: :func:`dropless_rows`'s — how far over the even share
+        the routing may go before the walk below is paid for.
 
     Returns ``(y (T, D) float32, stats)``: ``y`` is what the held
     experts add for the tokens routed to them — what the absent
@@ -137,10 +153,10 @@ def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
     if w1.shape[0] != count or not 0 <= first <= E - count:
         raise ValueError("held=%r of %d experts, %d expert matrices"
                          % (held, E, w1.shape[0]))
-    chunk, n_chunks = dropless_rows(T, top_k, E, count)
+    chunk, n_chunks = dropless_rows(T, top_k, E, count, slack)
     with jax.named_scope("moe_route"):
         idx, weights = sigmoid_route(x, gate_w, expert_bias, top_k,
-                                     norm_topk, scaling)
+                                     norm_topk, scaling, eps)
         local = idx.reshape(-1) - first
         # an assignment to an expert not held sorts past every held one
         key = jnp.where((local >= 0) & (local < count), local, count)

@@ -10,10 +10,17 @@ choices, for exactly one geometry family:
   * D is the FULL lane width (D % 128 == 0, up to 512) — one q/k/v
     row is one (or a few) native (8, 128) tiles, no head-dim blocking
     ever — or HALF of it, D = 64 (below);
-  * k/v for a (batch·head) slice live WHOLE in VMEM (S ≤ 2048 ×
-    D=128 × 4 B = 1 MB each — a fraction of 16 MB), so the only
-    streaming dimension is the query block: grid (B·H, S/block_q),
-    with the key loop a ``fori_loop`` over VMEM, never HBM;
+  * k/v for a (batch·head) slice of ONE call live WHOLE in VMEM
+    (``MAX_SEQ`` = 2048 × D=128 × 4 B = 1 MB each — a fraction of
+    16 MB), so the only streaming dimension is the query block: grid
+    (B·H, S/block_q), with the key loop a ``fori_loop`` over VMEM,
+    never HBM.  ``MAX_SEQ`` bounds a CALL, not a sequence (ISSUE 33):
+    a longer one, a multiple of it, is walked in chunks of
+    ``MAX_SEQ`` queries and keys — one :func:`flash_chunk` call for
+    every pair of chunks the mask leaves anything of, at the pair's
+    global origins, the partials of a query chunk merged by lse
+    (:func:`_chunked`: the ring's composition below, on one chip), so
+    k/v stream from HBM a chunk at a time and no S × S array exists;
   * a CAUSAL call walks only the score tiles it can see (ISSUE 30):
     each query block's key loop ends at the last key block that
     holds a column ≤ its last row, and the dk/dv kernel's query loop
@@ -28,6 +35,14 @@ choices, for exactly one geometry family:
     :func:`flash_tiles` is the same arithmetic on Python ints, and
     what a trace counts.  ``kv_len`` keeps its own mask on every
     tile and skips nothing;
+  * a ``window`` beside ``causal`` (row ``i`` sees key ``j`` iff
+    ``0 ≤ i − j < window``: sliding-window attention, ISSUE 33) gives
+    the walk a LOWER bound as the diagonal gives it an upper one —
+    three stretches: the tiles the window's edge crosses (masked),
+    the tiles seen whole (no mask), the tiles the diagonal crosses
+    (masked) — so a window layer's work follows the window: at (512,
+    512) tiles a causal call at 8,192 visits 136 of 256 tiles, a
+    call with a window of 2,048 visits 70;
   * matmul operands are bf16 (MXU-native), accumulation f32
     (``preferred_element_type``), the online-softmax statistics f32 —
     the same contract as ops/attention's bf16 mode;
@@ -150,12 +165,16 @@ LANE = 128
 #: The one head dim below a lane row that :func:`supports` admits.
 HALF_LANE = LANE // 2
 
-#: Upper sequence bound: the kernel keeps a (batch·head) slice's
-#: whole k/v in VMEM (S × D × 4 B each, double-buffered) next to its
-#: f32 score tiles; the backward's dk/dv kernel also keeps q and dO
-#: whole.  S=2048/D=128 forward and backward compile inside the
-#: v5e's 16 MB scoped VMEM (tests/test_tpu_compile.py); past it the
-#: tiles stop fitting, so dispatch selects the streaming scan.
+#: Upper bound of ONE kernel call's sequence: the kernel keeps a
+#: (batch·head) slice's whole k/v in VMEM (S × D × 4 B each,
+#: double-buffered) next to its f32 score tiles; the backward's
+#: dk/dv kernel also keeps q and dO whole.  S=2048/D=128 forward and
+#: backward compile inside the v5e's 16 MB scoped VMEM
+#: (tests/test_tpu_compile.py); past it the tiles stop fitting.  A
+#: longer sequence (a multiple of it) is walked in chunks of
+#: ``MAX_SEQ`` rows and keys, one :func:`flash_chunk` call a visible
+#: chunk pair, the partials merged by lse — the ring's composition
+#: on one chip (:func:`_chunked`).
 MAX_SEQ = 2048
 
 #: Decode-kernel query bound: past this many query rows the chunk is
@@ -179,9 +198,9 @@ def _pick_block(n, want):
 def supports(q_shape, k_shape, kv_len=None):
     """Whether the kernel's geometry contract holds: self-attention
     ((B, S, H, D) with equal q/k sequence), D lane-native (a multiple
-    of 128 up to 512) or 64, S tile-aligned up to ``MAX_SEQ``.
-    ``kv_len`` (the blockwise padding contract) is supported as a
-    static mask bound."""
+    of 128 up to 512) or 64, S tile-aligned up to ``MAX_SEQ`` or a
+    multiple of it.  ``kv_len`` (the blockwise padding contract) is
+    supported as a static mask bound, within ``MAX_SEQ``."""
     if len(q_shape) != 4 or len(k_shape) != 4:
         return False
     B, S, H, D = q_shape
@@ -189,7 +208,9 @@ def supports(q_shape, k_shape, kv_len=None):
         return False
     if D != HALF_LANE and (D % LANE or D > 4 * LANE):
         return False
-    if S % LANE or S < LANE or S > MAX_SEQ:
+    if S % LANE or S < LANE:
+        return False
+    if S > MAX_SEQ and (S % MAX_SEQ or kv_len is not None):
         return False
     if kv_len is not None and not isinstance(kv_len, int):
         return False
@@ -254,15 +275,16 @@ def supports_decode(q_shape, k_shape, interpret=False):
 
 
 def _mask_tile(grows0, gcols0, lcols0, bq, bk, causal, kv_len,
-               transposed=False):
+               transposed=False, window=None):
     """(bq, bk) boolean attend-mask for one score tile — (bk, bq)
     when ``transposed`` (the dk/dv kernel's key-major tile) — or
-    None when nothing masks.  Causality is judged on GLOBAL positions
-    (row/col origins ``grows0``/``gcols0`` — possibly traced scalars:
-    the ring offsets are data-dependent), while the ``kv_len``
-    padding bound applies to the chunk's LOCAL columns (origin
-    ``lcols0``) — it is the caller's own padding, wherever the chunk
-    sits globally."""
+    None when nothing masks.  Causality — and with ``window`` how far
+    back a row sees, ``row − col < window`` — is judged on GLOBAL
+    positions (row/col origins ``grows0``/``gcols0`` — possibly
+    traced scalars: the ring offsets are data-dependent), while the
+    ``kv_len`` padding bound applies to the chunk's LOCAL columns
+    (origin ``lcols0``) — it is the caller's own padding, wherever
+    the chunk sits globally."""
     shape, qdim, kdim = ((bk, bq), 1, 0) if transposed else \
         ((bq, bk), 0, 1)
     mask = None
@@ -270,6 +292,8 @@ def _mask_tile(grows0, gcols0, lcols0, bq, bk, causal, kv_len,
         rows = grows0 + jax.lax.broadcasted_iota(jnp.int32, shape, qdim)
         cols = gcols0 + jax.lax.broadcasted_iota(jnp.int32, shape, kdim)
         mask = rows >= cols
+        if window is not None:
+            mask = jnp.logical_and(mask, rows - cols < window)
     if kv_len is not None:
         cols = lcols0 + jax.lax.broadcasted_iota(jnp.int32, shape, kdim)
         kvm = cols < kv_len
@@ -311,8 +335,8 @@ def _clip(x, lo, hi):
     return jnp.minimum(jnp.maximum(x, lo), hi)
 
 
-def _key_stretches(causal, grows0, bq, gcols0, bk, nk):
-    """The key blocks ONE query block walks, as ``(lo, hi, diagonal)``
+def _key_stretches(causal, grows0, bq, gcols0, bk, nk, window=None):
+    """The key blocks ONE query block walks, as ``(lo, hi, masked)``
     stretches of block indices, in ascending order.  The query block
     holds global rows ``grows0 … grows0 + bq − 1``; key block ``j``
     holds global columns ``gcols0 + j·bk … gcols0 + (j + 1)·bk − 1``.
@@ -321,29 +345,52 @@ def _key_stretches(causal, grows0, bq, gcols0, bk, nk):
     below the diagonal (last column ≤ first row: no causal mask),
     then those the diagonal crosses.  The blocks past them are all
     zeros and are not visited.  The origins may be traced scalars
-    (the ring's are data-dependent), so the bounds may be too."""
+    (the ring's are data-dependent), so the bounds may be too.
+
+    With a ``window`` (row ``i`` sees column ``j`` iff ``0 ≤ i − j <
+    window``) the walk has a lower bound as it has an upper one: it
+    starts at the first block that holds a column the FIRST row still
+    sees, ``j ≥ (grows0 − window + 1 − gcols0) // bk``, and the blocks
+    up to the first one that the LAST row sees whole, ``j ≥
+    cdiv(grows0 + bq − window − gcols0, bk)``, are crossed by the
+    window's edge and masked like the diagonal's."""
     if not causal:
         return ((0, nk, False),)
     hi = _clip((grows0 + bq - gcols0 + bk - 1) // bk, 0, nk)
     below = _clip((grows0 - gcols0 + 1) // bk, 0, hi)
-    return ((0, below, False), (below, hi, True))
+    if window is None:
+        return ((0, below, False), (below, hi, True))
+    lo = _clip((grows0 - window + 1 - gcols0) // bk, 0, below)
+    inside = _clip((grows0 + bq - window - gcols0 + bk - 1) // bk, lo,
+                   below)
+    return ((lo, inside, True), (inside, below, False),
+            (below, hi, True))
 
 
-def _query_stretches(causal, gcols0, bk, grows0, bq, nq):
+def _query_stretches(causal, gcols0, bk, grows0, bq, nq, window=None):
     """The query blocks ONE key block walks (the dk/dv kernel's
     loop): the mirror of :func:`_key_stretches`.  Query block ``i``
     is visited if it holds a row ≥ the key block's first column,
     ``i ≥ (gcols0 − grows0) // bq``: first the blocks the diagonal
-    crosses, then those wholly below it (first row ≥ last column)."""
+    crosses, then those wholly below it (first row ≥ last column).
+    With a ``window`` the walk ends behind the last block that holds
+    a row the LAST column still reaches, and the blocks past the last
+    one the FIRST column reaches whole are masked."""
     if not causal:
         return ((0, nq, False),)
     lo = _clip((gcols0 - grows0) // bq, 0, nq)
     below = _clip((gcols0 + bk - 1 - grows0 + bq - 1) // bq, lo, nq)
-    return ((lo, below, True), (below, nq, False))
+    if window is None:
+        return ((lo, below, True), (below, nq, False))
+    hi = _clip((gcols0 + bk + window - 2 - grows0) // bq + 1, below, nq)
+    inside = _clip((gcols0 + window - grows0) // bq, below, hi)
+    return ((lo, below, True), (below, inside, False),
+            (inside, hi, True))
 
 
 def _walk(stretches, tile, carry):
-    """Folds ``tile(index, carry, diagonal)`` over the stretches."""
+    """Folds ``tile(index, carry, diagonal)`` over the stretches
+    (``diagonal``: whether an edge of the mask crosses the tile)."""
     for lo, hi, diagonal in stretches:
         carry = jax.lax.fori_loop(
             lo, hi, functools.partial(tile, diagonal=diagonal), carry)
@@ -351,7 +398,7 @@ def _walk(stretches, tile, carry):
 
 
 def flash_tiles(q_len, k_len, block_q, block_k, q_offset=0,
-                k_offset=0, causal=True):
+                k_offset=0, causal=True, window=None):
     """``(visited, total)`` score tiles of ONE (batch·head) slice of
     a forward call: what the key loops of its ``q_len // block_q``
     programs visit, and what the grid holds (the dq kernel walks the
@@ -364,13 +411,13 @@ def flash_tiles(q_len, k_len, block_q, block_k, q_offset=0,
     visited = sum(
         hi - lo for i in range(nq) for lo, hi, _ in _key_stretches(
             causal, q_offset + i * block_q, block_q, k_offset,
-            block_k, nk))
+            block_k, nk, window=window))
     return visited, nq * nk
 
 
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
                 lse_ref, *, scale, causal, kv_len, block_k,
-                kv_seq_len, od):
+                kv_seq_len, od, window=None):
     from jax.experimental import pallas as pl
     bq = q_ref.shape[1]
     D = q_ref.shape[2]
@@ -385,7 +432,8 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
         vb = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot(q, kb, od, trans_b=True) * scale
         mask = _mask_tile(grows0, koff + j * block_k, j * block_k,
-                          bq, block_k, diagonal, kv_len)
+                          bq, block_k, diagonal, kv_len,
+                          window=window)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         bm = s.max(axis=1, keepdims=True)
@@ -402,7 +450,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
     # leaves the carry as it starts: out 0 and lse ≈ -1e30 below.
     acc, m, l = _walk(
         _key_stretches(causal, grows0, bq, koff, block_k,
-                       kv_seq_len // block_k),
+                       kv_seq_len // block_k, window=window),
         tile,
         (jnp.zeros((bq, D), jnp.float32),
          jnp.full((bq, 1), NEG_INF, jnp.float32),
@@ -420,7 +468,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                lse_ref, delta_ref, dq_ref, *, scale, causal, kv_len,
-               block_k, kv_seq_len, od):
+               block_k, kv_seq_len, od, window=None):
     from jax.experimental import pallas as pl
     bq = q_ref.shape[1]
     D = q_ref.shape[2]
@@ -437,7 +485,8 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         vb = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot(q, kb, od, trans_b=True) * scale
         mask = _mask_tile(grows0, koff + j * block_k, j * block_k,
-                          bq, block_k, diagonal, kv_len)
+                          bq, block_k, diagonal, kv_len,
+                          window=window)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse)
@@ -449,13 +498,13 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
     dq_ref[0] = _walk(
         _key_stretches(causal, grows0, bq, koff, block_k,
-                       kv_seq_len // block_k),
+                       kv_seq_len // block_k, window=window),
         tile, jnp.zeros((bq, D), jnp.float32)).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 lse_ref, delta_ref, dk_ref, dv_ref, *, scale, causal,
-                kv_len, block_q, q_seq_len, od):
+                kv_len, block_q, q_seq_len, od, window=None):
     from jax.experimental import pallas as pl
     bk = k_ref.shape[1]
     D = k_ref.shape[2]
@@ -478,7 +527,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         st = _dot(k, qb, od, trans_b=True) * scale
         mask = _mask_tile(qoff + i * block_q, gcols0, lcols0,
                           block_q, bk, diagonal, kv_len,
-                          transposed=True)
+                          transposed=True, window=window)
         if mask is not None:
             st = jnp.where(mask, st, NEG_INF)
         pt = jnp.exp(st - lse)
@@ -492,7 +541,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
     dk, dv = _walk(
         _query_stretches(causal, gcols0, bk, qoff, block_q,
-                         q_seq_len // block_q),
+                         q_seq_len // block_q, window=window),
         tile,
         (jnp.zeros((bk, D), jnp.float32),
          jnp.zeros((bk, D), jnp.float32)))
@@ -549,7 +598,7 @@ def _off_operand(off):
 
 
 def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
-                    od, interpret):
+                    od, interpret, window=None):
     """(BH, Sq, D) × (BH, Sk, D) forward: returns (out, lse)."""
     from jax.experimental import pallas as pl
     BH, Sq, D = qf.shape
@@ -557,7 +606,7 @@ def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
     scale = 1.0 / (D ** 0.5)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              kv_len=kv_len, block_k=bk,
-                             kv_seq_len=Sk, od=od)
+                             kv_seq_len=Sk, od=od, window=window)
     out, lse = pl.pallas_call(
         kern,
         grid=(BH, Sq // bq),
@@ -576,7 +625,7 @@ def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
 
 
 def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
-                    causal, kv_len, bq, bk, od, interpret):
+                    causal, kv_len, bq, bk, od, interpret, window=None):
     from jax.experimental import pallas as pl
     BH, Sq, D = qf.shape
     Sk = kf.shape[1]
@@ -595,7 +644,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_k=bk, kv_seq_len=Sk,
-                          od=od),
+                          od=od, window=window),
         grid=(BH, Sq // bq),
         in_specs=[_off_spec(), _off_spec(),
                   _row_spec(bq, D, "blocked"),
@@ -612,7 +661,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_q=bq, q_seq_len=Sq,
-                          od=od),
+                          od=od, window=window),
         grid=(BH, Sk // bk),
         in_specs=[_off_spec(), _off_spec(),
                   _row_spec(Sq, D, "whole"),
@@ -655,24 +704,24 @@ def _lse_to_flat(l):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9,
-                                                    10))
+                                                    10, 11))
 def _flash_lse(q, k, v, qoff, koff, causal, kv_len, bq, bk, od,
-               interpret):
+               interpret, window=None):
     """The lse-carrying flash core: (out, lse) with a backward that
     recomputes probabilities from the saved lse.  ``qoff``/``koff``
     are (1, 1) f32 arrays (global causal origins, possibly traced —
     see :func:`_off_operand` for the shape/dtype contract)."""
     out, lse = _flash_lse_fwd(q, k, v, qoff, koff, causal, kv_len,
-                              bq, bk, od, interpret)[0]
+                              bq, bk, od, interpret, window)[0]
     return out, lse
 
 
 def _flash_lse_fwd(q, k, v, qoff, koff, causal, kv_len, bq, bk, od,
-                   interpret):
+                   interpret, window=None):
     B, Sq, H, D = q.shape
     of, lsef = _flash_fwd_flat(_to_flat(q), _to_flat(k), _to_flat(v),
                                qoff, koff, causal, kv_len, bq, bk,
-                               od, interpret)
+                               od, interpret, window)
     # Named in the layout the backward reads, and everything below
     # derives from the named values: a checkpoint that saves the two
     # names has no use left for the kernel in its recompute.  q, k
@@ -684,14 +733,15 @@ def _flash_lse_fwd(q, k, v, qoff, koff, causal, kv_len, bq, bk, od,
     return (out, lse), (q, k, v, of, lsef, qoff, koff)
 
 
-def _flash_lse_bwd(causal, kv_len, bq, bk, od, interpret, res, ct):
+def _flash_lse_bwd(causal, kv_len, bq, bk, od, interpret, window, res,
+                   ct):
     q, k, v, of, lsef, qoff, koff = res
     do, dlse = ct
     B, Sq, H, D = q.shape
     dqf, dkf, dvf = _flash_bwd_flat(
         _to_flat(q), _to_flat(k), _to_flat(v), of, _to_flat(do),
         lsef, _lse_to_flat(dlse), qoff, koff, causal, kv_len, bq, bk,
-        od, interpret)
+        od, interpret, window)
     return (_from_flat(dqf, B, H), _from_flat(dkf, B, H),
             _from_flat(dvf, B, H), jnp.zeros((1, 1), jnp.float32),
             jnp.zeros((1, 1), jnp.float32))
@@ -700,37 +750,89 @@ def _flash_lse_bwd(causal, kv_len, bq, bk, od, interpret, res, ct):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+def check_window(causal, window):
+    """``window`` as the kernels take it: None, or a positive static
+    int beside ``causal`` (row ``i`` sees ``0 ≤ i − j < window``)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError("window=%r needs causal attention and at "
+                         "least one column" % (window,))
+    return int(window)  # lint-ok: VL101 static config int
+
+
+def _chunked(q, k, v, causal, window, block_q, block_k, operand_dtype,
+             interpret, chunk):
+    """Attention over a sequence longer than one kernel call holds:
+    queries and keys in chunks of ``chunk`` rows, one
+    :func:`flash_chunk` a chunk pair in which the mask leaves anything
+    to see (10 of 16 pairs for a causal call at 4 chunks, 7 with a
+    window of one chunk), its tile walk bounded by the pair's GLOBAL
+    origins, the pairs of one query chunk folded by lse
+    (:func:`flash_resume`).  No S × S array anywhere; every partial
+    carries the forward rule's two names, so a layer's checkpoint
+    keeps them all and its recompute calls no kernel."""
+    S = q.shape[1]
+    outs = []
+    for q0 in range(0, S, chunk):
+        carry = None
+        for k0 in range(0, S, chunk):
+            if not flash_tiles(chunk, chunk, chunk, chunk, q0, k0,
+                               causal, window)[0]:
+                continue
+            carry = flash_resume(
+                carry, q[:, q0:q0 + chunk], k[:, k0:k0 + chunk],
+                v[:, k0:k0 + chunk], causal=causal, q_offset=q0,
+                k_offset=k0, window=window, block_q=block_q,
+                block_k=block_k, operand_dtype=operand_dtype,
+                interpret=interpret)
+        outs.append(carry[0].astype(q.dtype))
+    return jnp.concatenate(outs, axis=1)
+
+
 def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
                      block_k=None, operand_dtype=None,
-                     interpret=False):
+                     interpret=False, window=None, chunk=MAX_SEQ):
     """Flash attention over (B, S, H, D), differentiable (custom
     VJP).  Block shapes default to the geometry-tuned constants,
     shrunk to the largest power-of-two divisor of S — callers outside
     the ``supports`` contract must not reach here.
 
     ``operand_dtype``: matmul operand dtype — bf16 (default, the MXU
-    contract) or f32 (the exact-parity test mode)."""
+    contract) or f32 (the exact-parity test mode).  ``window``: a
+    causal row sees the ``window`` columns up to its own (None: all
+    of them).  ``chunk``: how many rows one kernel call holds (the
+    tests walk small sequences in small chunks); past it the call is
+    :func:`_chunked`."""
     B, S, H, D = q.shape
     if not supports(q.shape, k.shape, kv_len):
         raise ValueError(
             "geometry (%s, kv_len=%r) outside the pallas_attention "
             "contract — use ops.attention.blockwise_attention" %
             (q.shape, kv_len))
-    bq = _pick_block(S, block_q or DEFAULT_BLOCK_Q)
-    bk = _pick_block(S, block_k or DEFAULT_BLOCK_K)
+    window = check_window(causal, window)
+    bq = _pick_block(min(S, chunk), block_q or DEFAULT_BLOCK_Q)
+    bk = _pick_block(min(S, chunk), block_k or DEFAULT_BLOCK_K)
     od = jnp.dtype(operand_dtype or jnp.bfloat16).type
     if kv_len is not None:
         # Static by the supports() contract (isinstance(int) gate).
         kv_len = int(kv_len)  # lint-ok: VL101 static config int
     # Each TRACE counts what one (batch·head) slice of the forward
     # visits and what its grid holds, beside attention.kernel.pallas:
-    # how far the causal schedule engages at this call's geometry.
-    visited, total = flash_tiles(S, S, bq, bk, causal=bool(causal))
-    resilience.stats.incr("attention.flash.tiles_visited", visited)
-    resilience.stats.incr("attention.flash.tiles_total", total)
+    # how far the causal schedule engages at this call's geometry.  A
+    # call with a window counts into series labelled with it.
+    visited, total = flash_tiles(S, S, bq, bk, causal=bool(causal),
+                                 window=window)
+    labels = None if window is None else {"window": str(window)}
+    counter = resilience.stats.registry.counter
+    counter("attention.flash.tiles_visited", labels).inc(visited)
+    counter("attention.flash.tiles_total", labels).inc(total)
+    if S > chunk:
+        return _chunked(q, k, v, bool(causal), window, bq, bk, od,
+                        bool(interpret), chunk)
     zero = jnp.zeros((1, 1), jnp.float32)
     out, _lse = _flash_lse(q, k, v, zero, zero, bool(causal),
-                           kv_len, bq, bk, od, bool(interpret))
+                           kv_len, bq, bk, od, bool(interpret), window)
     return out
 
 
@@ -739,7 +841,7 @@ def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
 
 def flash_chunk(q, k, v, causal=False, q_offset=0, k_offset=0,
                 kv_len=None, block_q=None, block_k=None,
-                operand_dtype=None, interpret=False):
+                operand_dtype=None, interpret=False, window=None):
     """ONE flash partial: local queries (B, Sq, H, D) against one
     k/v chunk (B, Sk, H, D) whose global positions start at
     ``k_offset`` (queries at ``q_offset``) — the ring-attention step
@@ -747,7 +849,8 @@ def flash_chunk(q, k, v, causal=False, q_offset=0, k_offset=0,
     partial and ``lse`` (B, Sq, H) f32 its log-normalizer; fold
     partials with :func:`merge_partials`.  Offsets may be TRACED
     scalars (a ring step's source rank is data-dependent inside
-    ``shard_map``).  Differentiable: the backward recomputes
+    ``shard_map``); ``window`` is static and bounds the walk and the
+    mask from below as ``causal`` does from above.  Differentiable: the backward recomputes
     probabilities from lse per chunk (dq/dkv kernels), and the lse
     output's own cotangent folds into the delta row — so autodiff
     through a chunk+merge composition is exact, no custom ring VJP
@@ -767,7 +870,8 @@ def flash_chunk(q, k, v, causal=False, q_offset=0, k_offset=0,
     qoff = jnp.asarray(q_offset, jnp.float32).reshape(1, 1)
     koff = jnp.asarray(k_offset, jnp.float32).reshape(1, 1)
     return _flash_lse(q, k, v, qoff, koff, bool(causal), kv_len, bq,
-                      bk, od, bool(interpret))
+                      bk, od, bool(interpret),
+                      check_window(causal, window))
 
 
 def merge_partials(o1, lse1, o2, lse2):

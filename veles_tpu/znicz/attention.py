@@ -127,22 +127,35 @@ def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
                n_heads=4, kv_heads=None, qk_norm=False,
                rope_theta=None, bias=True, ffn_dim=None,
                conv_kernel=3, n_experts=0, top_k=1, held=None,
-               norm_topk=True, routed_scaling=1.0, norm_eps=1e-5):
+               norm_topk=True, routed_scaling=1.0, norm_eps=1e-5,
+               head_dim=None, window=None, attn_gate=False,
+               post_norm=False, shared_ffn_dim=None, route_eps=1e-6,
+               slack=(5, 4)):
     """One decoder layer, said as data: ``h + operator(norm(h))`` then
     ``+ ffn(norm(·))``.  A plain dict (it rides snapshots).
 
-    ``norm``: ``layer`` (LayerNorm, gain and bias) | ``rms`` (gain).
-    ``operator``: ``attention`` — ``n_heads`` query heads over
-    ``kv_heads`` key/value heads (None: as many), ``qk_norm`` a
+    ``norm``: ``layer`` (LayerNorm, gain and bias) | ``rms`` (gain);
+    ``post_norm`` puts a second norm of the same kind BEHIND the
+    operator and behind the FFN (the sandwich: ``h + norm(operator(
+    norm(h)))``).  ``operator``: ``attention`` — ``n_heads`` query
+    heads of ``head_dim`` (None: the layer's width over ``n_heads``)
+    over ``kv_heads`` key/value heads (None: as many), ``qk_norm`` a
     per-head RMS norm of q and k, ``rope_theta`` rotary positions
-    (None: the positions are the embedding's) — | ``shortconv``, the
-    gated short convolution of ``conv_kernel`` taps
-    (``ops/shortconv.py``).  ``ffn``: ``relu-mlp`` | ``gated-mlp``
-    (``silu(u W1) ⊙ (u W3)) W2``), both ``ffn_dim`` wide (None: 4 ×
-    the layer's width), | ``experts``: ``top_k`` of ``n_experts``
-    gated experts ``ffn_dim`` wide, dropless, of which this layer
-    HOLDS ``held = (first, count)`` (None: all) —
-    ``ops.moe.moe_dropless``.  ``bias``: whether the projections and
+    (None: the positions are the embedding's, or none), ``window`` how
+    many keys back a row sees (None: all; ``ops.attention``),
+    ``attn_gate`` an output gate ``sigmoid(u Wg)`` on the heads'
+    result before ``Wo`` — | ``shortconv``, the gated short
+    convolution of ``conv_kernel`` taps (``ops/shortconv.py``).
+    ``ffn``: ``relu-mlp`` | ``gated-mlp`` (``silu(u W1) ⊙ (u W3))
+    W2``), both ``ffn_dim`` wide (None: 4 × the layer's width), |
+    ``experts``: ``top_k`` of ``n_experts`` gated experts ``ffn_dim``
+    wide, dropless, of which this layer HOLDS ``held = (first,
+    count)`` (None: all) — ``ops.moe.moe_dropless``, its weights
+    normalised over ``sum + route_eps``, its common path compiled for
+    ``slack`` (a ratio) times the even share of the assignments
+    (``ops.moe.dropless_rows``) — and beside them, where
+    ``shared_ffn_dim`` is set, one gated MLP that wide over every
+    token (the shared expert).  ``bias``: whether the projections and
     the MLP carry biases."""
     if norm not in NORMS or operator not in OPERATORS or \
             ffn not in FFNS:
@@ -153,31 +166,46 @@ def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
     if n_heads % kv_heads:
         raise ValueError("%d query heads over %d key/value heads"
                          % (n_heads, kv_heads))
+    if operator != "attention" and (head_dim or window or attn_gate):
+        raise ValueError("head_dim, window and attn_gate are "
+                         "attention's, not %r's" % operator)
+    if window is not None and window < 1:
+        raise ValueError("a window of %r keys" % (window,))
     if ffn == "experts":
         held = tuple(held or (0, n_experts))
         if not (1 <= top_k <= n_experts and held[1] >= 1 and
                 0 <= held[0] <= n_experts - held[1]):
             raise ValueError("top %d of %d experts, held %r"
                              % (top_k, n_experts, held))
+    elif shared_ffn_dim:
+        raise ValueError("a shared expert beside %r" % ffn)
     return {"norm": norm, "operator": operator, "ffn": ffn,
             "n_heads": n_heads, "kv_heads": kv_heads,
             "qk_norm": bool(qk_norm), "rope_theta": rope_theta,
             "bias": bool(bias), "ffn_dim": ffn_dim,
             "conv_kernel": conv_kernel, "n_experts": n_experts,
             "top_k": top_k, "held": held, "norm_topk": bool(norm_topk),
-            "routed_scaling": routed_scaling, "norm_eps": norm_eps}
+            "routed_scaling": routed_scaling, "norm_eps": norm_eps,
+            "head_dim": head_dim, "window": window,
+            "attn_gate": bool(attn_gate), "post_norm": bool(post_norm),
+            "shared_ffn_dim": shared_ffn_dim, "route_eps": route_eps,
+            "slack": tuple(slack)}
 
 
 def layer_param_shapes(spec, embed, fused_qkv=False):
     """Parameter geometry of one layer — single source of truth for
     LMLayer and the pipelined stack (which prepends a stage dim).
-    ``fused_qkv`` swaps the three (E, E) projections for the single
-    (E, 3E) fused weight.
+    ``fused_qkv`` swaps the three projections for the single fused
+    weight.  The heads' width ``n_heads · head_dim`` is the layer's
+    own where the spec names no ``head_dim``.
 
     Dict ORDER is load-bearing: initialization draws from the seeded
     prng in iteration order, so the unfused OPT layout keeps the
     historical ordering bit-for-bit (seeded trajectories — and the
-    tests pinning them — depend on it)."""
+    tests pinning them — depend on it); what a newer spec key adds
+    (``wg``, ``ln1_post_*``, ``ws1`` / ``ws3`` / ``ws2``,
+    ``ln2_post_*``) comes behind its part's older leaves."""
+    spec = layer_spec(**spec)      # a snapshot's older spec lacks keys
     bias = spec["bias"]
 
     def norm(name):
@@ -188,25 +216,30 @@ def layer_param_shapes(spec, embed, fused_qkv=False):
 
     shapes = norm("ln1")
     if spec["operator"] == "attention":
-        head = embed // spec["n_heads"]
+        head = spec["head_dim"] or embed // spec["n_heads"]
+        inner = spec["n_heads"] * head
         kv = spec["kv_heads"] * head
         if fused_qkv:
-            shapes.update({"wqkv": (embed, 3 * embed),
-                           "wo": (embed, embed)})
+            shapes.update({"wqkv": (embed, 3 * inner),
+                           "wo": (inner, embed)})
             if bias:
-                shapes.update({"bqkv": (3 * embed,), "bo": (embed,)})
+                shapes.update({"bqkv": (3 * inner,), "bo": (embed,)})
         else:
-            shapes.update({"wq": (embed, embed), "wk": (embed, kv),
-                           "wv": (embed, kv), "wo": (embed, embed)})
+            shapes.update({"wq": (embed, inner), "wk": (embed, kv),
+                           "wv": (embed, kv), "wo": (inner, embed)})
             if bias:
-                shapes.update({"bq": (embed,), "bk": (kv,),
+                shapes.update({"bq": (inner,), "bk": (kv,),
                                "bv": (kv,), "bo": (embed,)})
+        if spec["attn_gate"]:
+            shapes["wg"] = (embed, inner)
         if spec["qk_norm"]:
             shapes.update({"q_norm_g": (head,), "k_norm_g": (head,)})
     else:
         shapes.update({"w_in": (embed, 3 * embed),
                        "w_conv": (embed, spec["conv_kernel"]),
                        "w_out": (embed, embed)})
+    if spec["post_norm"]:
+        shapes.update(norm("ln1_post"))
     shapes.update(norm("ln2"))
     hidden = spec["ffn_dim"] or 4 * embed
     if spec["ffn"] == "experts":
@@ -215,6 +248,11 @@ def layer_param_shapes(spec, embed, fused_qkv=False):
                        "w1": (count, embed, hidden),
                        "w3": (count, embed, hidden),
                        "w2": (count, hidden, embed)})
+        shared = spec["shared_ffn_dim"]
+        if shared:
+            shapes.update({"ws1": (embed, shared),
+                           "ws3": (embed, shared),
+                           "ws2": (shared, embed)})
     elif spec["ffn"] == "gated-mlp":
         shapes.update({"w1": (embed, hidden), "w3": (embed, hidden),
                        "w2": (hidden, embed)})
@@ -225,6 +263,8 @@ def layer_param_shapes(spec, embed, fused_qkv=False):
         shapes["w2"] = (hidden, embed)
         if bias:
             shapes["b2"] = (embed,)
+    if spec["post_norm"]:
+        shapes.update(norm("ln2_post"))
     return shapes
 
 
@@ -245,12 +285,16 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
     (docs/observability.md): ``ln1``, ``attention`` (q, k, v → o:
     scores, softmax, value matmul or the kernel — NOT the
     projections), ``rope`` (per-head norm and rotary positions),
-    ``shortconv`` (gates and taps, not the projections), ``ln2``,
-    ``mlp``, and the expert layer's ``moe_*``."""
+    ``attn_gate`` (the output gate's sigmoid and product, not its
+    projection), ``shortconv`` (gates and taps, not the projections),
+    ``ln1_post`` / ``ln2_post`` (the sandwich's second norms),
+    ``ln2``, ``mlp``, the expert layer's ``moe_*`` and the shared
+    expert's ``moe_shared``."""
     import jax
     import jax.numpy as jnp
     from ..ops import attention as A
     from ..ops.rotary import rms_norm, rotary
+    spec = layer_spec(**spec)      # a snapshot's older spec lacks keys
     B, S, E = x.shape
 
     def dot(a, w, b=None):
@@ -265,6 +309,13 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
                                    params[name + "_b"])
             return rms_norm(x, params[name + "_g"], spec["norm_eps"])
 
+    def gated(h, w1, w3, w2):
+        return dot(jax.nn.silu(dot(h, params[w1])) *
+                   dot(h, params[w3]), params[w2])
+
+    def post(name, y):
+        return norm(name, y) if spec["post_norm"] else y
+
     h = norm("ln1", x)
     if spec["operator"] == "shortconv":
         from ..ops.shortconv import gated_short_conv
@@ -272,7 +323,7 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
                                axis=-1)
         with jax.named_scope("shortconv"):
             mixed = gated_short_conv(b_, c_, x_, params["w_conv"])
-        x = x + dot(mixed, params["w_out"])
+        x = x + post("ln1_post", dot(mixed, params["w_out"]))
     else:
         n_heads, kv_heads = spec["n_heads"], spec["kv_heads"]
         if "wqkv" in params:
@@ -301,11 +352,17 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
                     q = rotary(q, spec["rope_theta"])
                     k = rotary(k, spec["rope_theta"])
         if attend is None:
-            attend = functools.partial(A.attention, causal=causal)
+            attend = functools.partial(A.attention, causal=causal,
+                                       window=spec["window"])
         with jax.named_scope("attention"):
             attn = attend(q.astype(cdt), k.astype(cdt), v.astype(cdt))
-        x = x + dot(attn.reshape(B, S, E), params["wo"],
-                    params.get("bo"))
+        attn = attn.reshape(B, S, -1)
+        if spec["attn_gate"]:
+            gate = dot(h, params["wg"])
+            with jax.named_scope("attn_gate"):
+                attn = attn * jax.nn.sigmoid(gate)
+        x = x + post("ln1_post", dot(attn, params["wo"],
+                                     params.get("bo")))
     h = norm("ln2", x)
     stats = None
     if spec["ffn"] == "experts":
@@ -315,18 +372,27 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
             buffers["expert_bias"], params["w1"], params["w3"],
             params["w2"], top_k=spec["top_k"], held=spec["held"],
             norm_topk=spec["norm_topk"],
-            scaling=spec["routed_scaling"], cdt=cdt)
-        x = x + y.reshape(B, S, E)
+            scaling=spec["routed_scaling"], cdt=cdt,
+            eps=spec["route_eps"], slack=spec["slack"])
+        y = y.reshape(B, S, E)
+        if spec["shared_ffn_dim"]:
+            with jax.named_scope("moe_shared"):
+                y = y + gated(h, "ws1", "ws3", "ws2")
+        x = x + post("ln2_post", y)
     else:
         with jax.named_scope("mlp"):
             if spec["ffn"] == "gated-mlp":
-                h = jax.nn.silu(dot(h, params["w1"])) * \
-                    dot(h, params["w3"])
-                x = x + dot(h, params["w2"])
+                y = gated(h, "w1", "w3", "w2")
             else:
                 h = jnp.maximum(
                     dot(h, params["w1"], params.get("b1")), 0.0)
-                x = x + dot(h, params["w2"], params.get("b2"))
+                y = dot(h, params["w2"], params.get("b2"))
+            if not spec["post_norm"]:
+                # the residual add stays under the MLP's scope, where
+                # the scope table of the older specs has it
+                x = x + y
+        if spec["post_norm"]:
+            x = x + norm("ln2_post", y)
     return x.astype(jnp.float32), stats
 
 
@@ -347,7 +413,8 @@ def _block_param_shapes(embed, hidden, fused_qkv=False):
 
 class Embedding(ForwardBase):
     """Token + learned positional embedding: int32 tokens (B, S) →
-    activations (B, S, E)."""
+    activations (B, S, E), times ``scale`` where one is given (the
+    ``sqrt(E)`` of a μP-scaled LM, ``samples/trinity.py``)."""
 
     MAPPING = "embedding"
 
@@ -359,6 +426,7 @@ class Embedding(ForwardBase):
         #: False: no learned position table (the layers carry rotary
         #: positions, or none).
         self.positions = kwargs.get("positions", True)
+        self.scale = float(kwargs.get("scale", 1.0))
         self.include_bias = False
         self.pos = Vector()
 
@@ -397,6 +465,8 @@ class Embedding(ForwardBase):
         out = w[tokens]
         if "pos" in params:
             out = out + params["pos"][:seq]
+        if getattr(self, "scale", 1.0) != 1.0:
+            out = out * self.scale
         write(self.output, out.astype(self.compute_dtype))
 
 
@@ -459,6 +529,11 @@ class LMLayer(ForwardBase):
                 "heads: sequence-parallel attention does not "
                 "broadcast groups" % (self.seq_axis, spec["kv_heads"],
                                       spec["n_heads"]))
+        if self.seq_axis and spec["window"]:
+            raise ValueError(
+                "seq_axis=%r with a window of %d keys: "
+                "sequence-parallel attention takes no window" %
+                (self.seq_axis, spec["window"]))
         self.batch_axis = kwargs.get("batch_axis", "data")
         #: Set by apply_dp_tp_sharding: attention keeps the head dim
         #: on this mesh axis inside its shard_map (tp, tp × sp).
@@ -515,7 +590,7 @@ class LMLayer(ForwardBase):
         super(LMLayer, self).initialize(device=device, **kwargs)
         batch, seq, embed = self.input.shape
         spec = self.spec
-        if embed % spec["n_heads"]:
+        if not spec["head_dim"] and embed % spec["n_heads"]:
             raise ValueError("embed dim %d not divisible by %d heads"
                              % (embed, spec["n_heads"]))
         stddev = self.weights_stddev or (1.0 / numpy.sqrt(embed))
@@ -565,8 +640,10 @@ class LMLayer(ForwardBase):
         if mesh is not None:
             return A.mesh_attention(
                 q, k, v, mesh, causal=self.causal,
-                batch_axis=self.batch_axis, head_axis=self.head_axis)
-        return A.attention(q, k, v, causal=self.causal)
+                batch_axis=self.batch_axis, head_axis=self.head_axis,
+                window=self.spec["window"])
+        return A.attention(q, k, v, causal=self.causal,
+                           window=self.spec["window"])
 
     def tforward(self, read, write, params, ctx, state=None):
         import jax.numpy as jnp

@@ -60,9 +60,12 @@ class TinyLMWorkflow(AcceleratedWorkflow):
     The body is ``n_blocks`` OPT blocks (``TransformerBlock``), or —
     ``layers``: a list of ``znicz.attention.layer_spec`` dicts — one
     ``LMLayer`` a spec, with no learned positions in the embedding
-    and an RMS norm (``final_norm``) before the tied head: the shape
-    of the hybrid LMs (``samples/lfm2.py``).  Either way the units
-    are ``block<i>`` and take the same placement arguments."""
+    and an RMS norm (``final_norm``) before the head: the shape of
+    the hybrid LMs (``samples/lfm2.py``, ``samples/trinity.py``).
+    Either way the units are ``block<i>`` and take the same placement
+    arguments.  ``tied_head``: the head is the embedding transposed
+    (default), or a matrix of its own; ``embed_scale``: what the
+    embedding's output is multiplied by."""
 
     def __init__(self, workflow, vocab_size=16, seq_len=32,
                  embed_dim=32, n_heads=4, n_blocks=1,
@@ -71,7 +74,7 @@ class TinyLMWorkflow(AcceleratedWorkflow):
                  sp_mode="ring", sp_kernel=None, sp_interpret=None,
                  pipelined=False, stage_axis=None, n_microbatches=4,
                  schedule=None, n_chunks=None, fused_qkv=None,
-                 layers=None,
+                 layers=None, tied_head=True, embed_scale=1.0,
                  loader_cls=FirstTokenLoader, loader_config=None,
                  **kwargs):
         super(TinyLMWorkflow, self).__init__(workflow, **kwargs)
@@ -86,7 +89,8 @@ class TinyLMWorkflow(AcceleratedWorkflow):
 
         self.embedding = Embedding(
             self, vocab_size=vocab_size, embed_dim=embed_dim,
-            positions=layers is None, name="embedding")
+            positions=layers is None, scale=embed_scale,
+            name="embedding")
         self.embedding.link_from(self.loader)
         self.embedding.input = self.loader.minibatch_data
 
@@ -133,8 +137,9 @@ class TinyLMWorkflow(AcceleratedWorkflow):
             self.forwards.append(norm)
             prev = norm
 
-        self.head = LMHead(self, vocab_size=vocab_size,
-                           tie_to=self.embedding, name="head")
+        self.head = LMHead(
+            self, vocab_size=vocab_size,
+            tie_to=self.embedding if tied_head else None, name="head")
         self.head.link_from(prev)
         self.head.input = prev.output
         self.forwards.append(self.head)
