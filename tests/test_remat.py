@@ -210,16 +210,17 @@ def test_unit_kwarg_overrides_config():
 # -- what the layers' checkpoint keeps (ISSUE 32) ------------------------
 
 
-def _layer_loss(wrap, interpret, n_layers=2):
-    """``(loss, params, x)``: ``n_layers`` OPT layers (2 heads of 64,
-    128 positions, bfloat16 operands), each under ``wrap``; attention
-    through the flash kernels in interpret mode, or XLA's."""
+def _layer_loss(wrap, interpret, n_layers=2, **spec):
+    """``(loss, params, x)``: ``n_layers`` layers of ``spec`` (OPT's
+    by default; 2 heads of 64, 128 positions, bfloat16 operands), each
+    under ``wrap``; attention through the flash kernels in interpret
+    mode, or XLA's."""
     import jax
     import jax.numpy as jnp
     from veles_tpu.ops import pallas_attention as PA
     from veles_tpu.znicz import attention as Z
     B, S, H, D = 2, 128, 2, 64
-    spec = Z.layer_spec(n_heads=H, ffn_dim=64)
+    spec = Z.layer_spec(n_heads=H, ffn_dim=64, **spec)
     key = jax.random.PRNGKey(0)
     params = {
         name: 0.02 * jax.random.normal(jax.random.fold_in(key, i), shape)
@@ -325,14 +326,22 @@ def test_ring_keeps_every_chunks_partial(wrap, forwards):
         "flash_fwd": forwards, "flash_dq": 2, "flash_dkv": 2}
 
 
-def test_checkpoint_over_xla_attention_is_the_bare_one():
-    """Where no value came out of the flash kernel the policy finds
-    nothing to save: the stored residuals are the bare
-    ``jax.checkpoint``'s one for one, the gradient's program lowers
-    to the same text, and the gradients are the same bits."""
+@pytest.mark.parametrize("spec", [
+    {}, dict(norm="rms", bias=False, ffn="gated-mlp", kv_heads=1,
+             qk_norm=True, rope_theta=1e4),
+    dict(norm="rms", bias=False, operator="shortconv",
+         ffn="gated-mlp")],
+    ids=["opt", "gqa + gated-mlp", "shortconv + gated-mlp"])
+def test_checkpoint_over_xla_attention_is_the_bare_one(spec):
+    """Where no value came out of the flash kernel or of an expert
+    layer the policy finds nothing to save — XLA's attention, a
+    short convolution, a dense MLP name nothing: the stored residuals
+    are the bare ``jax.checkpoint``'s one for one, the gradient's
+    program lowers to the same text, and the gradients are the same
+    bits."""
     import jax
-    ours, params, x = _layer_loss(_wrap("checkpointed"), False)
-    bare, _, _ = _layer_loss(_wrap("bare"), False)
+    ours, params, x = _layer_loss(_wrap("checkpointed"), False, **spec)
+    bare, _, _ = _layer_loss(_wrap("bare"), False, **spec)
     assert _residuals(ours, params, x) == _residuals(bare, params, x)
     assert _kernel_calls(ours, params, x)["flash_fwd"] == 0
     ours, bare = (jax.jit(jax.grad(f)) for f in (ours, bare))
@@ -355,6 +364,167 @@ def test_kept_output_gives_the_second_calls_bits():
     bare = jax.jit(jax.grad(bare))(params, x)
     for name in ours:
         numpy.testing.assert_array_equal(ours[name], bare[name])
+
+
+# -- what the layers' checkpoint keeps of an expert layer (ISSUE 34) -----
+
+#: The expert layer the cases below build: 256 tokens of 32, top 2 of
+#: 8 experts 16 wide, experts 2 and 3 held; with row tiles of 128 the
+#: common path is compiled for 256 of the 512 assignments, 2 chunks.
+_EXPERTS = dict(T=256, D=32, E=8, top_k=2, held=(2, 2), F=16,
+                row_tile=128, chunk=256)
+
+
+def _expert_layer_loss(wrap, monkeypatch, routing="common"):
+    """``(loss, params, x, landed)``: one short-convolution + experts
+    layer under ``wrap``.  ``routing``: ``common`` (no selection bias:
+    about a quarter of the assignments land, one chunk) or ``walk``
+    (the bias sends every token to the two held experts: all 512
+    land, and ``lax.cond`` takes the walk over both chunks)."""
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.ops import moe as M
+    from veles_tpu.znicz import attention as Z
+    e = _EXPERTS
+    monkeypatch.setattr(M, "ROW_TILE", e["row_tile"])
+    assert M.dropless_rows(e["T"], e["top_k"], e["E"],
+                           e["held"][1]) == (e["chunk"], 2)
+    spec = Z.layer_spec(norm="rms", operator="shortconv", bias=False,
+                        ffn="experts", ffn_dim=e["F"],
+                        n_experts=e["E"], top_k=e["top_k"],
+                        held=e["held"])
+    key = jax.random.PRNGKey(0)
+    params = {
+        name: 0.2 * jax.random.normal(jax.random.fold_in(key, i), shape)
+        for i, (name, shape) in enumerate(
+            Z.layer_param_shapes(spec, e["D"]).items())}
+    x = jax.random.normal(key, (2, e["T"] // 2, e["D"]))
+    bias = jnp.zeros((e["E"],))
+    if routing == "walk":
+        first, count = e["held"]
+        bias = bias.at[first:first + count].add(10.0)
+
+    def apply(p, h):
+        return Z.layer_apply(spec, p, h, jnp.bfloat16,
+                             buffers={"expert_bias": bias})
+
+    landed = float(apply(params, x)[1]["landed"])
+    layer = wrap(lambda p, h: apply(p, h)[0])
+
+    def loss(p, h):
+        return (layer(p, h) ** 2).sum()
+
+    return loss, params, x, landed
+
+
+def _count(jaxpr, counts=None):
+    """Primitive name → equations of ``jaxpr``, sub-jaxprs included."""
+    from jax._src import core
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] += 1
+        for sub in core.jaxprs_in_params(eqn.params):
+            _count(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("wrap,products,routing_ops,gathers", [
+    ("bare", 3, 1, 2), ("checkpointed", 1, 0, 1)])
+def test_recompute_of_an_expert_layer_holds_one_grouped_product(
+        monkeypatch, wrap, products, routing_ops, gathers):
+    """The ``remat2`` equation of a checkpointed expert layer's
+    gradient (recompute, then backward).  Under the layers' checkpoint
+    its recompute — the first ``cond``, whose common branch is the
+    second — holds ONE grouped product (``ys``: ``lax.ragged_dot``
+    stands in for ``gmm`` here), the gather of the chunk's weights
+    alone, and no sort, no ``top_k``, no router product (the float32
+    product of ``(T, D)`` by ``(D, E)``) and no gather of the chosen
+    scores; under a bare ``jax.checkpoint`` all three products, both
+    gathers and the whole routing.  The walk's
+    branch names nothing and is the same under both: its inner
+    checkpoint rebuilds a chunk from the layer's input."""
+    import jax
+    e = _EXPERTS
+    loss, params, x, _ = _expert_layer_loss(_wrap(wrap), monkeypatch)
+    remat, = [eqn for eqn in
+              jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr.eqns
+              if eqn.primitive.name == "remat2"]
+    body = remat.params["jaxpr"]
+    counts = _count(body)
+    router = [eqn for eqn in body.eqns
+              if eqn.primitive.name == "dot_general" and
+              [v.aval.shape for v in eqn.invars] ==
+              [(e["T"], e["D"]), (e["D"], e["E"])]]
+    chosen = [eqn for eqn in body.eqns if eqn.primitive.name in
+              ("jit", "pjit") and eqn.params["name"] == "take_along_axis"
+              and "gather" in _count(eqn.params["jaxpr"].jaxpr)]
+    assert (counts["sort"], counts["top_k"], len(router),
+            len(chosen)) == (routing_ops,) * 4
+    recompute, backward = [eqn for eqn in body.eqns
+                           if eqn.primitive.name == "cond"]
+    walk, common = (_count(branch.jaxpr)
+                    for branch in recompute.params["branches"])
+    assert common["ragged_dot_general"] == products
+    assert common["gather"] == gathers
+    assert walk["ragged_dot_general"] == 0 and walk["scan"] == 1
+    walk, common = (_count(branch.jaxpr)
+                    for branch in backward.params["branches"])
+    # dlhs and drhs of each product; the walk's inner checkpoint
+    # runs the three forward products once more
+    assert (common["ragged_dot_general"],
+            walk["ragged_dot_general"]) == (6, 9)
+
+
+@pytest.mark.parametrize("routing", ["common", "walk"])
+def test_kept_expert_values_give_the_recomputes_bits(monkeypatch,
+                                                     routing):
+    """A kept value is the value the recompute would have produced:
+    evaluated operation by operation, an expert layer's gradients
+    under the layers' checkpoint equal a bare ``jax.checkpoint``'s bit
+    for bit — on a routing that takes the common path, whose names
+    are kept, and on one that lands more than ``chunk``, where the
+    branch taken names nothing and the other hands zeros.  (Compiled
+    as ONE program the router's gradient is 1e-7 off on the CPU: XLA
+    fuses the derivative of the scores with their rebuilding where
+    there is one.)"""
+    import jax
+    grads = {}
+    for wrap in ("bare", "checkpointed"):
+        loss, params, x, landed = _expert_layer_loss(
+            _wrap(wrap), monkeypatch, routing)
+        assert (landed > _EXPERTS["chunk"]) == (routing == "walk")
+        with jax.disable_jit():
+            grads[wrap] = jax.grad(loss, argnums=(0, 1))(params, x)
+    for a, b in zip(*(jax.tree_util.tree_leaves(grads[wrap])
+                      for wrap in ("bare", "checkpointed"))):
+        assert float(abs(a).max()) > 0
+        numpy.testing.assert_array_equal(a, b)
+
+
+def test_checkpoint_keeps_what_the_expert_layer_names(monkeypatch):
+    """Beside what a bare ``jax.checkpoint`` stores, a checkpointed
+    expert layer keeps the router's scores (T, E) float32, the choice
+    (T, k) int32 and the scores it chose (T, k) float32, the order
+    (T · k) and the sizes (count) as int32, the gathered rows
+    (chunk, D) in the compute type and the two products before the
+    gate (chunk, F) float32 — and nothing else (a value
+    handed on through a jitted helper is listed once more: shapes, not
+    counts).  The selection bias is no longer stored: only the
+    choice's rebuilding read it."""
+    e = _EXPERTS
+    kept, bare = (
+        {r[:2] for r in _residuals(*_expert_layer_loss(
+            _wrap(wrap), monkeypatch)[:3])}
+        for wrap in ("checkpointed", "bare"))
+    assert bare - kept == {((e["E"],), "float32")}
+    assert kept - bare == {
+        ((e["T"], e["E"]), "float32"),
+        ((e["T"], e["top_k"]), "int32"),
+        ((e["T"], e["top_k"]), "float32"),
+        ((e["T"] * e["top_k"],), "int32"),
+        ((e["held"][1],), "int32"),
+        ((e["chunk"], e["D"]), "bfloat16"),
+        ((e["chunk"], e["F"]), "float32")}
 
 
 @pytest.mark.parametrize("pipelined", [False, True],

@@ -151,7 +151,9 @@ def test_spec_built_layers_name_their_inner_scopes(sample):
     convolution, attention with rotary positions, dense gated MLP,
     experts; ``samples/trinity.py``: the output gate, the sandwich's
     second norms, the shared expert), and the scope table places each
-    in every phase."""
+    in every phase — but the routing's ordering, which has no
+    gradient, and the gather of the rows, which the layers' checkpoint
+    keeps (docs/moe.md): no recompute holds it."""
     body, inner = SPEC_BODIES[sample]
     root.common.engine.remat = True
     launcher, wf = _tiny_lm(ticks_per_dispatch=2, layers=body())
@@ -162,7 +164,10 @@ def test_spec_built_layers_name_their_inner_scopes(sample):
         for scope in scopes:
             for phase in ("forward", "recompute", "backward"):
                 if (scope, phase) == ("moe_route", "backward"):
-                    continue        # its ordering has no gradient
+                    continue
+                if (scope, phase) == ("moe_dispatch", "recompute"):
+                    assert (phase, unit, scope) not in placed
+                    continue
                 assert (phase, unit, scope) in placed, (phase, unit,
                                                         scope)
     assert set(s for _body, units in SPEC_BODIES.values()
@@ -234,22 +239,15 @@ def test_parse_hlo_gives_a_nameless_fusion_its_bodys_placing():
     assert table["copy.1"] == (None, None, None)
 
 
-@pytest.mark.parametrize("forwards", [2, 4], ids=["kept", "twice"])
-def test_scopes_count_the_flash_calls_of_the_program(forwards):
-    """``scopes()`` sets ``attention.flash.fwd_calls`` / ``.dq_calls``
-    (labelled with the program) from the parse it makes anyway: by
-    instruction name, ``flash_fwd`` alone or ``flash_fwd.<n>``."""
-    from veles_tpu.observability.metrics import registry
-
+def _scopes_of_kernel_calls(calls):
+    """The scope table of a registered program whose text holds one
+    ``tpu_custom_call`` of each name in ``calls`` (its gauges are
+    set on the way)."""
     class Lowered(object):
         def compile(self, compiler_options=None):
             return self
 
         def as_text(self):
-            calls = ["flash_fwd"] + ["flash_fwd.%d" % i
-                                     for i in range(1, forwards)]
-            calls += ["flash_dq", "flash_dq.7", "flash_dkv.1",
-                      "flash_dkv.2", "flash_fwdish.3"]
             return "ENTRY %main () -> f32[] {\n" + "".join(
                 "  %%%s = f32[] custom-call(), custom_call_target="
                 '"tpu_custom_call"\n' % name for name in calls) + "}"
@@ -257,15 +255,48 @@ def test_scopes_count_the_flash_calls_of_the_program(forwards):
     programs.reset()
     programs.register("block_step", Lowered, ())
     try:
-        table = programs.scopes("block_step")
+        return programs.scopes("block_step")
     finally:
         programs.reset()
+
+
+@pytest.mark.parametrize("forwards", [2, 4], ids=["kept", "twice"])
+def test_scopes_count_the_flash_calls_of_the_program(forwards):
+    """``scopes()`` sets ``attention.flash.fwd_calls`` / ``.dq_calls``
+    (labelled with the program) from the parse it makes anyway: by
+    instruction name, ``flash_fwd`` alone or ``flash_fwd.<n>``."""
+    from veles_tpu.observability.metrics import registry
+    table = _scopes_of_kernel_calls(
+        ["flash_fwd"] + ["flash_fwd.%d" % i for i in range(1, forwards)]
+        + ["flash_dq", "flash_dq.7", "flash_dkv.1", "flash_dkv.2",
+           "flash_fwdish.3"])
     label = {"program": "block_step"}
     assert registry.peek("attention.flash.fwd_calls",
                          label).value == forwards
     assert registry.peek("attention.flash.dq_calls", label).value == 2
     assert programs.kernel_calls(table, "flash_dkv") == 2
     assert registry.peek("attention.flash.fwd_calls") is None
+    assert registry.peek("moe.gmm_calls", label).value == 0
+
+
+@pytest.mark.parametrize("gmm", [16, 18], ids=["kept", "again"])
+def test_scopes_count_the_megablox_calls_of_the_program(gmm):
+    """``scopes()`` sets ``moe.gmm_calls`` / ``moe.tgmm_calls`` the
+    same way, by the names the megablox kernels carry (``gmm.<n>``,
+    ``tgmm.<n>``: a ``tgmm`` is no ``gmm``): an expert layer holds six
+    ``tgmm`` and 16 ``gmm`` where its checkpoint kept the two
+    products before the gate, 18 where the recompute ran them
+    again."""
+    from veles_tpu.observability.metrics import registry
+    table = _scopes_of_kernel_calls(
+        ["gmm"] + ["gmm.%d" % i for i in range(1, gmm)] +
+        ["tgmm.%d" % i for i in range(6)] + ["gmmish.1", "flash_fwd.3"])
+    label = {"program": "block_step"}
+    assert registry.peek("moe.gmm_calls", label).value == gmm
+    assert registry.peek("moe.tgmm_calls", label).value == 6
+    assert registry.peek("attention.flash.fwd_calls", label).value == 1
+    assert programs.kernel_calls(table, "gmm") == gmm
+    assert registry.peek("moe.gmm_calls") is None
 
 
 def test_a_newer_compiles_program_takes_the_name_over():

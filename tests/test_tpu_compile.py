@@ -17,6 +17,7 @@ turned off around them — an entry written for a described chip cannot
 be read back without one.
 """
 
+import math
 import os
 import re
 
@@ -77,13 +78,29 @@ def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _flash_calls(text):
-    """(``flash_fwd``, ``flash_dq``, ``flash_dkv``) instructions of a
-    compiled program's text."""
+def _kernel_calls(text, *kernels):
+    """How many instructions of a compiled program's text are calls of
+    each of the Pallas ``kernels``, by the name it gives them."""
     return tuple(
         len(re.findall(r"%%%s[.\d]* = [^\n]*tpu_custom_call" % kernel,
                        text))
-        for kernel in ("flash_fwd", "flash_dq", "flash_dkv"))
+        for kernel in kernels)
+
+
+def _flash_calls(text):
+    return _kernel_calls(text, "flash_fwd", "flash_dq", "flash_dkv")
+
+
+def _megablox_calls(text):
+    return _kernel_calls(text, "gmm", "tgmm")
+
+
+def _wrap(name):
+    """What a case wraps a layer in: nothing, the layers' checkpoint,
+    or a ``jax.checkpoint`` with no policy."""
+    from veles_tpu.znicz.attention import checkpointed
+    return {"none": lambda fn: fn, "checkpointed": checkpointed,
+            "bare": jax.checkpoint}[name]
 
 
 #: ``lfm2-24b-a2b.train``'s attention layer (the MLP cut narrow: the
@@ -102,9 +119,8 @@ def _layer_step(wrap, shape, sharding, **spec):
     from veles_tpu.znicz import attention as Z
     B, S, H, D = shape
     spec = Z.layer_spec(n_heads=H, ffn_dim=256, **spec)
-    wrap = {"checkpointed": Z.checkpointed, "bare": jax.checkpoint}[wrap]
-    layer = wrap(lambda p, h: Z.layer_apply(spec, p, h,
-                                            jnp.bfloat16)[0])
+    layer = _wrap(wrap)(lambda p, h: Z.layer_apply(spec, p, h,
+                                                   jnp.bfloat16)[0])
 
     def loss(params, x):
         for p in params:
@@ -276,73 +292,151 @@ def test_trinity_layers_run_each_flash_kernel_once_a_pair(
     assert _flash_calls(text) == (calls, calls, calls)
 
 
-def test_trinity_expert_share_compiles(one_chip, monkeypatch):
+#: The two MoE cells' expert shares: tokens a tick, width, experts'
+#: width, routed experts, top k, how many are held here, what
+#: ``dropless_rows`` compiles the common path for, whether a norm
+#: reads the share's result inside the layer (the sandwich's), and the
+#: rest of the call.
+EXPERT_SHARES = {
+    "lfm2": dict(T=16384, D=2048, F=1536, E=64, k=4, held=8,
+                 rows=(10240, 7), post_norm=False, call={}),
+    "trinity": dict(T=8192, D=2048, F=1024, E=128, k=8, held=16,
+                    rows=(16384, 4), post_norm=True,
+                    call=dict(scaling=2.826, eps=1e-20, slack=(2, 1)))}
+#: ``gmm`` : ``tgmm`` calls of an expert share's forward + gradients.
+#: With no checkpoint: the common path 3 + 3 (``dlhs``) and 3
+#: ``tgmm``, the walk 3 + its inner checkpoint's 3 + 3 and 3.  Under
+#: a bare ``jax.checkpoint`` the recompute adds the common path's 3
+#: and, where a norm inside the layer reads the share's result, the
+#: walk's 3 (lfm2 adds the result to the stream and no more: its
+#: recompute's walk is empty).  The layers' checkpoint keeps the two
+#: products before the gate: two ``gmm`` fewer.
+MEGABLOX_CALLS = {
+    "lfm2": {"none": (15, 6), "checkpointed": (16, 6), "bare": (18, 6)},
+    "trinity": {"none": (15, 6), "checkpointed": (19, 6),
+                "bare": (21, 6)}}
+
+
+def _expert_share(cell, wrap, sharding):
+    """The loss of a cell's expert share (``moe_dropless``, and the
+    norm behind it where the cell's layers have one) under ``wrap``,
+    and the shapes to lower it from."""
+    from veles_tpu.ops import moe as M
+    c = EXPERT_SHARES[cell]
+    T, D, F, E, k, held = (c[n] for n in ("T", "D", "F", "E", "k",
+                                          "held"))
+    assert M.dropless_rows(T, k, E, held,
+                           c["call"].get("slack", (5, 4))) == c["rows"]
+    @_wrap(wrap)
+    def share(x, gate, bias, w1, w3, w2):
+        y, stats = M.moe_dropless(x, gate, bias, w1, w3, w2, top_k=k,
+                                  held=(0, held), **c["call"])
+        if c["post_norm"]:
+            y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + 1e-5)
+        return y, stats["landed"]
+
+    def loss(*operands):
+        y, landed = share(*operands)
+        return (y * y).sum() + landed
+
+    f32 = jnp.float32
+    return loss, (
+        _struct((T, D), f32, sharding), _struct((D, E), f32, sharding),
+        _struct((E,), f32, sharding), _struct((held, D, F), f32, sharding),
+        _struct((held, D, F), f32, sharding),
+        _struct((held, F, D), f32, sharding))
+
+
+def _expert_share_text(cell, wrap, sharding):
+    loss, structs = _expert_share(cell, wrap, sharding)
+    return _compiled_text(jax.grad(loss, argnums=(0, 1, 3, 4, 5)),
+                          *structs)
+
+
+@pytest.mark.parametrize("wrap", ["none", "checkpointed", "bare"])
+def test_trinity_expert_share_compiles(one_chip, monkeypatch, wrap):
     """``trinity-mini.train-8k``'s routed experts, forward and
     gradients: 8,192 tokens, top 8 of 128, 16 experts of 2048 x 1024
     held — ``lfm2-24b-a2b.train``'s even share, the common path
     compiled for twice it (``trinity_layers``' ``slack``: 16,384
     rows, 4 chunks).  A (1024, 1024) tile of the
     weights' gradient does not fit VMEM; ``grouped_dot`` keeps the
-    tile's elements at 1024 x 768 (``TILE_ELEMENTS``)."""
+    tile's elements at 1024 x 768 (``TILE_ELEMENTS``).  Under the
+    layers' checkpoint the share holds two ``gmm`` fewer than under a
+    bare one (:data:`MEGABLOX_CALLS`)."""
     from veles_tpu.ops import moe as M
     monkeypatch.setattr(M, "tpu_available", lambda: True)
-    T, D, F, E, k, held = 8192, 2048, 1024, 128, 8, 16
-    assert M.dropless_rows(T, k, E, held) == (10240, 7)
-    assert M.dropless_rows(T, k, E, held, (2, 1)) == (16384, 4)
-    f32 = jnp.float32
-
-    def loss(x, gate, bias, w1, w3, w2):
-        y, stats = M.moe_dropless(x, gate, bias, w1, w3, w2, top_k=k,
-                                  held=(0, held), scaling=2.826,
-                                  eps=1e-20, slack=(2, 1))
-        return (y * y).sum() + stats["landed"]
-    text = _compiled_text(
-        jax.grad(loss, argnums=(0, 1, 3, 4, 5)),
-        _struct((T, D), f32, one_chip), _struct((D, E), f32, one_chip),
-        _struct((E,), f32, one_chip), _struct((held, D, F), f32, one_chip),
-        _struct((held, D, F), f32, one_chip),
-        _struct((held, F, D), f32, one_chip))
-    assert text.count("tpu_custom_call") >= 9 and "conditional" in text
+    assert M.dropless_rows(8192, 8, 128, 16) == (10240, 7)
+    text = _expert_share_text("trinity", wrap, one_chip)
+    assert "conditional" in text
+    assert _megablox_calls(text) == MEGABLOX_CALLS["trinity"][wrap]
 
 
-@pytest.mark.parametrize("rows", [10240, 65536],
-                         ids=["common path", "every chunk"])
-def test_dropless_expert_share_compiles(one_chip, monkeypatch, rows):
+@pytest.mark.parametrize("rows,wrap", [
+    (10240, "none"), (65536, "none"), (65536, "checkpointed"),
+    (65536, "bare")],
+    ids=["common path", "every chunk", "every chunk, checkpointed",
+         "every chunk, bare"])
+def test_dropless_expert_share_compiles(one_chip, monkeypatch, rows,
+                                        wrap):
     """``lfm2-24b-a2b.train``'s expert layer, forward and gradients:
     16,384 tokens, top 4 of 64, 8 experts of 2048 x 1536 held.  On a
     TPU the grouped products are the megablox kernels (the selection
     is by platform: steered here, the process sees a CPU); their
     (512, 1024, 768 | 1024) tiles must fit VMEM.  ``rows``: the
     grouped product alone at the common path's 10,240 rows, and the
-    whole layer, which holds the walk over all 65,536."""
+    whole layer, which holds the walk over all 65,536 — with no
+    checkpoint, under the layers' and under a bare one
+    (:data:`MEGABLOX_CALLS`)."""
     from veles_tpu.ops import moe as M
     monkeypatch.setattr(M, "tpu_available", lambda: True)
-    T, D, F, E, k, held = 16384, 2048, 1536, 64, 4, 8
-    assert M.dropless_rows(T, k, E, held) == (10240, 7)
-    f32, bf16 = jnp.float32, jnp.bfloat16
     if rows == 10240:
+        c = EXPERT_SHARES["lfm2"]
+        bf16 = jnp.bfloat16
+
         def loss(lhs, rhs, sizes):
             return M.grouped_dot(lhs, rhs, sizes).sum()
         text = _compiled_text(
             jax.grad(loss, argnums=(0, 1)),
-            _struct((rows, D), bf16, one_chip),
-            _struct((held, D, F), bf16, one_chip),
-            _struct((held,), jnp.int32, one_chip))
+            _struct((rows, c["D"]), bf16, one_chip),
+            _struct((c["held"], c["D"], c["F"]), bf16, one_chip),
+            _struct((c["held"],), jnp.int32, one_chip))
         # the forward product is dead under a sum: dlhs (gmm), drhs (tgmm)
         assert text.count("tpu_custom_call") >= 2
         return
+    text = _expert_share_text("lfm2", wrap, one_chip)
+    assert "conditional" in text
+    assert _megablox_calls(text) == MEGABLOX_CALLS["lfm2"][wrap]
 
-    def loss(x, gate, bias, w1, w3, w2):
-        y, stats = M.moe_dropless(x, gate, bias, w1, w3, w2, top_k=k,
-                                  held=(0, held))
-        return (y * y).sum() + stats["landed"]
-    text = _compiled_text(
-        jax.grad(loss, argnums=(0, 1, 3, 4, 5)),
-        _struct((T, D), f32, one_chip), _struct((D, E), f32, one_chip),
-        _struct((E,), f32, one_chip), _struct((held, D, F), f32, one_chip),
-        _struct((held, D, F), f32, one_chip),
-        _struct((held, F, D), f32, one_chip))
-    assert text.count("tpu_custom_call") >= 9 and "conditional" in text
+
+@pytest.mark.parametrize("cell,kept_bytes", [
+    ("lfm2", 172777504), ("trinity", 206307392)])
+def test_kept_bytes_of_a_checkpointed_expert_share(one_chip,
+                                                   monkeypatch, cell,
+                                                   kept_bytes):
+    """What the layers' checkpoint keeps of a cell's expert share,
+    beside a bare ``jax.checkpoint``'s residuals: the scores (T, E)
+    float32, choice, order and sizes as int32, the chosen scores
+    (T, k) float32, the gathered rows (chunk, D) bfloat16 and — twice
+    — a product (chunk, F) float32: 172.8 MB a layer at
+    ``lfm2-24b-a2b.train`` (4.2 + 0.8 + 41.9 + 2 x 62.9), 206.3 at
+    ``trinity-mini.train-8k`` (4.2 + 0.8 + 67.1 + 2 x 67.1);
+    docs/moe.md."""
+    from jax._src.ad_checkpoint import saved_residuals
+    from veles_tpu.ops import moe as M
+    monkeypatch.setattr(M, "tpu_available", lambda: True)
+    c = EXPERT_SHARES[cell]
+
+    def stored(wrap):
+        loss, structs = _expert_share(cell, wrap, one_chip)
+        return {(tuple(a.shape), jnp.dtype(a.dtype))
+                for a, why in saved_residuals(loss, *structs)}
+
+    kept = stored("checkpointed") - stored("bare")
+    product = ((c["rows"][0], c["F"]), jnp.dtype("float32"))
+    assert product in kept and len(kept) == 7
+    assert sum(jnp.dtype(dtype).itemsize * math.prod(shape)
+               for shape, dtype in list(kept) + [product]) == kept_bytes
 
 
 @pytest.mark.parametrize("grad", [False, True],

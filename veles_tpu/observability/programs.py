@@ -199,9 +199,9 @@ def scopes(program):
     program was dispatched.  Instruction names are as the device
     trace gives them, without the ``%``.  The first call compiles
     (:func:`_compiled_text`) and parses, and sets the gauges
-    ``attention.flash.fwd_calls`` / ``.dq_calls`` (labelled with the
-    program's name) from that parse; later calls return the same
-    table."""
+    ``attention.flash.fwd_calls`` / ``.dq_calls`` and
+    ``moe.gmm_calls`` / ``.tgmm_calls`` (labelled with the program's
+    name) from that parse; later calls return the same table."""
     with _lock:
         entry = _programs.get(program)
     if entry is None:
@@ -210,12 +210,20 @@ def scopes(program):
         entry.table = parse_hlo(_compiled_text(entry.lower()),
                                 entry.units)
         entry.lower = None
-        # Equal counts: every checkpointed layer kept its forward
-        # kernel's output (``znicz.attention.checkpointed``); two to
-        # one: each recompute runs the kernel again.
+        # The flash pair equal: every checkpointed layer kept its
+        # forward kernel's output (``znicz.attention.checkpointed``);
+        # two to one: each recompute runs the kernel again.  The
+        # megablox pair (``ops.moe.grouped_dot``; ``tgmm`` is the
+        # weights' gradient, six an expert layer): two ``gmm`` a layer
+        # fewer where the checkpoint kept the two products before the
+        # gate (16 to 6 against 18 to 6; docs/observability.md).
         label = {"program": program}
         registry.gauge("attention.flash.fwd_calls", label).set(
             kernel_calls(entry.table, "flash_fwd"))
         registry.gauge("attention.flash.dq_calls", label).set(
             kernel_calls(entry.table, "flash_dq"))
+        registry.gauge("moe.gmm_calls", label).set(
+            kernel_calls(entry.table, "gmm"))
+        registry.gauge("moe.tgmm_calls", label).set(
+            kernel_calls(entry.table, "tgmm"))
     return entry.table

@@ -19,6 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..backends import tpu_available
 
@@ -36,6 +37,25 @@ DROPLESS_SLACK = (5, 4)
 #: float32 beside its double-buffered copies: 1024 × 768 compiles for
 #: a v5e's 16 MB, 1024 × 1024 does not (tests/test_tpu_compile.py).
 TILE_ELEMENTS = 1024 * 768
+
+#: Names (``jax.ad_checkpoint.checkpoint_name``) on what an expert
+#: layer's backward pass reads of its forward pass: the router's
+#: scores, the choice, the chosen scores (a gather of ``T · k``
+#: scalars), the order and the group sizes; on the common
+#: path the gathered rows and the two products before the gate.  The
+#: layers' checkpoint (``znicz.attention.checkpointed``) keeps them, so
+#: a layer's recompute holds no router, no sort, no gather and one
+#: grouped product (docs/moe.md gives the bytes).
+MOE_SCORES = "moe_scores"
+MOE_IDX = "moe_idx"
+MOE_WEIGHTS = "moe_weights"
+MOE_ORDER = "moe_order"
+MOE_SIZES = "moe_sizes"
+MOE_ROWS = "moe_rows"
+MOE_GATE = "moe_gate"
+MOE_UP = "moe_up"
+MOE_KEPT = (MOE_SCORES, MOE_IDX, MOE_WEIGHTS, MOE_ORDER, MOE_SIZES,
+            MOE_ROWS, MOE_GATE, MOE_UP)
 
 
 def _tile(n, most=1024, lane=128):
@@ -91,6 +111,23 @@ def dropless_rows(n_tokens, top_k, n_experts, count,
     return chunk, -(-worst // chunk)
 
 
+def _named_scores(logits):
+    return checkpoint_name(jax.nn.sigmoid(logits), MOE_SCORES)
+
+
+#: ``sigmoid(logits)`` under the name :data:`MOE_SCORES`, with a
+#: derivative that reads the NAMED value: ``lax.logistic``'s own rule
+#: reads its unnamed output, which a checkpoint that keeps the name
+#: would rebuild — and the router's product with it.
+_scores = jax.custom_jvp(_named_scores)
+
+
+@_scores.defjvp
+def _scores_jvp(primals, tangents):
+    scores = _named_scores(*primals)
+    return scores, tangents[0] * scores * (1 - scores)
+
+
 def sigmoid_route(x, gate_w, expert_bias, top_k, norm_topk=True,
                   scaling=1.0, eps=1e-6):
     """The LFM2 / DeepSeek-V3 router: ``s = sigmoid(x @ W_gate)`` over
@@ -101,13 +138,15 @@ def sigmoid_route(x, gate_w, expert_bias, top_k, norm_topk=True,
     float32 from float32 operands at ``highest``: a flipped choice is
     a discrete error, not a rounding.  Returns (idx (T, k) int32,
     weights (T, k) float32)."""
-    scores = jax.nn.sigmoid(jnp.dot(
+    scores = _scores(jnp.dot(
         x.astype(jnp.float32), gate_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(
         scores + jax.lax.stop_gradient(expert_bias.astype(jnp.float32)),
         top_k)
-    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    idx = checkpoint_name(idx, MOE_IDX)
+    weights = checkpoint_name(
+        jnp.take_along_axis(scores, idx, axis=-1), MOE_WEIGHTS)
     if norm_topk:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
     return idx, weights * scaling
@@ -144,7 +183,10 @@ def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
     that maps the same chunk function over all ``n_chunks`` (each
     rematerialised, so it holds one chunk's intermediates whatever
     lands): no routing drops a token, and only a routing far from
-    even pays for the walk.  The inner scopes ``moe_route``,
+    even pays for the walk.  Under the layers' checkpoint the routing
+    and, on the common path, the gathered rows and the two products
+    before the gate are kept (:data:`MOE_KEPT`); the walk names
+    nothing, which is its purpose.  The inner scopes ``moe_route``,
     ``moe_dispatch``, ``moe_experts``, ``moe_combine`` are the scope
     vocabulary's (docs/observability.md)."""
     T, D = x.shape
@@ -160,16 +202,22 @@ def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
         local = idx.reshape(-1) - first
         # an assignment to an expert not held sorts past every held one
         key = jnp.where((local >= 0) & (local < count), local, count)
-        order = jnp.pad(jnp.argsort(key, stable=True),
-                        (0, chunk * n_chunks - T * top_k))
-        sizes = jnp.bincount(key, length=count + 1)[:count].astype(
-            jnp.int32)
+        order = checkpoint_name(
+            jnp.pad(jnp.argsort(key, stable=True),
+                    (0, chunk * n_chunks - T * top_k)), MOE_ORDER)
+        sizes = checkpoint_name(
+            jnp.bincount(key, length=count + 1)[:count].astype(
+                jnp.int32), MOE_SIZES)
         ends = jnp.cumsum(sizes)
         landed = ends[-1]
 
-    def held_part(x, weights, w1, w3, w2, start):
+    def held_part(x, weights, w1, w3, w2, start,
+                  kept=lambda value, name: value):
         """Rows ``start … start + chunk`` of the ordered assignments:
-        (their tokens, what their experts give, weighted)."""
+        (their tokens, what their experts give, weighted).  ``kept``
+        names the gathered rows and the two products before the gate
+        (the common path's ``checkpoint_name``; the walk names
+        nothing)."""
         with jax.named_scope("moe_route"):
             rows = jax.lax.dynamic_slice(order, (start,), (chunk,))
             token = rows // top_k
@@ -178,10 +226,12 @@ def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
                 jnp.clip(ends - sizes, start, stop)
             valid = (start + jnp.arange(chunk) < landed)[:, None]
         with jax.named_scope("moe_dispatch"):
-            xs = jnp.where(valid, x[token].astype(cdt), 0)
+            xs = kept(jnp.where(valid, x[token].astype(cdt), 0),
+                      MOE_ROWS)
         with jax.named_scope("moe_experts"):
             dot = functools.partial(grouped_dot, group_sizes=here)
-            h = jax.nn.silu(dot(xs, w1)) * dot(xs, w3)
+            h = jax.nn.silu(kept(dot(xs, w1), MOE_GATE)) * \
+                kept(dot(xs, w3), MOE_UP)
             ys = dot(jnp.where(valid, h, 0).astype(cdt), w2)
         with jax.named_scope("moe_combine"):
             ys = jnp.where(valid, ys, 0) * \
@@ -193,7 +243,7 @@ def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
             return jnp.zeros((T, D), jnp.float32).at[token].add(ys)
 
     def one_chunk(*operands):
-        return combine(*held_part(*operands, 0))
+        return combine(*held_part(*operands, 0, kept=checkpoint_name))
 
     def every_chunk(*operands):
         token, ys = jax.lax.map(

@@ -34,13 +34,19 @@ def remat_enabled(unit_flag):
     O(S/N) attention memory, but without remat the backward still
     stores every block's full residual stream.
 
-    What a checkpointed layer KEEPS from its forward pass: its input,
-    and — where its attention ran the flash kernel — that kernel's
-    output and log-sum-exp rows (one compute-dtype activation of the
-    layer, B·S·E elements, plus B·H·S float32: 64 MB + 1 MB at 4 ×
-    2048 tokens of 4096 in bfloat16; as much at 2048 wide with heads
-    of 64, whose rows are padded to a lane tile).  Everything else is
-    computed again in the backward pass."""
+    What a checkpointed layer KEEPS from its forward pass: its input;
+    where its attention ran the flash kernel, that kernel's output and
+    log-sum-exp rows (one compute-dtype activation of the layer,
+    B·S·E elements, plus B·H·S float32: 64 MB + 1 MB at 4 × 2048
+    tokens of 4096 in bfloat16; as much at 2048 wide with heads of
+    64, whose rows are padded to a lane tile); and, where it has an
+    expert layer, the router's scores, the choice and the chosen
+    scores, the order and the held experts' counts (5 MB) and, on the
+    common path, the
+    gathered rows and the two grouped products before the gate (42 +
+    2 × 63 MB at 16,384 tokens of 2048, top 4, an eighth of the
+    experts 1536 wide held: 172.8 MB a layer; docs/moe.md).
+    Everything else is computed again in the backward pass."""
     if unit_flag is not None:
         return bool(unit_flag)
     return bool(config_get(root.common.engine.remat, False))
@@ -49,21 +55,31 @@ def remat_enabled(unit_flag):
 def checkpointed(fn):
     """``fn`` under THE layers' checkpoint — both sites
     (``LMLayer.tforward``, the pipelined stack's block function) go
-    through here.  ``jax.checkpoint`` with one rule: save what came
-    out of the flash forward kernel (the two names
-    ``ops/pallas_attention.py`` gives inside its forward rule),
-    recompute the rest.  The kernel runs at a fifth of its roofline,
-    so its second call was the dearest part of the recompute for the
-    bytes it costs to keep (:func:`remat_enabled`); q, k and v are
-    XLA projections and are rebuilt as before.  The rule observes
-    only that a value came out of the kernel: where none did — XLA's
-    attention, a short-convolution layer — nothing is saved and the
-    program is a bare ``jax.checkpoint``'s."""
+    through here.  ``jax.checkpoint`` with one rule: save what carries
+    one of the names below, recompute the rest.  The names are given
+    where the dearest parts of a layer hand over what the backward
+    pass reads: inside the flash forward kernel's rule
+    (``ops/pallas_attention.py``: its output and rows — the kernel
+    runs at a fifth of its roofline, so its second call was the
+    dearest part of the recompute for the bytes it costs to keep) and
+    inside the expert layer (``ops/moe.py`` ``MOE_KEPT``: the router's
+    scores, the choice, the chosen scores, the order, the sizes, the
+    gathered rows and the two grouped products before the gate — the
+    router and the sort were run again to recover 5 MB, two megablox
+    calls at
+    a quarter of their roofline to recover 126; the third product and
+    the gate are rebuilt, and the walk over every chunk names
+    nothing).  :func:`remat_enabled` gives the bytes; q, k and v and
+    every dense product are XLA matmuls and are rebuilt as before.
+    The rule observes only that a value carries a name: where none
+    does — XLA's attention, a short convolution, a dense MLP —
+    nothing is saved and the program is a bare ``jax.checkpoint``'s."""
     import jax
+    from ..ops.moe import MOE_KEPT
     from ..ops.pallas_attention import FLASH_OUT, FLASH_LSE
     return jax.checkpoint(
         fn, policy=jax.checkpoint_policies.save_only_these_names(
-            FLASH_OUT, FLASH_LSE))
+            FLASH_OUT, FLASH_LSE, *MOE_KEPT))
 
 
 def fused_qkv_enabled(unit_flag):
