@@ -129,6 +129,16 @@ def _trinity_body():
         num_dense_layers=1, held=(0, 2))
 
 
+def _qwen3_next_body():
+    from veles_tpu.znicz.samples.qwen3_next import qwen3_next_layers
+    return qwen3_next_layers(
+        2, n_heads=2, kv_heads=1, head_dim=8, linear_key_heads=1,
+        linear_value_heads=2, linear_key_dim=8, linear_value_dim=8,
+        moe_intermediate_size=16, n_experts=4, top_k=2,
+        shared_expert_intermediate_size=16, full_attention_interval=2,
+        rope_theta=1e4, held=(0, 2), linear_chunk=4)
+
+
 SPEC_BODIES = {
     "lfm2": (_lfm2_body, {
         "block0": ("ln1", "shortconv", "ln2", "mlp"),
@@ -141,6 +151,11 @@ SPEC_BODIES = {
         "block1": ("attention", "attn_gate", "ln1_post", "moe_route",
                    "moe_experts", "moe_shared", "ln2_post"),
         "block2": ("attention", "attn_gate", "moe_shared", "ln2_post")}),
+    "qwen3_next": (_qwen3_next_body, {
+        "block0": ("ln1", "shortconv", "gdn_gate", "gated_delta",
+                   "gdn_norm", "ln2", "moe_route", "moe_experts",
+                   "moe_shared"),
+        "block1": ("rope", "attention", "attn_gate", "moe_shared")}),
 }
 
 
@@ -150,7 +165,10 @@ def test_spec_built_layers_name_their_inner_scopes(sample):
     is opened by a layer built from a spec (``samples/lfm2.py``:
     convolution, attention with rotary positions, dense gated MLP,
     experts; ``samples/trinity.py``: the output gate, the sandwich's
-    second norms, the shared expert), and the scope table places each
+    second norms, the shared expert; ``samples/qwen3_next.py``: the
+    gated delta rule, its gates and its gated norm, whose scan of
+    sequence / chunk steps the gauges ``linear_attention.scan_steps``
+    / ``.chunk`` report), and the scope table places each
     in every phase — but the routing's ordering, which has no
     gradient, and the gather of the rows, which the layers' checkpoint
     keeps (docs/moe.md): no recompute holds it."""
@@ -174,6 +192,12 @@ def test_spec_built_layers_name_their_inner_scopes(sample):
                for scopes in units.values() for s in scopes) == \
         set(programs.INNER_SCOPES)
     assert ("forward", "final_norm", None) in placed
+    from veles_tpu.observability.metrics import registry
+    found = [registry.peek("linear_attention." + what,
+                           {"program": "block_step"})
+             for what in ("scan_steps", "chunk")]
+    assert [g.value for g in found] == (
+        [2, 4] if sample == "qwen3_next" else [0, 0])     # 8 rows by 4
 
 
 def test_scopes_answer_after_stop_and_array_deletion(block_run):

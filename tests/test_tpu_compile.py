@@ -39,6 +39,9 @@ LFM2 = (8, 2048, 32, 64)
 #: broadcast to the 32 query heads of 128; four chunks of ``MAX_SEQ``.
 TRINITY = (1, 8192, 32, 128)
 TRINITY_WINDOW = 2048
+#: ``qwen3-next.train-8k``: 1 x 8192 tokens on a 2,048-wide stream;
+#: the full layer's 2 kv heads broadcast to 16 query heads of 256.
+QWEN3_NEXT = (1, 8192, 16, 256)
 #: Decode: one new token per row over a 2048-slot gathered table.
 DECODE_L = 2048
 #: Ring shard: S=1024 over a 2-way seq axis.
@@ -292,7 +295,56 @@ def test_trinity_layers_run_each_flash_kernel_once_a_pair(
     assert _flash_calls(text) == (calls, calls, calls)
 
 
-#: The two MoE cells' expert shares: tokens a tick, width, experts'
+#: ``qwen3-next.train-8k``'s two kinds of layer (the FFN cut narrow:
+#: the compile is of the operator's path).
+QWEN3_NEXT_LINEAR = dict(
+    norm="rms", bias=False, norm_eps=1e-6, operator="gated_delta",
+    linear_key_heads=16, linear_value_heads=32, linear_key_dim=128,
+    linear_value_dim=128, conv_kernel=4, ffn="gated-mlp")
+QWEN3_NEXT_FULL = dict(
+    norm="rms", bias=False, norm_eps=1e-6, kv_heads=2, head_dim=256,
+    qk_norm=True, attn_gate=True, rope_theta=1e7, rope_fraction=0.25,
+    ffn="gated-mlp")
+
+
+@pytest.mark.parametrize("kind,spec,flash,scan", [
+    ("linear", QWEN3_NEXT_LINEAR, 0, (128, 64)),
+    ("full", QWEN3_NEXT_FULL, 20, None)])
+def test_qwen3_next_layers_compile(one_chip, monkeypatch, kind, spec,
+                                   flash, scan):
+    """The cell's two kinds of spec-built layer, two of each under the
+    layers' checkpoint, forward + backward at 1 x 8,192 tokens of
+    2,048.  The linear layer holds no Pallas kernel: the gated delta
+    rule is XLA's chunked form, and what ``programs.linear_scan``
+    reads of the compiled text is its scan of 8,192 / 64 = 128 steps
+    in chunks of 64 (``linear_attention.scan_steps`` / ``.chunk``).
+    The full layer is the flash kernels' first call at a head of 256:
+    10 visible chunk pairs a layer, each forward kept."""
+    from veles_tpu.observability import programs
+    from veles_tpu.ops import attention as A
+    from veles_tpu.znicz import attention as Z
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    B, S, H, _ = QWEN3_NEXT
+    spec = Z.layer_spec(n_heads=H, ffn_dim=256, **spec)
+    layer = Z.checkpointed(lambda p, h: Z.layer_apply(
+        spec, p, h, jnp.bfloat16)[0])
+
+    def loss(params, x):
+        for p in params:
+            x = layer(p, x)
+        return (x * x).sum()
+
+    params = [{name: _struct(shape, jnp.float32, one_chip)
+               for name, shape in
+               Z.layer_param_shapes(spec, 2048).items()}] * 2
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        params, _struct((B, S, 2048), jnp.float32, one_chip)
+    ).compile().as_text()
+    assert _flash_calls(text) == (flash, flash, flash)
+    assert programs.linear_scan(text) == scan
+
+
+#: The MoE cells' expert shares: tokens a tick, width, experts'
 #: width, routed experts, top k, how many are held here, what
 #: ``dropless_rows`` compiles the common path for, whether a norm
 #: reads the share's result inside the layer (the sandwich's), and the
@@ -302,7 +354,11 @@ EXPERT_SHARES = {
                  rows=(10240, 7), post_norm=False, call={}),
     "trinity": dict(T=8192, D=2048, F=1024, E=128, k=8, held=16,
                     rows=(16384, 4), post_norm=True,
-                    call=dict(scaling=2.826, eps=1e-20, slack=(2, 1)))}
+                    call=dict(scaling=2.826, eps=1e-20, slack=(2, 1))),
+    "qwen3-next": dict(T=8192, D=2048, F=512, E=512, k=10, held=32,
+                       rows=(10240, 8), post_norm=False,
+                       call=dict(eps=0.0, slack=(2, 1),
+                                 score="softmax"))}
 #: ``gmm`` : ``tgmm`` calls of an expert share's forward + gradients.
 #: With no checkpoint: the common path 3 + 3 (``dlhs``) and 3
 #: ``tgmm``, the walk 3 + its inner checkpoint's 3 + 3 and 3.  Under
@@ -314,7 +370,8 @@ EXPERT_SHARES = {
 MEGABLOX_CALLS = {
     "lfm2": {"none": (15, 6), "checkpointed": (16, 6), "bare": (18, 6)},
     "trinity": {"none": (15, 6), "checkpointed": (19, 6),
-                "bare": (21, 6)}}
+                "bare": (21, 6)},
+    "qwen3-next": {"checkpointed": (16, 6), "bare": (18, 6)}}
 
 
 def _expert_share(cell, wrap, sharding):
@@ -370,6 +427,24 @@ def test_trinity_expert_share_compiles(one_chip, monkeypatch, wrap):
     text = _expert_share_text("trinity", wrap, one_chip)
     assert "conditional" in text
     assert _megablox_calls(text) == MEGABLOX_CALLS["trinity"][wrap]
+
+
+@pytest.mark.parametrize("wrap", ["checkpointed", "bare"])
+def test_qwen3_next_expert_share_compiles(one_chip, monkeypatch, wrap):
+    """``qwen3-next.train-8k``'s routed experts, forward and
+    gradients: 8,192 tokens, a softmax over 512, top 10, 32 experts of
+    2048 x 512 held — groups of about 160 rows, the many-small-experts
+    end of the megablox tiling: (512, 1024, 512) and (512, 512, 1024)
+    tiles.  81,920 assignments are sorted; the common path is compiled
+    for twice the even share (10,240 rows, 8 chunks).  The kept values
+    hold under the softmax router as under the sigmoid one: two
+    ``gmm`` fewer than under a bare checkpoint."""
+    from veles_tpu.ops import moe as M
+    monkeypatch.setattr(M, "tpu_available", lambda: True)
+    assert M.dropless_rows(8192, 10, 512, 32) == (6656, 13)
+    text = _expert_share_text("qwen3-next", wrap, one_chip)
+    assert "conditional" in text
+    assert _megablox_calls(text) == MEGABLOX_CALLS["qwen3-next"][wrap]
 
 
 @pytest.mark.parametrize("rows,wrap", [

@@ -7,7 +7,8 @@ under ``jax.named_scope(unit.scope_name)``, the update rules under
 ``update``, the health sentinel under ``health``, and the block
 function opens ``ln1`` / ``attention`` / ``ln2`` / ``mlp`` (and
 ``rope``, ``shortconv``, ``moe_*``, ``attn_gate``, ``ln1_post`` /
-``ln2_post`` where a spec asks for them) inside
+``ln2_post``, ``gdn_gate``, ``gated_delta``, ``gdn_norm`` where a
+spec asks for them) inside
 a unit; JAX adds ``jvp(...)``, ``transpose(jvp(...))`` and the
 checkpoint's ``rematted_computation`` by itself.  This module keeps,
 per program name (``block_step``, ``train_step``, ``infer_step``),
@@ -43,7 +44,7 @@ STEP_SCOPES = ("update", "health")
 INNER_SCOPES = ("ln1", "attention", "ln2", "mlp", "rope", "shortconv",
                 "moe_route", "moe_dispatch", "moe_experts",
                 "moe_combine", "attn_gate", "moe_shared", "ln1_post",
-                "ln2_post")
+                "ln2_post", "gdn_gate", "gated_delta", "gdn_norm")
 
 _lock = threading.Lock()
 _programs = {}
@@ -163,6 +164,38 @@ def kernel_calls(table, kernel):
                if name.split(".")[0] == kernel)
 
 
+_WHILE = re.compile(r" while\(.*condition=%?([\w.\-]+)")
+_TRIPS = re.compile(r'known_trip_count[^0-9]*(\d+)')
+_BOUND = re.compile(r" = s32\[\][^ ]* constant\((\d+)\)")
+_CHUNK = re.compile(r"gated_delta\)?/chunk(\d+)[/\"]")
+
+
+def linear_scan(text):
+    """``(steps, chunk)`` of the gated delta rule's scan in a compiled
+    program's HLO text, or None where the program holds no such loop.
+    ``chunk``: the rows of a chunk, which ``ops.linear_attention``
+    writes into its scope (``gated_delta/chunk64``).  ``steps``: the
+    trip count of a ``while`` under that scope (the longest, were they
+    to differ) — its ``known_trip_count`` where the backend prints
+    one, else the bound its condition compares the counter with."""
+    bounds, loops, computation = {}, [], None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+            continue
+        bound = _BOUND.search(line)
+        if bound:
+            bounds.setdefault(computation, []).append(int(bound.group(1)))
+        rows, loop = _CHUNK.search(line), _WHILE.search(line)
+        if rows and loop:
+            loops.append((int(rows.group(1)), loop.group(1),
+                          _TRIPS.search(line)))
+    steps = [int(trips.group(1)) if trips else max(bounds.get(cond, [0]))
+             for _rows, cond, trips in loops]
+    return (max(steps), loops[0][0]) if loops else None
+
+
 #: A compiler option at its default.  ``Lowered.compile()`` hands
 #: back the executable it already has, and the lowering is the one
 #: the dispatch ran from; with an option it compiles anew.
@@ -200,15 +233,16 @@ def scopes(program):
     trace gives them, without the ``%``.  The first call compiles
     (:func:`_compiled_text`) and parses, and sets the gauges
     ``attention.flash.fwd_calls`` / ``.dq_calls`` and
-    ``moe.gmm_calls`` / ``.tgmm_calls`` (labelled with the program's
-    name) from that parse; later calls return the same table."""
+    ``moe.gmm_calls`` / ``.tgmm_calls`` and ``linear_attention.
+    scan_steps`` / ``.chunk`` (all labelled with the program's name)
+    from that parse; later calls return the same table."""
     with _lock:
         entry = _programs.get(program)
     if entry is None:
         return None
     if entry.table is None:
-        entry.table = parse_hlo(_compiled_text(entry.lower()),
-                                entry.units)
+        text = _compiled_text(entry.lower())
+        entry.table = parse_hlo(text, entry.units)
         entry.lower = None
         # The flash pair equal: every checkpointed layer kept its
         # forward kernel's output (``znicz.attention.checkpointed``);
@@ -226,4 +260,10 @@ def scopes(program):
             kernel_calls(entry.table, "gmm"))
         registry.gauge("moe.tgmm_calls", label).set(
             kernel_calls(entry.table, "tgmm"))
+        # The rule's chunked scan: sequence / chunk dependent steps a
+        # layer (``ops/linear_attention.py``); nought where the
+        # program carries no such state.
+        steps, chunk = linear_scan(text) or (0, 0)
+        registry.gauge("linear_attention.scan_steps", label).set(steps)
+        registry.gauge("linear_attention.chunk", label).set(chunk)
     return entry.table

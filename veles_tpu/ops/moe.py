@@ -2,10 +2,11 @@
 (docs/moe.md).
 
 Not in the 2013-15 reference (SURVEY §5).  An expert layer here HOLDS
-a share of the experts — expert parallelism's unit of work: a sigmoid
-router with a selection bias scores all ``E`` experts
-(:func:`sigmoid_route`), the assignments to the ``count`` experts held
-here are ordered by expert and go through grouped matrix products
+a share of the experts — expert parallelism's unit of work: a router
+scores all ``E`` experts (:func:`sigmoid_route`, with a selection
+bias, or :func:`softmax_route`), the assignments to the ``count``
+experts held here are ordered by expert and go through grouped
+matrix products
 (:func:`grouped_dot`: the megablox kernels on a TPU), gated, and are
 added back by their weights (:func:`moe_dropless`).  No assignment is
 ever dropped, and the work follows the assignments that landed, not
@@ -128,6 +129,19 @@ def _scores_jvp(primals, tangents):
     return scores, tangents[0] * scores * (1 - scores)
 
 
+def _choose(scores, chosen_by, top_k, norm_topk, scaling, eps):
+    """The ``top_k`` largest of ``chosen_by`` a row and their
+    ``scores`` as weights, under the names the layers' checkpoint
+    keeps."""
+    _, idx = jax.lax.top_k(chosen_by, top_k)
+    idx = checkpoint_name(idx, MOE_IDX)
+    weights = checkpoint_name(
+        jnp.take_along_axis(scores, idx, axis=-1), MOE_WEIGHTS)
+    if norm_topk:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
+    return idx, weights * scaling
+
+
 def sigmoid_route(x, gate_w, expert_bias, top_k, norm_topk=True,
                   scaling=1.0, eps=1e-6):
     """The LFM2 / DeepSeek-V3 router: ``s = sigmoid(x @ W_gate)`` over
@@ -141,25 +155,58 @@ def sigmoid_route(x, gate_w, expert_bias, top_k, norm_topk=True,
     scores = _scores(jnp.dot(
         x.astype(jnp.float32), gate_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(
+    return _choose(
+        scores,
         scores + jax.lax.stop_gradient(expert_bias.astype(jnp.float32)),
-        top_k)
-    idx = checkpoint_name(idx, MOE_IDX)
-    weights = checkpoint_name(
-        jnp.take_along_axis(scores, idx, axis=-1), MOE_WEIGHTS)
-    if norm_topk:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
-    return idx, weights * scaling
+        top_k, norm_topk, scaling, eps)
+
+
+def _named_probabilities(logits):
+    return checkpoint_name(jax.nn.softmax(logits, axis=-1), MOE_SCORES)
+
+
+#: ``softmax(logits)`` under the name :data:`MOE_SCORES`, its
+#: derivative from the NAMED value, as :data:`_scores`.
+_probabilities = jax.custom_jvp(_named_probabilities)
+
+
+@_probabilities.defjvp
+def _probabilities_jvp(primals, tangents):
+    p = _named_probabilities(*primals)
+    lift = tangents[0] * p
+    return p, lift - p * lift.sum(axis=-1, keepdims=True)
+
+
+def softmax_route(x, gate_w, expert_bias, top_k, norm_topk=True,
+                  scaling=1.0, eps=1e-6):
+    """The Qwen-MoE router: ``p = softmax(x @ W_gate)`` over ALL E
+    experts, the choice the k largest ``p``, the weights those ``p``
+    normalised over the chosen k (their sum + ``eps``) and scaled.
+    There is no selection bias: ``expert_bias`` is taken, as
+    :func:`sigmoid_route` takes it, and not read.  Float32 from
+    float32 operands at ``highest`` and the same checkpoint names as
+    :func:`sigmoid_route`.  Returns (idx (T, k) int32, weights (T, k)
+    float32)."""
+    del expert_bias
+    p = _probabilities(jnp.dot(
+        x.astype(jnp.float32), gate_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    return _choose(p, p, top_k, norm_topk, scaling, eps)
+
+
+#: The score functions :func:`moe_dropless` routes by.
+ROUTERS = {"sigmoid": sigmoid_route, "softmax": softmax_route}
 
 
 def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
                  norm_topk=True, scaling=1.0, cdt=jnp.bfloat16,
-                 eps=1e-6, slack=DROPLESS_SLACK):
+                 eps=1e-6, slack=DROPLESS_SLACK, score="sigmoid"):
     """A share of a dropless top-k expert layer with gated experts.
 
     Args:
       x: (T, D) tokens; gate_w: (D, E) router over ALL E experts;
       expert_bias: (E,) selection bias (:func:`sigmoid_route`);
+      score: ``sigmoid`` | ``softmax`` (:data:`ROUTERS`);
       w1, w3: (count, D, F), w2: (count, F, D) — the experts held
         here, ``silu(x @ w1) * (x @ w3) @ w2`` each;
       held: ``(first, count)`` — experts ``first … first + count − 1``
@@ -197,8 +244,8 @@ def moe_dropless(x, gate_w, expert_bias, w1, w3, w2, top_k, held,
                          % (held, E, w1.shape[0]))
     chunk, n_chunks = dropless_rows(T, top_k, E, count, slack)
     with jax.named_scope("moe_route"):
-        idx, weights = sigmoid_route(x, gate_w, expert_bias, top_k,
-                                     norm_topk, scaling, eps)
+        idx, weights = ROUTERS[score](x, gate_w, expert_bias, top_k,
+                                      norm_topk, scaling, eps)
         local = idx.reshape(-1) - first
         # an assignment to an expert not held sorts past every held one
         key = jnp.where((local >= 0) & (local < count), local, count)

@@ -4,6 +4,8 @@ front of them, per head.  Both in float32: the angles reach
 ``S · θ^0`` radians and a bfloat16 cosine of 2047 is noise.
 """
 
+import math
+
 import jax.numpy as jnp
 
 
@@ -25,14 +27,21 @@ def rotary_tables(seq, head_dim, theta):
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def rotary(x, theta):
+def rotary(x, theta, fraction=None):
     """Rotates (B, S, H, D) by position: the halves ``x1 = x[..., :D/2]``
     and ``x2 = x[..., D/2:]`` go to ``(x1 cos − x2 sin, x2 cos + x1
-    sin)``.  Float32 out."""
-    D = x.shape[-1]
+    sin)``.  ``fraction`` (None: the whole head) rotates the FIRST
+    ``D · fraction`` elements of a head so, halves and frequencies
+    taken inside them, and passes the rest on untouched (the
+    ``partial_rotary_factor`` of the public checkpoints).  Float32
+    out."""
+    D = x.shape[-1] if fraction is None else \
+        math.floor(x.shape[-1] * fraction)
     cos, sin = rotary_tables(x.shape[1], D, theta)
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :D // 2], xf[..., D // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x2 * cos + x1 * sin], axis=-1)
+    x1, x2 = xf[..., :D // 2], xf[..., D // 2:D]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if D < x.shape[-1]:
+        parts.append(xf[..., D:])
+    return jnp.concatenate(parts, axis=-1)

@@ -135,8 +135,9 @@ def _layer_norm(x, gamma, beta, eps=1e-5):
 
 #: What a layer spec may say (:func:`layer_spec`).
 NORMS = ("layer", "rms")
-OPERATORS = ("attention", "shortconv")
+OPERATORS = ("attention", "shortconv", "gated_delta")
 FFNS = ("relu-mlp", "gated-mlp", "experts")
+SCORES = ("sigmoid", "softmax")
 
 
 def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
@@ -146,7 +147,10 @@ def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
                norm_topk=True, routed_scaling=1.0, norm_eps=1e-5,
                head_dim=None, window=None, attn_gate=False,
                post_norm=False, shared_ffn_dim=None, route_eps=1e-6,
-               slack=(5, 4)):
+               slack=(5, 4), rope_fraction=None, score="sigmoid",
+               shared_gate=False, linear_key_heads=None,
+               linear_value_heads=None, linear_key_dim=None,
+               linear_value_dim=None, linear_chunk=64):
     """One decoder layer, said as data: ``h + operator(norm(h))`` then
     ``+ ffn(norm(·))``.  A plain dict (it rides snapshots).
 
@@ -157,11 +161,18 @@ def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
     heads of ``head_dim`` (None: the layer's width over ``n_heads``)
     over ``kv_heads`` key/value heads (None: as many), ``qk_norm`` a
     per-head RMS norm of q and k, ``rope_theta`` rotary positions
-    (None: the positions are the embedding's, or none), ``window`` how
+    (None: the positions are the embedding's, or none) on the first
+    ``rope_fraction`` of each head (None: all of it), ``window`` how
     many keys back a row sees (None: all; ``ops.attention``),
     ``attn_gate`` an output gate ``sigmoid(u Wg)`` on the heads'
     result before ``Wo`` — | ``shortconv``, the gated short
-    convolution of ``conv_kernel`` taps (``ops/shortconv.py``).
+    convolution of ``conv_kernel`` taps (``ops/shortconv.py``) — |
+    ``gated_delta``, linear attention with a carried state
+    (``ops/linear_attention.py``): ``linear_key_heads`` query/key
+    heads of ``linear_key_dim`` serving ``linear_value_heads`` value
+    heads of ``linear_value_dim``, q, k and v through ``conv_kernel``
+    causal taps a channel and a SiLU, the rule in chunks of
+    ``linear_chunk`` rows, a gated RMS norm a head behind it.
     ``ffn``: ``relu-mlp`` | ``gated-mlp`` (``silu(u W1) ⊙ (u W3))
     W2``), both ``ffn_dim`` wide (None: 4 × the layer's width), |
     ``experts``: ``top_k`` of ``n_experts`` gated experts ``ffn_dim``
@@ -169,22 +180,46 @@ def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
     count)`` (None: all) — ``ops.moe.moe_dropless``, its weights
     normalised over ``sum + route_eps``, its common path compiled for
     ``slack`` (a ratio) times the even share of the assignments
-    (``ops.moe.dropless_rows``) — and beside them, where
-    ``shared_ffn_dim`` is set, one gated MLP that wide over every
-    token (the shared expert).  ``bias``: whether the projections and
-    the MLP carry biases."""
+    (``ops.moe.dropless_rows``), routed by ``score`` (``sigmoid``
+    with the selection bias | ``softmax`` over all the experts, which
+    reads no bias: the buffer stays, at nought) — and beside them,
+    where ``shared_ffn_dim`` is set, one gated MLP that wide over
+    every token (the shared expert), times ``sigmoid(u w_sg)`` a token
+    where ``shared_gate``.  ``bias``: whether the projections and the
+    MLP carry biases."""
     if norm not in NORMS or operator not in OPERATORS or \
             ffn not in FFNS:
         raise ValueError("layer spec: norm %r of %s, operator %r of "
                          "%s, ffn %r of %s" % (norm, NORMS, operator,
                                                OPERATORS, ffn, FFNS))
+    if score not in SCORES:
+        raise ValueError("a router's score %r of %s" % (score, SCORES))
     kv_heads = kv_heads or n_heads
     if n_heads % kv_heads:
         raise ValueError("%d query heads over %d key/value heads"
                          % (n_heads, kv_heads))
-    if operator != "attention" and (head_dim or window or attn_gate):
-        raise ValueError("head_dim, window and attn_gate are "
-                         "attention's, not %r's" % operator)
+    if operator != "attention" and (head_dim or window or attn_gate
+                                    or rope_fraction):
+        raise ValueError("head_dim, window, attn_gate and "
+                         "rope_fraction are attention's, not %r's"
+                         % operator)
+    if rope_fraction is not None and not (
+            rope_theta and 0 < rope_fraction <= 1):
+        raise ValueError("rope_fraction %r of a head, rope_theta %r"
+                         % (rope_fraction, rope_theta))
+    linear = (linear_key_heads, linear_value_heads, linear_key_dim,
+              linear_value_dim)
+    if operator == "gated_delta":
+        if not all(linear) or linear_value_heads % linear_key_heads \
+                or linear_chunk < 1:
+            raise ValueError(
+                "gated_delta: %r key heads of %r, %r value heads of "
+                "%r, chunks of %r" % (linear_key_heads, linear_key_dim,
+                                      linear_value_heads,
+                                      linear_value_dim, linear_chunk))
+    elif any(linear):
+        raise ValueError("linear_* are gated_delta's, not %r's"
+                         % operator)
     if window is not None and window < 1:
         raise ValueError("a window of %r keys" % (window,))
     if ffn == "experts":
@@ -195,6 +230,8 @@ def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
                              % (top_k, n_experts, held))
     elif shared_ffn_dim:
         raise ValueError("a shared expert beside %r" % ffn)
+    if shared_gate and not shared_ffn_dim:
+        raise ValueError("shared_gate without a shared expert")
     return {"norm": norm, "operator": operator, "ffn": ffn,
             "n_heads": n_heads, "kv_heads": kv_heads,
             "qk_norm": bool(qk_norm), "rope_theta": rope_theta,
@@ -205,7 +242,13 @@ def layer_spec(norm="layer", operator="attention", ffn="relu-mlp",
             "head_dim": head_dim, "window": window,
             "attn_gate": bool(attn_gate), "post_norm": bool(post_norm),
             "shared_ffn_dim": shared_ffn_dim, "route_eps": route_eps,
-            "slack": tuple(slack)}
+            "slack": tuple(slack), "rope_fraction": rope_fraction,
+            "score": score, "shared_gate": bool(shared_gate),
+            "linear_key_heads": linear_key_heads,
+            "linear_value_heads": linear_value_heads,
+            "linear_key_dim": linear_key_dim,
+            "linear_value_dim": linear_value_dim,
+            "linear_chunk": linear_chunk}
 
 
 def layer_param_shapes(spec, embed, fused_qkv=False):
@@ -219,8 +262,11 @@ def layer_param_shapes(spec, embed, fused_qkv=False):
     prng in iteration order, so the unfused OPT layout keeps the
     historical ordering bit-for-bit (seeded trajectories — and the
     tests pinning them — depend on it); what a newer spec key adds
-    (``wg``, ``ln1_post_*``, ``ws1`` / ``ws3`` / ``ws2``,
-    ``ln2_post_*``) comes behind its part's older leaves."""
+    (``wg``, ``ln1_post_*``, ``ws1`` / ``ws3`` / ``ws2``, ``wsg``,
+    ``ln2_post_*``) comes behind its part's older leaves, and a
+    ``gated_delta`` operator's own (``w_qkvz``, ``w_ba``, ``w_conv``,
+    ``a_log``, ``dt_bias``, ``gdn_norm_g``, ``w_out``) where an
+    older operator's would stand."""
     spec = layer_spec(**spec)      # a snapshot's older spec lacks keys
     bias = spec["bias"]
 
@@ -250,10 +296,21 @@ def layer_param_shapes(spec, embed, fused_qkv=False):
             shapes["wg"] = (embed, inner)
         if spec["qk_norm"]:
             shapes.update({"q_norm_g": (head,), "k_norm_g": (head,)})
-    else:
+    elif spec["operator"] == "shortconv":
         shapes.update({"w_in": (embed, 3 * embed),
                        "w_conv": (embed, spec["conv_kernel"]),
                        "w_out": (embed, embed)})
+    else:
+        heads = spec["linear_value_heads"]
+        keys = spec["linear_key_heads"] * spec["linear_key_dim"]
+        values = heads * spec["linear_value_dim"]
+        shapes.update({
+            "w_qkvz": (embed, 2 * keys + 2 * values),
+            "w_ba": (embed, 2 * heads),
+            "w_conv": (2 * keys + values, spec["conv_kernel"]),
+            "a_log": (heads,), "dt_bias": (heads,),
+            "gdn_norm_g": (spec["linear_value_dim"],),
+            "w_out": (values, embed)})
     if spec["post_norm"]:
         shapes.update(norm("ln1_post"))
     shapes.update(norm("ln2"))
@@ -269,6 +326,8 @@ def layer_param_shapes(spec, embed, fused_qkv=False):
             shapes.update({"ws1": (embed, shared),
                            "ws3": (embed, shared),
                            "ws2": (shared, embed)})
+            if spec["shared_gate"]:
+                shapes["wsg"] = (embed, 1)
     elif spec["ffn"] == "gated-mlp":
         shapes.update({"w1": (embed, hidden), "w3": (embed, hidden),
                        "w2": (hidden, embed)})
@@ -305,7 +364,10 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
     projection), ``shortconv`` (gates and taps, not the projections),
     ``ln1_post`` / ``ln2_post`` (the sandwich's second norms),
     ``ln2``, ``mlp``, the expert layer's ``moe_*`` and the shared
-    expert's ``moe_shared``."""
+    expert's ``moe_shared`` (its gate too); of a ``gated_delta``
+    operator ``shortconv`` (taps and SiLU), ``gdn_gate`` (beta, g, the
+    l2 norms of q and k), ``gated_delta`` (the rule alone) and
+    ``gdn_norm`` (the gated norm a head)."""
     import jax
     import jax.numpy as jnp
     from ..ops import attention as A
@@ -340,6 +402,9 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
         with jax.named_scope("shortconv"):
             mixed = gated_short_conv(b_, c_, x_, params["w_conv"])
         x = x + post("ln1_post", dot(mixed, params["w_out"]))
+    elif spec["operator"] == "gated_delta":
+        x = x + post("ln1_post", _gated_delta_operator(
+            spec, params, h, cdt, dot))
     else:
         n_heads, kv_heads = spec["n_heads"], spec["kv_heads"]
         if "wqkv" in params:
@@ -365,8 +430,10 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
                     k = rms_norm(k, params["k_norm_g"],
                                  spec["norm_eps"])
                 if spec["rope_theta"]:
-                    q = rotary(q, spec["rope_theta"])
-                    k = rotary(k, spec["rope_theta"])
+                    q = rotary(q, spec["rope_theta"],
+                               spec["rope_fraction"])
+                    k = rotary(k, spec["rope_theta"],
+                               spec["rope_fraction"])
         if attend is None:
             attend = functools.partial(A.attention, causal=causal,
                                        window=spec["window"])
@@ -389,11 +456,16 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
             params["w2"], top_k=spec["top_k"], held=spec["held"],
             norm_topk=spec["norm_topk"],
             scaling=spec["routed_scaling"], cdt=cdt,
-            eps=spec["route_eps"], slack=spec["slack"])
+            eps=spec["route_eps"], slack=spec["slack"],
+            score=spec["score"])
         y = y.reshape(B, S, E)
         if spec["shared_ffn_dim"]:
             with jax.named_scope("moe_shared"):
-                y = y + gated(h, "ws1", "ws3", "ws2")
+                shared = gated(h, "ws1", "ws3", "ws2")
+                if spec["shared_gate"]:
+                    shared = shared * jax.nn.sigmoid(
+                        dot(h, params["wsg"]))
+                y = y + shared
         x = x + post("ln2_post", y)
     else:
         with jax.named_scope("mlp"):
@@ -410,6 +482,49 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
         if spec["post_norm"]:
             x = x + norm("ln2_post", y)
     return x.astype(jnp.float32), stats
+
+
+def _gated_delta_operator(spec, params, h, cdt, dot):
+    """The Gated DeltaNet operator between its norms: ``h`` (B, S, E)
+    normed → what is added to the stream, before any post norm.
+    ``[q | k | v | z] = h W_qkvz``, ``[b | a] = h W_ba``; q, k, v
+    through the causal taps and a SiLU; ``beta = sigmoid(b)``, ``g =
+    -exp(a_log) softplus(a + dt_bias)``; q and k l2-normalised a head
+    (q over ``sqrt(Dk)`` more); the rule; a gated RMS norm a head,
+    ``gain * o / rms(o) * silu(z)``; ``W_out``.  Gates, norms, taps
+    and the rule's decays are float32; q, k, v and z travel in
+    ``cdt``."""
+    import jax
+    import jax.numpy as jnp
+    from ..ops.linear_attention import gated_delta_rule
+    from ..ops.rotary import rms_norm
+    from ..ops.shortconv import causal_depthwise_conv
+    B, S, _ = h.shape
+    kh, vh = spec["linear_key_heads"], spec["linear_value_heads"]
+    kd, vd = spec["linear_key_dim"], spec["linear_value_dim"]
+    keys, values = kh * kd, vh * vd
+    qkv, z = jnp.split(dot(h, params["w_qkvz"]).astype(cdt),
+                       [2 * keys + values], axis=-1)
+    b, a = jnp.split(dot(h, params["w_ba"]), 2, axis=-1)
+    with jax.named_scope("shortconv"):
+        qkv = jax.nn.silu(causal_depthwise_conv(qkv, params["w_conv"]))
+    with jax.named_scope("gdn_gate"):
+        q, k, v = jnp.split(qkv, [keys, 2 * keys], axis=-1)
+        q, k = (t.reshape(B, S, kh, kd) for t in (q, k))
+        q = q * jax.lax.rsqrt((q * q).sum(-1, keepdims=True) + 1e-6) \
+            * kd ** -0.5
+        k = k * jax.lax.rsqrt((k * k).sum(-1, keepdims=True) + 1e-6)
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(params["a_log"]) * \
+            jax.nn.softplus(a + params["dt_bias"])
+    with jax.named_scope("gated_delta"):
+        o = gated_delta_rule(q.astype(cdt), k.astype(cdt),
+                             v.reshape(B, S, vh, vd), g, beta,
+                             chunk=spec["linear_chunk"])
+    with jax.named_scope("gdn_norm"):
+        y = rms_norm(o, params["gdn_norm_g"], spec["norm_eps"]) * \
+            jax.nn.silu(z.reshape(B, S, vh, vd).astype(jnp.float32))
+    return dot(y.reshape(B, S, values), params["w_out"])
 
 
 def transformer_block_apply(params, x, n_heads, causal, cdt,
@@ -489,9 +604,10 @@ class Embedding(ForwardBase):
 class LMLayer(ForwardBase):
     """THE decoder-layer unit, built from a spec (:func:`layer_spec`):
     the norm kind, the operator (attention with grouped keys/values,
-    per-head norm and rotary positions, or the gated short
-    convolution) and the FFN (ReLU MLP, gated MLP, or a held share
-    of a dropless expert layer) are data, not classes
+    per-head norm and rotary positions, the gated short convolution,
+    or the gated delta rule's linear attention) and the FFN (ReLU
+    MLP, gated MLP, or a held share of a dropless expert layer) are
+    data, not classes
     (docs/attention.md, "Layers from a spec").
 
     kwargs: ``spec`` (a :func:`layer_spec` dict, or its keyword
@@ -511,7 +627,9 @@ class LMLayer(ForwardBase):
     ``batch_axis`` and, where ``apply_dp_tp_sharding`` set it, the
     heads on ``head_axis``; without a mesh ``ops.attention.
     attention``.  The ring does not broadcast grouped key/value
-    heads: such a spec with ``seq_axis`` is refused here.
+    heads: such a spec with ``seq_axis`` is refused here, as is a
+    ``gated_delta`` layer, whose state no exchange carries from one
+    chip's rows to the next's.
 
     An ``experts`` layer carries two buffers beside its trainables:
     ``expert_bias`` (n_experts,), the router's selection bias, which
@@ -545,6 +663,11 @@ class LMLayer(ForwardBase):
                 "heads: sequence-parallel attention does not "
                 "broadcast groups" % (self.seq_axis, spec["kv_heads"],
                                       spec["n_heads"]))
+        if self.seq_axis and spec["operator"] == "gated_delta":
+            raise ValueError(
+                "seq_axis=%r with a gated_delta layer: its state is "
+                "carried along the sequence on one chip" %
+                (self.seq_axis,))
         if self.seq_axis and spec["window"]:
             raise ValueError(
                 "seq_axis=%r with a window of %d keys: "
@@ -626,6 +749,16 @@ class LMLayer(ForwardBase):
                 self.rand().fill_normal(arr, stddev=stddev)
             elif name.endswith("_g"):
                 arr[...] = 1.0
+            elif name == "a_log":
+                # Gated DeltaNet's own: g = -A softplus(a + dt_bias), A
+                # uniform under 16 and the step log-uniform in
+                # [1e-3, 1e-1], so that heads forget at every pace
+                arr[...] = numpy.log(self.rand().uniform(
+                    1e-3, 16.0, shape))
+            elif name == "dt_bias":
+                dt = numpy.exp(self.rand().uniform(
+                    numpy.log(1e-3), numpy.log(1e-1), shape))
+                arr[...] = dt + numpy.log(-numpy.expm1(-dt))
             vec.mem = arr
             vec.initialize(self.device)
         if self.has_experts:

@@ -27,7 +27,7 @@ from ..attention import (Embedding, EvaluatorLM, GDEmbedding,
                          GDLMHead, GDLMLayer, GDPipelinedStack,
                          GDRMSNorm, GDTransformerBlock, LMHead,
                          LMLayer, PipelinedTransformerStack, RMSNorm,
-                         TransformerBlock)
+                         TransformerBlock, layer_spec)
 from ..decision import DecisionGD
 
 
@@ -61,7 +61,8 @@ class TinyLMWorkflow(AcceleratedWorkflow):
     ``layers``: a list of ``znicz.attention.layer_spec`` dicts — one
     ``LMLayer`` a spec, with no learned positions in the embedding
     and an RMS norm (``final_norm``) before the head: the shape of
-    the hybrid LMs (``samples/lfm2.py``, ``samples/trinity.py``).
+    the hybrid LMs (``samples/lfm2.py``, ``samples/trinity.py``,
+    ``samples/qwen3_next.py``).
     Either way the units are ``block<i>`` and take the same placement
     arguments.  ``tied_head``: the head is the embedding transposed
     (default), or a matrix of its own; ``embed_scale``: what the
@@ -131,7 +132,9 @@ class TinyLMWorkflow(AcceleratedWorkflow):
             self.forwards.append(block)
             prev = block
         if layers is not None:
-            norm = RMSNorm(self, name="final_norm")
+            # the layers' own norm once more, at the last one's eps
+            norm = RMSNorm(self, name="final_norm",
+                           eps=layer_spec(**layers[-1])["norm_eps"])
             norm.link_from(prev)
             norm.input = prev.output
             self.forwards.append(norm)
