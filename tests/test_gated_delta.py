@@ -3,10 +3,14 @@ the program runs against the recurrence that defines it — written here
 a row at a time, and the benchmark's own (``benchmark/models/
 qwen3_next.py`` ``delta_recurrence``) — forward and the gradients of
 all five operands, at one, two and five chunks, chunks of 64 and
-smaller, strong and weak decay, like and unlike keys; the two faults a
-chunked program can have told apart; and, where ``transformers`` and
-``torch`` import, the family's own modelling code at a tiny config on
-the same weights."""
+smaller, strong and weak decay, like and unlike keys; the Pallas
+kernels (``ops/pallas_gated_delta.py``, interpreted) against the same
+recurrence, against XLA's form under the layers' checkpoint, and the
+choice between the two paths; the two faults a chunked program can
+have told apart; and, where ``transformers`` and ``torch`` import, the
+family's own modelling code at a tiny config on the same weights."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +18,10 @@ import numpy
 import pytest
 
 from benchmark.models import qwen3_next as REF
+from veles_tpu import resilience
 from veles_tpu.ops import linear_attention as L
 from veles_tpu.ops import moe as M
+from veles_tpu.ops import pallas_gated_delta as PG
 from veles_tpu.znicz import attention as Z
 
 B, HK, HV, DK, DV = 2, 2, 4, 16, 8
@@ -40,7 +46,7 @@ def recurrence(q, k, v, g, beta):
     return jnp.moveaxis(jax.lax.scan(row, state, xs)[1], 0, 1)
 
 
-def operands(S, decay, like=0.0, seed=1):
+def operands(S, decay, like=0.0, seed=1, HK=HK):
     """q, k normalised as the layer hands them over; ``decay`` scales
     g (8: a row forgets nearly all; 0.01: hundreds of rows are
     remembered); ``like`` gives every key a common part."""
@@ -75,6 +81,110 @@ def test_chunked_rule_is_the_recurrence(S, chunk, decay):
             scale = float(jnp.abs(b).max())
             numpy.testing.assert_allclose(
                 a, b, rtol=2e-4, atol=2e-4 * scale, err_msg=name)
+
+
+def _gradients(rule, args, weight):
+    return jax.grad(lambda *a: (rule(*a) * weight).sum(),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+
+
+@pytest.mark.parametrize("decay", [8.0, 0.01], ids=["strong", "weak"])
+@pytest.mark.parametrize("ratio", [1, 2])
+@pytest.mark.parametrize("S", [64, 128, 320])
+def test_the_kernels_are_the_recurrence(S, ratio, decay):
+    """The three kernels, interpreted (one, two and five chunks: a
+    block of one, of two, of five; one and two value heads a key
+    head): ``o`` and the gradients of all five operands against the
+    recurrence a row at a time, at the chunked form's tolerances."""
+    with jax.default_matmul_precision("highest"):
+        args = operands(S, decay, like=float(S == 128), HK=HV // ratio)
+        weight = jax.random.normal(jax.random.PRNGKey(9), (B, S, HV, DV))
+        rule = functools.partial(PG.gated_delta, interpret=True)
+        got = rule(*args)
+        assert got.shape == (B, S, HV, DV) and got.dtype == jnp.float32
+        numpy.testing.assert_allclose(got, recurrence(*args), rtol=2e-5,
+                                      atol=2e-5)
+        for name, a, b in zip("q k v g beta".split(),
+                              _gradients(rule, args, weight),
+                              _gradients(recurrence, args, weight)):
+            assert a.dtype == b.dtype and a.shape == b.shape, name
+            numpy.testing.assert_allclose(
+                a, b, rtol=2e-4, atol=2e-4 * float(jnp.abs(b).max()),
+                err_msg=name)
+
+
+def _steer_to_the_kernels(monkeypatch):
+    """What a TPU would select, interpreted: the choice is the
+    program's (``L._selects_pallas``), the steering the test's."""
+    monkeypatch.setattr(L, "tpu_available", lambda: True)
+    monkeypatch.setattr(PG, "LANE", 8)
+    monkeypatch.setattr(PG, "gated_delta", functools.partial(
+        PG.gated_delta, interpret=True))
+
+
+def test_both_paths_agree_under_the_layers_checkpoint(monkeypatch):
+    """A ``gated_delta`` layer under ``Z.checkpointed``, bfloat16
+    operands: value and every parameter's gradient through the kernels
+    against XLA's form — the kept names change what is saved, not
+    what is computed — and each trace counted by the path it took."""
+    spec = Z.layer_spec(
+        norm="rms", bias=False, norm_eps=1e-6, operator="gated_delta",
+        linear_key_heads=2, linear_value_heads=4, linear_key_dim=16,
+        linear_value_dim=8, conv_kernel=4, n_heads=2, ffn="gated-mlp",
+        ffn_dim=16)
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 32))
+    params = {name: 0.3 * jax.random.normal(next(keys), shape)
+              for name, shape in Z.layer_param_shapes(spec, 32).items()}
+    x = jax.random.normal(next(keys), (2, 128, 32))
+
+    def loss(params, x):
+        y = Z.checkpointed(lambda p, h: Z.layer_apply(
+            spec, p, h, jnp.bfloat16)[0])(params, x)
+        return (y * y).mean()
+
+    def run():
+        names = ("linear_attention.kernel.pallas",
+                 "linear_attention.kernel.xla")
+        before = [resilience.stats.get(name) for name in names]
+        out = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+        return out, {name: resilience.stats.get(name) - was
+                     for name, was in zip(names, before)}
+
+    (want, (wp, wx)), counted = run()
+    assert counted == {"linear_attention.kernel.pallas": 0,
+                       "linear_attention.kernel.xla": 1}
+    _steer_to_the_kernels(monkeypatch)
+    (got, (gp, gx)), counted = run()
+    assert counted == {"linear_attention.kernel.pallas": 1,
+                       "linear_attention.kernel.xla": 0}
+    numpy.testing.assert_allclose(got, want, rtol=2e-3)
+
+    def close(a, b, name):
+        assert float(jnp.linalg.norm(a - b)) <= \
+            0.02 * float(jnp.linalg.norm(b)) + 1e-6, name
+
+    close(gx, wx, "x")
+    for name in wp:
+        close(gp[name], wp[name], name)
+
+
+@pytest.mark.parametrize("case,selected", [
+    ("the cell's", True), ("a CPU", False), ("Dk 64", False),
+    ("Dv 64", False), ("a chunk of 32", False),
+    ("rows that 64 does not divide", False)])
+def test_the_path_is_chosen_by_platform_and_shape(monkeypatch, case,
+                                                  selected):
+    q, v, chunk = (1, 8192, 16, 128), (1, 8192, 32, 128), 64
+    monkeypatch.setattr(L, "tpu_available", lambda: case != "a CPU")
+    if case == "Dk 64":
+        q = q[:3] + (64,)
+    if case == "Dv 64":
+        v = v[:3] + (64,)
+    if case == "a chunk of 32":
+        chunk = 32
+    if case == "rows that 64 does not divide":
+        q, v = (1, 8160) + q[2:], (1, 8160) + v[2:]
+    assert L._selects_pallas(q, v, chunk) is selected
 
 
 def test_the_benchmarks_recurrence_is_the_same_function():

@@ -323,6 +323,73 @@ def test_scopes_count_the_megablox_calls_of_the_program(gmm):
     assert registry.peek("moe.gmm_calls") is None
 
 
+@pytest.mark.parametrize("forwards", [3, 6], ids=["kept", "twice"])
+def test_scopes_count_the_gated_delta_calls_of_the_program(forwards):
+    """``scopes()`` sets ``linear_attention.fwd_calls`` / ``.bwd_calls``
+    the same way, by the names the rule's kernels carry
+    (``ops/pallas_gated_delta.py``): as many forward sweeps as reverse
+    ones where every checkpointed layer kept what the sweep produced,
+    twice as many where each recompute runs it again; the inverse's
+    kernel is neither."""
+    from veles_tpu.observability.metrics import registry
+    table = _scopes_of_kernel_calls(
+        ["gated_delta_fwd"] +
+        ["gated_delta_fwd.%d" % i for i in range(1, forwards)] +
+        ["gated_delta_bwd", "gated_delta_bwd.4", "gated_delta_bwd.5",
+         "gated_delta_inv.1", "gated_delta_fwdish.2", "flash_fwd.3"])
+    label = {"program": "block_step"}
+    assert registry.peek("linear_attention.fwd_calls",
+                         label).value == forwards
+    assert registry.peek("linear_attention.bwd_calls", label).value == 3
+    assert programs.kernel_calls(table, "gated_delta_inv") == 1
+    assert registry.peek("linear_attention.fwd_calls") is None
+    assert registry.peek("linear_attention.scan_steps", label).value == 0
+
+
+@pytest.mark.parametrize("path,scan", [("xla", [2, 64]),
+                                       ("pallas", [0, 0])])
+def test_scopes_read_the_rules_scan_where_the_program_holds_one(
+        monkeypatch, path, scan):
+    """One checkpointed ``gated_delta`` layer, forward + backward, 128
+    rows in chunks of 64: XLA's form holds a ``while`` of 128 / 64
+    steps under the scope ``gated_delta/chunk64``, and the gauges
+    ``linear_attention.scan_steps`` / ``.chunk`` say so; the kernels
+    (interpreted here: the grid is a loop of its own, under no such
+    scope) carry the state themselves and both read 0."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from veles_tpu.observability.metrics import registry
+    from veles_tpu.ops import linear_attention as L
+    from veles_tpu.znicz import attention as Z
+    if path == "pallas":
+        monkeypatch.setattr(L, "_selects_pallas", lambda *shape: True)
+        monkeypatch.setattr(L.PG, "gated_delta", functools.partial(
+            L.PG.gated_delta, interpret=True))
+    spec = Z.layer_spec(
+        norm="rms", bias=False, operator="gated_delta", n_heads=2,
+        linear_key_heads=1, linear_value_heads=2, linear_key_dim=8,
+        linear_value_dim=8, conv_kernel=4, ffn="gated-mlp", ffn_dim=16)
+    params = {name: jnp.full(shape, 0.1)
+              for name, shape in Z.layer_param_shapes(spec, 16).items()}
+    layer = Z.checkpointed(lambda p, h: Z.layer_apply(
+        spec, p, h, jnp.float32)[0])
+
+    def loss(params, x):
+        with jax.named_scope("block0"):
+            return (layer(params, x) ** 2).sum()
+
+    programs.register("block_step", lambda: jax.jit(jax.grad(loss)).lower(
+        params, jnp.ones((1, 128, 16))), ("block0",))
+    placed = set(programs.scopes("block_step").values())
+    for phase in ("forward", "backward"):
+        assert (phase, "block0", "gated_delta") in placed
+    label = {"program": "block_step"}
+    assert [registry.peek("linear_attention." + what, label).value
+            for what in ("scan_steps", "chunk")] == scan
+    assert registry.peek("linear_attention.fwd_calls", label).value == 0
+
+
 def test_a_newer_compiles_program_takes_the_name_over():
     """Within one compile the program with more ticks a dispatch
     keeps the name (a remainder block does not take it); a program of
@@ -695,7 +762,7 @@ def test_every_pallas_call_is_named():
             assert re.search(r'\bname="[a-z_]+"', text[match.end():end]), \
                 "%s: pallas_call without name= at offset %d" % (
                     name, match.start())
-    assert calls == 5
+    assert calls == 8
 
 
 def test_every_exported_program_is_named():

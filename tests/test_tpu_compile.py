@@ -307,41 +307,69 @@ QWEN3_NEXT_FULL = dict(
     ffn="gated-mlp")
 
 
-@pytest.mark.parametrize("kind,spec,flash,scan", [
-    ("linear", QWEN3_NEXT_LINEAR, 0, (128, 64)),
-    ("full", QWEN3_NEXT_FULL, 20, None)])
+def _needed_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes +
+            m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _gated_delta_calls(text):
+    return _kernel_calls(text, "gated_delta_inv", "gated_delta_fwd",
+                         "gated_delta_bwd")
+
+
+@pytest.mark.parametrize("kind,spec,flash,rule", [
+    ("linear", QWEN3_NEXT_LINEAR, 0, (2, 2, 2)),
+    ("full", QWEN3_NEXT_FULL, 20, (0, 0, 0))])
 def test_qwen3_next_layers_compile(one_chip, monkeypatch, kind, spec,
-                                   flash, scan):
+                                   flash, rule):
     """The cell's two kinds of spec-built layer, two of each under the
     layers' checkpoint, forward + backward at 1 x 8,192 tokens of
-    2,048.  The linear layer holds no Pallas kernel: the gated delta
-    rule is XLA's chunked form, and what ``programs.linear_scan``
-    reads of the compiled text is its scan of 8,192 / 64 = 128 steps
-    in chunks of 64 (``linear_attention.scan_steps`` / ``.chunk``).
-    The full layer is the flash kernels' first call at a head of 256:
-    10 visible chunk pairs a layer, each forward kept."""
+    2,048.  The linear layer's gated delta rule is the Pallas kernels
+    of ``ops/pallas_gated_delta.py``: one inverse, one forward sweep
+    and one reverse sweep a layer — the checkpoint kept what the two
+    forward kernels produced, so the recompute holds neither — and no
+    scan for ``programs.linear_scan`` to find; the program needs no
+    more bytes than XLA's form of the rule (a scan of 8,192 / 64 = 128
+    steps in chunks of 64) at the same shapes.  The full layer is the
+    flash kernels' first call at a head of 256: 10 visible chunk
+    pairs a layer, each forward kept."""
     from veles_tpu.observability import programs
     from veles_tpu.ops import attention as A
+    from veles_tpu.ops import linear_attention as L
     from veles_tpu.znicz import attention as Z
     monkeypatch.setattr(A, "tpu_available", lambda: True)
     B, S, H, _ = QWEN3_NEXT
     spec = Z.layer_spec(n_heads=H, ffn_dim=256, **spec)
-    layer = Z.checkpointed(lambda p, h: Z.layer_apply(
-        spec, p, h, jnp.bfloat16)[0])
-
-    def loss(params, x):
-        for p in params:
-            x = layer(p, x)
-        return (x * x).sum()
-
     params = [{name: _struct(shape, jnp.float32, one_chip)
                for name, shape in
                Z.layer_param_shapes(spec, 2048).items()}] * 2
-    text = jax.jit(jax.value_and_grad(loss)).lower(
-        params, _struct((B, S, 2048), jnp.float32, one_chip)
-    ).compile().as_text()
+
+    def compiled(on_tpu):
+        # traced anew each time: the path is chosen at trace time
+        monkeypatch.setattr(L, "tpu_available", lambda: on_tpu)
+        layer = Z.checkpointed(lambda p, h: Z.layer_apply(
+            spec, p, h, jnp.bfloat16)[0])
+
+        def loss(params, x):
+            for p in params:
+                x = layer(p, x)
+            return (x * x).sum()
+
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            params, _struct((B, S, 2048), jnp.float32, one_chip)
+        ).compile()
+
+    program = compiled(True)
+    text = program.as_text()
     assert _flash_calls(text) == (flash, flash, flash)
-    assert programs.linear_scan(text) == scan
+    assert _gated_delta_calls(text) == rule
+    assert programs.linear_scan(text) is None
+    if kind == "linear":
+        xla = compiled(False)
+        assert _gated_delta_calls(xla.as_text()) == (0, 0, 0)
+        assert programs.linear_scan(xla.as_text()) == (128, 64)
+        assert _needed_bytes(program) <= _needed_bytes(xla)
 
 
 #: The MoE cells' expert shares: tokens a tick, width, experts'

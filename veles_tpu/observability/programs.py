@@ -171,8 +171,9 @@ _CHUNK = re.compile(r"gated_delta\)?/chunk(\d+)[/\"]")
 
 
 def linear_scan(text):
-    """``(steps, chunk)`` of the gated delta rule's scan in a compiled
-    program's HLO text, or None where the program holds no such loop.
+    """``(steps, chunk)`` of the gated delta rule's scan (XLA's form)
+    in a compiled program's HLO text, or None where the program holds
+    no such loop (no linear layer, or the Pallas kernels).
     ``chunk``: the rows of a chunk, which ``ops.linear_attention``
     writes into its scope (``gated_delta/chunk64``).  ``steps``: the
     trip count of a ``while`` under that scope (the longest, were they
@@ -233,9 +234,10 @@ def scopes(program):
     trace gives them, without the ``%``.  The first call compiles
     (:func:`_compiled_text`) and parses, and sets the gauges
     ``attention.flash.fwd_calls`` / ``.dq_calls`` and
-    ``moe.gmm_calls`` / ``.tgmm_calls`` and ``linear_attention.
-    scan_steps`` / ``.chunk`` (all labelled with the program's name)
-    from that parse; later calls return the same table."""
+    ``moe.gmm_calls`` / ``.tgmm_calls``, ``linear_attention.
+    fwd_calls`` / ``.bwd_calls`` and ``linear_attention.scan_steps`` /
+    ``.chunk`` (all labelled with the program's name) from that
+    parse; later calls return the same table."""
     with _lock:
         entry = _programs.get(program)
     if entry is None:
@@ -260,9 +262,18 @@ def scopes(program):
             kernel_calls(entry.table, "gmm"))
         registry.gauge("moe.tgmm_calls", label).set(
             kernel_calls(entry.table, "tgmm"))
-        # The rule's chunked scan: sequence / chunk dependent steps a
-        # layer (``ops/linear_attention.py``); nought where the
-        # program carries no such state.
+        # The gated delta rule's kernels
+        # (``ops/pallas_gated_delta.py``): equal — every checkpointed
+        # layer kept what its forward sweep produced; two to one: each
+        # recompute runs the sweep again; nought: XLA's form.
+        registry.gauge("linear_attention.fwd_calls", label).set(
+            kernel_calls(entry.table, "gated_delta_fwd"))
+        registry.gauge("linear_attention.bwd_calls", label).set(
+            kernel_calls(entry.table, "gated_delta_bwd"))
+        # XLA's form of the rule, a chunked scan: sequence / chunk
+        # dependent steps a layer (``ops/linear_attention.py``);
+        # nought where the program holds no such loop — it carries no
+        # such state, or the kernels carry it themselves.
         steps, chunk = linear_scan(text) or (0, 0)
         registry.gauge("linear_attention.scan_steps", label).set(steps)
         registry.gauge("linear_attention.chunk", label).set(chunk)

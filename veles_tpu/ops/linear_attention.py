@@ -37,15 +37,40 @@ cumulated sums, the inverse (:func:`unit_lower_inverse`) and the state
 are float32; the chunk products take their operands in the type ``q``
 arrives in (bfloat16 on the training path; ``S`` is rounded to it where
 a product reads it, never where it is carried) and accumulate in
-float32.  The backward pass is autodiff's through the scan — its
-residuals a chunk are the rounded state and ``V'`` — but for the
-inverse, whose rule is two products (``dA = T^T dT T^T``).
+float32.
+
+TWO paths run that form, chosen by what the process can observe
+(:func:`_selects_pallas`: the backend is a TPU, both head sizes are
+multiples of 128, the chunk is 64 rows and divides the sequence):
+
+- the Pallas kernels of ``ops/pallas_gated_delta.py`` — the inverse of
+  every chunk in one call, then a sweep along the sequence that keeps a
+  head's state in VMEM, and under their ``custom_vjp`` a reverse sweep
+  that carries ``dS`` there and gives all five cotangents.  q and k are
+  read by key head (no repeat), nothing ``(…, C, C)`` in float32 and no
+  stack of per-chunk operands reaches HBM, and what the forward kernels
+  produced — ``o``, ``T``, the states at the chunks' starts — carries
+  ``checkpoint_name``s that the layers' checkpoint keeps
+  (``znicz.attention.checkpointed``): its recompute runs no kernel;
+- :func:`gated_delta_rule_xla`, everywhere else (a CPU, a head of 64,
+  another chunk): XLA's ops and a ``lax.scan`` that carries the state,
+  the kernels' oracle in the tests.  Its backward pass is autodiff's
+  through the scan — its residuals a chunk are the rounded state and
+  ``V'`` — but for the inverse, whose rule is two products (``dA = T^T
+  dT T^T``).
+
+The precisions above are both paths'.  Each TRACE of either counts
+into ``linear_attention.kernel.pallas`` / ``.xla``.
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+
+from .. import resilience
+from ..backends import tpu_available
+from . import pallas_gated_delta as PG
 
 #: Rows of a chunk unless a caller says otherwise.
 CHUNK = 64
@@ -99,6 +124,15 @@ def _inverse_bwd(t, ct):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
+def _selects_pallas(q_shape, v_shape, chunk):
+    """Whether the rule at this geometry runs the Pallas kernels: the
+    backend is a TPU and the geometry is inside their contract.  A
+    SELECTION by what the process can observe, made before a kernel
+    is touched (``ops.attention._selects_pallas``'s rule): once
+    selected, a kernel that fails to lower raises."""
+    return PG.supports(q_shape, v_shape, chunk) and tpu_available()
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     """The gated delta rule over whole sequences, chunked (module
     docstring).  q, k: (B, S, Hk, Dk), already normalised and scaled;
@@ -106,13 +140,24 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     serves value heads ``j r … j r + r - 1``; g, beta: (B, S, Hv).
     Returns o (B, S, Hv, Dv) float32.  ``S`` must be a multiple of
     ``chunk``."""
-    f32 = jnp.float32
-    B, S, Hk, _ = q.shape
-    Hv = v.shape[2]
+    S, Hk, Hv = q.shape[1], q.shape[2], v.shape[2]
     if S % chunk or Hv % Hk:
         raise ValueError(
             "gated_delta_rule: a sequence of %d rows in chunks of %d, "
             "%d value heads over %d key heads" % (S, chunk, Hv, Hk))
+    if _selects_pallas(q.shape, v.shape, chunk):
+        resilience.stats.incr("linear_attention.kernel.pallas")
+        return PG.gated_delta(q, k, v, g, beta)
+    resilience.stats.incr("linear_attention.kernel.xla")
+    return gated_delta_rule_xla(q, k, v, g, beta, chunk)
+
+
+def gated_delta_rule_xla(q, k, v, g, beta, chunk=CHUNK):
+    """:func:`gated_delta_rule` as XLA's ops and a scan (module
+    docstring): the path off a TPU or outside the kernels' geometry."""
+    f32 = jnp.float32
+    B, S, Hk, _ = q.shape
+    Hv = v.shape[2]
     cdt = q.dtype
     N = S // chunk
     dot = functools.partial(jnp.einsum, preferred_element_type=f32)

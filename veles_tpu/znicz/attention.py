@@ -45,7 +45,11 @@ def remat_enabled(unit_flag):
     common path, the
     gathered rows and the two grouped products before the gate (42 +
     2 × 63 MB at 16,384 tokens of 2048, top 4, an eighth of the
-    experts 1536 wide held: 172.8 MB a layer; docs/moe.md).
+    experts 1536 wide held: 172.8 MB a layer; docs/moe.md); and,
+    where its operator is the gated delta rule run by the Pallas
+    kernels, their output, the chunks' inverses and the states at the
+    chunks' starts (134 + 33.5 + 268 MB at 8,192 rows of 32 value
+    heads of 128 x 128 in bfloat16; docs/attention.md).
     Everything else is computed again in the backward pass."""
     if unit_flag is not None:
         return bool(unit_flag)
@@ -69,17 +73,23 @@ def checkpointed(fn):
     calls at
     a quarter of their roofline to recover 126; the third product and
     the gate are rebuilt, and the walk over every chunk names
-    nothing).  :func:`remat_enabled` gives the bytes; q, k and v and
-    every dense product are XLA matmuls and are rebuilt as before.
-    The rule observes only that a value carries a name: where none
-    does — XLA's attention, a short convolution, a dense MLP —
-    nothing is saved and the program is a bare ``jax.checkpoint``'s."""
+    nothing) and inside the gated delta rule's kernels' rule
+    (``ops/pallas_gated_delta.py`` ``GATED_DELTA_KEPT``: the output,
+    the chunks' inverses, the states at the chunks' starts — all the
+    backward kernel reads beside the operands, so the recompute runs
+    neither forward kernel).  :func:`remat_enabled` gives the bytes;
+    q, k and v and every dense product are XLA matmuls and are
+    rebuilt as before.  The rule observes only that a value carries a
+    name: where none does — XLA's attention or gated delta rule, a
+    short convolution, a dense MLP — nothing is saved and the program
+    is a bare ``jax.checkpoint``'s."""
     import jax
     from ..ops.moe import MOE_KEPT
     from ..ops.pallas_attention import FLASH_OUT, FLASH_LSE
+    from ..ops.pallas_gated_delta import GATED_DELTA_KEPT
     return jax.checkpoint(
         fn, policy=jax.checkpoint_policies.save_only_these_names(
-            FLASH_OUT, FLASH_LSE, *MOE_KEPT))
+            FLASH_OUT, FLASH_LSE, *MOE_KEPT, *GATED_DELTA_KEPT))
 
 
 def fused_qkv_enabled(unit_flag):
