@@ -118,46 +118,38 @@ def check(condition, message):
 
 # -- compile accounting ----------------------------------------------------
 
-class CompileMeter(object):
-    """Sums what JAX reports through ``jax.monitoring``: seconds spent
-    in backend compiles, and persistent-cache hits and misses.
-    ``take()`` returns the figures since the last call, so each phase
-    reports its own."""
+class CompileCounters(object):
+    """What the program's own ``compile.*`` counters
+    (``veles_tpu.observability.startup``: JAX's backend compiles and
+    persistent-cache hits and misses, by ``jax.monitoring``) gained
+    since the last ``take()``, so each phase reports its own."""
+
+    FIELDS = {"compile.seconds": "compile_s",
+              "compile.programs": "programs_compiled",
+              "compile.cache_hits": "cache_hits",
+              "compile.cache_misses": "cache_misses"}
 
     def __init__(self):
-        import jax.monitoring as monitoring
-        self._lock = threading.Lock()
-        self._zero()
-        monitoring.register_event_duration_secs_listener(
-            self._on_duration)
-        monitoring.register_event_listener(self._on_event)
+        from veles_tpu.observability import startup
+        startup.install()
+        self._seen = self._read()
 
-    def _zero(self):
-        self.compile_s = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        self.cache_misses = 0
-
-    def _on_duration(self, event, seconds, **_kw):
-        if event.endswith("backend_compile_duration"):
-            with self._lock:
-                self.compile_s += seconds
-                self.compiles += 1
-
-    def _on_event(self, event, **_kw):
-        with self._lock:
-            if event.endswith("/cache_hits"):
-                self.cache_hits += 1
-            elif event.endswith("/cache_misses"):
-                self.cache_misses += 1
+    def _read(self):
+        from veles_tpu.observability import metrics
+        totals = dict.fromkeys(self.FIELDS.values(), 0)
+        for series in metrics.registry.metrics():
+            field = self.FIELDS.get(series.name)
+            # of the seconds, the backend compiles (or cached loads)
+            if field and series.labels.get("stage", "backend") == \
+                    "backend":
+                totals[field] += series.value
+        return totals
 
     def take(self):
-        with self._lock:
-            out = {"compile_s": round(self.compile_s, 3),
-                   "programs_compiled": self.compiles,
-                   "cache_hits": self.cache_hits,
-                   "cache_misses": self.cache_misses}
-            self._zero()
+        now, seen = self._read(), self._seen
+        self._seen = now
+        out = {k: v - seen[k] for k, v in now.items()}
+        out["compile_s"] = round(out["compile_s"], 3)
         return out
 
 
@@ -564,7 +556,7 @@ def run(chips, geometry=LM_GEOMETRY, seed=20260926, backend="tpu",
     size (tests/test_chip_smoke.py) — the command line fixes them."""
     from veles_tpu.backends import enable_compilation_cache
     cache_dir = enable_compilation_cache()
-    meter = CompileMeter()
+    meter = CompileCounters()
     emit(phase="start", chips=chips, seed=seed,
          compile_cache_dir=cache_dir,
          compile_cache_entries=len(os.listdir(cache_dir))

@@ -321,10 +321,13 @@ def test_step_compiler_publishes_device_time():
     wf = _mnist_pair(3, max_epochs=1)
     replies = []
     wf.note_slave_protocol("w", {})
-    job = wf.generate_data_for_slave("w")
-    wf.do_job(job, None, replies.append)
-    assert replies
-    assert metrics.registry.peek("device.dispatches").value >= 1
+    # two jobs: the dispatch that compiles the step stays out of the
+    # step_ms gauge (PR 37), the next one sets it
+    for _ in range(2):
+        job = wf.generate_data_for_slave("w")
+        wf.do_job(job, None, replies.append)
+    assert len(replies) == 2
+    assert metrics.registry.peek("device.dispatches").value >= 2
     assert metrics.registry.peek("device.step_ms").value > 0
     assert metrics.registry.peek("device.mfu") is None
     assert attribution.perf_summary()["dispatches"] >= 1

@@ -312,6 +312,15 @@ class StepCompiler(object):
         return tuple(parts)
 
     def compile(self):
+        """Builds the step's closures and their ``jax.jit`` wrappers
+        (nothing is traced before the first dispatch) inside the
+        ``step.build`` set-up span (docs/observability.md,
+        "Start-up")."""
+        from .observability import startup
+        with startup.span("step.build"):
+            self._build()
+
+    def _build(self):
         import jax
 
         # Compile sentinel (analysis.runtime.strict_step): a re-trace
@@ -720,18 +729,19 @@ class StepCompiler(object):
 
     # -- execution ---------------------------------------------------------
 
-    def _program_flops(self, key, name, fn, ticks, *args):
+    def _program_flops(self, step, key, fn, ticks, *args):
         """What is done ONCE per compiled program, cached under
         ``key`` — ("block", K) for block mode: a remainder block
         (epoch length % ticks_per_dispatch) is a different program
-        with different FLOPs.  Registers the program under ``name``
-        with ``observability.programs`` as a thunk that lowers it from
-        its arguments' shapes, once (so ``scopes(name)`` can answer
-        later, when the arrays are gone), and returns the
-        per-dispatch FLOP estimate for the live MFU gauge.  The
-        estimate calls that thunk (XLA HLO cost analysis, no compile)
-        and only runs when a peak FLOP/s is known for this device
-        (the MFU denominator) — never on CPU test hardware, where the
+        with different FLOPs.  Registers the program under the open
+        dispatch's ``step.program`` with ``observability.programs`` as
+        a thunk that lowers it from its arguments' shapes, once (so
+        ``scopes(name)`` can answer later, when the arrays are gone),
+        and returns the per-dispatch FLOP estimate for the live MFU
+        gauge.  The estimate calls that thunk (XLA HLO cost analysis,
+        no compile) inside the dispatch's ``step.lower`` span and
+        only runs when a peak FLOP/s is known for this device (the
+        MFU denominator) — never on CPU test hardware, where the
         program is lowered only if somebody asks for its scopes."""
         import functools
         import jax
@@ -747,12 +757,13 @@ class StepCompiler(object):
                 x.shape, x.dtype,
                 sharding=x.sharding if x.committed else None), args)
         lower = functools.lru_cache(None)(lambda: fn.lower(*shapes))
-        programs.register(name, lower, self.scope_units, ticks,
-                          compiled_by=self._compiled)
+        programs.register(step.program, lower, self.scope_units,
+                          ticks, compiled_by=self._compiled)
         flops = None
         if attribution.enabled() and \
                 attribution.peak_flops() is not None:
-            flops = attribution.lowered_flops(lower())
+            with step.lower():
+                flops = attribution.lowered_flops(lower())
         self._step_flops_[key] = flops or 0.0
         return flops
 
@@ -798,7 +809,7 @@ class StepCompiler(object):
                 hyper_args = (hvals,)
                 step.program = "train_step_hyper"
             step.flops = self._program_flops(
-                mode, step.program, step_fn, 1,
+                step, mode, step_fn, 1,
                 params, states, batch, consts, key, *hyper_args)
             with step.enqueue():
                 if training:
@@ -874,7 +885,7 @@ class StepCompiler(object):
                 hyper_args = (hvals,)
                 step.program = "block_step_hyper"
             step.flops = self._program_flops(
-                ("block", ticks), step.program, block_fn, ticks,
+                step, ("block", ticks), block_fn, ticks,
                 params, states, blocks, consts, key, flag,
                 *hyper_args)
             with step.enqueue():

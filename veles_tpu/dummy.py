@@ -17,7 +17,7 @@ class DummyLauncher(Launcher):
     def __init__(self, **kwargs):
         super(DummyLauncher, self).__init__(**kwargs)
 
-    def initialize(self, **kwargs):
+    def _initialize(self, **kwargs):
         from . import backends
         self.device = kwargs.pop("device", None) or \
             backends.Device.create("auto")

@@ -186,7 +186,14 @@ class Launcher(Logger):
     def initialize(self, **kwargs):
         """Brings up the process group (if distributed), selects the
         device, and initializes the workflow
-        (reference: launcher.py:431)."""
+        (reference: launcher.py:431) — all of it inside the
+        ``launcher.initialize`` set-up span
+        (docs/observability.md, "Start-up")."""
+        from .observability import startup
+        with startup.span("launcher.initialize"):
+            return self._initialize(**kwargs)
+
+    def _initialize(self, **kwargs):
         from . import backends
         if self._mode == "distributed" and self.num_processes > 1:
             import jax

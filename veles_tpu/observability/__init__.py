@@ -1,7 +1,7 @@
 """Unified observability: span tracing, typed metrics, device/MFU
 attribution (docs/observability.md).
 
-Four legs over one substrate:
+Five legs over one substrate:
 
 * :mod:`.tracing` — ``span("net.send")`` context managers feeding a
   bounded ring collector, cross-node clock alignment, and Chrome
@@ -16,6 +16,11 @@ Four legs over one substrate:
   + ``cost_analysis()`` FLOPs → a live MFU gauge (heartbeat ``perf``
   section, web_status row), and the ``--xprof DIR`` capture window,
   which :mod:`.profile` reduces and prints;
+* :mod:`.startup` — one record per program the process compiles
+  (JAX's own trace, lowering and compile events: seconds, cache hit
+  or miss, the span it fell inside) and the set-up spans
+  (``launcher.initialize``, ``step.build``), on the dispatch
+  records' clock;
 * :mod:`.programs` — the scope table of each compiled step program:
   instruction name → (phase, unit, inner scope), read back from the
   ``jax.named_scope``s the step is traced under.
@@ -25,7 +30,7 @@ passive counters; attribution adds one host sync per dispatched
 block (``root.common.observability.attribution=False`` disables).
 """
 
-from . import metrics, tracing, attribution  # noqa: F401
+from . import metrics, tracing, startup, attribution  # noqa: F401
 
 
 def init_parser(parser):

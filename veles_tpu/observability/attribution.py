@@ -8,15 +8,21 @@ span and its children (``loader.serve_block``, ``step.upload``,
 ``step.enqueue``, ``step.wait``) on the profiler's clock, waits for a
 small output leaf (``block_until_ready``: waiting, not transferring —
 all outputs of one XLA computation complete together) and keeps ONE
-record per dispatch: ordinal, program, ticks and the host's split
+record per dispatch: ordinal, program, ticks, the host's split
 (``serve_s``, ``upload_s``, ``enqueue_s``, ``wait_s``, ``gc_s``,
-``gc_full``).  The last 64 records are :func:`recent`; the newest
-one's split rides :func:`perf_summary` (launcher heartbeat ``perf``
-section, web_status row).  Combined with a FLOP count per compiled
-step from XLA's HLO cost analysis (the ``Lowered`` is made once per
-program and kept for ``observability.programs``), that yields the
-live ``device.mfu`` gauge: XLA's count — recomputation included —
-over host-timed dispatches, NOT the benchmark's ``train_mfu_pct``.
+``gc_full``), what compiling took of it (``lower_s``, ``compile_s``,
+``compiled``, ``build_s``: ``observability.startup`` keeps the record
+a program) and a clock (``t0``, ``t1``, ``gap_s``).  The last 64
+records are :func:`recent`; the newest one's split rides
+:func:`perf_summary` (launcher heartbeat ``perf`` section, web_status
+row).  A dispatch that took more than :data:`LATE_FACTOR` × the median
+of its program's recent ones counts into ``device.late_dispatches``
+and is logged WITH its record (:func:`_judge`).  Combined with a FLOP
+count per compiled step from XLA's HLO cost analysis (the ``Lowered``
+is made once per program and kept for ``observability.programs``),
+the record yields the live ``device.mfu`` gauge: XLA's count —
+recomputation included — over host-timed dispatches, NOT the
+benchmark's ``train_mfu_pct``.
 
 Also owns the ``--xprof DIR`` capture window: a ``jax.profiler``
 trace opened at the first fused dispatch and closed after N of them,
@@ -40,10 +46,10 @@ import collections
 import gc
 import itertools
 import logging
+import statistics
 import threading
-import time
 
-from . import metrics, tracing
+from . import metrics, startup, tracing
 from ..config import root, get as config_get
 
 #: device_kind substring → peak dense bf16 TFLOP/s (the MFU
@@ -61,6 +67,20 @@ DEVICE_PEAK_TFLOPS = (
 #: EWMA smoothing for the live gauges (per dispatch).
 EWMA_ALPHA = 0.25
 
+#: A dispatch is late when it took (its ``t1 - t0``) more than this
+#: many times the median of what its program's last
+#: :data:`LATE_RECORDS` dispatches took, once :data:`LATE_KNOWN` of
+#: them are known.  The time BETWEEN two dispatches (``gap_s``: an
+#: epoch's end, a snapshot, an evaluation) is reported beside it and
+#: makes none late.
+LATE_FACTOR = 1.25
+LATE_RECORDS = 15
+LATE_KNOWN = 3
+#: Seconds between two late-dispatch warnings (the counter counts
+#: every late dispatch): on a shared host a step of milliseconds
+#: passes the factor by jitter alone, some 4 to 6 times in a hundred.
+LATE_WARN_EVERY_S = 10.0
+
 _lock = threading.Lock()
 _state = {
     "last_ms": None,       # the newest dispatch, unsmoothed
@@ -69,6 +89,7 @@ _state = {
     "dispatches": 0,
     "ticks": 0,
     "device_s_total": 0.0,
+    "late_warned": None,   # when the last late-dispatch warning went
 }
 #: How many dispatch records :func:`recent` keeps.
 RECENT = 64
@@ -82,8 +103,10 @@ _xprof = {"dir": None, "steps": 0, "done": 0, "started": False}
 #: compile): the configured kind(s), total slot bytes, and the ZeRO
 #: shard fraction each dp rank persistently stores (1.0 = replicated).
 _optimizer = {"kind": None, "state_bytes": None, "shard_frac": None}
-_timer = time.perf_counter  # injectable for tests
 _ordinals = itertools.count(1)
+#: program -> [what its recent dispatches took, their median once
+#: LATE_KNOWN are known, dispatches since that median was taken].
+_paces = {}
 #: configured-peak-value -> resolved FLOP/s (the device probe and
 #: config walk are constant per process; never pay them per
 #: dispatch).
@@ -110,13 +133,17 @@ def reset():
     _ordinals = itertools.count(1)
     with _lock:
         _state.update(last_ms=None, device_ms=None, mfu=None,
-                      dispatches=0, ticks=0, device_s_total=0.0)
+                      dispatches=0, ticks=0, device_s_total=0.0,
+                      late_warned=None)
         _recent.clear()
+        _paces.clear()
         _optimizer.update(kind=None, state_bytes=None,
                           shard_frac=None)
     _xprof.update(dir=None, steps=0, done=0, started=False)
-    _local.dispatch = _gc["target"] = _gc["t0"] = None
+    _local.dispatch = _local.closed = None
+    _gc["target"] = _gc["t0"] = None
     _peak_cache.clear()
+    startup.reset()
     metrics.registry.remove_prefix("device.")
     metrics.registry.remove_prefix("optimizer.")
     metrics.registry.remove_prefix("moe.")
@@ -228,9 +255,9 @@ def _on_gc(phase, info):
     if target is None:
         return
     if phase == "start":
-        _gc["t0"] = _timer()
+        _gc["t0"] = startup._timer()
     elif _gc["t0"] is not None:
-        target.gc_s += _timer() - _gc["t0"]
+        target.gc_s += startup._timer() - _gc["t0"]
         target.gc_full += info.get("generation") == 2
         _gc["t0"] = None
 
@@ -247,8 +274,9 @@ class Dispatch(object):
     same one."""
 
     __slots__ = ("ordinal", "program", "ticks", "flops", "gc_s",
-                 "gc_full", "_span", "_serve", "_upload", "_enqueue",
-                 "_wait", "_depth", "_t0", "_leaf", "_timed")
+                 "gc_full", "_span", "_serve", "_upload", "_lower",
+                 "_enqueue", "_wait", "_depth", "_t0", "_leaf",
+                 "_timed", "_opened", "_charged")
 
     def __init__(self):
         self.ordinal = next(_ordinals)
@@ -259,6 +287,7 @@ class Dispatch(object):
         self.gc_full = 0
         self._span = self._t0 = self._leaf = None
         self._serve = self._upload = self._enqueue = self._wait = None
+        self._lower = self._opened = self._charged = None
         self._depth = 0
         self._timed = False
 
@@ -267,9 +296,12 @@ class Dispatch(object):
         if self._depth == 1:
             if _on_gc not in gc.callbacks:
                 gc.callbacks.append(_on_gc)
+            startup.install()
             # Before the span: an annotation opened ahead of the
             # profiler session is not in its trace.
             _xprof_step_begin()
+            self._charged = startup.charged()
+            self._opened = startup._timer()
             self._span = tracing.annotated(
                 "step", ordinal=self.ordinal, ticks=self.ticks)
             _local.dispatch = _gc["target"] = self
@@ -289,9 +321,18 @@ class Dispatch(object):
         self._upload = tracing.annotated("step.upload")
         return self._upload
 
+    def lower(self):
+        """``step.lower``: once a compiled program, its lowering
+        from its arguments' shapes (trace and MLIR module) and XLA's
+        cost analysis of it, for the FLOP estimate."""
+        self._lower = tracing.annotated("step.lower")
+        return self._lower
+
     def enqueue(self):
-        """``step.enqueue``: the jitted call until it returns."""
-        self._t0 = _timer()
+        """``step.enqueue``: the jitted call until it returns — the
+        first of a program holds its backend compile or the load of
+        its cached executable (the record's ``compile_s``)."""
+        self._t0 = startup._timer()
         self._enqueue = tracing.annotated("step.enqueue")
         return self._enqueue
 
@@ -316,21 +357,33 @@ class Dispatch(object):
         self._span.set(program=self.program, ticks=self.ticks,
                        gc_s=self.gc_s, gc_full=self.gc_full)
         self._span.__exit__(exc_type, exc, tb)
+        closed = startup._timer()
+        before, _local.closed = getattr(_local, "closed", None), closed
         if exc_type is None:
             if self._timed and self._t0 is not None:
-                device_s = _timer() - self._t0
+                device_s = closed - self._t0
+                charged = startup.charged()
+                record = {
+                    "ordinal": self.ordinal,
+                    "program": self.program,
+                    "ticks": self.ticks,
+                    "device_s": device_s,
+                    "serve_s": _seconds(self._serve),
+                    "upload_s": _seconds(self._upload),
+                    "enqueue_s": _seconds(self._enqueue),
+                    "wait_s": _seconds(self._wait),
+                    "gc_s": self.gc_s,
+                    "gc_full": int(self.gc_full),
+                    "lower_s": _seconds(self._lower),
+                    "compile_s": charged[0] - self._charged[0],
+                    "compiled": charged[1] - self._charged[1],
+                    "build_s": charged[2] - self._charged[2],
+                    "t0": self._opened, "t1": closed,
+                    "gap_s": None if before is None
+                    else self._opened - before}
+                _judge(record)
                 record_step(device_s, flops=self.flops,
-                            ticks=self.ticks, record={
-                                "ordinal": self.ordinal,
-                                "program": self.program,
-                                "ticks": self.ticks,
-                                "device_s": device_s,
-                                "serve_s": _seconds(self._serve),
-                                "upload_s": _seconds(self._upload),
-                                "enqueue_s": _seconds(self._enqueue),
-                                "wait_s": _seconds(self._wait),
-                                "gc_s": self.gc_s,
-                                "gc_full": int(self.gc_full)})
+                            ticks=self.ticks, record=record)
             # After the record: closing the window reduces the trace
             # (and may compile for the scope table), which is none of
             # this dispatch's time.
@@ -356,12 +409,69 @@ def dispatch(program=None, ticks=None):
 def recent():
     """The last :data:`RECENT` dispatch records, oldest first: dicts
     of ``ordinal``, ``program``, ``ticks``, ``device_s`` (enqueue to
-    ready) and the host's split ``serve_s``, ``upload_s``,
+    ready), the host's split ``serve_s``, ``upload_s``,
     ``enqueue_s``, ``wait_s``, ``gc_s`` (seconds of garbage
     collections inside the dispatch) and ``gc_full`` (how many of
-    them were full ones)."""
+    them were full ones); what compiling took of the dispatch —
+    ``lower_s`` (the ``step.lower`` span), ``compile_s`` (seconds of
+    JAX's traces, lowerings and backend compiles or cached loads that
+    fell inside it and outside ``step.lower``), ``compiled`` (the
+    programs whose compile ended inside it), ``build_s`` (the
+    ``step.build`` span, where ``StepCompiler.compile()`` ran inside
+    it) — and its clock: ``t0``, ``t1`` (the ``step`` span's opening
+    and closing on ``time.perf_counter``, the clock of
+    ``startup.compiles()``) and ``gap_s`` (``t0`` minus the closing
+    of this thread's dispatch before it; None for the first)."""
     with _lock:
         return list(_recent)
+
+
+def _judge(record):
+    """Whether this dispatch is late: what it took against
+    :data:`LATE_FACTOR` × the median of what its program's recent
+    ones took.  A late one counts into ``device.late_dispatches`` and
+    is logged with its whole split and the compile context (at most
+    once in :data:`LATE_WARN_EVERY_S` seconds).  A dispatch that
+    compiled is neither judged nor remembered.  The median is taken
+    again when a dispatch passes it, and every :data:`LATE_RECORDS`
+    dispatches."""
+    if record["compiled"]:
+        return
+    took = record["t1"] - record["t0"]
+    with _lock:
+        pace = _paces.get(record["program"])
+        if pace is None:
+            pace = _paces[record["program"]] = [
+                collections.deque(maxlen=LATE_RECORDS), None, 0]
+        usual = pace[1]
+        if usual is not None and took > LATE_FACTOR * usual:
+            # against the newest median: the pace may have changed
+            usual = statistics.median(pace[0])
+        late = usual is not None and took > LATE_FACTOR * usual
+        pace[0].append(took)
+        pace[2] += 1
+        if (late or pace[1] is None or pace[2] >= LATE_RECORDS) \
+                and len(pace[0]) >= LATE_KNOWN:
+            pace[1], pace[2] = statistics.median(pace[0]), 0
+    if not late:
+        return
+    metrics.registry.counter("device.late_dispatches").inc()
+    with _lock:
+        warned = _state["late_warned"]
+        if warned is not None and \
+                record["t1"] - warned < LATE_WARN_EVERY_S:
+            return
+        _state["late_warned"] = record["t1"]
+    programs, ended = startup.compiled()
+    logging.getLogger("attribution").warning(
+        "dispatch %d of %s late: %.4g s against %.4g; serve %.4g "
+        "upload %.4g enqueue %.4g wait %.4g gc %.4g (%d full) gap "
+        "%.4g; %d programs compiled%s", record["ordinal"],
+        record["program"], took, usual, record["serve_s"],
+        record["upload_s"], record["enqueue_s"], record["wait_s"],
+        record["gc_s"], record["gc_full"], record["gap_s"] or 0.0,
+        programs, "" if ended is None else
+        ", the last ended %.4g s before" % (record["t0"] - ended))
 
 
 def record_step(device_seconds, flops=None, ticks=1, record=None):
@@ -374,13 +484,18 @@ def record_step(device_seconds, flops=None, ticks=1, record=None):
     peak = peak_flops() if flops else None
     if flops and peak:
         mfu = float(flops) / device_seconds / peak
+    # A dispatch that compiled holds the compile in its seconds (30
+    # to 140 s on the chip): it keeps its record and is not folded
+    # into the EWMA, which would read wrong for twenty dispatches.
+    steady = not (record and record.get("compiled"))
     with _lock:
         ms = device_seconds * 1e3
         _state["last_ms"] = ms
-        prev = _state["device_ms"]
-        _state["device_ms"] = ms if prev is None else \
-            prev + EWMA_ALPHA * (ms - prev)
-        if mfu is not None:
+        if steady:
+            prev = _state["device_ms"]
+            _state["device_ms"] = ms if prev is None else \
+                prev + EWMA_ALPHA * (ms - prev)
+        if steady and mfu is not None:
             prev = _state["mfu"]
             _state["mfu"] = mfu if prev is None else \
                 prev + EWMA_ALPHA * (mfu - prev)
@@ -393,7 +508,8 @@ def record_step(device_seconds, flops=None, ticks=1, record=None):
     reg = metrics.registry
     reg.counter("device.dispatches").inc()
     reg.counter("device.ticks").inc(int(ticks))
-    reg.gauge("device.step_ms").set(round(snap["device_ms"], 3))
+    if snap["device_ms"] is not None:
+        reg.gauge("device.step_ms").set(round(snap["device_ms"], 3))
     if snap["mfu"] is not None:
         reg.gauge("device.mfu").set(round(snap["mfu"], 4))
     return snap

@@ -230,7 +230,7 @@ def _trace_annotation():
 class AnnotatedSpan(object):
     """A span on the dispatch path (see :func:`annotated`)."""
 
-    __slots__ = ("seconds", "_annotation", "_span", "_t0")
+    __slots__ = ("seconds", "_annotation", "_span", "_t0", "_open")
 
     def __init__(self, name, attrs):
         self.seconds = None
@@ -238,6 +238,8 @@ class AnnotatedSpan(object):
             "veles." + name, **attrs)
         self._annotation.__enter__()
         self._span = Span(name, attrs) if _enabled else None
+        self._open = _open_annotated()
+        self._open.append(name)
         self._t0 = time.perf_counter()
 
     def set(self, **attrs):
@@ -251,10 +253,26 @@ class AnnotatedSpan(object):
 
     def __exit__(self, *exc):
         self.seconds = time.perf_counter() - self._t0
+        self._open.pop()
         if self._span is not None:
             self._span.finish()
         self._annotation.__exit__(*exc)
         return False
+
+
+def _open_annotated():
+    names = getattr(_local, "annotated", None)
+    if names is None:
+        names = _local.annotated = []
+    return names
+
+
+def inside():
+    """Name of the innermost :func:`annotated` span open on this
+    thread (``"step.enqueue"``), or None: what a compile record's
+    ``inside`` says (``observability.startup``)."""
+    names = getattr(_local, "annotated", None)
+    return names[-1] if names else None
 
 
 def annotated(name, **attrs):
