@@ -771,7 +771,7 @@ def test_every_pallas_call_is_named():
             assert re.search(r'\bname="[a-z_]+"', text[match.end():end]), \
                 "%s: pallas_call without name= at offset %d" % (
                     name, match.start())
-    assert calls == 8
+    assert calls == 10
 
 
 def test_every_exported_program_is_named():

@@ -372,6 +372,68 @@ def test_qwen3_next_layers_compile(one_chip, monkeypatch, kind, spec,
         assert _needed_bytes(program) <= _needed_bytes(xla)
 
 
+def _shortconv_calls(text):
+    return _kernel_calls(text, "shortconv_fwd", "shortconv_bwd")
+
+
+#: ``lfm2-24b-a2b.train``'s short-convolution layer: 8 x 2048 tokens of
+#: 2,048, three taps behind and before their gates.
+LFM2_CONV = dict(norm="rms", bias=False, operator="shortconv",
+                 conv_kernel=3, ffn="gated-mlp")
+
+
+@pytest.mark.parametrize("kind,spec,shape,calls", [
+    ("qwen3-next linear", QWEN3_NEXT_LINEAR, (1, 8192, 16), (4, 2)),
+    ("lfm2 gated", LFM2_CONV, (8, 2048, 32), (0, 0))])
+def test_the_operators_taps_compile_as_the_shortconv_kernels(
+        one_chip, monkeypatch, kind, spec, shape, calls):
+    """Two spec-built layers under the layers' checkpoint, forward +
+    backward at a cell's size (1 x 8,192 tokens of 2,048 at
+    ``qwen3-next.train-8k``: q, k and v are C = 8,192 channels, K = 4).
+    The gated delta operator's taps and SiLU are the Pallas kernels of
+    ``ops/pallas_shortconv.py``: ``shortconv_fwd`` twice a layer (the
+    forward and the recompute) and ``shortconv_bwd`` once, reading the
+    whole ``[q | k | v | z]`` projection in place, and the program
+    needs fewer bytes than with XLA's taps.  LFM2's gated short
+    convolution keeps XLA's form: no kernel."""
+    from veles_tpu.ops import attention as A
+    from veles_tpu.ops import linear_attention as L
+    from veles_tpu.ops import shortconv as SC
+    from veles_tpu.znicz import attention as Z
+    monkeypatch.setattr(A, "tpu_available", lambda: True)
+    monkeypatch.setattr(L, "tpu_available", lambda: True)
+    B, S, H = shape
+    spec = Z.layer_spec(n_heads=H, ffn_dim=256, **spec)
+    params = [{name: _struct(shape, jnp.float32, one_chip)
+               for name, shape in
+               Z.layer_param_shapes(spec, 2048).items()}] * 2
+
+    def compiled(on_tpu):
+        monkeypatch.setattr(SC, "tpu_available", lambda: on_tpu)
+        layer = Z.checkpointed(lambda p, h: Z.layer_apply(
+            spec, p, h, jnp.bfloat16)[0])
+
+        def loss(params, x):
+            for p in params:
+                x = layer(p, x)
+            return (x * x).sum()
+
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            params, _struct((B, S, 2048), jnp.float32, one_chip)
+        ).compile()
+
+    program = compiled(True)
+    text = program.as_text()
+    assert _shortconv_calls(text) == calls
+    if calls[0]:
+        # the kernel's operand is the projection, not a copy of its slice
+        assert re.search(r"shortconv_fwd[.\d]* = [^\n]*operand_layout_"
+                         r"constraints=\{bf16\[1,8192,12288\]", text)
+        xla = compiled(False)
+        assert _shortconv_calls(xla.as_text()) == (0, 0)
+        assert _needed_bytes(program) < _needed_bytes(xla)
+
+
 #: The MoE cells' expert shares: tokens a tick, width, experts'
 #: width, routed experts, top k, how many are held here, what
 #: ``dropless_rows`` compiles the common path for, whether a norm

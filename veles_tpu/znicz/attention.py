@@ -508,16 +508,17 @@ def _gated_delta_operator(spec, params, h, cdt, dot):
     import jax.numpy as jnp
     from ..ops.linear_attention import gated_delta_rule
     from ..ops.rotary import rms_norm
-    from ..ops.shortconv import causal_depthwise_conv
+    from ..ops.shortconv import conv_silu
     B, S, _ = h.shape
     kh, vh = spec["linear_key_heads"], spec["linear_value_heads"]
     kd, vd = spec["linear_key_dim"], spec["linear_value_dim"]
     keys, values = kh * kd, vh * vd
-    qkv, z = jnp.split(dot(h, params["w_qkvz"]).astype(cdt),
-                       [2 * keys + values], axis=-1)
+    qkvz = dot(h, params["w_qkvz"]).astype(cdt)
+    z = qkvz[..., 2 * keys + values:]
     b, a = jnp.split(dot(h, params["w_ba"]), 2, axis=-1)
     with jax.named_scope("shortconv"):
-        qkv = jax.nn.silu(causal_depthwise_conv(qkv, params["w_conv"]))
+        # the taps read q, k and v, the first channels, in place
+        qkv = conv_silu(qkvz, params["w_conv"])
     with jax.named_scope("gdn_gate"):
         q, k, v = jnp.split(qkv, [keys, 2 * keys], axis=-1)
         q, k = (t.reshape(B, S, kh, kd) for t in (q, k))
