@@ -383,7 +383,7 @@ def test_supports_decode_contract():
     assert not supports_decode((4, 1, 2, 64), table)
     assert not supports_decode(q1, (4, 1000, 2, 128))
     assert not supports_decode((4, 1, 2), (4, 1024, 2))
-    # No MAX_SEQ bound: the split-k/v grid streams long tables.
+    # No sequence bound: the split-k/v grid streams long tables.
     assert supports_decode(q1, (4, 16384, 2, 128))
     # Interpret mode relaxes alignment, not the S_q bound.
     assert supports_decode((1, 1, 1, 4), (1, 10, 1, 4),

@@ -36,7 +36,7 @@ OPT_6_7B = (4, 2048, 32, 128)
 #: to the 32 query heads of 64 before the call.
 LFM2 = (8, 2048, 32, 64)
 #: ``trinity-mini.train-8k``: 1 x 8192 tokens, the 4 kv heads
-#: broadcast to the 32 query heads of 128; four chunks of ``MAX_SEQ``.
+#: broadcast to the 32 query heads of 128; one call of each kernel.
 TRINITY = (1, 8192, 32, 128)
 TRINITY_WINDOW = 2048
 #: ``qwen3-next.train-8k``: 1 x 8192 tokens on a 2,048-wide stream;
@@ -154,9 +154,10 @@ def _layer_step(wrap, shape, sharding, **spec):
                          ids=["fwd", "fwd+bwd"])
 def test_flash_attention_compiles(one_chip, grad, operands, shape,
                                   inputs):
-    """The training kernel at the LM geometry; at ``MAX_SEQ`` — the
-    longest sequence ``supports()`` admits, where the backward's
-    dk/dv kernel (q, dO, lse, delta resident) must still fit VMEM;
+    """The training kernel at the LM geometry; at ``SCOPED_SEQ`` — the
+    longest call made with no compiler parameters, where the backward's
+    dk/dv kernel (q, dO, lse, delta resident) must still fit Mosaic's
+    default scoped VMEM;
     and at ``opt-1.3b.train``'s own geometry, head size 64 (half a
     lane row) with bf16 inputs as the block hands them over: a
     (1, block, 64) block spans the array's whole last dimension,
@@ -165,7 +166,7 @@ def test_flash_attention_compiles(one_chip, grad, operands, shape,
     computed from the offset operands and ``program_id``, which
     Mosaic must take as the bounds of an ``scf.for``."""
     from veles_tpu.ops import pallas_attention as PA
-    assert shape[1] <= PA.MAX_SEQ and PA.supports(shape, shape)
+    assert shape[1] <= PA.SCOPED_SEQ and PA.supports(shape, shape)
     od = jnp.dtype(operands).type
 
     def fwd(q, k, v):
@@ -228,21 +229,20 @@ def test_scopes_publish_the_flash_call_counts(one_chip, monkeypatch,
     assert programs.kernel_calls(table, "flash_dkv") == 2
 
 
-@pytest.mark.parametrize("window,pairs", [(TRINITY_WINDOW, 7),
-                                          (None, 10)],
+@pytest.mark.parametrize("window", [TRINITY_WINDOW, None],
                          ids=["window", "full"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
-def test_flash_attention_past_max_seq_compiles(one_chip, grad, window,
-                                               pairs):
-    """``trinity-mini.train-8k``'s two calls: 8,192 tokens in four
-    chunks of ``MAX_SEQ``, one call of each kernel a chunk pair the
-    mask leaves anything of — 7 under the window of 2,048 (the walk's
-    lower bound, computed in the kernel from the pair's global
-    origins, is one more scalar Mosaic must take as an ``scf.for``
-    bound), 10 under the causal mask alone; no S x S array, so the
-    program's temporaries stay far under one (268 MB a head)."""
+def test_flash_attention_past_max_seq_compiles(one_chip, grad, window):
+    """``trinity-mini.train-8k``'s two calls: 8,192 tokens past
+    ``SCOPED_SEQ`` in ONE call of each kernel, k and v (the dk/dv
+    kernel's q and dO) whole in VMEM under the limit the call states —
+    under the window of 2,048 the walk's lower bound, computed in the
+    kernel from the global origins, is one more scalar Mosaic must take
+    as an ``scf.for`` bound; no S x S array, so the program's
+    temporaries stay far under one (268 MB a head)."""
     from veles_tpu.ops import pallas_attention as PA
-    assert TRINITY[1] == 4 * PA.MAX_SEQ and PA.supports(TRINITY, TRINITY)
+    assert TRINITY[1] > PA.SCOPED_SEQ and PA.supports(TRINITY, TRINITY)
+    assert PA.call_rows(8192, 128, 128, 2) == 8192
 
     def fwd(q, k, v):
         return PA.pallas_attention(q, k, v, causal=True, window=window,
@@ -256,7 +256,7 @@ def test_flash_attention_past_max_seq_compiles(one_chip, grad, window,
     x = _struct(TRINITY, jnp.bfloat16, one_chip)
     compiled = jax.jit(fn).lower(x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") == \
-        pairs * (3 if grad else 1)
+        (3 if grad else 1)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
@@ -268,16 +268,19 @@ TRINITY_LAYER = dict(norm="rms", post_norm=True, kv_heads=4,
                      bias=False, ffn="gated-mlp")
 
 
-@pytest.mark.parametrize("kind,spec,calls", [
+@pytest.mark.parametrize("kind,spec", [
     ("sliding", dict(TRINITY_LAYER, window=TRINITY_WINDOW,
-                     rope_theta=1e4), 14),
-    ("full", TRINITY_LAYER, 20)])
+                     rope_theta=1e4)),
+    ("full", TRINITY_LAYER)])
 def test_trinity_layers_run_each_flash_kernel_once_a_pair(
-        one_chip, monkeypatch, kind, spec, calls):
+        one_chip, monkeypatch, kind, spec):
     """The cell's two kinds of spec-built layer, two of each under the
-    layers' checkpoint, forward + backward: every chunk pair's forward
-    output is kept, so the program holds as many ``flash_fwd`` as
-    ``flash_dq`` as ``flash_dkv`` — 7 a sliding layer, 10 a full one."""
+    layers' checkpoint, forward + backward: each layer's 8,192 rows are
+    one call of each kernel and its forward output is kept, so the
+    program holds 2 ``flash_fwd``, 2 ``flash_dq`` and 2 ``flash_dkv``
+    — where the chunked walk would hold one of each a visible pair of
+    2,048-row chunks: 14 for two sliding layers, 20 for two full
+    ones."""
     from veles_tpu.ops import attention as A
     from veles_tpu.znicz import attention as Z
     monkeypatch.setattr(A, "tpu_available", lambda: True)
@@ -297,7 +300,7 @@ def test_trinity_layers_run_each_flash_kernel_once_a_pair(
     text = jax.jit(jax.value_and_grad(loss)).lower(
         params, _struct((B, S, 2048), jnp.float32, one_chip)
     ).compile().as_text()
-    assert _flash_calls(text) == (calls, calls, calls)
+    assert _flash_calls(text) == (2, 2, 2)
 
 
 #: ``qwen3-next.train-8k``'s two kinds of layer (the FFN cut narrow:
@@ -317,8 +320,7 @@ def test_flash_attention_at_a_value_head_of_its_own_compiles(one_chip,
                                                               grad):
     """``joyai-flash.train-8k``'s call: keys 192 wide — a block that
     spans the array's whole last dimension, as head 64's does — against
-    values 128 wide, 8,192 tokens in the 10 visible chunk pairs of
-    ``MAX_SEQ``."""
+    values 128 wide, 8,192 tokens in ONE call of each kernel."""
     from veles_tpu.ops import pallas_attention as PA
     B, S, H, D = JOYAI
     values = (B, S, H, JOYAI_VALUES)
@@ -337,8 +339,63 @@ def test_flash_attention_at_a_value_head_of_its_own_compiles(one_chip,
     compiled = jax.jit(fn).lower(
         x, x, _struct(values, jnp.bfloat16, one_chip)).compile()
     assert compiled.as_text().count("tpu_custom_call") == \
-        10 * (3 if grad else 1)
+        (3 if grad else 1)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("shape,values", [
+    (JOYAI, JOYAI_VALUES), (QWEN3_NEXT, QWEN3_NEXT[3]),
+    (TRINITY, TRINITY[3])], ids=["joyai", "qwen3-next", "trinity"])
+def test_whole_sequence_kernels_compile_under_their_vmem_limit(
+        one_chip, shape, values):
+    """Each 8k cell's call, forward + backward at 8,192 tokens: ONE
+    call of each kernel, every one stating the scoped VMEM limit its
+    shapes reckon (``call_bytes``, twice over, under the core's 128
+    MiB) and compiling under it; the program's temporaries fall
+    against the chunked walk at the same shapes (chunks of 2,048: no
+    slices of every pair, no partials to merge)."""
+    from veles_tpu.ops import pallas_attention as PA
+    B, S, H, D = shape
+    limit = -(-2 * PA.call_bytes(S, D, values, 2, 512, 512) >> 20) << 20
+    assert PA.call_rows(S, D, values, 2) == S and limit < 128 << 20
+
+    def compiled(chunk):
+        def loss(q, k, v):
+            return PA.pallas_attention(
+                q, k, v, causal=True, operand_dtype=jnp.bfloat16,
+                chunk=chunk).astype(jnp.float32).sum()
+        fn = jax.grad(loss, argnums=(0, 1, 2))
+        x = _struct(shape, jnp.bfloat16, one_chip)
+        structs = (x, x, _struct((B, S, H, values), jnp.bfloat16,
+                                 one_chip))
+        return _vmem_limits(fn, *structs), \
+            jax.jit(fn).lower(*structs).compile()
+
+    limits, whole = compiled(None)
+    assert limits == [limit] * 3
+    unstated, chunked = compiled(2048)
+    assert unstated == [None] * 30
+    assert whole.as_text().count("tpu_custom_call") == 3
+    assert chunked.as_text().count("tpu_custom_call") == 30
+    assert whole.memory_analysis().temp_size_in_bytes < \
+        chunked.memory_analysis().temp_size_in_bytes
+
+
+def _vmem_limits(fn, *structs):
+    """The scoped VMEM limit each ``pallas_call`` of ``fn``'s jaxpr
+    states (None: Mosaic's default), nested calls included."""
+    from jax._src import core
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                params = eqn.params["compiler_params"].get("mosaic_tpu")
+                found.append(params and params.vmem_limit_bytes)
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*structs).jaxpr)
+    return found
 
 
 #: ``joyai-flash.train-8k``'s latent operator (the FFN cut narrow).
@@ -352,9 +409,9 @@ JOYAI_LAYER = dict(
 def test_joyai_latent_layers_run_each_flash_kernel_once_a_pair(
         one_chip, monkeypatch):
     """Two latent layers under the layers' checkpoint, forward +
-    backward at 1 x 8,192 tokens of 2,048: each layer's 10 chunk pairs
-    run each kernel once (the forward's outputs kept), and the program
-    needs under 4 GB."""
+    backward at 1 x 8,192 tokens of 2,048: each layer runs each kernel
+    once over the whole sequence (the forward's output kept), and the
+    program needs under 4 GB."""
     from veles_tpu.ops import attention as A
     from veles_tpu.znicz import attention as Z
     monkeypatch.setattr(A, "tpu_available", lambda: True)
@@ -373,7 +430,7 @@ def test_joyai_latent_layers_run_each_flash_kernel_once_a_pair(
                Z.layer_param_shapes(spec, 2048).items()}] * 2
     compiled = jax.jit(jax.value_and_grad(loss)).lower(
         params, _struct((B, S, 2048), jnp.float32, one_chip)).compile()
-    assert _flash_calls(compiled.as_text()) == (20, 20, 20)
+    assert _flash_calls(compiled.as_text()) == (2, 2, 2)
     assert _needed_bytes(compiled) < 4 << 30
 
 
@@ -390,7 +447,7 @@ def _gated_delta_calls(text):
 
 @pytest.mark.parametrize("kind,spec,flash,rule", [
     ("linear", QWEN3_NEXT_LINEAR, 0, (2, 2, 2)),
-    ("full", QWEN3_NEXT_FULL, 20, (0, 0, 0))])
+    ("full", QWEN3_NEXT_FULL, 2, (0, 0, 0))])
 def test_qwen3_next_layers_compile(one_chip, monkeypatch, kind, spec,
                                    flash, rule):
     """The cell's two kinds of spec-built layer, two of each under the
@@ -402,8 +459,8 @@ def test_qwen3_next_layers_compile(one_chip, monkeypatch, kind, spec,
     scan for ``programs.linear_scan`` to find; the program needs no
     more bytes than XLA's form of the rule (a scan of 8,192 / 64 = 128
     steps in chunks of 64) at the same shapes.  The full layer is the
-    flash kernels' first call at a head of 256: 10 visible chunk
-    pairs a layer, each forward kept."""
+    flash kernels' first call at a head of 256: one call of each
+    kernel a layer over the whole sequence, its forward kept."""
     from veles_tpu.observability import programs
     from veles_tpu.ops import attention as A
     from veles_tpu.ops import linear_attention as L

@@ -1,8 +1,8 @@
 """The window beside ``causal`` (row ``i`` sees key ``j`` iff ``0 ≤ i −
 j < window``) in every attention formulation: the XLA formulations
 against an explicit mask, the flash kernels (interpreted) with their
-tile walk bounded from below, the chunked call past ``MAX_SEQ``, the
-tile counts by hand and the labelled counters (ISSUE 33)."""
+tile walk bounded from below, one call and the chunked walk, the tile
+counts by hand and the labelled counters."""
 
 import itertools
 
@@ -142,12 +142,13 @@ def test_flash_tiles_with_a_window_by_hand(S, window, blocks, visited):
     n = S // blocks
     assert PA.flash_tiles(S, S, blocks, blocks, causal=True,
                           window=window) == (visited, n * n)
-    if S == 4 * PA.MAX_SEQ:
-        # the chunked call walks the same tiles, pair by pair
-        pairs = [PA.flash_tiles(PA.MAX_SEQ, PA.MAX_SEQ, blocks, blocks,
-                                q0, k0, True, window)[0]
-                 for q0 in range(0, S, PA.MAX_SEQ)
-                 for k0 in range(0, S, PA.MAX_SEQ)]
+    chunk = PA.SCOPED_SEQ
+    if S == 4 * chunk:
+        # the chunked walk visits the same tiles, pair by pair
+        pairs = [PA.flash_tiles(chunk, chunk, blocks, blocks, q0, k0,
+                                True, window)[0]
+                 for q0 in range(0, S, chunk)
+                 for k0 in range(0, S, chunk)]
         assert sum(pairs) == visited
         seen = sum(1 for p in pairs if p)
         assert seen == {2048: 7, None: 10, 8192: 10}.get(window, seen)

@@ -272,8 +272,10 @@ def attention(q, k, v, causal=False, precision=None, kernel=None,
     there beyond the matmul operand dtype).  ``window`` (a static
     int, beside ``causal``): row ``i`` sees key ``j`` iff ``0 ≤ i − j
     < window`` — sliding-window attention; the kernel's tile walk
-    follows it, and past ``pallas_attention.MAX_SEQ`` the kernel is
-    called a visible pair of k/v and query chunks at a time.
+    follows it.  The kernel takes the whole sequence in one call
+    wherever its operands fit VMEM (``pallas_attention.call_rows``:
+    every cell's, up to 8,192 rows); only a longer one is called a
+    visible pair of k/v and query chunks at a time.
 
     Grouped-query attention: where k and v carry fewer heads than q
     (H a multiple of theirs) each key/value head is broadcast over

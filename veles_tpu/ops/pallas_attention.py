@@ -10,17 +10,24 @@ choices, for exactly one geometry family:
   * D is the FULL lane width (D % 128 == 0, up to 512) — one q/k/v
     row is one (or a few) native (8, 128) tiles, no head-dim blocking
     ever — or HALF of it, D = 64 (below);
-  * k/v for a (batch·head) slice of ONE call live WHOLE in VMEM
-    (``MAX_SEQ`` = 2048 × D=128 × 4 B = 1 MB each — a fraction of
-    16 MB), so the only streaming dimension is the query block: grid
-    (B·H, S/block_q), with the key loop a ``fori_loop`` over VMEM,
-    never HBM.  ``MAX_SEQ`` bounds a CALL, not a sequence (ISSUE 33):
-    a longer one, a multiple of it, is walked in chunks of
-    ``MAX_SEQ`` queries and keys — one :func:`flash_chunk` call for
-    every pair of chunks the mask leaves anything of, at the pair's
-    global origins, the partials of a query chunk merged by lse
-    (:func:`_chunked`: the ring's composition below, on one chip), so
-    k/v stream from HBM a chunk at a time and no S × S array exists;
+  * k/v for a (batch·head) slice of ONE call live WHOLE in VMEM (the
+    dk/dv kernel's q and dO likewise), so the only streaming
+    dimension is the query block: grid (B·H, S/block_q), with the key
+    loop a ``fori_loop`` over VMEM, never HBM.  How many rows one
+    call takes follows from the shapes it observes
+    (:func:`call_rows`): the VMEM the largest kernel holds at that
+    length (:func:`call_bytes` — the resident operands double-buffered,
+    rows padded to whole lane tiles, beside the blocks, the
+    statistics and the float32 tile work) against ``VMEM_BUDGET``.
+    At every head the contract admits a sequence of 8,192 rows is ONE
+    call.  A call past ``SCOPED_SEQ`` rows asks Mosaic for the VMEM
+    it reckons (``vmem_limit_bytes``); one no longer is made with no
+    compiler parameters at all.  Only a sequence too long for the
+    budget is walked in chunks — the longest that fit and divide it —
+    one call for every pair of chunks the mask leaves anything of, at
+    the pair's global origins, the partials of a query chunk merged
+    by lse (:func:`_chunked`: the ring's composition below, on one
+    chip), so no S × S array exists;
   * a CAUSAL call walks only the score tiles it can see (ISSUE 30):
     each query block's key loop ends at the last key block that
     holds a column ≤ its last row, and the dk/dv kernel's query loop
@@ -165,17 +172,24 @@ LANE = 128
 #: The one head dim below a lane row that :func:`supports` admits.
 HALF_LANE = LANE // 2
 
-#: Upper bound of ONE kernel call's sequence: the kernel keeps a
-#: (batch·head) slice's whole k/v in VMEM (S × D × 4 B each,
-#: double-buffered) next to its f32 score tiles; the backward's
-#: dk/dv kernel also keeps q and dO whole.  S=2048/D=128 forward and
-#: backward compile inside the v5e's 16 MB scoped VMEM
-#: (tests/test_tpu_compile.py); past it the tiles stop fitting.  A
-#: longer sequence (a multiple of it) is walked in chunks of
-#: ``MAX_SEQ`` rows and keys, one :func:`flash_chunk` call a visible
-#: chunk pair, the partials merged by lse — the ring's composition
-#: on one chip (:func:`_chunked`).
-MAX_SEQ = 2048
+#: The longest call made inside Mosaic's default scoped VMEM limit
+#: (16 MiB on a v5e): the 2,048-row cells' calls compile there in
+#: float32 and in bfloat16 (tests/test_tpu_compile.py).  A call of at
+#: most this many rows states no compiler parameters; a longer one
+#: raises the limit to what :func:`call_bytes` reckons.  It also
+#: bounds a ring shard (:func:`supports_ring`) and a call with
+#: ``kv_len``, and a longer sequence must be a multiple of it
+#: (:func:`supports`).
+SCOPED_SEQ = 2048
+
+#: The VMEM one call may reckon on (:func:`call_bytes`): half of a
+#: v5e core's 128 MiB, the other half left to what Mosaic adds of its
+#: own.  At bfloat16 with (512, 512) tiles a call at 8,192 rows
+#: reckons 18 MiB at heads of 128, 23 MiB at 192 keys / 128 values and
+#: 28 MiB at 256 — Mosaic asks for more than its default 16 MiB at the
+#: last two — so each is one call; past the budget :func:`call_rows`
+#: chunks the sequence.
+VMEM_BUDGET = 64 << 20
 
 #: Decode-kernel query bound: past this many query rows the chunk is
 #: a prefill, which the full flash kernel (or the dense cached path)
@@ -208,11 +222,13 @@ def supports(q_shape, k_shape, kv_len=None, v_shape=None):
     """Whether the kernel's geometry contract holds: self-attention
     ((B, S, H, D) with equal q/k sequence and head), D 64, 192 or a
     multiple of 128 up to 512 (:func:`_head_fits`), S tile-aligned up
-    to ``MAX_SEQ`` or a multiple of it.  ``v_shape`` (None: k's) may have a head of its
-    own, ``D_v``, within the same rule — latent attention's keys are
-    192 wide and its values 128.  ``kv_len`` (the blockwise padding
-    contract) is supported as a static mask bound, within
-    ``MAX_SEQ``."""
+    to ``SCOPED_SEQ`` or a multiple of it.  How many of those rows one
+    call takes is :func:`call_rows`' to say: all of them wherever the
+    call fits ``VMEM_BUDGET``.  ``v_shape`` (None: k's) may have a
+    head of its own, ``D_v``, within the same rule — latent
+    attention's keys are 192 wide and its values 128.  ``kv_len``
+    (the blockwise padding contract) is supported as a static mask
+    bound, within ``SCOPED_SEQ``."""
     v_shape = k_shape if v_shape is None else v_shape
     if len(q_shape) != 4 or len(k_shape) != 4 or len(v_shape) != 4:
         return False
@@ -224,7 +240,7 @@ def supports(q_shape, k_shape, kv_len=None, v_shape=None):
         return False
     if S % LANE or S < LANE:
         return False
-    if S > MAX_SEQ and (S % MAX_SEQ or kv_len is not None):
+    if S > SCOPED_SEQ and (S % SCOPED_SEQ or kv_len is not None):
         return False
     if kv_len is not None and not isinstance(kv_len, int):
         return False
@@ -254,7 +270,7 @@ def supports_ring(q_shape, k_shape, interpret=False):
     if D % LANE or D > 4 * LANE:
         return False
     for S in (Sq, Sk):
-        if S % LANE or S < LANE or S > MAX_SEQ:
+        if S % LANE or S < LANE or S > SCOPED_SEQ:
             return False
     return True
 
@@ -262,7 +278,7 @@ def supports_ring(q_shape, k_shape, interpret=False):
 def supports_decode(q_shape, k_shape, interpret=False):
     """The :func:`pallas_decode_attention` contract: a small query
     chunk (S_q ≤ ``DECODE_MAX_Q`` — decode steps, not prefills)
-    against a long gathered key table.  The table has NO ``MAX_SEQ``
+    against a long gathered key table.  The table has NO sequence
     bound — the split-k/v grid streams it block by block instead of
     holding it whole in VMEM.  ``interpret`` relaxes tile alignment
     exactly as in :func:`supports_ring`."""
@@ -611,6 +627,61 @@ def _off_operand(off):
     return jnp.asarray(off, jnp.int32).reshape(1, 1)
 
 
+def _lanes(width):
+    """``width`` padded to whole 128-lane rows, as VMEM holds it."""
+    return -(-width // LANE) * LANE
+
+
+def call_bytes(S, D, Dv, itemsize, bq, bk):
+    """The VMEM the largest of the three kernels holds in a call of
+    ``S`` queries against ``S`` keys, ``D`` wide and values ``Dv``
+    wide in operands of ``itemsize`` bytes, rows padded to whole lane
+    tiles: the resident operands (k and v in the forward and dq
+    kernels, q and dO in the dk/dv kernel: ``S`` rows of each width)
+    and the streamed blocks (one in and one out of each width), all
+    double-buffered; the lse and delta statistics in float32 (a
+    block's row, or the dk/dv kernel's whole (S / bq, bq) tile,
+    double-buffered); and a tile's float32 work — the accumulators
+    and eight score-sized temporaries."""
+    D, Dv = _lanes(D), _lanes(Dv)
+    block = max(bq, bk)
+    resident = 2 * S * (D + Dv) * itemsize
+    streamed = 2 * 2 * block * (D + Dv) * itemsize
+    stats = 2 * 2 * max(8, -(-(S // bq) // 8) * 8) * bq * 4
+    work = 4 * (block * (D + Dv + 2 * LANE) + 8 * bq * bk)
+    return resident + streamed + stats + work
+
+
+def call_rows(S, D, Dv, itemsize, block_q=None, block_k=None):
+    """How many of a sequence's ``S`` rows ONE call takes: all of them
+    where :func:`call_bytes` fits ``VMEM_BUDGET``; else the longest
+    multiple of a lane tile that divides ``S`` and fits — the chunks
+    :func:`_chunked` walks."""
+    for rows in range(S, LANE, -LANE):
+        if S % rows:
+            continue
+        bq = _pick_block(rows, block_q or DEFAULT_BLOCK_Q)
+        bk = _pick_block(rows, block_k or DEFAULT_BLOCK_K)
+        if call_bytes(rows, D, Dv, itemsize, bq, bk) <= VMEM_BUDGET:
+            return rows
+    return LANE
+
+
+def _flat_params(qf, kf, vf, bq, bk):
+    """The ``compiler_params`` of a call over flat (BH, S, ·) operands:
+    None up to ``SCOPED_SEQ`` rows (the call as it always was, inside
+    Mosaic's default scoped limit); past it a scoped VMEM limit of
+    what :func:`call_bytes` reckons and as much again, in whole MiB,
+    for what Mosaic adds of its own."""
+    S = max(qf.shape[1], kf.shape[1])
+    if S <= SCOPED_SEQ:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+    itemsize = max(x.dtype.itemsize for x in (qf, kf, vf))
+    need = 2 * call_bytes(S, qf.shape[2], vf.shape[2], itemsize, bq, bk)
+    return pltpu.CompilerParams(vmem_limit_bytes=-(-need >> 20) << 20)
+
+
 def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
                     od, interpret, window=None):
     """(BH, Sq, D) × (BH, Sk, D) forward against values (BH, Sk, D_v):
@@ -633,6 +704,7 @@ def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
                    _stat_row_spec(bq)),
         out_shape=(jax.ShapeDtypeStruct((BH, Sq, Dv), qf.dtype),
                    jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32)),
+        compiler_params=_flat_params(qf, kf, vf, bq, bk),
         interpret=interpret,
         name="flash_fwd",
     )(_off_operand(qoff), _off_operand(koff), qf, kf, vf)
@@ -656,6 +728,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
     rows = (lse.reshape(BH, 1, Sq), delta.reshape(BH, 1, Sq))
     tiles = (lse.reshape(BH, Sq // bq, bq),
              delta.reshape(BH, Sq // bq, bq))
+    params = _flat_params(qf, kf, vf, bq, bk)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_k=bk, kv_seq_len=Sk,
@@ -670,6 +743,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
                   _stat_row_spec(bq)],
         out_specs=_row_spec(bq, D, "blocked"),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
+        compiler_params=params,
         interpret=interpret,
         name="flash_dq",
     )(*offs, qf, kf, vf, dof, *rows)
@@ -689,6 +763,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
                    _row_spec(bk, Dv, "blocked")),
         out_shape=(jax.ShapeDtypeStruct((BH, Sk, D), qf.dtype),
                    jax.ShapeDtypeStruct((BH, Sk, Dv), qf.dtype)),
+        compiler_params=params,
         interpret=interpret,
         name="flash_dkv",
     )(*offs, qf, kf, vf, dof, *tiles)
@@ -778,35 +853,44 @@ def check_window(causal, window):
 
 def _chunked(q, k, v, causal, window, block_q, block_k, operand_dtype,
              interpret, chunk):
-    """Attention over a sequence longer than one kernel call holds:
-    queries and keys in chunks of ``chunk`` rows, one partial
-    (:func:`_partial`, :func:`flash_chunk`'s call) a chunk pair in
-    which the mask leaves anything to see (10 of 16 pairs for a causal
-    call at 4 chunks, 7 with a window of one chunk), its tile walk
-    bounded by the pair's GLOBAL origins, the pairs of one query chunk
-    folded by lse (:func:`flash_resume`'s fold).  No S × S array
-    anywhere; every partial carries the forward rule's two names, so a
-    layer's checkpoint keeps them all and its recompute calls no
-    kernel."""
+    """Attention over a sequence longer than one kernel call holds
+    (:func:`call_rows`: one that does not fit ``VMEM_BUDGET``, or any
+    longer than the caller's ``chunk``): queries and keys in chunks of
+    ``chunk`` rows, one partial (:func:`_partial`, :func:`flash_chunk`'s
+    call) a chunk pair in which the mask leaves anything to see (10 of
+    16 pairs for a causal call at 4 chunks, 7 with a window of one
+    chunk), its tile walk bounded by the pair's GLOBAL origins, the
+    pairs of one query chunk folded by lse (:func:`flash_resume`'s
+    fold).  No S × S array anywhere; every partial carries the forward
+    rule's two names, so a layer's checkpoint keeps them all and its
+    recompute calls no kernel.  Each trace counts its partials into
+    ``attention.flash.calls`` labelled ``path="chunked"``."""
     S = q.shape[1]
-    outs = []
+    outs, calls = [], 0
     for q0 in range(0, S, chunk):
         carry = None
         for k0 in range(0, S, chunk):
             if not flash_tiles(chunk, chunk, chunk, chunk, q0, k0,
                                causal, window)[0]:
                 continue
+            calls += 1
             carry = _fold(carry, _partial(
                 q[:, q0:q0 + chunk], k[:, k0:k0 + chunk],
                 v[:, k0:k0 + chunk], causal, q0, k0, None, block_q,
                 block_k, operand_dtype, interpret, window))
         outs.append(carry[0].astype(q.dtype))
+    _count_calls("chunked", calls)
     return jnp.concatenate(outs, axis=1)
+
+
+def _count_calls(path, calls):
+    resilience.stats.registry.counter(
+        "attention.flash.calls", {"path": path}).inc(calls)
 
 
 def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
                      block_k=None, operand_dtype=None,
-                     interpret=False, window=None, chunk=MAX_SEQ):
+                     interpret=False, window=None, chunk=None):
     """Flash attention over (B, S, H, D), differentiable (custom
     VJP).  Block shapes default to the geometry-tuned constants,
     shrunk to the largest power-of-two divisor of S — callers outside
@@ -815,9 +899,13 @@ def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
     ``operand_dtype``: matmul operand dtype — bf16 (default, the MXU
     contract) or f32 (the exact-parity test mode).  ``window``: a
     causal row sees the ``window`` columns up to its own (None: all
-    of them).  ``chunk``: how many rows one kernel call holds (the
-    tests walk small sequences in small chunks); past it the call is
-    :func:`_chunked`."""
+    of them).  ``chunk``: how many rows one kernel call holds — None:
+    :func:`call_rows` of what the call observes (S, the two heads, the
+    operands' bytes), the whole sequence wherever it fits; the tests
+    force small chunks on small sequences.  Past it the call is
+    :func:`_chunked`.  Each trace counts the kernel calls it makes
+    into ``attention.flash.calls`` labelled ``path="whole"`` (one) or
+    ``"chunked"`` (one a visible chunk pair)."""
     B, S, H, D = q.shape
     if not supports(q.shape, k.shape, kv_len, v.shape):
         raise ValueError(
@@ -825,6 +913,10 @@ def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
             "contract — use ops.attention.blockwise_attention" %
             (q.shape, kv_len))
     window = check_window(causal, window)
+    if chunk is None:
+        chunk = call_rows(S, D, v.shape[3],
+                          max(x.dtype.itemsize for x in (q, k, v)),
+                          block_q, block_k)
     bq = _pick_block(min(S, chunk), block_q or DEFAULT_BLOCK_Q)
     bk = _pick_block(min(S, chunk), block_k or DEFAULT_BLOCK_K)
     od = jnp.dtype(operand_dtype or jnp.bfloat16).type
@@ -844,6 +936,7 @@ def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
     if S > chunk:
         return _chunked(q, k, v, bool(causal), window, bq, bk, od,
                         bool(interpret), chunk)
+    _count_calls("whole", 1)
     zero = jnp.zeros((1, 1), jnp.float32)
     out, _lse = _flash_lse(q, k, v, zero, zero, bool(causal),
                            kv_len, bq, bk, od, bool(interpret), window)
