@@ -150,6 +150,15 @@ def _joyai_body():
     return {"layers": layers, "mtp": mtp, "tied_head": False}
 
 
+def _sdar_body():
+    from veles_tpu.znicz.samples.sdar import sdar_layers
+    return {"layers": sdar_layers(2, n_heads=2, kv_heads=1, head_dim=8,
+                                  moe_intermediate_size=16, n_experts=4,
+                                  top_k=2, held=(0, 2)),
+            "tied_head": False,
+            "diffusion": {"block": 4, "eps": 1e-3, "mask_id": 31}}
+
+
 SPEC_BODIES = {
     "lfm2": (_lfm2_body, {
         "block0": ("ln1", "shortconv", "ln2", "mlp"),
@@ -172,6 +181,10 @@ SPEC_BODIES = {
                    "attention", "ln2", "mlp"),
         "mtp": ("mla_compress", "mla_expand", "rope", "attention",
                 "moe_route", "moe_experts", "moe_shared")}),
+    "sdar": (_sdar_body, {
+        "embedding": ("bd_noise",),
+        "block0": ("ln1", "rope", "attention", "ln2", "moe_route",
+                   "moe_experts")}),
 }
 
 
@@ -186,10 +199,12 @@ def test_spec_built_layers_name_their_inner_scopes(sample):
     sequence / chunk steps the gauges ``linear_attention.scan_steps``
     / ``.chunk`` report; ``samples/joyai_llm_flash.py``: latent
     attention's compressions and expansions, in the body and in the
-    MTP module, a unit of its own), and the scope table places each
-    in every phase — but the routing's ordering, which has no
-    gradient, and the gather of the rows, which the layers' checkpoint
-    keeps (docs/moe.md): no recompute holds it."""
+    MTP module, a unit of its own; ``samples/sdar.py``: block
+    diffusion's noising in the embedding), and the scope table places
+    each in every phase — but the routing's ordering, which has no
+    gradient, the gather of the rows, which the layers' checkpoint
+    keeps (docs/moe.md): no recompute holds it, and the noising, which
+    no gradient reaches and no checkpoint wraps: forward alone."""
     body, inner = SPEC_BODIES[sample]
     root.common.engine.remat = True
     made = body()
@@ -201,7 +216,8 @@ def test_spec_built_layers_name_their_inner_scopes(sample):
     for unit, scopes in inner.items():
         for scope in scopes:
             for phase in ("forward", "recompute", "backward"):
-                if (scope, phase) == ("moe_route", "backward"):
+                if (scope, phase) == ("moe_route", "backward") or \
+                        (scope == "bd_noise" and phase != "forward"):
                     continue
                 if (scope, phase) == ("moe_dispatch", "recompute"):
                     assert (phase, unit, scope) not in placed

@@ -47,6 +47,11 @@ QWEN3_NEXT = (1, 8192, 16, 256)
 #: whose values are 128.
 JOYAI = (1, 8192, 32, 192)
 JOYAI_VALUES = 128
+#: ``sdar-30b.train-4k``: two streams of 4,096 ids (noised, clean) in
+#: blocks of 4 — 8,192 rows, the 4 kv heads broadcast to 32 query heads
+#: of 128.
+SDAR = (1, 8192, 32, 128)
+SDAR_DIFFUSION = (4096, 4)
 #: Decode: one new token per row over a 2048-slot gathered table.
 DECODE_L = 2048
 #: Ring shard: S=1024 over a 2-way seq axis.
@@ -379,6 +384,38 @@ def test_whole_sequence_kernels_compile_under_their_vmem_limit(
     assert chunked.as_text().count("tpu_custom_call") == 30
     assert whole.memory_analysis().temp_size_in_bytes < \
         chunked.memory_analysis().temp_size_in_bytes
+
+
+def test_block_diffusion_kernels_compile_as_one_call_each(one_chip):
+    """``sdar-30b.train-4k``'s call, forward + backward over the 8,192
+    rows of the two streams: ONE call of each kernel, each stating the
+    scoped VMEM limit the causal call at this shape states, the walk's
+    bounds and the mask judged inside the kernels (no (2L)² mask
+    among the operands), and a trace counts 80 of 256 tiles."""
+    from veles_tpu import resilience
+    from veles_tpu.ops import pallas_attention as PA
+    B, S, H, D = SDAR
+    limit = -(-2 * PA.call_bytes(S, D, D, 2, 512, 512) >> 20) << 20
+    label = {"mask": "block_diffusion"}
+    counter = resilience.stats.registry.counter
+    before = [counter("attention.flash.tiles_" + what, label).value
+              for what in ("visited", "total")]
+
+    def loss(q, k, v):
+        return PA.pallas_attention(
+            q, k, v, diffusion=SDAR_DIFFUSION,
+            operand_dtype=jnp.bfloat16).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2))
+    x = _struct(SDAR, jnp.bfloat16, one_chip)
+    assert _vmem_limits(fn, x, x, x) == [limit] * 3
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    after = [counter("attention.flash.tiles_" + what, label).value
+             for what in ("visited", "total")]
+    traces = (after[1] - before[1]) // 256
+    assert traces >= 1 and after[0] - before[0] == 80 * traces
 
 
 def _vmem_limits(fn, *structs):

@@ -93,6 +93,10 @@ def _unit_entry(unit):
                   "export has no forward, cached or paged program "
                   "for a layer built from a spec (docs/attention.md, "
                   "\"Layers from a spec\")" % (unit.name, mapping))
+    if mapping == "embedding" and getattr(unit, "diffusion", None):
+        raise Bug("unit %s: block diffusion trains but is not served yet "
+                  "— a step that fills a block over several denoising "
+                  "steps is not in export (samples/sdar.py)" % unit.name)
     if mapping == "embedding" and getattr(unit, "scale", 1.0) != 1.0:
         raise Bug("unit %s: an embedding scaled by %r trains but is "
                   "not served yet (samples/trinity.py)" %

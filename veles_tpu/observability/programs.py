@@ -8,8 +8,8 @@ under ``jax.named_scope(unit.scope_name)``, the update rules under
 function opens ``ln1`` / ``attention`` / ``ln2`` / ``mlp`` (and
 ``rope``, ``shortconv``, ``moe_*``, ``attn_gate``, ``ln1_post`` /
 ``ln2_post``, ``gdn_gate``, ``gated_delta``, ``gdn_norm``,
-``mla_compress``, ``mla_expand`` where a spec asks for them) inside
-a unit; JAX adds ``jvp(...)``, ``transpose(jvp(...))`` and the
+``mla_compress``, ``mla_expand`` where a spec asks for them; the
+embedding's ``bd_noise`` under block diffusion) inside a unit; JAX adds ``jvp(...)``, ``transpose(jvp(...))`` and the
 checkpoint's ``rematted_computation`` by itself.  This module keeps,
 per program name (``block_step``, ``train_step``, ``infer_step``),
 what it takes to read that back::
@@ -45,7 +45,7 @@ INNER_SCOPES = ("ln1", "attention", "ln2", "mlp", "rope", "shortconv",
                 "moe_route", "moe_dispatch", "moe_experts",
                 "moe_combine", "attn_gate", "moe_shared", "ln1_post",
                 "ln2_post", "gdn_gate", "gated_delta", "gdn_norm",
-                "mla_compress", "mla_expand")
+                "mla_compress", "mla_expand", "bd_noise")
 
 _lock = threading.Lock()
 _programs = {}

@@ -165,7 +165,7 @@ def _ring_kernel_mode():
 
 
 def _selects_pallas(q_shape, k_shape, kv_len=None, mode=None,
-                    v_shape=None):
+                    v_shape=None, diffusion=None):
     """Whether attention at this geometry runs the Pallas flash
     kernel: the knob (or the explicit ``mode`` override) asks for
     it, the backend is a TPU and the geometry — values of their own
@@ -175,12 +175,12 @@ def _selects_pallas(q_shape, k_shape, kv_len=None, mode=None,
     identically, so a CPU test run with the flag on still exercises
     the reference path."""
     return (mode or _kernel_mode()) != "xla" and \
-        PA.supports(q_shape, k_shape, kv_len, v_shape) and \
+        PA.supports(q_shape, k_shape, kv_len, v_shape, diffusion) and \
         tpu_available()
 
 
 def _try_pallas(q, k, v, causal, kv_len=None, mode=None,
-                precision=None, window=None):
+                precision=None, window=None, diffusion=None):
     """Runs the Pallas flash kernel where :func:`_selects_pallas`
     says so; returns None (→ caller runs the jnp formulation)
     otherwise.  Once selected, a kernel that fails to lower raises —
@@ -191,13 +191,17 @@ def _try_pallas(q, k, v, causal, kv_len=None, mode=None,
     flip table documents.  Each TRACE of either choice counts into
     ``attention.kernel.pallas`` / ``attention.kernel.xla``, so a run
     can say which formulation its programs were built from."""
-    if not _selects_pallas(q.shape, k.shape, kv_len, mode, v.shape):
+    if not _selects_pallas(q.shape, k.shape, kv_len, mode, v.shape,
+                           diffusion):
         resilience.stats.incr("attention.kernel.xla")
         return None
     resilience.stats.incr("attention.kernel.pallas")
+    # the mode rides along only where it is set: a causal or window
+    # call is the call it was
+    mode = {} if diffusion is None else {"diffusion": diffusion}
     return PA.pallas_attention(
         q, k, v, causal=causal, kv_len=kv_len, window=window,
-        operand_dtype=attention_compute_dtype(precision))
+        operand_dtype=attention_compute_dtype(precision), **mode)
 
 
 def _block_update(acc, m, l, q, k, v, *, scale, mask=None):
@@ -258,7 +262,7 @@ def _causal_mask(sq, sk, q_offset, k_offset, window=None):
 
 
 def attention(q, k, v, causal=False, precision=None, kernel=None,
-              window=None):
+              window=None, diffusion=None):
     """Full O(S²)-memory attention (B, S, H, D) — the reference
     formulation the streaming variants are tested against.
 
@@ -284,7 +288,14 @@ def attention(q, k, v, causal=False, precision=None, kernel=None,
     same shapes as for full multi-head attention; autodiff sums dk
     and dv over the group.  Values may have a head width of their own
     (latent attention: q and k 192 wide, v 128); the output has
-    theirs."""
+    theirs.
+
+    ``diffusion`` ``(L, B)`` (static, in place of ``causal``): block
+    diffusion over two streams of ``L`` rows, noised then clean, in
+    blocks of ``B`` positions (``pallas_attention.diffusion_visible``
+    says which key a row sees); the kernels walk only the tiles that
+    hold a visible pair, and this formulation builds the same mask
+    from the same function."""
     if k.shape[2] != q.shape[2]:
         group = q.shape[2] // k.shape[2]
         if group * k.shape[2] != q.shape[2] or v.shape[2] != k.shape[2]:
@@ -294,14 +305,21 @@ def attention(q, k, v, causal=False, precision=None, kernel=None,
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
     PA.check_window(causal, window)
+    diffusion = PA.check_diffusion(diffusion, causal, window,
+                                   seq=q.shape[1])
     out = _try_pallas(q, k, v, causal, mode=kernel,
-                      precision=precision, window=window)
+                      precision=precision, window=window,
+                      diffusion=diffusion)
     if out is not None:
         return out
     dt = attention_compute_dtype(precision)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     mask = _causal_mask(q.shape[1], k.shape[1], 0, 0, window) \
         if causal else None
+    if diffusion is not None:
+        rows = jnp.arange(q.shape[1])
+        mask = PA.diffusion_visible(rows[:, None], rows[None, :],
+                                    *diffusion)
     B, Sq, H = q.shape[:3]
     acc = jnp.zeros((B, Sq, H, v.shape[-1]), dt)
     m = jnp.full((B, Sq, H), NEG_INF, jnp.float32)
@@ -312,7 +330,7 @@ def attention(q, k, v, causal=False, precision=None, kernel=None,
 
 
 def mesh_attention(q, k, v, mesh, causal=False, batch_axis=None,
-                   head_axis=None, window=None):
+                   head_axis=None, window=None, diffusion=None):
     """:func:`attention` inside a step that GSPMD partitions over
     ``mesh``.  The XLA formulation partitions by itself.  A Mosaic
     kernel does not ("Mosaic kernels cannot be automatically
@@ -323,8 +341,10 @@ def mesh_attention(q, k, v, mesh, causal=False, batch_axis=None,
     (B/n, S, H/m, D) shard and no collective is needed inside.  An
     axis the mesh lacks, or that does not divide its dimension,
     leaves that dimension whole."""
-    if not _selects_pallas(q.shape, k.shape, v_shape=v.shape):
-        return attention(q, k, v, causal=causal, window=window)
+    if not _selects_pallas(q.shape, k.shape, v_shape=v.shape,
+                           diffusion=diffusion):
+        return attention(q, k, v, causal=causal, window=window,
+                         diffusion=diffusion)
     from jax.sharding import PartitionSpec as P
 
     def fits(axis, dim):
@@ -333,7 +353,8 @@ def mesh_attention(q, k, v, mesh, causal=False, batch_axis=None,
 
     spec = P(fits(batch_axis, 0), None, fits(head_axis, 2), None)
     fn = jax.shard_map(
-        functools.partial(attention, causal=causal, window=window),
+        functools.partial(attention, causal=causal, window=window,
+                          diffusion=diffusion),
         mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return fn(q, k, v)

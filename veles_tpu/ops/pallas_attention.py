@@ -50,6 +50,18 @@ choices, for exactly one geometry family:
     (masked) — so a window layer's work follows the window: at (512,
     512) tiles a causal call at 8,192 visits 136 of 256 tiles, a
     call with a window of 2,048 visits 70;
+  * a BLOCK-DIFFUSION call (``diffusion=(L, B)``, :func:`diffusion_visible`)
+    runs two streams of ``L`` rows — noised rows 0 … L − 1, clean rows
+    L … 2L − 1, both at positions 0 … L − 1, in blocks of ``B`` —
+    through ONE call: a noised row sees the noised keys of its own
+    block and the clean keys of earlier blocks, a clean row the clean
+    keys of its own and earlier blocks and no noised key.  The walk
+    (:func:`_bd_key_stretches`, :func:`_query_stretches`' mirror
+    :func:`_bd_query_stretches`) visits only the tiles that hold a
+    visible pair, masked where an edge crosses them, and the mask is
+    judged in the kernel from (row, col) → (stream, position): at (512,
+    512) tiles over 8,192 rows 80 of 256 tiles, where a causal call
+    visits 136;
   * matmul operands are bf16 (MXU-native), accumulation f32
     (``preferred_element_type``), the online-softmax statistics f32 —
     the same contract as ops/attention's bf16 mode;
@@ -218,7 +230,8 @@ def _head_fits(D):
         (D % LANE == 0 and 0 < D <= 4 * LANE)
 
 
-def supports(q_shape, k_shape, kv_len=None, v_shape=None):
+def supports(q_shape, k_shape, kv_len=None, v_shape=None,
+             diffusion=None):
     """Whether the kernel's geometry contract holds: self-attention
     ((B, S, H, D) with equal q/k sequence and head), D 64, 192 or a
     multiple of 128 up to 512 (:func:`_head_fits`), S tile-aligned up
@@ -228,7 +241,14 @@ def supports(q_shape, k_shape, kv_len=None, v_shape=None):
     head of its own, ``D_v``, within the same rule — latent
     attention's keys are 192 wide and its values 128.  ``kv_len``
     (the blockwise padding contract) is supported as a static mask
-    bound, within ``SCOPED_SEQ``."""
+    bound, within ``SCOPED_SEQ``.  ``diffusion`` ``(L, B)``: two
+    streams of ``L`` rows (S = 2L) in blocks of a power of two, with
+    no ``kv_len``."""
+    if diffusion is not None:
+        L, B = diffusion
+        if len(q_shape) != 4 or q_shape[1] != 2 * L or kv_len is not None \
+                or B < 1 or B & (B - 1):
+            return False
     v_shape = k_shape if v_shape is None else v_shape
     if len(q_shape) != 4 or len(k_shape) != 4 or len(v_shape) != 4:
         return False
@@ -418,6 +438,155 @@ def _query_stretches(causal, gcols0, bk, grows0, bq, nq, window=None):
             (inside, hi, True))
 
 
+def _select(cond, a, b):
+    """``a if cond else b`` for Python values and traced scalars alike."""
+    if isinstance(cond, bool):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+def _block_of(pos, block):
+    """``pos // block`` for a power-of-two ``block`` as a shift, which
+    the vector unit has and an integer division it lacks; any other
+    block (the XLA formulation's) by division."""
+    if block & (block - 1):
+        return pos // block
+    return pos >> (block.bit_length() - 1)
+
+
+def diffusion_visible(rows, cols, stream, block):
+    """Block diffusion's mask: True where query row ``rows`` sees key
+    row ``cols`` (integer arrays or ints, broadcast together) over two
+    streams of ``stream`` rows — noised rows ``0 … stream − 1``, clean
+    rows ``stream … 2·stream − 1``, both at positions ``0 … stream −
+    1``, blocks ``β(p) = p // block``.  A noised row sees the noised
+    keys of its own block and the clean keys of EARLIER blocks; a
+    clean row the clean keys of its own and earlier blocks and no
+    noised key.  The kernels judge their tiles by it and the XLA
+    formulation builds its mask from it."""
+    r_clean, c_clean = rows >= stream, cols >= stream
+    rb = _block_of(jnp.where(r_clean, rows - stream, rows), block)
+    cb = _block_of(jnp.where(c_clean, cols - stream, cols), block)
+    # boolean algebra, not a select of booleans: Mosaic has no
+    # truncation to i1 for the latter
+    return (c_clean & (cb <= rb) & (r_clean | (cb < rb))) | \
+        (~r_clean & ~c_clean & (cb == rb))
+
+
+def _bd_mask_tile(grows0, gcols0, bq, bk, diffusion, transposed=False):
+    """(bq, bk) — (bk, bq) when ``transposed`` — block-diffusion mask
+    of one score tile at GLOBAL row / column origins."""
+    shape, qdim, kdim = ((bk, bq), 1, 0) if transposed else \
+        ((bq, bk), 0, 1)
+    rows = grows0 + jax.lax.broadcasted_iota(jnp.int32, shape, qdim)
+    cols = gcols0 + jax.lax.broadcasted_iota(jnp.int32, shape, kdim)
+    return diffusion_visible(rows, cols, *diffusion)
+
+
+def _bd_stretches(intervals, origin, b, n):
+    """Block stretches of a walk over ``n`` blocks of ``b`` rows whose
+    first row is global row ``origin``, from ascending intervals
+    ``(lo, hi, full_lo, full_hi)`` of GLOBAL rows: the walk visits the
+    blocks that hold a row of ``[lo, hi)``, unmasked those wholly
+    inside ``[full_lo, full_hi)`` (the rows that see the other side's
+    whole block), masked the rest.  A block two intervals share is
+    visited once, by the first; an empty interval adds nothing."""
+    out, prev = [], 0
+    for interval in intervals:
+        lo, hi, flo, fhi = (_clip(x - origin, 0, n * b) for x in interval)
+        lo_b = _clip(lo // b, prev, n)
+        hi_b = _clip(_select(hi > lo, -(-hi // b), lo_b), lo_b, n)
+        f_lo = _clip(-(-flo // b), lo_b, hi_b)
+        f_hi = _clip(fhi // b, f_lo, hi_b)
+        out += [(lo_b, f_lo, True), (f_lo, f_hi, False), (f_hi, hi_b, True)]
+        prev = hi_b
+    return tuple(out)
+
+
+def _min(a, b):
+    return _select(a < b, a, b)
+
+
+def _max(a, b):
+    return _select(a < b, b, a)
+
+
+def _bd_key_stretches(grows0, bq, gcols0, bk, nk, diffusion):
+    """The key blocks ONE query block walks under block diffusion
+    (rows ``grows0 … grows0 + bq − 1``; key block ``j`` holds columns
+    ``gcols0 + j·bk …``): the noised keys of the noised rows' own
+    blocks, masked; then the clean keys that some row sees, ``L + [0,
+    V)``, unmasked where every row of the block sees them, ``L + [0,
+    F)``.  A query block may hold rows of both streams."""
+    L, B = diffusion
+    r1 = grows0 + bq
+    has_n, has_c = grows0 < L, r1 > L
+    n_lo, n_hi = grows0, _min(r1, L) - 1
+    c_lo, c_hi = _max(grows0, L) - L, r1 - 1 - L
+    noised = (_select(has_n, B * _block_of(n_lo, B), 0),
+              _select(has_n, _min(B * (_block_of(n_hi, B) + 1), L), 0),
+              0, 0)
+    seen = _max(_select(has_n, B * _block_of(n_hi, B), 0),
+                _select(has_c, _min(B * (_block_of(c_hi, B) + 1), L), 0))
+    whole = _min(_select(has_n, B * _block_of(n_lo, B), L),
+                 _select(has_c, _min(B * (_block_of(c_lo, B) + 1), L), L))
+    clean = (L, L + seen, L, L + whole)
+    return _bd_stretches((noised, clean), gcols0, bk, nk)
+
+
+def _bd_query_stretches(gcols0, bk, grows0, bq, nq, diffusion):
+    """The query blocks ONE key block walks under block diffusion (the
+    dk/dv kernel's loop; the mirror of :func:`_bd_key_stretches`): the
+    noised rows that see a key of it — of its noised keys' blocks, or
+    after its clean keys' first block — then the clean rows from its
+    clean keys' first block on.  Rows see the whole key block only
+    where it holds clean keys alone."""
+    L, B = diffusion
+    k1 = gcols0 + bk
+    has_n, has_c = gcols0 < L, k1 > L
+    kn_lo, kn_hi = gcols0, _min(k1, L) - 1
+    pc_lo, pc_hi = _max(gcols0, L) - L, k1 - 1 - L
+    after = B * (_block_of(pc_lo, B) + 1)
+    later = has_c & (after < L)
+    lo = _min(_select(has_n, B * _block_of(kn_lo, B), L),
+              _select(later, after, L))
+    hi = _max(_select(has_n, _min(B * (_block_of(kn_hi, B) + 1), L), 0),
+              _select(later, L, 0))
+    full = _select(has_n, lo, B * (_block_of(pc_hi, B) + 1))
+    noised = (lo, hi, full, _select(has_n, lo, L))
+    first = _select(has_c, L + B * _block_of(pc_lo, B), 2 * L)
+    full = _select(has_n, first, L + B * _block_of(pc_hi, B))
+    clean = (first, 2 * L, full, _select(has_n, first, 2 * L))
+    return _bd_stretches((noised, clean), grows0, bq, nq)
+
+
+def _tile_mask(grows0, gcols0, lcols0, bq, bk, diagonal, kv_len, window,
+               diffusion, transposed=False):
+    """A tile's mask in the call's mode: :func:`_mask_tile`'s, or where
+    ``diagonal`` says an edge crosses it :func:`_bd_mask_tile`'s."""
+    if diffusion is None:
+        return _mask_tile(grows0, gcols0, lcols0, bq, bk, diagonal, kv_len,
+                          transposed=transposed, window=window)
+    return _bd_mask_tile(grows0, gcols0, bq, bk, diffusion,
+                         transposed) if diagonal else None
+
+
+def _keys_walk(causal, grows0, bq, gcols0, bk, nk, window, diffusion):
+    """The key stretches of a query block in the call's mode."""
+    if diffusion is None:
+        return _key_stretches(causal, grows0, bq, gcols0, bk, nk,
+                              window=window)
+    return _bd_key_stretches(grows0, bq, gcols0, bk, nk, diffusion)
+
+
+def _queries_walk(causal, gcols0, bk, grows0, bq, nq, window, diffusion):
+    """The query stretches of a key block in the call's mode."""
+    if diffusion is None:
+        return _query_stretches(causal, gcols0, bk, grows0, bq, nq,
+                                window=window)
+    return _bd_query_stretches(gcols0, bk, grows0, bq, nq, diffusion)
+
+
 def _walk(stretches, tile, carry):
     """Folds ``tile(index, carry, diagonal)`` over the stretches
     (``diagonal``: whether an edge of the mask crosses the tile)."""
@@ -428,7 +597,7 @@ def _walk(stretches, tile, carry):
 
 
 def flash_tiles(q_len, k_len, block_q, block_k, q_offset=0,
-                k_offset=0, causal=True, window=None):
+                k_offset=0, causal=True, window=None, diffusion=None):
     """``(visited, total)`` score tiles of ONE (batch·head) slice of
     a forward call: what the key loops of its ``q_len // block_q``
     programs visit, and what the grid holds (the dq kernel walks the
@@ -436,18 +605,18 @@ def flash_tiles(q_len, k_len, block_q, block_k, q_offset=0,
     Static offsets only — it is the count a trace records
     (``attention.flash.tiles_visited`` / ``.tiles_total``) and the
     tests pin; the kernels get their bounds from the same
-    :func:`_key_stretches`."""
+    :func:`_key_stretches` (:func:`_bd_key_stretches` under
+    ``diffusion``)."""
     nq, nk = q_len // block_q, k_len // block_k
-    visited = sum(
-        hi - lo for i in range(nq) for lo, hi, _ in _key_stretches(
-            causal, q_offset + i * block_q, block_q, k_offset,
-            block_k, nk, window=window))
+    visited = sum(hi - lo for i in range(nq) for lo, hi, _ in _keys_walk(
+        causal, q_offset + i * block_q, block_q, k_offset, block_k, nk,
+        window, diffusion))
     return visited, nq * nk
 
 
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
                 lse_ref, *, scale, causal, kv_len, block_k,
-                kv_seq_len, od, window=None):
+                kv_seq_len, od, window=None, diffusion=None):
     from jax.experimental import pallas as pl
     bq = q_ref.shape[1]
     Dv = v_ref.shape[2]
@@ -461,9 +630,9 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
         kb = k_ref[0, pl.ds(j * block_k, block_k), :]
         vb = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot(q, kb, od, trans_b=True) * scale
-        mask = _mask_tile(grows0, koff + j * block_k, j * block_k,
-                          bq, block_k, diagonal, kv_len,
-                          window=window)
+        mask = _tile_mask(grows0, koff + j * block_k, j * block_k,
+                          bq, block_k, diagonal, kv_len, window,
+                          diffusion)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         bm = s.max(axis=1, keepdims=True)
@@ -479,8 +648,8 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
     # A chunk wholly after the queries is walked zero times and
     # leaves the carry as it starts: out 0 and lse ≈ -1e30 below.
     acc, m, l = _walk(
-        _key_stretches(causal, grows0, bq, koff, block_k,
-                       kv_seq_len // block_k, window=window),
+        _keys_walk(causal, grows0, bq, koff, block_k,
+                   kv_seq_len // block_k, window, diffusion),
         tile,
         (jnp.zeros((bq, Dv), jnp.float32),
          jnp.full((bq, 1), NEG_INF, jnp.float32),
@@ -498,7 +667,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                lse_ref, delta_ref, dq_ref, *, scale, causal, kv_len,
-               block_k, kv_seq_len, od, window=None):
+               block_k, kv_seq_len, od, window=None, diffusion=None):
     from jax.experimental import pallas as pl
     bq = q_ref.shape[1]
     D = q_ref.shape[2]
@@ -514,9 +683,9 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         kb = k_ref[0, pl.ds(j * block_k, block_k), :]
         vb = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot(q, kb, od, trans_b=True) * scale
-        mask = _mask_tile(grows0, koff + j * block_k, j * block_k,
-                          bq, block_k, diagonal, kv_len,
-                          window=window)
+        mask = _tile_mask(grows0, koff + j * block_k, j * block_k,
+                          bq, block_k, diagonal, kv_len, window,
+                          diffusion)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse)
@@ -527,14 +696,15 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         return dq + _dot(ds, kb, od)
 
     dq_ref[0] = _walk(
-        _key_stretches(causal, grows0, bq, koff, block_k,
-                       kv_seq_len // block_k, window=window),
+        _keys_walk(causal, grows0, bq, koff, block_k,
+                   kv_seq_len // block_k, window, diffusion),
         tile, jnp.zeros((bq, D), jnp.float32)).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 lse_ref, delta_ref, dk_ref, dv_ref, *, scale, causal,
-                kv_len, block_q, q_seq_len, od, window=None):
+                kv_len, block_q, q_seq_len, od, window=None,
+                diffusion=None):
     from jax.experimental import pallas as pl
     bk = k_ref.shape[1]
     D, Dv = k_ref.shape[2], v_ref.shape[2]
@@ -555,9 +725,9 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         lse = lse_ref[0, pl.ds(i, 1), :]
         delta = delta_ref[0, pl.ds(i, 1), :]
         st = _dot(k, qb, od, trans_b=True) * scale
-        mask = _mask_tile(qoff + i * block_q, gcols0, lcols0,
-                          block_q, bk, diagonal, kv_len,
-                          transposed=True, window=window)
+        mask = _tile_mask(qoff + i * block_q, gcols0, lcols0, block_q,
+                          bk, diagonal, kv_len, window, diffusion,
+                          transposed=True)
         if mask is not None:
             st = jnp.where(mask, st, NEG_INF)
         pt = jnp.exp(st - lse)
@@ -570,8 +740,8 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         return dk, dv
 
     dk, dv = _walk(
-        _query_stretches(causal, gcols0, bk, qoff, block_q,
-                         q_seq_len // block_q, window=window),
+        _queries_walk(causal, gcols0, bk, qoff, block_q,
+                      q_seq_len // block_q, window, diffusion),
         tile,
         (jnp.zeros((bk, D), jnp.float32),
          jnp.zeros((bk, Dv), jnp.float32)))
@@ -683,7 +853,7 @@ def _flat_params(qf, kf, vf, bq, bk):
 
 
 def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
-                    od, interpret, window=None):
+                    od, interpret, window=None, diffusion=None):
     """(BH, Sq, D) × (BH, Sk, D) forward against values (BH, Sk, D_v):
     returns (out (BH, Sq, D_v), lse)."""
     from jax.experimental import pallas as pl
@@ -692,7 +862,8 @@ def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
     scale = 1.0 / (D ** 0.5)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              kv_len=kv_len, block_k=bk,
-                             kv_seq_len=Sk, od=od, window=window)
+                             kv_seq_len=Sk, od=od, window=window,
+                             diffusion=diffusion)
     out, lse = pl.pallas_call(
         kern,
         grid=(BH, Sq // bq),
@@ -712,7 +883,8 @@ def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
 
 
 def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
-                    causal, kv_len, bq, bk, od, interpret, window=None):
+                    causal, kv_len, bq, bk, od, interpret, window=None,
+                    diffusion=None):
     from jax.experimental import pallas as pl
     BH, Sq, D = qf.shape
     Sk, Dv = kf.shape[1], vf.shape[2]
@@ -732,7 +904,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_k=bk, kv_seq_len=Sk,
-                          od=od, window=window),
+                          od=od, window=window, diffusion=diffusion),
         grid=(BH, Sq // bq),
         in_specs=[_off_spec(), _off_spec(),
                   _row_spec(bq, D, "blocked"),
@@ -750,7 +922,7 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_q=bq, q_seq_len=Sq,
-                          od=od, window=window),
+                          od=od, window=window, diffusion=diffusion),
         grid=(BH, Sk // bk),
         in_specs=[_off_spec(), _off_spec(),
                   _row_spec(Sq, D, "whole"),
@@ -794,24 +966,24 @@ def _lse_to_flat(l):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9,
-                                                    10, 11))
+                                                    10, 11, 12))
 def _flash_lse(q, k, v, qoff, koff, causal, kv_len, bq, bk, od,
-               interpret, window=None):
+               interpret, window=None, diffusion=None):
     """The lse-carrying flash core: (out, lse) with a backward that
     recomputes probabilities from the saved lse.  ``qoff``/``koff``
     are (1, 1) f32 arrays (global causal origins, possibly traced —
     see :func:`_off_operand` for the shape/dtype contract)."""
     out, lse = _flash_lse_fwd(q, k, v, qoff, koff, causal, kv_len,
-                              bq, bk, od, interpret, window)[0]
+                              bq, bk, od, interpret, window, diffusion)[0]
     return out, lse
 
 
 def _flash_lse_fwd(q, k, v, qoff, koff, causal, kv_len, bq, bk, od,
-                   interpret, window=None):
+                   interpret, window=None, diffusion=None):
     B, Sq, H, D = q.shape
     of, lsef = _flash_fwd_flat(_to_flat(q), _to_flat(k), _to_flat(v),
                                qoff, koff, causal, kv_len, bq, bk,
-                               od, interpret, window)
+                               od, interpret, window, diffusion)
     # Named in the layout the backward reads, and everything below
     # derives from the named values: a checkpoint that saves the two
     # names has no use left for the kernel in its recompute.  q, k
@@ -823,15 +995,15 @@ def _flash_lse_fwd(q, k, v, qoff, koff, causal, kv_len, bq, bk, od,
     return (out, lse), (q, k, v, of, lsef, qoff, koff)
 
 
-def _flash_lse_bwd(causal, kv_len, bq, bk, od, interpret, window, res,
-                   ct):
+def _flash_lse_bwd(causal, kv_len, bq, bk, od, interpret, window,
+                   diffusion, res, ct):
     q, k, v, of, lsef, qoff, koff = res
     do, dlse = ct
     B, Sq, H, D = q.shape
     dqf, dkf, dvf = _flash_bwd_flat(
         _to_flat(q), _to_flat(k), _to_flat(v), of, _to_flat(do),
         lsef, _lse_to_flat(dlse), qoff, koff, causal, kv_len, bq, bk,
-        od, interpret, window)
+        od, interpret, window, diffusion)
     return (_from_flat(dqf, B, H), _from_flat(dkf, B, H),
             _from_flat(dvf, B, H), jnp.zeros((1, 1), jnp.float32),
             jnp.zeros((1, 1), jnp.float32))
@@ -851,8 +1023,26 @@ def check_window(causal, window):
     return int(window)  # lint-ok: VL101 static config int
 
 
+def check_diffusion(diffusion, causal=False, window=None, kv_len=None,
+                    seq=None):
+    """``diffusion`` as the kernels take it: None, or a static ``(L,
+    B)`` — two streams of ``L`` rows in blocks of ``B`` positions,
+    ``B`` dividing ``L`` — with no causal mask, window or ``kv_len``
+    beside it and, where ``seq`` is given, ``2 L`` rows."""
+    if diffusion is None:
+        return None
+    L, B = (int(x) for x in diffusion)  # lint-ok: VL101 static ints
+    if causal or window is not None or kv_len is not None or B < 1 or \
+            L % B or (seq is not None and seq != 2 * L):
+        raise ValueError(
+            "diffusion=%r over %r rows: two streams of L rows in blocks "
+            "of B, B dividing L, and no causal mask, window or kv_len "
+            "beside it" % (diffusion, seq))
+    return L, B
+
+
 def _chunked(q, k, v, causal, window, block_q, block_k, operand_dtype,
-             interpret, chunk):
+             interpret, chunk, diffusion=None):
     """Attention over a sequence longer than one kernel call holds
     (:func:`call_rows`: one that does not fit ``VMEM_BUDGET``, or any
     longer than the caller's ``chunk``): queries and keys in chunks of
@@ -871,13 +1061,13 @@ def _chunked(q, k, v, causal, window, block_q, block_k, operand_dtype,
         carry = None
         for k0 in range(0, S, chunk):
             if not flash_tiles(chunk, chunk, chunk, chunk, q0, k0,
-                               causal, window)[0]:
+                               causal, window, diffusion)[0]:
                 continue
             calls += 1
             carry = _fold(carry, _partial(
                 q[:, q0:q0 + chunk], k[:, k0:k0 + chunk],
                 v[:, k0:k0 + chunk], causal, q0, k0, None, block_q,
-                block_k, operand_dtype, interpret, window))
+                block_k, operand_dtype, interpret, window, diffusion))
         outs.append(carry[0].astype(q.dtype))
     _count_calls("chunked", calls)
     return jnp.concatenate(outs, axis=1)
@@ -890,7 +1080,8 @@ def _count_calls(path, calls):
 
 def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
                      block_k=None, operand_dtype=None,
-                     interpret=False, window=None, chunk=None):
+                     interpret=False, window=None, chunk=None,
+                     diffusion=None):
     """Flash attention over (B, S, H, D), differentiable (custom
     VJP).  Block shapes default to the geometry-tuned constants,
     shrunk to the largest power-of-two divisor of S — callers outside
@@ -905,14 +1096,17 @@ def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
     force small chunks on small sequences.  Past it the call is
     :func:`_chunked`.  Each trace counts the kernel calls it makes
     into ``attention.flash.calls`` labelled ``path="whole"`` (one) or
-    ``"chunked"`` (one a visible chunk pair)."""
+    ``"chunked"`` (one a visible chunk pair).  ``diffusion`` ``(L,
+    B)``: the block-diffusion mask over two streams of ``L`` rows
+    (:func:`diffusion_visible`) in place of a causal one."""
     B, S, H, D = q.shape
-    if not supports(q.shape, k.shape, kv_len, v.shape):
+    if not supports(q.shape, k.shape, kv_len, v.shape, diffusion):
         raise ValueError(
-            "geometry (%s, kv_len=%r) outside the pallas_attention "
-            "contract — use ops.attention.blockwise_attention" %
-            (q.shape, kv_len))
+            "geometry (%s, kv_len=%r, diffusion=%r) outside the "
+            "pallas_attention contract — use ops.attention's "
+            "formulations" % (q.shape, kv_len, diffusion))
     window = check_window(causal, window)
+    diffusion = check_diffusion(diffusion, causal, window, kv_len, S)
     if chunk is None:
         chunk = call_rows(S, D, v.shape[3],
                           max(x.dtype.itemsize for x in (q, k, v)),
@@ -926,20 +1120,24 @@ def pallas_attention(q, k, v, causal=False, kv_len=None, block_q=None,
     # Each TRACE counts what one (batch·head) slice of the forward
     # visits and what its grid holds, beside attention.kernel.pallas:
     # how far the causal schedule engages at this call's geometry.  A
-    # call with a window counts into series labelled with it.
+    # call with a window counts into series labelled with it, a
+    # block-diffusion call into series labelled mask="block_diffusion".
     visited, total = flash_tiles(S, S, bq, bk, causal=bool(causal),
-                                 window=window)
+                                 window=window, diffusion=diffusion)
     labels = None if window is None else {"window": str(window)}
+    if diffusion is not None:
+        labels = {"mask": "block_diffusion"}
     counter = resilience.stats.registry.counter
     counter("attention.flash.tiles_visited", labels).inc(visited)
     counter("attention.flash.tiles_total", labels).inc(total)
     if S > chunk:
         return _chunked(q, k, v, bool(causal), window, bq, bk, od,
-                        bool(interpret), chunk)
+                        bool(interpret), chunk, diffusion)
     _count_calls("whole", 1)
     zero = jnp.zeros((1, 1), jnp.float32)
     out, _lse = _flash_lse(q, k, v, zero, zero, bool(causal),
-                           kv_len, bq, bk, od, bool(interpret), window)
+                           kv_len, bq, bk, od, bool(interpret), window,
+                           diffusion)
     return out
 
 
@@ -979,7 +1177,7 @@ def flash_chunk(q, k, v, causal=False, q_offset=0, k_offset=0,
 
 
 def _partial(q, k, v, causal, q_offset, k_offset, kv_len, bq, bk, od,
-             interpret, window):
+             interpret, window, diffusion=None):
     """One partial ``(out, lse)`` at global origins, its geometry
     checked by the caller: :func:`flash_chunk` against the ring's
     contract, :func:`_chunked` against :func:`supports` (whose heads
@@ -988,7 +1186,7 @@ def _partial(q, k, v, causal, q_offset, k_offset, kv_len, bq, bk, od,
     qoff = jnp.asarray(q_offset, jnp.float32).reshape(1, 1)
     koff = jnp.asarray(k_offset, jnp.float32).reshape(1, 1)
     return _flash_lse(q, k, v, qoff, koff, bool(causal), kv_len, bq,
-                      bk, od, bool(interpret), window)
+                      bk, od, bool(interpret), window, diffusion)
 
 
 def merge_partials(o1, lse1, o2, lse2):

@@ -18,16 +18,19 @@ def rms_norm(x, gain, eps=1e-5):
     return (xf * scale * gain).astype(x.dtype)
 
 
-def rotary_tables(seq, head_dim, theta):
+def rotary_tables(seq, head_dim, theta, positions=None):
     """``(cos, sin)`` of shape (S, D / 2), float32, for positions
-    0 … S − 1 and the inverse frequencies ``θ^(−2i / D)``."""
+    0 … S − 1 (or the S ``positions`` given) and the inverse
+    frequencies ``θ^(−2i / D)``."""
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                       dtype=jnp.float32) / head_dim))
-    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
+    at = jnp.arange(seq, dtype=jnp.float32) if positions is None else \
+        jnp.asarray(positions, jnp.float32)
+    angles = at[:, None] * inv[None, :]
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def rotary(x, theta, fraction=None, interleave=False):
+def rotary(x, theta, fraction=None, interleave=False, positions=None):
     """Rotates (B, S, H, D) by position: the halves ``x1 = x[..., :D/2]``
     and ``x2 = x[..., D/2:]`` go to ``(x1 cos − x2 sin, x2 cos + x1
     sin)``.  ``fraction`` (None: the whole head) rotates the FIRST
@@ -37,10 +40,12 @@ def rotary(x, theta, fraction=None, interleave=False):
     ``interleave`` rotates the pairs ``(x[2i], x[2i + 1])`` in place of
     ``(x[i], x[i + D/2])`` at the same frequencies (``rope_interleave``
     of the DeepSeek-V3 checkpoints): the same function with the head's
-    columns permuted.  Float32 out."""
+    columns permuted.  ``positions`` (S,) (None: 0 … S − 1) are the
+    rows' positions: block diffusion's two streams both sit at 0 …
+    L − 1.  Float32 out."""
     D = x.shape[-1] if fraction is None else \
         math.floor(x.shape[-1] * fraction)
-    cos, sin = rotary_tables(x.shape[1], D, theta)
+    cos, sin = rotary_tables(x.shape[1], D, theta, positions)
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
     xf = x.astype(jnp.float32)
     if interleave:

@@ -399,14 +399,15 @@ def layer_param_shapes(spec, embed, fused_qkv=False):
 
 
 def layer_apply(spec, params, x, cdt, causal=True, attend=None,
-                buffers=None):
+                buffers=None, positions=None):
     """Pure decoder layer from its spec (:func:`layer_spec`):
     ``x + operator(norm(x))``, then ``+ ffn(norm(·))``.  Shared by
     LMLayer.tforward, the pipelined stack (whose stages must be a
     pure (params, x) → y function) and the serving forward.
     ``attend(q, k, v)`` is where the caller places attention (None:
     ``ops.attention.attention``).  ``buffers`` holds what the layer
-    reads and no gradient reaches (``expert_bias``).  Returns
+    reads and no gradient reaches (``expert_bias``).  ``positions``
+    (S,) are the rows' rotary positions (None: 0 … S − 1).  Returns
     ``(y float32, stats)``; ``stats`` is ``ops.moe.moe_dropless``'s
     for an ``experts`` layer, else None.
 
@@ -467,7 +468,7 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
     elif spec["operator"] == "latent":
         x = x + post("ln1_post", _latent_operator(
             spec, params, h, cdt, dot, attend or functools.partial(
-                A.attention, causal=causal)))
+                A.attention, causal=causal), positions))
     else:
         n_heads, kv_heads = spec["n_heads"], spec["kv_heads"]
         if "wqkv" in params:
@@ -494,9 +495,11 @@ def layer_apply(spec, params, x, cdt, causal=True, attend=None,
                                  spec["norm_eps"])
                 if spec["rope_theta"]:
                     q = rotary(q, spec["rope_theta"],
-                               spec["rope_fraction"])
+                               spec["rope_fraction"],
+                               positions=positions)
                     k = rotary(k, spec["rope_theta"],
-                               spec["rope_fraction"])
+                               spec["rope_fraction"],
+                               positions=positions)
         if attend is None:
             attend = functools.partial(A.attention, causal=causal,
                                        window=spec["window"])
@@ -591,7 +594,7 @@ def _gated_delta_operator(spec, params, h, cdt, dot):
     return dot(y.reshape(B, S, values), params["w_out"])
 
 
-def _latent_operator(spec, params, h, cdt, dot, attend):
+def _latent_operator(spec, params, h, cdt, dot, attend, positions=None):
     """Multi-head latent attention between its norms: ``h`` (B, S, E)
     normed → what is added to the stream, before any post norm.
     ``c_q = rms(h W_dq)``, ``[q_nope | q_pe] = c_q W_uq`` a head;
@@ -622,9 +625,11 @@ def _latent_operator(spec, params, h, cdt, dot, attend):
             B, S, H, nope + vd), [nope], axis=-1)
     with jax.named_scope("rope"):
         q_pe = rotary(q_pe, spec["rope_theta"],
-                      interleave=spec["rope_interleave"])
+                      interleave=spec["rope_interleave"],
+                      positions=positions)
         k_pe = rotary(k_pe.reshape(B, S, 1, rope), spec["rope_theta"],
-                      interleave=spec["rope_interleave"])
+                      interleave=spec["rope_interleave"],
+                      positions=positions)
     with jax.named_scope("mla_expand"):
         q = jnp.concatenate([q_nope.astype(cdt), q_pe.astype(cdt)],
                             axis=-1)
@@ -650,10 +655,54 @@ def _block_param_shapes(embed, hidden, fused_qkv=False):
         layer_spec(ffn_dim=hidden), embed, fused_qkv=fused_qkv)
 
 
+def block_noise(key, rows, length, block, eps):
+    """Block diffusion's forward process for ``rows`` sequences of
+    ``length`` clean ids in blocks of ``block``: a mask rate ``t ~ U(eps,
+    1)`` a block (from ``fold_in(key, 0)``) and a mask ``m_i ~
+    Bernoulli(t_β(i))`` a position (``u_i < t_β(i)``, ``u`` uniform from
+    ``fold_in(key, 1)``).  Returns ``(t, m)``, both (rows, length): the
+    block's rate at each of its positions, and True where the position
+    is masked."""
+    import jax
+    import jax.numpy as jnp
+    t = jax.random.uniform(jax.random.fold_in(key, 0),
+                           (rows, length // block), jnp.float32, eps, 1.0)
+    t = jnp.repeat(t, block, axis=1)
+    u = jax.random.uniform(jax.random.fold_in(key, 1), (rows, length),
+                           jnp.float32)
+    return t, u < t
+
+
+def diffusion_key(seed_words, tick):
+    """The noising key of one tick: ``fold_in(fold_in(PRNGKey(hi), lo),
+    tick)`` where the seed is ``hi · 2^31 + lo`` (``seed_words`` =
+    ``[hi, lo]``) and ``tick`` counts the ticks since the start."""
+    import jax
+    return jax.random.fold_in(jax.random.fold_in(
+        jax.random.PRNGKey(seed_words[0]), seed_words[1]), tick)
+
+
 class Embedding(ForwardBase):
     """Token + learned positional embedding: int32 tokens (B, S) →
     activations (B, S, E), times ``scale`` where one is given (the
-    ``sqrt(E)`` of a μP-scaled LM, ``samples/trinity.py``)."""
+    ``sqrt(E)`` of a μP-scaled LM, ``samples/trinity.py``).  With
+    ``diffusion`` (``TinyLMWorkflow(diffusion=...)``, ``samples/sdar.py``)
+    it is block diffusion's noising step and two-stream input: (B, S)
+    clean ids → (B, 2S, E), the noised stream then the clean one, and
+    the loss's weights a position in ``noise_weights``.
+
+    ``diffusion`` (a dict: ``block``, ``eps``, ``mask_id``, ``seed``):
+    under the inner scope ``bd_noise`` the step draws each block's mask
+    rate and each position's mask (:func:`block_noise`, keyed by
+    :func:`diffusion_key` from the state ``noise_seed`` and the tick
+    count ``noise_tick`` (1,)), puts ``mask_id`` where a position is
+    masked, and embeds the two streams ``[noised ; clean]``, while
+    ``noise_weights`` (B, S) gets ``m_i / t_β(i)``, the loss's weight a
+    position (0 where unmasked).  Its output stays float32, the
+    residual stream's type: every masked position starts from the ONE
+    MASK row, so that row's rounding to the compute type would be one
+    offset shared by them all, moving their common expert choice as a
+    block (PERF.md §6)."""
 
     MAPPING = "embedding"
 
@@ -666,8 +715,12 @@ class Embedding(ForwardBase):
         #: positions, or none).
         self.positions = kwargs.get("positions", True)
         self.scale = float(kwargs.get("scale", 1.0))
+        self.diffusion = kwargs.get("diffusion")
         self.include_bias = False
         self.pos = Vector()
+        self.noise_seed = Vector()
+        self.noise_tick = Vector()
+        self.noise_weights = Vector()
 
     @property
     def trainables(self):
@@ -676,9 +729,38 @@ class Embedding(ForwardBase):
             t["pos"] = self.pos
         return t
 
+    @property
+    def tstate(self):
+        if not getattr(self, "diffusion", None):
+            return {}
+        return {"noise_seed": self.noise_seed,
+                "noise_tick": self.noise_tick}
+
     def initialize(self, device=None, **kwargs):
         super(Embedding, self).initialize(device=device, **kwargs)
         batch, seq = self.input.shape[:2]
+        diffusion = getattr(self, "diffusion", None)
+        if diffusion:
+            if seq % diffusion["block"] or self.positions:
+                raise ValueError(
+                    "block diffusion over %d ids in blocks of %d, learned "
+                    "positions %r" % (seq, diffusion["block"],
+                                      self.positions))
+            if not self.noise_seed:
+                seed = diffusion.get("seed")
+                if seed is None:
+                    seed = int(self.rand().randint(0, 2 ** 31))
+                self.noise_seed.mem = numpy.array(
+                    [int(seed) >> 31, int(seed) & 0x7FFFFFFF],
+                    dtype=numpy.uint32)
+            if not self.noise_tick:
+                self.noise_tick.mem = numpy.zeros(1, dtype=numpy.int32)
+            self.noise_weights.mem = numpy.zeros((batch, seq),
+                                                 dtype=numpy.float32)
+            for vec in (self.noise_seed, self.noise_tick,
+                        self.noise_weights):
+                vec.initialize(self.device)
+            seq *= 2
         max_len = self.max_len or seq
         if not self.weights:
             stddev = self.weights_stddev or 0.02
@@ -700,13 +782,31 @@ class Embedding(ForwardBase):
     def tforward(self, read, write, params, ctx, state=None):
         tokens = read(self.input).astype("int32")
         w = params["weights"]
+        diffusion = getattr(self, "diffusion", None)
+        update = None
+        if diffusion:
+            import jax
+            import jax.numpy as jnp
+            with jax.named_scope("bd_noise"):
+                t, masked = block_noise(
+                    diffusion_key(state["noise_seed"],
+                                  state["noise_tick"][0]),
+                    tokens.shape[0], tokens.shape[1], diffusion["block"],
+                    diffusion["eps"])
+                write(self.noise_weights, masked / t)
+                tokens = jnp.concatenate(
+                    [jnp.where(masked, diffusion["mask_id"], tokens),
+                     tokens], axis=1)
+            update = {"noise_tick": state["noise_tick"] + 1}
         seq = tokens.shape[1]
         out = w[tokens]
         if "pos" in params:
             out = out + params["pos"][:seq]
         if getattr(self, "scale", 1.0) != 1.0:
             out = out * self.scale
-        write(self.output, out.astype(self.compute_dtype))
+        write(self.output, out if diffusion else
+              out.astype(self.compute_dtype))
+        return update
 
 
 class LMLayer(ForwardBase):
@@ -719,7 +819,12 @@ class LMLayer(ForwardBase):
     (docs/attention.md, "Layers from a spec").
 
     kwargs: ``spec`` (a :func:`layer_spec` dict, or its keyword
-    arguments as a dict); ``causal`` (default True); ``remat``;
+    arguments as a dict); ``causal`` (default True); ``diffusion``
+    (None, or ``(L, B)``: the input is block diffusion's two streams of
+    ``L`` rows, noised then clean, both at positions 0 … L − 1, and
+    attention takes the block-diffusion mask in place of the causal one
+    — a property of the sequence the workflow hands every layer, as it
+    hands ``causal``, not a key of the spec); ``remat``;
     ``fused_qkv`` (one (E, 3E) projection where the spec's attention
     has as many key/value heads as query heads); and for an
     ``experts`` layer the loader's ``minibatch_class_vec`` /
@@ -758,6 +863,7 @@ class LMLayer(ForwardBase):
         super(LMLayer, self).__init__(workflow, **kwargs)
         self.spec = spec = layer_spec(**kwargs["spec"])
         self.causal = kwargs.get("causal", True)
+        self.diffusion = kwargs.get("diffusion")
         self.seq_axis = kwargs.get("seq_axis")
         self.sp_mode = kwargs.get("sp_mode", "ring")
         from ..ops.attention import SP_MODES
@@ -786,6 +892,15 @@ class LMLayer(ForwardBase):
                 "seq_axis=%r with a window of %d keys: "
                 "sequence-parallel attention takes no window" %
                 (self.seq_axis, spec["window"]))
+        if self.diffusion and (self.seq_axis or spec["window"] or
+                               spec["operator"] not in ("attention",
+                                                        "latent")):
+            raise ValueError(
+                "block diffusion %r with seq_axis=%r, a window of %r, "
+                "operator %r: its mask is attention's, over the whole "
+                "two-stream sequence on one chip" % (
+                    self.diffusion, self.seq_axis, spec["window"],
+                    spec["operator"]))
         self.batch_axis = kwargs.get("batch_axis", "data")
         #: Set by apply_dp_tp_sharding: attention keeps the head dim
         #: on this mesh axis inside its shard_map (tp, tp × sp).
@@ -905,13 +1020,24 @@ class LMLayer(ForwardBase):
                 batch_axis=self.batch_axis, mode=self.sp_mode,
                 head_axis=self.head_axis, kernel=self.sp_kernel,
                 interpret=self.sp_interpret)
+        diffusion = getattr(self, "diffusion", None)
+        causal = self.causal and not diffusion
         if mesh is not None:
             return A.mesh_attention(
-                q, k, v, mesh, causal=self.causal,
+                q, k, v, mesh, causal=causal,
                 batch_axis=self.batch_axis, head_axis=self.head_axis,
-                window=self.spec["window"])
-        return A.attention(q, k, v, causal=self.causal,
-                           window=self.spec["window"])
+                window=self.spec["window"], diffusion=diffusion)
+        return A.attention(q, k, v, causal=causal,
+                           window=self.spec["window"], diffusion=diffusion)
+
+    def _positions(self):
+        """The rows' rotary positions: block diffusion's two streams
+        both at 0 … L − 1; None (0 … S − 1) otherwise."""
+        diffusion = getattr(self, "diffusion", None)
+        if not diffusion:
+            return None
+        import jax.numpy as jnp
+        return jnp.tile(jnp.arange(diffusion[0]), 2)
 
     def tforward(self, read, write, params, ctx, state=None):
         x = read(self.input)
@@ -921,7 +1047,8 @@ class LMLayer(ForwardBase):
         def apply(p, b, h):
             return layer_apply(self.spec, p, h, self.compute_dtype,
                                causal=self.causal,
-                               attend=self._attend, buffers=b)
+                               attend=self._attend, buffers=b,
+                               positions=self._positions())
 
         if remat_enabled(self.remat):
             apply = checkpointed(apply)
@@ -1222,13 +1349,15 @@ class GDPipelinedStack(GradientDescentBase):
 
 class LMHead(ForwardBase):
     """Tied or free projection to vocabulary logits:
-    (B, S, E) → (B, S, V)."""
+    (B, S, E) → (B, S, V) — of the first ``rows`` rows only where that
+    is given (block diffusion's head reads the noised stream alone)."""
 
     MAPPING = "lm_head"
 
     def __init__(self, workflow, **kwargs):
         super(LMHead, self).__init__(workflow, **kwargs)
         self.vocab_size = kwargs["vocab_size"]
+        self.rows = kwargs.get("rows")
         #: Weight tying to an Embedding unit (standard LM practice;
         #: gradients flow to the embedding through the read).
         self.tie_to = kwargs.get("tie_to")
@@ -1260,12 +1389,15 @@ class LMHead(ForwardBase):
                                         dtype=numpy.float32)
             self.bias.initialize(self.device)
         self.output.mem = numpy.zeros(
-            (batch, seq, self.vocab_size), dtype=numpy.float32)
+            (batch, getattr(self, "rows", None) or seq, self.vocab_size),
+            dtype=numpy.float32)
         self.output.initialize(self.device)
 
     def tforward(self, read, write, params, ctx, state=None):
         import jax.numpy as jnp
         x = read(self.input)
+        if getattr(self, "rows", None):
+            x = x[:, :self.rows]
         cdt = self.compute_dtype
         if self.tie_to is not None:
             w = read(self.tie_to.weights).T
@@ -1278,22 +1410,51 @@ class LMHead(ForwardBase):
         write(self.output, y)
 
 
+def diffusion_loss(nll, pred, labels, weights, tok_mask):
+    """Masked diffusion's loss from per-position cross-entropies ``nll``
+    (B, S) against the clean ids ``labels``, the loss's ``weights`` (B,
+    S) — ``m_i / t_β(i)``, 0 where unmasked — and the valid rows' token
+    mask: ``Σ w · nll`` over the valid tokens' count (``(1 / S) Σ_i w_i
+    · CE_i`` a row, averaged over the rows).  Returns ``(loss, errors
+    among the masked positions, the masked positions (B, S) as 0 / 1)``."""
+    import jax.numpy as jnp
+    w = weights * tok_mask
+    masked = (w > 0).astype(jnp.float32)
+    return (nll * w).sum() / jnp.maximum(tok_mask.sum(), 1.0), \
+        ((pred != labels) * masked).sum(), masked
+
+
 class EvaluatorLM(EvaluatorBase):
     """Next-token cross-entropy over (B, S, V) logits vs (B, S)
     labels, with per-SAMPLE validity mask; rides the on-device epoch
     accumulator like every evaluator (n_err/n_valid count tokens).
+    Under block diffusion (``weights`` linked) it is masked diffusion's
+    loss instead: the cross-entropy of each masked position against its
+    own clean id, weighed by ``1 / t``, over the tokens
+    (:func:`diffusion_loss`; gauge ``diffusion.masked_frac``).
 
     Where ``mtp_loss`` is linked (an :class:`MTPModule`'s output) the
     loss is ``CE + mtp_weight · mtp_loss`` — what is differentiated
     and accumulated — and ``mtp_acc`` (3 classes × [the MTP term's
-    sum, ticks]) keeps the term apart: :meth:`read_mtp_loss`."""
+    sum, ticks]) keeps the term apart: :meth:`read_mtp_loss`.
+
+    Where ``weights`` is linked (block diffusion: the embedding's
+    ``noise_weights``, ``m_i / t_β(i)``) the labels are the clean ids
+    and the loss is masked diffusion's: ``(1 / S) Σ_i w_i ·
+    CE(logits_i, labels_i)`` a valid row, averaged over the rows;
+    ``n_err`` counts the MASKED positions whose argmax misses, and
+    ``n_valid`` the tokens; ``diffusion_acc`` (3 classes × [masked
+    positions, tokens]) feeds :meth:`read_masked_frac`."""
 
     def __init__(self, workflow, **kwargs):
         super(EvaluatorLM, self).__init__(workflow, **kwargs)
         self.labels = None
         self.mtp_loss = None
+        self.weights = None
         self.mtp_weight = kwargs.get("mtp_weight", 0.3)
         self.mtp_acc = Vector(numpy.zeros((3, 2), dtype=numpy.float32))
+        self.diffusion_acc = Vector(numpy.zeros((3, 2),
+                                                dtype=numpy.float32))
         self.demand("labels", "mask", "minibatch_class_vec")
 
     @property
@@ -1301,7 +1462,26 @@ class EvaluatorLM(EvaluatorBase):
         state = super(EvaluatorLM, self).tstate
         if getattr(self, "mtp_loss", None) is not None:
             state["mtp_acc"] = self.mtp_acc
+        if getattr(self, "weights", None) is not None:
+            state["diffusion_acc"] = self.diffusion_acc
         return state
+
+    def read_masked_frac(self, cls):
+        """Of the tokens since the last read of ``cls``, the share whose
+        position was masked (None without block diffusion or a token),
+        published as the gauge ``diffusion.masked_frac`` and then
+        cleared."""
+        if getattr(self, "weights", None) is None:
+            return None
+        self.diffusion_acc.map_read()
+        masked, tokens = (float(v) for v in self.diffusion_acc.mem[cls])
+        self.diffusion_acc.map_write()
+        self.diffusion_acc.mem[cls] = 0.0
+        if not tokens:
+            return None
+        from ..observability.metrics import registry
+        registry.gauge("diffusion.masked_frac").set(masked / tokens)
+        return masked / tokens
 
     def read_mtp_loss(self, cls):
         """The MTP term's mean a tick since the last read of ``cls``
@@ -1333,9 +1513,14 @@ class EvaluatorLM(EvaluatorBase):
                                   axis=-1)
         nll = -jnp.take_along_axis(
             logp, labels[..., None], axis=-1)[..., 0]
-        loss = (nll * tok_mask).sum() / n_valid
         pred = jnp.argmax(logits, axis=-1)
-        n_err = ((pred != labels) * tok_mask).sum()
+        weights = getattr(self, "weights", None)
+        if weights is None:
+            loss = (nll * tok_mask).sum() / n_valid
+            n_err = ((pred != labels) * tok_mask).sum()
+        else:
+            loss, n_err, masked = diffusion_loss(
+                nll, pred, labels, read(weights), tok_mask)
         mtp = getattr(self, "mtp_loss", None)
         if mtp is not None:
             term = read(mtp)
@@ -1350,6 +1535,10 @@ class EvaluatorLM(EvaluatorBase):
             update["mtp_acc"] = state["mtp_acc"].at[
                 read(self.minibatch_class_vec)].add(
                     jnp.stack([term * valid, valid]))
+        if weights is not None and update is not None:
+            update["diffusion_acc"] = state["diffusion_acc"].at[
+                read(self.minibatch_class_vec)].add(
+                    jnp.stack([masked.sum(), tok_mask.sum()]))
         return update
 
 

@@ -124,6 +124,10 @@ class DecisionGD(DecisionBase, IResultProvider):
         if read_mtp is not None:
             # the multi-token-prediction term apart (gauge mtp.loss)
             read_mtp(cls)
+        read_masked = getattr(self.evaluator, "read_masked_frac", None)
+        if read_masked is not None:
+            # block diffusion's masked share (diffusion.masked_frac)
+            read_masked(cls)
 
     def _fetch_moe_share(self, cls):
         """Folds the accumulators of the layers that hold a SHARE of a

@@ -70,7 +70,15 @@ class TinyLMWorkflow(AcceleratedWorkflow):
     embedding's output is multiplied by.  ``mtp``: a layer spec for a
     multi-token-prediction module (``MTPModule``, unit ``mtp``) that
     reads the last layer's output, the embedding and the head, and
-    whose loss the evaluator adds ``mtp_weight`` times (layers only)."""
+    whose loss the evaluator adds ``mtp_weight`` times (layers only).
+    ``diffusion``: block diffusion (``samples/sdar.py``; layers and a
+    head of their own only) — a dict ``block`` (B), ``eps`` (the least
+    mask rate), ``mask_id`` (the MASK row of the vocabulary) and
+    optionally ``seed``: the embedding draws the noise and embeds the
+    two streams ``[noised ; clean]``, every layer gets ``diffusion=(S,
+    B)``, the head reads the noised rows alone, and the evaluator
+    weighs the cross-entropy of the masked positions against the clean
+    ids (the loader's data) by ``1 / t``."""
 
     def __init__(self, workflow, vocab_size=16, seq_len=32,
                  embed_dim=32, n_heads=4, n_blocks=1,
@@ -80,7 +88,7 @@ class TinyLMWorkflow(AcceleratedWorkflow):
                  pipelined=False, stage_axis=None, n_microbatches=4,
                  schedule=None, n_chunks=None, fused_qkv=None,
                  layers=None, tied_head=True, embed_scale=1.0,
-                 mtp=None, mtp_weight=0.3,
+                 mtp=None, mtp_weight=0.3, diffusion=None,
                  loader_cls=FirstTokenLoader, loader_config=None,
                  **kwargs):
         super(TinyLMWorkflow, self).__init__(workflow, **kwargs)
@@ -93,10 +101,16 @@ class TinyLMWorkflow(AcceleratedWorkflow):
             **(loader_config or {}))
         self.loader.link_from(self.repeater)
 
+        if diffusion is not None and (layers is None or tied_head or
+                                      mtp is not None):
+            raise ValueError("block diffusion runs layers built from "
+                             "specs and a head of their own, with no MTP "
+                             "module: give layers=[specs], "
+                             "tied_head=False")
         self.embedding = Embedding(
             self, vocab_size=vocab_size, embed_dim=embed_dim,
             positions=layers is None, scale=embed_scale,
-            name="embedding")
+            diffusion=diffusion, name="embedding")
         self.embedding.link_from(self.loader)
         self.embedding.input = self.loader.minibatch_data
 
@@ -123,8 +137,10 @@ class TinyLMWorkflow(AcceleratedWorkflow):
             self.forwards.append(stack)
             prev = stack
             n_blocks = 0
-        bodies = [(LMLayer, {"spec": spec}) for spec in layers] \
-            if layers is not None else \
+        streams = None if diffusion is None else \
+            (seq_len, diffusion["block"])
+        bodies = [(LMLayer, {"spec": spec, "diffusion": streams})
+                  for spec in layers] if layers is not None else \
             [(TransformerBlock, {"n_heads": n_heads})] * n_blocks
         for i, (cls, what) in enumerate(bodies):
             block = cls(
@@ -152,7 +168,8 @@ class TinyLMWorkflow(AcceleratedWorkflow):
 
         self.head = LMHead(
             self, vocab_size=vocab_size,
-            tie_to=self.embedding if tied_head else None, name="head")
+            tie_to=self.embedding if tied_head else None,
+            rows=seq_len if diffusion is not None else None, name="head")
         self.head.link_from(prev)
         self.head.input = prev.output
         self.forwards.append(self.head)
@@ -174,6 +191,9 @@ class TinyLMWorkflow(AcceleratedWorkflow):
         self.evaluator.link_from(prev)
         self.evaluator.input = self.head.output
         self.evaluator.labels = self.loader.minibatch_labels
+        if diffusion is not None:
+            self.evaluator.labels = self.loader.minibatch_data
+            self.evaluator.weights = self.embedding.noise_weights
         self.evaluator.mask = self.loader.minibatch_mask
         self.evaluator.minibatch_class_vec = \
             self.loader.minibatch_class_vec
