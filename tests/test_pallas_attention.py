@@ -7,6 +7,8 @@ a long call states, one call against the chunked walk, and the count
 of calls by path.  The kernels at the cells' real size compile in
 ``tests/test_tpu_compile.py``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy
@@ -188,20 +190,32 @@ def _kernel_calls(fn, *structs):
     return found
 
 
-@pytest.mark.parametrize("shape,D_v,chunk,calls", [
-    ((4, 2048, 32, 128), 128, None, 1), ((8, 2048, 32, 64), 64, None, 1),
-    ((1, 8192, 32, 192), 128, None, 1), ((1, 8192, 16, 256), 256, None, 1),
-    ((1, 8192, 32, 192), 128, 2048, 10)],
+def _mib(n):
+    """``n`` bytes in whole MiB, rounded up, as a stated limit is."""
+    return -(-n >> 20) << 20
+
+
+@pytest.mark.parametrize("shape,D_v,chunk,calls,fused", [
+    ((4, 2048, 32, 128), 128, None, 1, True),
+    ((8, 2048, 32, 64), 64, None, 1, True),
+    ((1, 8192, 32, 192), 128, None, 1, True),
+    ((1, 8192, 16, 256), 256, None, 1, True),
+    ((1, 8192, 32, 192), 128, 2048, 10, True),
+    ((1, 8192, 8, 512), 512, None, 1, False)],
     ids=["opt-6.7b", "lfm2", "joyai-flash 8k", "qwen3-next 8k",
-         "joyai-flash 8k, chunked"])
+         "joyai-flash 8k, chunked", "head 512 8k, split"])
 def test_a_call_states_its_vmem_past_the_scoped_length(shape, D_v, chunk,
-                                                       calls):
-    """Forward and gradients traced for the chip (no interpret): a call
-    of at most ``SCOPED_SEQ`` rows — the 2,048-row cells', and each
-    pair of the chunked walk — states no compiler parameters, as it
-    never did; a whole 8,192-row call states a scoped VMEM limit of
-    twice what ``call_bytes`` reckons, under the core's 128 MiB.  One
-    ``flash_fwd``, ``flash_dq`` and ``flash_dkv`` a call."""
+                                                       calls, fused):
+    """Forward and gradients traced for the chip (no interpret): a
+    forward call of at most ``SCOPED_SEQ`` rows — the 2,048-row cells',
+    and each pair of the chunked walk — states no compiler parameters,
+    as it never did; a whole 8,192-row call states a scoped VMEM limit
+    of twice what ``call_bytes`` reckons, under the core's 128 MiB.
+    The backward is ONE ``flash_dkv`` a call wherever ``fused_bytes``
+    fits ``VMEM_BUDGET`` — every cell's call — stating twice what that
+    reckons at every length; past the budget (heads of 512 at 8,192
+    rows: 79 MiB) it is ``flash_dq`` and ``flash_dkv``, which state
+    what the forward does."""
     B, S, H, D = shape
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     values = jax.ShapeDtypeStruct((B, S, H, D_v), jnp.bfloat16)
@@ -209,15 +223,150 @@ def test_a_call_states_its_vmem_past_the_scoped_length(shape, D_v, chunk,
         lambda q, k, v: PA.pallas_attention(
             q, k, v, causal=True, chunk=chunk).astype(jnp.float32).sum(),
         argnums=(0, 1, 2)), x, x, values)
-    assert sorted(name for name, _ in found) == sorted(
-        ["flash_fwd", "flash_dq", "flash_dkv"] * calls)
     rows = min(S, chunk or S)
-    if rows <= PA.SCOPED_SEQ:
-        assert {limit for _, limit in found} == {None}
-    else:
-        need = 2 * PA.call_bytes(S, D, D_v, 2, 512, 512)
-        assert {limit for _, limit in found} == {-(-need >> 20) << 20}
-        assert need <= 128 << 20
+    assert PA.fuses(rows, rows, D, D_v, 2, 512, 512) == fused
+    backward = ["flash_dkv"] if fused else ["flash_dq", "flash_dkv"]
+    assert sorted(name for name, _ in found) == sorted(
+        (["flash_fwd"] + backward) * calls)
+    stated = None if rows <= PA.SCOPED_SEQ else \
+        _mib(2 * PA.call_bytes(rows, D, D_v, 2, 512, 512))
+    want = {"flash_fwd": stated, "flash_dq": stated, "flash_dkv": stated}
+    if fused:
+        want["flash_dkv"] = _mib(2 * PA.fused_bytes(rows, D, D_v, 2, 512,
+                                                    512))
+    assert all(limit == want[name] for name, limit in found), found
+    assert all(limit is None or limit <= 128 << 20 for _, limit in found)
+
+
+#: The fused backward's VMEM at each cell's call, bfloat16 (``fuses``
+#: true at all), and where it passes the budget while the forward's
+#: call still fits: heads of 512 at 8,192 rows in bfloat16, heads of 256
+#: at 8,192 rows in float32.
+@pytest.mark.parametrize("S,D,Dv,itemsize,fused", [
+    (8192, 192, 128, 2, True), (8192, 256, 256, 2, True),
+    (8192, 128, 128, 2, True), (2048, 128, 128, 2, True),
+    (2048, 64, 64, 2, True), (2048, 128, 128, 4, True),
+    (8192, 512, 512, 2, False), (8192, 256, 256, 4, False)])
+def test_the_backward_fuses_where_its_vmem_fits(S, D, Dv, itemsize,
+                                                fused):
+    """``fused_bytes`` is the dk/dv kernel's reckoning and dq over the
+    call's rows at whole lane tiles — a float32 accumulator and the
+    resident output, double-buffered: +16 MiB at joyai-flash's 8,192
+    rows of 192 (256 lanes) in bfloat16, 38.9 MiB in all."""
+    extra = PA.fused_bytes(S, D, Dv, itemsize, 512, 512) - \
+        PA.call_bytes(S, D, Dv, itemsize, 512, 512)
+    assert extra == S * PA._lanes(D) * (4 + 2 * itemsize)
+    assert PA.fuses(S, S, D, Dv, itemsize, 512, 512) == fused
+    assert PA.call_rows(S, D, Dv, itemsize) == S
+    if (S, D, Dv, itemsize) == (8192, 192, 128, 2):
+        assert extra == 16 << 20
+        assert 38 << 20 < PA.fused_bytes(S, D, Dv, 2, 512, 512) < 39 << 20
+
+
+# -- the fused backward against the two kernels and the oracle -------------
+
+
+def _dense(q, k, v, mask):
+    """The oracle: softmax attention over a (Sq, Sk) boolean ``mask``
+    at float32 ``highest``, and its log-sum-exp rows (B, Sq, H)."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / \
+        numpy.sqrt(q.shape[-1])
+    s = jnp.where(mask, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v,
+                     precision="highest")
+    return out, lse.transpose(0, 2, 1)
+
+
+def _visible(mode, Sq, Sk):
+    """Each mode's mask by hand, from the rows' and columns' meaning."""
+    i = numpy.arange(Sq)[:, None]
+    j = numpy.arange(Sk)[None, :]
+    if mode == "causal":
+        return i >= j
+    if mode == "window":
+        return (i >= j) & (i - j < 200)
+    if mode == "kv_len":
+        return numpy.broadcast_to(j < 200, (Sq, Sk))
+    if mode == "ring":
+        return i + 128 >= j
+    L, B = Sq // 2, 4
+    row_clean, col_clean = i >= L, j >= L
+    row_block, col_block = (i % L) // B, (j % L) // B
+    return numpy.where(
+        row_clean, col_clean & (col_block <= row_block),
+        numpy.where(col_clean, col_block < row_block,
+                    col_block == row_block))
+
+
+def _attend(mode, q, k, v):
+    """``(out, lse or None)`` of the kernels in ``mode``: 128-row blocks,
+    float32 operands, interpreted; the whole sequence one call."""
+    common = dict(block_q=128, block_k=128, operand_dtype=jnp.float32,
+                  interpret=True)
+    if mode == "ring":
+        return PA.flash_chunk(q, k, v, causal=True, q_offset=128,
+                              k_offset=0, **common)
+    kwargs = {"causal": dict(causal=True), "kv_len": dict(kv_len=200),
+              "window": dict(causal=True, window=200),
+              "block diffusion": dict(diffusion=(q.shape[1] // 2, 4))}
+    return PA.pallas_attention(q, k, v, chunk=q.shape[1], **common,
+                               **kwargs[mode]), None
+
+
+@pytest.mark.parametrize("mode,S,D_qk,D_v", [
+    ("causal", 256, 64, 64), ("causal", 256, 128, 128),
+    ("causal", 256, 192, 128), ("causal", 256, 256, 256),
+    ("window", 512, 128, 128), ("block diffusion", 256, 128, 128),
+    ("kv_len", 256, 192, 128), ("ring", 256, 128, 128)])
+def test_fused_backward_matches_the_two_kernels_and_the_oracle(
+        monkeypatch, mode, S, D_qk, D_v):
+    """dq, dk and dv of the ONE backward call — the dk/dv kernel's walk
+    over the query blocks that see each key block, dq summed in a
+    float32 accumulator over the call's rows — against the two kernels
+    (``VMEM_BUDGET`` 0 leaves the fused call no room) to float32
+    rounding, and against the dense oracle: causal at heads of 64,
+    128, 192 / 128 and 256; a window of 200 rows over 512 (the
+    window's edge, the tiles it sees whole and the diagonal); block
+    diffusion over two streams of 128 in blocks of 4; ``kv_len`` 200
+    of 256, no causal mask; a ring partial at query offset 128,
+    traced through ``flash_chunk``, its lse's cotangent in the loss.
+    Each backward traced counts once into
+    ``attention.flash.bwd.fused`` or ``.split``."""
+    from veles_tpu import resilience
+    registry = resilience.stats.registry
+    q, k, v = _operands(S, 2, D_qk, D_v, D_qk + D_v + S)
+    keys = jax.random.split(jax.random.PRNGKey(11), 2)
+    weight = jax.random.normal(keys[0], (1, S, 2, D_v))
+    lse_weight = jax.random.normal(keys[1], (1, S, 2))
+
+    def grads(fn):
+        def loss(q, k, v):
+            out, lse = fn(q, k, v)
+            total = (out * weight).sum()
+            return total if lse is None else total + (lse * lse_weight).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    def counts():
+        return [getattr(registry.peek("attention.flash.bwd." + path),
+                        "value", 0) for path in ("fused", "split")]
+
+    before = counts()
+    fused = grads(functools.partial(_attend, mode))
+    middle = counts()
+    monkeypatch.setattr(PA, "VMEM_BUDGET", 0)
+    split = grads(functools.partial(_attend, mode))
+    after = counts()
+    assert [b - a for a, b in zip(before, middle)] == [1, 0]
+    assert [b - a for a, b in zip(middle, after)] == [0, 1]
+    mask = _visible(mode, S, S)
+    want = grads(lambda q, k, v: _dense(q, k, v, mask)
+                 if mode == "ring" else (_dense(q, k, v, mask)[0], None))
+    for got, two, ref, name in zip(fused, split, want, "qkv"):
+        numpy.testing.assert_allclose(got, two, rtol=1e-5, atol=1e-6,
+                                      err_msg="d" + name)
+        numpy.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5,
+                                      err_msg="d" + name)
 
 
 @pytest.mark.parametrize("chunk,window,path,calls", [

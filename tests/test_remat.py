@@ -289,13 +289,13 @@ def test_checkpoint_keeps_what_the_flash_forward_produced():
 @pytest.mark.parametrize("wrap,forwards", [
     ("none", 2), ("bare", 4), ("checkpointed", 2)])
 def test_recompute_holds_no_flash_forward(wrap, forwards):
-    """Two attention layers: the gradient holds one ``flash_dq`` and
-    one ``flash_dkv`` a layer whatever wraps it, and ONE
-    ``flash_fwd`` a layer under the layers' checkpoint — as without
-    any; a checkpoint with no policy runs the kernel again in every
-    recompute."""
+    """Two attention layers: the gradient holds one ``flash_dkv`` a
+    layer — the one backward call, which makes dq too — and no
+    ``flash_dq`` whatever wraps it, and ONE ``flash_fwd`` a layer
+    under the layers' checkpoint — as without any; a checkpoint with
+    no policy runs the kernel again in every recompute."""
     assert _kernel_calls(*_layer_loss(_wrap(wrap), True)) == {
-        "flash_fwd": forwards, "flash_dq": 2, "flash_dkv": 2}
+        "flash_fwd": forwards, "flash_dq": 0, "flash_dkv": 2}
 
 
 @pytest.mark.parametrize("wrap,forwards", [("bare", 4),
@@ -323,7 +323,7 @@ def test_ring_keeps_every_chunks_partial(wrap, forwards):
         return (attend(q, k, v) ** 2).sum()
 
     assert _kernel_calls(loss, q, k, v) == {
-        "flash_fwd": forwards, "flash_dq": 2, "flash_dkv": 2}
+        "flash_fwd": forwards, "flash_dq": 0, "flash_dkv": 2}
 
 
 @pytest.mark.parametrize("spec", [

@@ -322,19 +322,20 @@ def _scopes_of_kernel_calls(calls):
 
 @pytest.mark.parametrize("forwards", [2, 4], ids=["kept", "twice"])
 def test_scopes_count_the_flash_calls_of_the_program(forwards):
-    """``scopes()`` sets ``attention.flash.fwd_calls`` / ``.dq_calls``
+    """``scopes()`` sets ``attention.flash.fwd_calls`` / ``.bwd_calls``
     (labelled with the program) from the parse it makes anyway: by
-    instruction name, ``flash_fwd`` alone or ``flash_fwd.<n>``."""
+    instruction name, ``flash_fwd`` alone or ``flash_fwd.<n>``; the
+    backward is ``flash_dkv``, one call a layer that makes dq too."""
     from veles_tpu.observability.metrics import registry
     table = _scopes_of_kernel_calls(
         ["flash_fwd"] + ["flash_fwd.%d" % i for i in range(1, forwards)]
-        + ["flash_dq", "flash_dq.7", "flash_dkv.1", "flash_dkv.2",
-           "flash_fwdish.3"])
+        + ["flash_dkv", "flash_dkv.7", "flash_fwdish.3"])
     label = {"program": "block_step"}
     assert registry.peek("attention.flash.fwd_calls",
                          label).value == forwards
-    assert registry.peek("attention.flash.dq_calls", label).value == 2
+    assert registry.peek("attention.flash.bwd_calls", label).value == 2
     assert programs.kernel_calls(table, "flash_dkv") == 2
+    assert programs.kernel_calls(table, "flash_dq") == 0
     assert registry.peek("attention.flash.fwd_calls") is None
     assert registry.peek("moe.gmm_calls", label).value == 0
 

@@ -234,7 +234,7 @@ def scopes(program):
     program was dispatched.  Instruction names are as the device
     trace gives them, without the ``%``.  The first call compiles
     (:func:`_compiled_text`) and parses, and sets the gauges
-    ``attention.flash.fwd_calls`` / ``.dq_calls`` and
+    ``attention.flash.fwd_calls`` / ``.bwd_calls`` and
     ``moe.gmm_calls`` / ``.tgmm_calls``, ``linear_attention.
     fwd_calls`` / ``.bwd_calls`` and ``linear_attention.scan_steps`` /
     ``.chunk`` (all labelled with the program's name) from that
@@ -247,9 +247,11 @@ def scopes(program):
         text = _compiled_text(entry.lower())
         entry.table = parse_hlo(text, entry.units)
         entry.lower = None
-        # The flash pair equal: every checkpointed layer kept its
-        # forward kernel's output (``znicz.attention.checkpointed``);
-        # two to one: each recompute runs the kernel again.  The
+        # The flash pair (forward; the backward, ``flash_dkv``, which
+        # makes dq too where it fits VMEM) equal: every checkpointed
+        # layer kept its forward kernel's output
+        # (``znicz.attention.checkpointed``); two to one: each
+        # recompute runs the kernel again.  The
         # megablox pair (``ops.moe.grouped_dot``; ``tgmm`` is the
         # weights' gradient, six an expert layer): two ``gmm`` a layer
         # fewer where the checkpoint kept the two products before the
@@ -257,8 +259,8 @@ def scopes(program):
         label = {"program": program}
         registry.gauge("attention.flash.fwd_calls", label).set(
             kernel_calls(entry.table, "flash_fwd"))
-        registry.gauge("attention.flash.dq_calls", label).set(
-            kernel_calls(entry.table, "flash_dq"))
+        registry.gauge("attention.flash.bwd_calls", label).set(
+            kernel_calls(entry.table, "flash_dkv"))
         registry.gauge("moe.gmm_calls", label).set(
             kernel_calls(entry.table, "gmm"))
         registry.gauge("moe.tgmm_calls", label).set(

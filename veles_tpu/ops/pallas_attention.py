@@ -67,9 +67,14 @@ choices, for exactly one geometry family:
     the same contract as ops/attention's bf16 mode;
   * the backward recomputes probabilities from the saved logsumexp
     (FLOPs are free at this arithmetic intensity; HBM traffic is
-    not): one kernel produces dq gridded over query blocks, one
-    produces dk/dv gridded over key blocks — no atomics, no
-    cross-block races;
+    not) in ONE kernel, ``flash_dkv``, gridded over key blocks: each
+    tile's scores, probabilities and dP, made once, give dk and dv
+    and add into a float32 dq accumulator over the call's rows in
+    VMEM — five products a tile where a dq kernel beside a dk/dv
+    kernel made seven.  Where that accumulator does not fit
+    ``VMEM_BUDGET`` (:func:`fuses`) the backward is the two kernels:
+    ``flash_dq`` gridded over query blocks, ``flash_dkv`` over key
+    blocks — no atomics, no cross-block races either way;
   * the forward rule NAMES what only the kernel can rebuild, its
     output and logsumexp rows (``FLASH_OUT``, ``FLASH_LSE``): the
     layers' checkpoint (``znicz.attention.checkpointed``) keeps the
@@ -351,10 +356,13 @@ def _mask_tile(grows0, gcols0, lcols0, bq, bk, causal, kv_len,
     return mask
 
 
-def _dot(a, b, od, trans_b=False):
+def _dot(a, b, od, trans_b=False, trans_a=False):
     """MXU matmul: ``od`` operands (bf16 in production, f32 for the
-    exact-parity tests), f32 accumulation."""
-    dims = (((1,), (1,) if trans_b else (0,)), ((), ()))
+    exact-parity tests), f32 accumulation; ``trans_a`` / ``trans_b``
+    take ``aᵀ`` / ``bᵀ`` (a contraction over dim 0 of ``a`` / dim 1
+    of ``b``)."""
+    dims = (((0,) if trans_a else (1,), (1,) if trans_b else (0,)),
+            ((), ()))
     return jax.lax.dot_general(a.astype(od), b.astype(od), dims,
                                preferred_element_type=jnp.float32)
 
@@ -600,8 +608,8 @@ def flash_tiles(q_len, k_len, block_q, block_k, q_offset=0,
                 k_offset=0, causal=True, window=None, diffusion=None):
     """``(visited, total)`` score tiles of ONE (batch·head) slice of
     a forward call: what the key loops of its ``q_len // block_q``
-    programs visit, and what the grid holds (the dq kernel walks the
-    same tiles, the dk/dv kernel the same set from the other side).
+    programs visit, and what the grid holds (the backward walks the
+    same set from the key blocks' side).
     Static offsets only — it is the count a trace records
     (``attention.flash.tiles_visited`` / ``.tiles_total``) and the
     tests pin; the kernels get their bounds from the same
@@ -702,9 +710,20 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, delta_ref, dk_ref, dv_ref, *, scale, causal,
-                kv_len, block_q, q_seq_len, od, window=None,
+                lse_ref, delta_ref, dk_ref, dv_ref, *dq_refs, scale,
+                causal, kv_len, block_q, q_seq_len, od, window=None,
                 diffusion=None):
+    """dk and dv of one key block, walking the query blocks that see
+    it.  With ``dq_refs`` — the dq output, resident over the call's
+    whole sequence, and its float32 accumulator in VMEM — it is the
+    FUSED backward: each tile's ``dsᵀ`` also adds ``ds·k`` (a product
+    that contracts the key axis of both) into the accumulator's rows
+    of that query block, so the scores, the probabilities and dP are
+    made once for all three gradients.  The accumulator is zeroed at
+    the first key block of each (batch·head) and stored at the last:
+    the grid's key axis is ``"arbitrary"``, and a query block's dq
+    sums its key blocks in ascending order, as the dq kernel's walk
+    does."""
     from jax.experimental import pallas as pl
     bk = k_ref.shape[1]
     D, Dv = k_ref.shape[2], v_ref.shape[2]
@@ -714,6 +733,12 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     qoff = qoff_ref[0, 0]
     gcols0 = koff_ref[0, 0] + j * bk
     lcols0 = j * bk
+    if dq_refs:
+        dq_ref, dq_acc = dq_refs
+
+        @pl.when(j == 0)
+        def _zero():
+            dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     def tile(i, carry, diagonal):
         # Key-major tiles (bk, bq): lse/delta arrive as (1, bq) rows
@@ -737,6 +762,9 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dpt = _dot(v, dob, od, trans_b=True)
         dst = pt * (dpt - delta) * scale
         dk = dk + _dot(dst, qb, od)
+        if dq_refs:
+            rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            dq_acc[rows, :] += _dot(dst, k, od, trans_a=True)
         return dk, dv
 
     dk, dv = _walk(
@@ -747,6 +775,10 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
          jnp.zeros((bk, Dv), jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+    if dq_refs:
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _store():
+            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 # -- pallas_call plumbing ------------------------------------------------
@@ -822,6 +854,15 @@ def call_bytes(S, D, Dv, itemsize, bq, bk):
     return resident + streamed + stats + work
 
 
+def fused_bytes(S, D, Dv, itemsize, bq, bk):
+    """The VMEM the fused backward holds (:func:`_dkv_kernel` with dq):
+    what :func:`call_bytes` reckons, and beside it dq over the call's
+    whole ``S`` rows, ``D`` wide padded to whole lane tiles — the
+    float32 accumulator and the resident output, double-buffered."""
+    return call_bytes(S, D, Dv, itemsize, bq, bk) + \
+        S * _lanes(D) * (4 + 2 * itemsize)
+
+
 def call_rows(S, D, Dv, itemsize, block_q=None, block_k=None):
     """How many of a sequence's ``S`` rows ONE call takes: all of them
     where :func:`call_bytes` fits ``VMEM_BUDGET``; else the longest
@@ -837,19 +878,34 @@ def call_rows(S, D, Dv, itemsize, block_q=None, block_k=None):
     return LANE
 
 
-def _flat_params(qf, kf, vf, bq, bk):
+def _flat_params(qf, kf, vf, bq, bk, fused=False):
     """The ``compiler_params`` of a call over flat (BH, S, ·) operands:
     None up to ``SCOPED_SEQ`` rows (the call as it always was, inside
     Mosaic's default scoped limit); past it a scoped VMEM limit of
     what :func:`call_bytes` reckons and as much again, in whole MiB,
-    for what Mosaic adds of its own."""
+    for what Mosaic adds of its own.  The ``fused`` backward states
+    its limit (:func:`fused_bytes`, likewise twice over) at every
+    length — its dq alone passes the default limit at 2,048 rows in
+    float32 — and its key axis as ``"arbitrary"``: dq accumulates
+    across it."""
     S = max(qf.shape[1], kf.shape[1])
-    if S <= SCOPED_SEQ:
+    itemsize = max(x.dtype.itemsize for x in (qf, kf, vf))
+    if S <= SCOPED_SEQ and not fused:
         return None
     from jax.experimental.pallas import tpu as pltpu
-    itemsize = max(x.dtype.itemsize for x in (qf, kf, vf))
-    need = 2 * call_bytes(S, qf.shape[2], vf.shape[2], itemsize, bq, bk)
-    return pltpu.CompilerParams(vmem_limit_bytes=-(-need >> 20) << 20)
+    reckon = fused_bytes if fused else call_bytes
+    need = 2 * reckon(S, qf.shape[2], vf.shape[2], itemsize, bq, bk)
+    semantics = ("parallel", "arbitrary") if fused else None
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=-(-need >> 20) << 20)
+
+
+def fuses(Sq, Sk, D, Dv, itemsize, bq, bk):
+    """Whether a backward call of ``Sq`` queries against ``Sk`` keys is
+    the fused kernel: wherever :func:`fused_bytes` fits
+    ``VMEM_BUDGET``; else dq and dk/dv are two calls."""
+    return fused_bytes(max(Sq, Sk), D, Dv, itemsize, bq, bk) <= \
+        VMEM_BUDGET
 
 
 def _flash_fwd_flat(qf, kf, vf, qoff, koff, causal, kv_len, bq, bk,
@@ -897,29 +953,46 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
              of.astype(jnp.float32)).sum(axis=-1) - \
         dlse.astype(jnp.float32)
     offs = (_off_operand(qoff), _off_operand(koff))
-    rows = (lse.reshape(BH, 1, Sq), delta.reshape(BH, 1, Sq))
     tiles = (lse.reshape(BH, Sq // bq, bq),
              delta.reshape(BH, Sq // bq, bq))
-    params = _flat_params(qf, kf, vf, bq, bk)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          kv_len=kv_len, block_k=bk, kv_seq_len=Sk,
-                          od=od, window=window, diffusion=diffusion),
-        grid=(BH, Sq // bq),
-        in_specs=[_off_spec(), _off_spec(),
-                  _row_spec(bq, D, "blocked"),
-                  _row_spec(Sk, D, "whole"),
-                  _row_spec(Sk, Dv, "whole"),
-                  _row_spec(bq, Dv, "blocked"),
-                  _stat_row_spec(bq),
-                  _stat_row_spec(bq)],
-        out_specs=_row_spec(bq, D, "blocked"),
-        out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
-        compiler_params=params,
-        interpret=interpret,
-        name="flash_dq",
-    )(*offs, qf, kf, vf, dof, *rows)
-    dk, dv = pl.pallas_call(
+    fused = fuses(Sq, Sk, D, Dv,
+                  max(x.dtype.itemsize for x in (qf, kf, vf)), bq, bk)
+    resilience.stats.registry.counter(
+        "attention.flash.bwd." + ("fused" if fused else "split")).inc()
+    params = _flat_params(qf, kf, vf, bq, bk, fused)
+    out_specs = [_row_spec(bk, D, "blocked"), _row_spec(bk, Dv, "blocked")]
+    out_shape = [jax.ShapeDtypeStruct((BH, Sk, D), qf.dtype),
+                 jax.ShapeDtypeStruct((BH, Sk, Dv), qf.dtype)]
+    scratch = []
+    if fused:
+        from jax.experimental.pallas import tpu as pltpu
+        out_specs.append(_row_spec(Sq, D, "whole"))
+        out_shape.append(jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype))
+        scratch.append(pltpu.VMEM((Sq, D), jnp.float32))
+    else:
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, scale=scale, causal=causal,
+                              kv_len=kv_len, block_k=bk, kv_seq_len=Sk,
+                              od=od, window=window, diffusion=diffusion),
+            grid=(BH, Sq // bq),
+            in_specs=[_off_spec(), _off_spec(),
+                      _row_spec(bq, D, "blocked"),
+                      _row_spec(Sk, D, "whole"),
+                      _row_spec(Sk, Dv, "whole"),
+                      _row_spec(bq, Dv, "blocked"),
+                      _stat_row_spec(bq),
+                      _stat_row_spec(bq)],
+            out_specs=_row_spec(bq, D, "blocked"),
+            out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qf.dtype),
+            compiler_params=params,
+            interpret=interpret,
+            name="flash_dq",
+        )(*offs, qf, kf, vf, dof, lse.reshape(BH, 1, Sq),
+          delta.reshape(BH, 1, Sq))
+    # The fused call keeps the dk/dv kernel's NAME: the benchmark's
+    # flash rooflines sum the self time of the events named
+    # flash_fwd / flash_dq / flash_dkv.
+    grads = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           kv_len=kv_len, block_q=bq, q_seq_len=Sq,
                           od=od, window=window, diffusion=diffusion),
@@ -931,14 +1004,17 @@ def _flash_bwd_flat(qf, kf, vf, of, dof, lse, dlse, qoff, koff,
                   _row_spec(Sq, Dv, "whole"),
                   _stat_tile_spec(Sq // bq, bq),
                   _stat_tile_spec(Sq // bq, bq)],
-        out_specs=(_row_spec(bk, D, "blocked"),
-                   _row_spec(bk, Dv, "blocked")),
-        out_shape=(jax.ShapeDtypeStruct((BH, Sk, D), qf.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, Dv), qf.dtype)),
+        out_specs=tuple(out_specs),
+        out_shape=tuple(out_shape),
+        scratch_shapes=scratch,
         compiler_params=params,
         interpret=interpret,
         name="flash_dkv",
     )(*offs, qf, kf, vf, dof, *tiles)
+    if fused:
+        dk, dv, dq = grads
+    else:
+        dk, dv = grads
     return dq, dk, dv
 
 
@@ -1156,7 +1232,7 @@ def flash_chunk(q, k, v, causal=False, q_offset=0, k_offset=0,
     scalars (a ring step's source rank is data-dependent inside
     ``shard_map``); ``window`` is static and bounds the walk and the
     mask from below as ``causal`` does from above.  Differentiable: the backward recomputes
-    probabilities from lse per chunk (dq/dkv kernels), and the lse
+    probabilities from lse per chunk (the fused backward), and the lse
     output's own cotangent folds into the delta row — so autodiff
     through a chunk+merge composition is exact, no custom ring VJP
     needed."""
